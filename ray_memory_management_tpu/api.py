@@ -7,8 +7,11 @@ ActorClass._remote) with the same defaults: tasks take 1 CPU and 4 retries,
 actors take 0 lifetime CPUs and 0 restarts, ``num_returns=1``.
 
 Accelerators: ``num_tpus`` is the first-class resource (the reference's
-``num_gpus`` analog, _private/resource_spec.py:88-101); fractional values
-time-share a chip, integral values get ``TPU_VISIBLE_CHIPS`` isolation.
+``num_gpus`` analog, _private/resource_spec.py:88-101). A request for whole
+chips is served by a worker of its own that sees exactly those chips
+(``TPU_VISIBLE_CHIPS``) and exits when the lease ends; a fractional request
+is resource arithmetic only and sees no chip, since a chip belongs to one
+process at a time.
 """
 
 from __future__ import annotations
@@ -470,6 +473,9 @@ def init(
             if ignore_reinit_error:
                 return _worker_context.get_runtime()
             raise RmtError("already initialized (use shutdown() first)")
+        from .utils import compile_cache
+
+        compile_cache.adopt()
         cfg = _config or Config()
         if object_store_memory:
             cfg.object_store_memory = object_store_memory
@@ -490,31 +496,23 @@ def init(
 
 def _detect_tpu_chips() -> int:
     """TPU autodetection analog of GPU autodetect (_private/resource_spec.py:273):
-    honor TPU_VISIBLE_CHIPS, else count devices of an ALREADY-INITIALIZED
-    accelerator backend. Never import jax or trigger backend creation here —
-    that would claim the chips (and can block on a busy TPU) just because the
-    scheduler asked how many exist."""
+    honor TPU_VISIBLE_CHIPS, else count the chip device nodes this host
+    exposes: ``/dev/accel<N>`` (through v4) or the numbered VFIO groups
+    ``/dev/vfio/<N>`` (v5e and later), which is where libtpu itself looks.
+    Listing a directory opens no device. The PCI bus is NOT the place to
+    count: a machine handed one chip of a four-chip host shows four chips
+    there and one node here (v5e, PR 21). Never import jax or trigger
+    backend creation here — that would claim the chips for the driver just
+    because the scheduler asked how many exist, and the worker that later
+    leases one could not open it."""
     env = os.environ.get("TPU_VISIBLE_CHIPS")
     if env:
         return len([c for c in env.split(",") if c != ""])
-    import sys
+    import glob
 
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return 0
-    try:
-        from jax._src import xla_bridge
-
-        initialized = getattr(xla_bridge, "_backends", {})
-        count = 0
-        for platform, backend in initialized.items():
-            if platform != "cpu":
-                # local count only: on a multi-host slice device_count() is
-                # the global chip count, which would oversubscribe this node
-                count += backend.local_device_count()
-        return count
-    except Exception:
-        return 0
+    return len(glob.glob("/dev/accel[0-9]*")) + len(
+        [p for p in glob.glob("/dev/vfio/*")
+         if os.path.basename(p).isdigit()])
 
 
 def shutdown() -> None:

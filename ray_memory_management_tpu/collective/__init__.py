@@ -18,7 +18,7 @@ from .collective import (  # noqa: F401
     reducescatter,
     send,
 )
-from .mesh_group import HAS_SHARD_MAP, MeshCollectives  # noqa: F401
+from .mesh_group import MeshCollectives  # noqa: F401
 from .types import (  # noqa: F401
     PRECISIONS, Backend, ReduceOp, resolve_precision,
 )

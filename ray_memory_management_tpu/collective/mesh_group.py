@@ -28,11 +28,6 @@ from .types import ReduceOp
 _AXIS = "ranks"
 
 
-from ..utils.jax_compat import (  # noqa: F401 — HAS_SHARD_MAP re-exported
-    HAS_SHARD_MAP,
-    shard_map as _shard_map_compat,
-)
-
 _INT8_BLOCK = 256  # must match core/codec.py's block-wise scale grain
 
 
@@ -122,17 +117,12 @@ class MeshCollectives:
         return jax.device_put(stacked, self._sharding)
 
     def _smap(self, fn, out_spec=P(_AXIS)):
-        # check disabled (check_vma on new jax, check_rep on old):
-        # collective bodies intentionally produce values whose replication
-        # XLA cannot infer statically (e.g. all_gather then replicated
-        # output)
-        if not HAS_SHARD_MAP:
-            raise RuntimeError(
-                "this jax installation provides no shard_map "
-                "(neither jax.shard_map nor jax.experimental.shard_map); "
-                "xla-backend collectives are unavailable")
-        return _shard_map_compat(
-            fn, mesh=self.mesh, in_specs=P(_AXIS), out_specs=out_spec)
+        # check_vma off: collective bodies intentionally produce values
+        # whose replication XLA cannot infer statically (e.g. all_gather
+        # then replicated output)
+        return jax.shard_map(
+            fn, mesh=self.mesh, in_specs=P(_AXIS), out_specs=out_spec,
+            check_vma=False)
 
     def _precision(self, precision):
         from .types import resolve_precision

@@ -55,27 +55,34 @@ def is_device_array(value: Any) -> bool:
     return _is_jax_array(value)
 
 
-def resolve_capacity(config) -> int:
-    """Device-tier budget in bytes for this process. Explicit flag wins;
-    0 = auto from the backend's device memory stats (60% of the first
-    local device's reported limit — the rest belongs to the program's
-    own compute), falling back to 1 GiB when the backend reports
-    nothing (CPU-backed jax arrays in tier-1). Negative disables
-    eviction (unbounded pinning)."""
-    cap = int(getattr(config, "device_store_capacity_bytes", 0) or 0)
-    if cap:
-        return cap
-    try:
-        import jax
+# budget for arrays on a device that reports no memory limit: the CPU
+# backend (jax arrays in host RAM), whose memory_stats() is None
+_HOST_BACKED_BUDGET = 1 << 30
 
-        stats = jax.local_devices()[0].memory_stats() or {}
-        limit = int(stats.get("bytes_limit")
-                    or stats.get("bytes_reservable_limit") or 0)
-        if limit > 0:
-            return int(limit * 0.6)
-    except Exception:  # noqa: BLE001 — stats are a hint, not a contract
-        pass
-    return 1 << 30
+
+def configured_capacity(config) -> Optional[int]:
+    """The explicit ``device_store_capacity_bytes`` flag, or None when it
+    is 0 (auto): the budget is then taken from the device on the first
+    put, in the process that holds the device. Reading it earlier would
+    create a jax backend, and with it take the chip, in every process
+    that merely builds a store. Negative disables eviction."""
+    return int(getattr(config, "device_store_capacity_bytes", 0) or 0) or None
+
+
+def device_budget(array: Any) -> int:
+    """Auto budget from the device holding ``array``: 60% of the memory
+    limit it reports (the rest belongs to the program's own compute)."""
+    device = next(iter(array.devices()))
+    stats = device.memory_stats()
+    if stats is None:
+        return _HOST_BACKED_BUDGET
+    limit = int(stats.get("bytes_limit")
+                or stats.get("bytes_reservable_limit") or 0)
+    if limit <= 0:
+        raise RuntimeError(
+            f"{device} reports memory stats without a byte limit "
+            f"({sorted(stats)}); set device_store_capacity_bytes")
+    return int(limit * 0.6)
 
 
 class _Entry:
@@ -104,14 +111,16 @@ class DeviceObjectStore:
     eviction is deferred, never lossy.
     """
 
-    def __init__(self, capacity_bytes: int = -1,
+    def __init__(self, capacity_bytes: Optional[int] = -1,
                  on_demote: Optional[Callable[[bytes, Any], bool]] = None):
         self._lock = threading.Lock()
         # MRU at the end; OrderedDict gives O(1) LRU via move_to_end
         self._objects: "OrderedDict[bytes, _Entry]" = OrderedDict()  # guarded-by: _lock
         self._total = 0  # guarded-by: _lock
         self._bytes_avoided = 0  # guarded-by: _lock
-        self.capacity_bytes = int(capacity_bytes)
+        # None = auto, resolved by the first put (device_budget)
+        self.capacity_bytes = (None if capacity_bytes is None
+                               else int(capacity_bytes))
         self._on_demote = on_demote
         self._victim_rank: Optional[Callable[[bytes], int]] = None
 
@@ -137,6 +146,8 @@ class DeviceObjectStore:
         when under budget, eviction is disabled, or nothing was
         evictable)."""
         n = _entry_nbytes(array)
+        if self.capacity_bytes is None:
+            self.capacity_bytes = device_budget(array)
         with self._lock:
             prev = self._objects.pop(object_id, None)
             if prev is not None:

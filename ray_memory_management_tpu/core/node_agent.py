@@ -79,6 +79,7 @@ class NodeAgent:
 
         self.channel = Client((head_host, head_port), authkey=authkey)
         self._cluster_authkey = authkey
+        self.num_tpus = num_tpus  # this host's chips (chip_lease_env)
         self._channel_lock = threading.Lock()
         # this host's reachable IP on the route to the head, and the head's
         # IP as we see it — peers dial us at the former; obj_fetch frames
@@ -168,7 +169,10 @@ class NodeAgent:
         # permission-trusted worker socket, like the head's (0600 file;
         # no HMAC challenge — two round trips saved per worker connect)
         self._socket_path = f"/tmp/rmtA_{os.getpid()}_{os.urandom(4).hex()}.sock"
-        self._listener = Listener(self._socket_path, family="AF_UNIX")
+        from .node_manager import WORKER_LISTEN_BACKLOG
+
+        self._listener = Listener(self._socket_path, family="AF_UNIX",
+                                  backlog=WORKER_LISTEN_BACKLOG)
         os.chmod(self._socket_path, 0o600)
         self._workers: Dict[bytes, Any] = {}        # wid -> conn  # guarded-by: _lock
         self._worker_procs: Dict[bytes, Any] = {}   # wid -> Popen  # guarded-by: _lock
@@ -333,6 +337,15 @@ class NodeAgent:
                 self._send({"type": "lease_dead", "task_id": tid})
             except (OSError, BrokenPipeError):
                 break
+        with self._lock:
+            proc = self._worker_procs.get(wid)
+        if proc is not None:
+            # the head hands a dead worker's chips to the next lease the
+            # moment it hears of the death: report it once the process,
+            # not just its pipe, is gone
+            from .node_manager import await_exit
+
+            await_exit(proc)
         try:
             self._send({"type": "wdeath", "wid": wid})
         except (OSError, BrokenPipeError):
@@ -347,9 +360,11 @@ class NodeAgent:
             # actor / conda workers never take leased leaf tasks
             with self._lock:
                 self._lease_dedicated.add(wid)
+        chips = msg.get("chips")
         env = build_worker_env(wid_hex, self.node_id.hex(), self.store_name,
                                self._socket_path, "",
-                               self.config)
+                               self.config, chips=chips,
+                               host_chips=self.num_tpus)
         env.update(msg.get("env") or {})
         bootstrap = msg.get("bootstrap")
         conda_spec = msg.get("conda")
@@ -364,7 +379,8 @@ class NodeAgent:
         def spawn(python_exe=None):
             proc = spawn_worker_process(env, self.config, bootstrap,
                                         queue_bootstrap,
-                                        python_exe=python_exe)
+                                        python_exe=python_exe,
+                                        cold=bool(chips))
             with self._lock:
                 self._worker_procs[wid] = proc
 

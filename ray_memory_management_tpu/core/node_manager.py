@@ -6,9 +6,13 @@ single-host TPU node needs:
     idle workers, dedicated (non-returning) workers for actors;
   - LocalTaskManager dispatch (local_task_manager.cc:99,256): leased tasks
     queue here until an idle worker and node resources are available;
-  - TPU chip assignment: the node tracks free chip indices and passes a
-    ``TPU_VISIBLE_CHIPS`` value with each lease — the accelerator-isolation
-    analog of CUDA_VISIBLE_DEVICES assignment (_private/utils.py:349-362).
+  - TPU chip assignment: the node tracks free chip indices, and a lease of
+    whole chips is served by a worker cold-spawned FOR that lease with
+    ``TPU_VISIBLE_CHIPS`` in its environment from interpreter start (the
+    accelerator-isolation analog of CUDA_VISIBLE_DEVICES assignment,
+    _private/utils.py:349-362). A chip belongs to one process at a time,
+    so that worker is never pooled: it exits when the lease ends, and only
+    then do its chip ids return to ``free_chips``.
 
 Runs inside the driver process; worker processes are real OS processes
 spawned via multiprocessing (spawn context, so children never inherit the
@@ -23,13 +27,18 @@ import sys
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional, Set
+from typing import Callable, Dict, List, Optional, Sequence, Set
 
 from ..config import Config
 from ..ids import NodeID, WorkerID
 from .object_store import NodeObjectStore
 from .resources import CPU, NodeResources, Resources, TPU
 from .task_spec import TaskSpec
+
+# listen backlog of the socket workers dial back into (the runtime's, and
+# a node agent's): workers started together all connect within the time the
+# accept loop spends on one of them
+WORKER_LISTEN_BACKLOG = 128
 
 # shared zero request for placement-group tasks (their resources were
 # already deducted at bundle reservation); Resources is immutable-by-
@@ -43,7 +52,7 @@ class WorkerHandle:
                  "lease_resources", "visible_chips", "pending_msgs",
                  "death_processed", "send_lock", "steal_pending",
                  "re_inflight", "conda_key", "spawned_at",
-                 "_alive_checked_at", "device_mesh")
+                 "_alive_checked_at", "device_mesh", "chip_lease")
 
     def __init__(self, worker_id: WorkerID, proc, node_id: NodeID):
         self.worker_id = worker_id
@@ -69,6 +78,10 @@ class WorkerHandle:
         self.re_inflight = 0  # inflight tasks carrying a runtime_env
         self.lease_resources: Optional[Resources] = None
         self.visible_chips: Optional[List[int]] = None
+        # spawned to serve ONE chip-leased task: never pooled, retired
+        # when that lease ends (actors' dedicated workers die with the
+        # actor anyway and do not need the mark)
+        self.chip_lease = False
         self.pending_msgs: List[dict] = []  # queued until registration
         self.spawned_at = 0.0  # set at spawn; boot latency at ready
         self._alive_checked_at = 0.0
@@ -122,19 +135,62 @@ def package_env() -> Dict[str, str]:
     parts = [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p]
     if pkg_parent not in parts:
         env["PYTHONPATH"] = os.pathsep.join([pkg_parent] + parts)
+    from ..utils import compile_cache
+
+    compile_cache.export(env)
     return env
 
 
+# shape of a sub-host slice by chip count, for TPU_CHIPS_PER_PROCESS_BOUNDS
+_SLICE_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}
+
+
+def chip_lease_env(chips: Sequence[int], host_chips: int) -> Dict[str, str]:
+    """Environment that scopes a process to its leased chips: the ids,
+    ascending, in ``TPU_VISIBLE_CHIPS``. A lease of only part of the host
+    also describes its slice as a one-process topology of its own
+    (``TPU_CHIPS_PER_PROCESS_BOUNDS``, ``TPU_PROCESS_BOUNDS=1,1,1``).
+    Nothing else is needed on libtpu 0.0.34: several such processes share
+    a v5e host without a controller port each (README, "Running on the
+    CPU and on the chip", has what was tried)."""
+    ids = sorted(chips)
+    env = {"TPU_VISIBLE_CHIPS": ",".join(str(c) for c in ids)}
+    if len(ids) < host_chips:
+        bounds = _SLICE_BOUNDS.get(len(ids))
+        if bounds is None:
+            raise ValueError(
+                f"a lease of {len(ids)} of {host_chips} chips has no slice "
+                f"shape; lease one of {sorted(_SLICE_BOUNDS)} chips")
+        env.update({"TPU_CHIPS_PER_PROCESS_BOUNDS": bounds,
+                    "TPU_PROCESS_BOUNDS": "1,1,1"})
+    return env
+
+
+def await_exit(proc, grace_s: float = 5.0) -> None:
+    """Block until a worker process that has been told to stop (or whose
+    pipe closed) is gone, killing it if it outstays ``grace_s``. A chip
+    is free for the next process only once its holder has exited."""
+    try:
+        proc.wait(timeout=grace_s)
+    except subprocess.TimeoutExpired:
+        proc.kill()
+        proc.wait(timeout=grace_s)
+
+
 def build_worker_env(worker_id_hex: str, node_id_hex: str, store_name: str,
-                     socket_path: str, authkey_hex: str,
-                     config: Config) -> Dict[str, str]:
+                     socket_path: str, authkey_hex: str, config: Config,
+                     chips: Optional[Sequence[int]] = None,
+                     host_chips: int = 0) -> Dict[str, str]:
     """Environment for a spawned worker process — shared by the local
     worker pool and the remote node agent so the two can never diverge.
 
-    Workers default to CPU jax — they never see the driver's TPU (the
-    driver's JAX_PLATFORMS is deliberately NOT inherited). Set
-    RMT_WORKER_JAX_PLATFORMS=tpu on the driver to spawn TPU-capable
-    workers for tasks/actors leased chips."""
+    Which worker can see a chip is decided per lease, here. A worker
+    spawned for a lease of ``chips`` gets ``chip_lease_env`` and inherits
+    ``JAX_PLATFORMS`` from this process's environment as it stands (unset
+    where jax should find the TPU; ``cpu`` where the operator has hidden
+    the chip from the whole cluster). Every other worker is pinned to
+    ``JAX_PLATFORMS=cpu``, so no controller, router or helper can take
+    the chip from under the process that leased it."""
     env = package_env()
     env.update({
         "RMT_WORKER_ID": worker_id_hex,
@@ -148,29 +204,25 @@ def build_worker_env(worker_id_hex: str, node_id_hex: str, store_name: str,
         # flush window); explicit so local pool and agent spawn agree
         "RMT_REPLY_FLUSH_WINDOW_S": str(config.reply_flush_window_s),
         "RMT_REPLY_FLUSH_MAX": str(config.reply_flush_max),
-        "JAX_PLATFORMS": env.get("RMT_WORKER_JAX_PLATFORMS", "cpu"),
     })
-    if env["JAX_PLATFORMS"] == "cpu":
-        # CPU workers skip the TPU plugin bootstrap some images run from
-        # sitecustomize at interpreter start (it imports jax + registers a
-        # PJRT backend, ~2s); dropping the trigger env vars cuts worker
-        # spawn from ~2s to ~0.2s. TPU-platform workers keep them.
-        for var in config.cpu_worker_env_drop.split(","):
-            if var:
-                env.pop(var.strip(), None)
+    if chips:
+        env.update(chip_lease_env(chips, host_chips))
+    else:
+        env["JAX_PLATFORMS"] = "cpu"
     return env
 
 
 def spawn_worker_process(env: Dict[str, str], config: Config,
                          bootstrap: Optional[dict] = None,
                          on_cold_bootstrap=None,
-                         python_exe: Optional[str] = None):
-    """Start one worker process: forked from the warm zygote when the
-    worker is CPU-platform (ms instead of a cold interpreter), else — and
+                         python_exe: Optional[str] = None,
+                         cold: bool = False):
+    """Start one worker process: forked from the warm zygote (ms instead
+    of a cold interpreter), else — with ``cold``, under a conda python, or
     whenever the zygote is unavailable — a fresh ``subprocess.Popen``.
-    TPU-platform workers always cold-spawn: the PJRT plugin must register
-    at interpreter startup, which a fork of the (deliberately
-    TPU-ignorant) zygote cannot provide.
+    Chip-leased workers always cold-spawn: their environment has to be
+    the lease's from interpreter start, and the zygote is pinned to the
+    CPU platform (a preloaded class may have imported jax under it).
 
     ``bootstrap`` is a message the worker should process immediately at
     startup (the dedicated-worker startup token, worker_pool.h:446). The
@@ -178,8 +230,7 @@ def spawn_worker_process(env: Dict[str, str], config: Config,
     ``on_cold_bootstrap`` is invoked BEFORE the process is created — the
     caller queues the message for delivery at registration, race-free
     because the worker cannot register before it exists."""
-    if python_exe is None and config.worker_fork_server \
-            and env.get("JAX_PLATFORMS") == "cpu":
+    if python_exe is None and not cold and config.worker_fork_server:
         from . import zygote
 
         z = zygote.get_global()
@@ -331,7 +382,8 @@ class NodeManager:
     def start_worker(self, dedicated: bool = False,
                      bootstrap: Optional[dict] = None,
                      on_handle=None,
-                     conda_spec=None) -> WorkerHandle:
+                     conda_spec=None,
+                     chips: Optional[List[int]] = None) -> WorkerHandle:
         """Spawn one worker process (WorkerPool::StartWorkerProcess analog,
         worker_pool.h:427): a worker that dials back into the runtime's
         Unix socket — the same exec-then-connect handshake the raylet uses
@@ -341,7 +393,9 @@ class NodeManager:
         is queued for delivery at registration. ``conda_spec`` makes the
         worker a dedicated conda-env process (cold spawn under the env's
         python; resolution/creation may block the caller — actor creation
-        tolerates this the way it tolerates pip installs).
+        tolerates this the way it tolerates pip installs). ``chips`` makes
+        it the worker of a chip lease: cold-spawned with those chips (and
+        no others) visible from interpreter start.
 
         The handle is registered — and ``on_handle`` (caller bookkeeping
         that must be visible before any reply from the worker) runs —
@@ -355,12 +409,16 @@ class NodeManager:
         worker_id = WorkerID.from_random()
         env = build_worker_env(worker_id.hex(), self.node_id.hex(),
                                self.store_name, self.socket_path,
-                               self.authkey_hex, self.config)
+                               self.authkey_hex, self.config, chips=chips,
+                               host_chips=int(self.resources.total.get(TPU)))
         handle = WorkerHandle(worker_id, _PendingProc(), self.node_id)
         if dedicated:
             # claimed for an actor before registration: never enters the
             # idle pool (dedicated workers, worker_pool.h:446)
             handle.actor_id = b"__pending__"
+        elif chips:
+            handle.chip_lease = True
+        handle.visible_chips = chips
         with self._lock:
             self.workers[worker_id] = handle
             if not dedicated:
@@ -380,7 +438,8 @@ class NodeManager:
         handle.spawned_at = time.monotonic()
         handle.proc = spawn_worker_process(env, self.config, bootstrap,
                                            queue_bootstrap,
-                                           python_exe=python_exe)
+                                           python_exe=python_exe,
+                                           cold=bool(chips))
         if not self.alive:
             # remove_node ran while we were spawning: its terminate loop
             # saw only the _PendingProc placeholder, so the real process
@@ -407,7 +466,7 @@ class NodeManager:
             self.starting = max(0, self.starting - 1)
             if handle.conda_key is not None:
                 self._conda_starting.discard(handle.conda_key)
-            if handle.actor_id is None:
+            if handle.actor_id is None and not handle.chip_lease:
                 handle.idle = True
                 if handle.conda_key is not None:
                     self.conda_idle.setdefault(
@@ -416,6 +475,10 @@ class NodeManager:
                     self.idle_workers.append(handle)
 
     def remove_worker(self, handle: WorkerHandle) -> None:
+        if handle.visible_chips:
+            # the chips go back only once their holder is gone: a closed
+            # pipe (how death is seen) precedes the end of the process
+            await_exit(handle.proc)
         with self._lock:
             self.workers.pop(handle.worker_id, None)
             self.busy_pool.discard(handle)
@@ -631,14 +694,29 @@ class NodeManager:
                             self.start_conda_worker(conda_spec, ckey)
                         break  # head-of-line: wait for the env worker
                 elif req.fits_in(self.resources.available):
-                    while self.idle_workers:
-                        cand = self.idle_workers.popleft()
-                        if cand.alive() and cand.ready:
-                            handle = cand
+                    # (a PG task's req is empty: its bundle paid, but the
+                    # chip ids are still handed out here)
+                    n_chips = int(spec.req.get(TPU))
+                    if n_chips > 0:
+                        # a chip lease gets a worker of its own, spawned
+                        # with the chips visible; the task waits in its
+                        # pending_msgs until it registers
+                        chips = None
+                        if len(self.workers) < \
+                                self.config.max_workers_per_node:
+                            chips = self.take_chips(n_chips)
+                        if chips is not None:
+                            handle = self.start_worker(chips=chips)
                             lease = True
-                            break
-                    if handle is None:
-                        self._start_workers_for_backlog(req)
+                    else:
+                        while self.idle_workers:
+                            cand = self.idle_workers.popleft()
+                            if cand.alive() and cand.ready:
+                                handle = cand
+                                lease = True
+                                break
+                        if handle is None:
+                            self._start_workers_for_backlog(req)
                 if handle is None:
                     handle = self._pick_pipeline_worker(spec, req)
                     if handle is None:
@@ -651,12 +729,7 @@ class NodeManager:
                 if lease:
                     self.resources.allocate(req)
                     handle.lease_resources = req
-                    n_chips = int(req.get(TPU))
-                    if n_chips > 0:
-                        handle.visible_chips = [
-                            self.free_chips.pop() for _ in range(n_chips)
-                        ]
-                    if handle.actor_id is None:
+                    if handle.actor_id is None and not handle.chip_lease:
                         self.busy_pool.add(handle)
                 to_send.append((handle, spec))
         # sends happen outside the node lock: a slow pipe write must not
@@ -714,9 +787,6 @@ class NodeManager:
             if not handle.inflight and handle.lease_resources is not None:
                 self.resources.free(handle.lease_resources)
                 handle.lease_resources = None
-                if handle.visible_chips:
-                    self.free_chips.extend(handle.visible_chips)
-                    handle.visible_chips = None
                 self.busy_pool.discard(handle)
                 if handle.actor_id is None and handle.alive():
                     handle.idle = True
@@ -777,9 +847,13 @@ class NodeManager:
                 best_depth = len(cand.inflight)
         return best
 
-    def finish_task(self, handle: WorkerHandle, task_id: bytes) -> None:
+    def finish_task(self, handle: WorkerHandle, task_id: bytes) -> bool:
         """Release the task; free the lease and return the worker to the
-        pool once its pipeline drains."""
+        pool once its pipeline drains. True means the worker served a chip
+        lease and the caller must now retire it: its resources and chip
+        ids stay with it until ``remove_worker`` has seen the process go,
+        because the next lease may land on another process, which cannot
+        open a chip this one still holds."""
         with self._lock:
             spec = handle.inflight.pop(task_id, None)
             if spec is not None and spec.runtime_env:
@@ -789,26 +863,30 @@ class NodeManager:
                 self.leaf_local.discard(task_id)
                 self.leaf_credits += 1
             if handle.inflight:
-                return  # pipelined tasks still riding this lease
+                return False  # pipelined tasks still riding this lease
+            if handle.actor_id is not None:
+                # an actor's lease lasts as long as the actor: a finished
+                # method call returns nothing (remove_worker does, at death)
+                return False
+            if handle.chip_lease:
+                return True
             if handle.lease_resources is not None:
                 self.resources.free(handle.lease_resources)
                 handle.lease_resources = None
-            if handle.visible_chips:
-                self.free_chips.extend(handle.visible_chips)
-                handle.visible_chips = None
             self.busy_pool.discard(handle)
-            if handle.actor_id is None and handle.alive():
+            if handle.alive():
                 handle.idle = True
                 if handle.conda_key is not None:
                     # back to its env's warm dedicated pool
                     self.conda_idle.setdefault(
                         handle.conda_key, deque()).appendleft(handle)
-                    return
+                    return False
                 # LIFO: reuse the hottest worker — on small tasks this keeps
                 # one process warm (caches, branch state) and lets dispatch
                 # batches coalesce on its pipe instead of round-robining
                 # wakeups across the whole pool
                 self.idle_workers.appendleft(handle)
+            return False
 
     def dedicate_to_actor(self, handle: WorkerHandle, actor_id: bytes,
                           req: Resources, chips: Optional[List[int]]) -> None:
@@ -827,10 +905,18 @@ class NodeManager:
             handle.visible_chips = chips
 
     def take_chips(self, n: int) -> Optional[List[int]]:
+        """``n`` free chip ids, ascending, or None. Always an aligned run
+        of ids (0-1 or 2-3, never 1-2 or 1,3): libtpu takes the ids of a
+        sub-host lease as a slice of the host's topology."""
         with self._lock:
-            if len(self.free_chips) < n:
-                return None
-            return [self.free_chips.pop() for _ in range(n)]
+            free = set(self.free_chips)
+            total = int(self.resources.total.get(TPU))
+            for start in range(0, total - n + 1, n):
+                block = list(range(start, start + n))
+                if free.issuperset(block):
+                    self.free_chips = sorted(free.difference(block))
+                    return block
+            return None
 
     def shutdown(self, unlink_store: bool = True) -> None:
         with self._lock:

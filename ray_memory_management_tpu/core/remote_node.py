@@ -61,6 +61,11 @@ class RemoteProc:
     def poll(self):
         return self.returncode
 
+    def wait(self, timeout=None):
+        # nothing to wait for here: the agent reports a death only after
+        # it has seen the process go (node_agent._worker_reader)
+        return self.returncode
+
     def terminate(self) -> None:
         self._node.channel_send({"type": "kill_worker", "wid": self._wid})
 
@@ -574,7 +579,8 @@ class RemoteNodeManager(NodeManager):
     def start_worker(self, dedicated: bool = False,
                      bootstrap: Optional[dict] = None,
                      on_handle=None,
-                     conda_spec=None) -> WorkerHandle:
+                     conda_spec=None,
+                     chips: Optional[List[int]] = None) -> WorkerHandle:
         # mirror NodeManager: register the handle and run the caller's
         # bookkeeping BEFORE the spawn frame leaves — a bootstrapped fork
         # on the agent can answer before this function returns
@@ -583,6 +589,9 @@ class RemoteNodeManager(NodeManager):
                               self.node_id)
         if dedicated:
             handle.actor_id = b"__pending__"
+        elif chips:
+            handle.chip_lease = True
+        handle.visible_chips = chips
         with self._lock:
             self.workers[worker_id] = handle
             if not dedicated:
@@ -604,6 +613,9 @@ class RemoteNodeManager(NodeManager):
             # conda envs are HOST-local: the agent resolves/creates the
             # env on its own machine and spawns under its python
             msg["conda"] = conda_spec
+        if chips:
+            # the agent builds the lease's environment on its own host
+            msg["chips"] = chips
         # BEFORE the frame leaves: a bootstrapped fork on the agent can
         # register before channel_send returns, and on_worker_ready skips
         # the boot sample when spawned_at is still 0

@@ -50,7 +50,8 @@ from .gcs import (
 )
 from . import codec as wire_codec
 from . import metrics_defs as mdefs
-from .node_manager import NodeManager, WorkerHandle
+from .node_manager import (WORKER_LISTEN_BACKLOG, NodeManager,
+                           WorkerHandle)
 from .object_ref import ObjectRef
 from .object_store import StoreClient
 from .resources import CPU, NodeResources, Resources, TPU, task_resources
@@ -347,7 +348,7 @@ class _TaskRecord:
 
 class _ActorInfo:
     __slots__ = ("spec", "record", "node_id", "handle", "seq", "pending",
-                 "creation_future", "handle_count")
+                 "creation_future", "handle_count", "drained")
 
     def __init__(self, spec: ActorCreationSpec, record: ActorRecord):
         self.spec = spec
@@ -356,6 +357,10 @@ class _ActorInfo:
         self.handle: Optional[WorkerHandle] = None
         self.seq = itertools.count()
         self.pending: deque = deque()  # TaskSpecs waiting for ALIVE
+        # True once an ALIVE actor's pending queue has been sent: only
+        # then may a submit dispatch directly, or it would overtake tasks
+        # submitted before it that still wait in ``pending``
+        self.drained = False
         self.creation_future: Future = Future()
         self.handle_count = 0
 
@@ -405,15 +410,13 @@ class Runtime:
 
         # owner state
         self.memory_store: Dict[bytes, bytes] = {}  # small objects (serialized)
-        from .device_store import DeviceObjectStore
-
-        from .device_store import resolve_capacity
+        from .device_store import DeviceObjectStore, configured_capacity
 
         # driver-pinned jax.Arrays: a budgeted HBM tier that LRU-demotes
         # unpinned entries into the head node's shm store (which spills
         # below itself), bf16-downcasting f32 payloads when configured
         self.device_store = DeviceObjectStore(
-            capacity_bytes=resolve_capacity(config),
+            capacity_bytes=configured_capacity(config),
             on_demote=self._demote_device_object)
         # job-aware demotion order: under HBM pressure a low-priority
         # tenant's cold pins demote before a high-priority tenant's
@@ -637,7 +640,13 @@ class Runtime:
         self._socket_path = f"/tmp/{self.namespace}.sock"
         from multiprocessing.connection import Listener
 
-        self._listener = Listener(self._socket_path, family="AF_UNIX")
+        # the accept loop takes one worker at a time and waits for its
+        # "ready"; with the default backlog of 1 a third worker dialing in
+        # meanwhile is refused, gives up after its retries and exits
+        # quietly (four chip-leased actors cold-spawned together on a
+        # 30-core v5e host lost one every time, PR 21)
+        self._listener = Listener(self._socket_path, family="AF_UNIX",
+                                  backlog=WORKER_LISTEN_BACKLOG)
         os.chmod(self._socket_path, 0o600)
         self._workers_by_id: Dict[bytes, WorkerHandle] = {}
         self._accept_thread = threading.Thread(
@@ -2305,10 +2314,6 @@ class Runtime:
             if spec.fn_id not in handle.known_fns:
                 msg["fn_blob"] = self.fn_blobs[spec.fn_id]
                 handle.known_fns.add(spec.fn_id)
-            if handle.visible_chips is not None:
-                msg["visible_chips"] = ",".join(
-                    str(c) for c in handle.visible_chips
-                )
         if spec.trace_ctx:
             # the dispatch frame carries the task's trace context so the
             # worker's exec span (and any nested submit inside the task
@@ -2368,8 +2373,10 @@ class Runtime:
             task_id = m["task_id"]
             spec = handle.inflight.get(task_id)
             if spec is not None:
-                if nm:
-                    nm.finish_task(handle, task_id)
+                if nm and nm.finish_task(handle, task_id):
+                    # chip lease over: the worker exits, and its death
+                    # (remove_worker) is what returns the chips
+                    self._sender_enqueue(handle, {"type": "shutdown"})
             elif nm:
                 # agent-leased leaf task: the head's worker handle never
                 # saw the dispatch, so finish_task would re-idle an
@@ -2602,9 +2609,7 @@ class Runtime:
                     f"no resources to place actor {spec.name}"
                 )
         except Exception as e:
-            self.gcs.set_actor_state(info.record.actor_id, ACTOR_DEAD, str(e))
-            info.creation_future.set_exception(ActorDiedError(str(e)))
-            self._fail_actor_queue(info, ActorDiedError(str(e)))
+            self._fail_actor_creation(info, str(e))
             return
         nm = self.nodes[node_id]
         info.node_id = node_id
@@ -2612,6 +2617,11 @@ class Runtime:
         n_chips = int(req.get(TPU))
         if n_chips:
             chips = nm.take_chips(n_chips)
+            if chips is None:
+                self._fail_actor_creation(
+                    info, f"node {node_id.hex()[:12]} granted {n_chips} TPU "
+                    f"to actor {spec.name} but has no free run of chip ids")
+                return
         # PG actors: the bundle reservation already deducted node resources
         lease = Resources({}) if spec.placement is not None else req
         msg = {
@@ -2626,8 +2636,6 @@ class Runtime:
         }
         if spec.runtime_env:
             msg["runtime_env"] = spec.runtime_env
-        if chips is not None:
-            msg["visible_chips"] = ",".join(str(c) for c in chips)
 
         def on_handle(h):
             # runs BEFORE the spawn: a bootstrapped fork can reply
@@ -2646,17 +2654,21 @@ class Runtime:
         # actor-creation critical path. Conda actors cold-spawn under the
         # env's python (dedicated runtime-env worker); local resolution
         # may block this (request-pool) thread like a pip install would.
+        # An actor that leased chips cold-spawns with them visible.
         conda_spec = (spec.runtime_env or {}).get("conda") \
             if spec.runtime_env else None
         try:
             nm.start_worker(dedicated=True, bootstrap=msg,
-                            on_handle=on_handle, conda_spec=conda_spec)
+                            on_handle=on_handle, conda_spec=conda_spec,
+                            chips=chips)
         except Exception as e:  # noqa: BLE001 — conda env unavailable
-            self.gcs.set_actor_state(info.record.actor_id, ACTOR_DEAD,
-                                     str(e))
-            if not info.creation_future.done():
-                info.creation_future.set_exception(ActorDiedError(str(e)))
-            self._fail_actor_queue(info, ActorDiedError(str(e)))
+            self._fail_actor_creation(info, str(e))
+
+    def _fail_actor_creation(self, info: _ActorInfo, cause: str) -> None:
+        self.gcs.set_actor_state(info.record.actor_id, ACTOR_DEAD, cause)
+        if not info.creation_future.done():
+            info.creation_future.set_exception(ActorDiedError(cause))
+        self._fail_actor_queue(info, ActorDiedError(cause))
 
     def _on_actor_created(self, handle: WorkerHandle, msg: dict) -> None:
         actor_id = msg["actor_id"]
@@ -2676,12 +2688,23 @@ class Runtime:
         self.gcs.set_actor_state(info.record.actor_id, ACTOR_ALIVE)
         if not info.creation_future.done():
             info.creation_future.set_result(True)
-        flush = []
-        with self._lock:
-            while info.pending:
-                flush.append(info.pending.popleft())
-        for spec in flush:
-            self._dispatch_actor_task(info, spec)
+        # send what queued up while the actor was starting, batch by batch
+        # until a look under the lock finds nothing more: only then may
+        # submits go direct (``drained``). A dispatch to a worker that has
+        # died meanwhile puts its spec back in ``pending``; those belong to
+        # the death handler (restart or fail), not to this loop
+        while True:
+            with self._lock:
+                if not info.pending:
+                    info.drained = True
+                    return
+                batch = list(info.pending)
+                info.pending.clear()
+            for spec in batch:
+                self._dispatch_actor_task(info, spec)
+            with self._lock:
+                if info.pending and info.pending[0] is batch[0]:
+                    return
 
     def submit_actor_task(self, payload: dict,
                           adopt_returns: bool = True) -> List[bytes]:
@@ -2735,15 +2758,21 @@ class Runtime:
             for oid in self._ref_deps(spec):
                 self._incref(oid)
                 self._lineage_dependents[oid] += 1
-        state = info.record.state
+        # decided under the lock the death and creation handlers drain
+        # ``pending`` under, so a task is never queued behind their back.
+        # Alive and drained: straight to the worker. Pending, restarting,
+        # or alive with earlier submits still queued (_on_actor_created is
+        # sending them): queue in seq order
+        with self._lock:
+            state = info.record.state
+            direct = state == ACTOR_ALIVE and info.drained
+            if state != ACTOR_DEAD and not direct:
+                info.pending.append(spec)
         if state == ACTOR_DEAD:
             self._fail_task(spec, ActorDiedError(
                 info.record.death_cause or "actor is dead"))
-        elif state == ACTOR_ALIVE:
+        elif direct:
             self._dispatch_actor_task(info, spec)
-        else:  # pending / restarting: queue in seq order
-            with self._lock:
-                info.pending.append(spec)
         return return_ids
 
     def _dispatch_actor_task(self, info: _ActorInfo, spec: TaskSpec) -> None:
@@ -2996,6 +3025,7 @@ class Runtime:
                             "actor died while running task (no retries left)"
                         ))
                 info.handle = None
+                info.drained = False
             self._request_pool.submit(self._start_actor, info)
         else:
             self.gcs.set_actor_state(

@@ -1243,26 +1243,22 @@ def ici_allgather_move(arr, mesh_devices, dst_index: int):
     on every mesh position, from which ``dst_index`` keeps its shard —
     the collective spelling of a point-to-point move for backends where
     direct device_put between chips bounces through the host. Falls
-    back to :func:`ici_move` when shard_map is unavailable or the mesh
-    is a single device."""
-    from ..utils.jax_compat import HAS_SHARD_MAP
-
-    if not HAS_SHARD_MAP or len(mesh_devices) < 2:
+    back to :func:`ici_move` when the mesh is a single device."""
+    if len(mesh_devices) < 2:
         return ici_move(arr, mesh_devices[dst_index])
     try:
         import jax
         import jax.numpy as jnp
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-        from ..utils.jax_compat import shard_map
-
         mesh = Mesh(list(mesh_devices), ("x",))
 
         def _relay(x):
             return jax.lax.psum(x, "x")
 
-        moved = shard_map(_relay, mesh=mesh, in_specs=P(),
-                          out_specs=P())(jnp.asarray(arr))
+        moved = jax.shard_map(_relay, mesh=mesh, in_specs=P(),
+                              out_specs=P(),
+                              check_vma=False)(jnp.asarray(arr))
         out = jax.device_put(moved, mesh_devices[dst_index])
         out.block_until_ready()
         _count("device_ici_transfers")

@@ -12,9 +12,11 @@ the TPU host-process model:
     proxied over the pipe to the owner runtime (the reference gives every
     worker a full CoreWorker; centralizing ownership in the driver is a
     single-host simplification, revisited for multi-host in the DCN plane).
-  - Accelerator isolation: an exec message may carry ``visible_chips``; the
-    worker exports ``TPU_VISIBLE_CHIPS`` before user code imports jax — the
-    TPU analog of per-task CUDA_VISIBLE_DEVICES (_raylet.pyx:563).
+  - Accelerator isolation: the worker of a chip lease is spawned with
+    ``TPU_VISIBLE_CHIPS`` already in its environment (node_manager
+    ``build_worker_env``) — the TPU analog of per-task CUDA_VISIBLE_DEVICES
+    (_raylet.pyx:563). Nothing here imports jax before a task does, so a
+    process opens a chip only when user code asks for it.
 
 Concurrency: the main thread is a pure receive loop. Normal tasks and each
 actor run on their own serial executor (max_concurrency>1 widens the actor's
@@ -685,7 +687,7 @@ class Worker:
     def __init__(self, conn, worker_id: bytes, node_id: bytes,
                  store_name: str, inline_limit: int):
         from ..config import global_config
-        from .device_store import DeviceObjectStore, resolve_capacity
+        from .device_store import DeviceObjectStore, configured_capacity
 
         self.conn = conn
         self.worker_id = worker_id
@@ -694,7 +696,7 @@ class Worker:
         # workers see the env-driven config (RMT_* vars travel through the
         # pool spawn), so capacity/precision knobs apply per-process
         self.device_store = DeviceObjectStore(
-            capacity_bytes=resolve_capacity(global_config()),
+            capacity_bytes=configured_capacity(global_config()),
             on_demote=self._demote_device_object)
         # oids this process demoted (re-promotion candidates on read);
         # benign races only — a miss just skips one re-pin
@@ -785,18 +787,6 @@ class Worker:
         return encoded
 
     # -- execution ------------------------------------------------------------
-    @staticmethod
-    def _apply_chip_lease(msg: dict) -> None:
-        """Export the leased chips before user code imports jax — the TPU
-        analog of per-task CUDA_VISIBLE_DEVICES (_raylet.pyx:563). The pool
-        pins workers to JAX_PLATFORMS=cpu by default; a chip lease lifts that
-        so jax can claim the TPU."""
-        chips = msg.get("visible_chips")
-        if chips is not None:
-            os.environ["TPU_VISIBLE_CHIPS"] = chips
-            if os.environ.get("JAX_PLATFORMS") == "cpu":
-                del os.environ["JAX_PLATFORMS"]
-
     def _resolve_function(self, msg) -> Any:
         fn_id = msg["fn_id"]
         fn = self.functions.get(fn_id)
@@ -831,7 +821,6 @@ class Worker:
             task_id.hex(), trace_ctx[0] if trace_ctx else None)
         ru0 = profiler.task_rusage_begin(self.device_store)
         try:
-            self._apply_chip_lease(msg)
             fn = self._resolve_function(msg)
             # fault site: an injected error rides the normal app-error
             # path, so recovery is the task-retry machinery itself
@@ -994,7 +983,6 @@ class Worker:
     def create_actor(self, msg: dict) -> None:
         actor_id = msg["actor_id"]
         try:
-            self._apply_chip_lease(msg)
             cls_id = msg["cls_id"]
             cls = self.classes.get(cls_id) or PRELOADED_CLASSES.get(cls_id)
             if cls is None:

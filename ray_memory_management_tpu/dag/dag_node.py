@@ -1,6 +1,6 @@
 """DAG node types and execution.
 
-Mirrors the reference's node taxonomy (python/ray/dag/: DAGNode base
+Mirrors the reference's node class hierarchy (python/ray/dag/: DAGNode base
 dag_node.py:23, FunctionNode, ClassMethodNode, InputNode/InputAttributeNode
 input_node.py, MultiOutputNode output_node.py) re-founded on this runtime's
 task/actor API. Execution is owner-side: one pass over the graph submits

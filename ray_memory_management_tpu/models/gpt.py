@@ -37,7 +37,7 @@ class TransformerConfig:
     rope_theta: float = 10_000.0
     dtype: Any = jnp.bfloat16         # activation/compute dtype (MXU)
     param_dtype: Any = jnp.float32
-    attention: str = "auto"           # auto|flash|ref|ring|ulysses
+    attention: str = "auto"  # auto|flash|flash-interpret|ref|ring|ulysses
     remat: bool = False               # jax.checkpoint each block
     # layer-scan unroll factor: 1 compiles O(1) in depth; n_layers trades
     # compile time for a few % step time (XLA drops the scan-carry
@@ -162,8 +162,10 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh, sp_axis):
 
     if mode == "ref":
         return reference_attention(q, k, v, causal=True)
-    use = None if mode == "auto" else "on"
-    return flash_attention(q, k, v, causal=True, use_pallas=use)
+    # "flash-interpret" runs the kernel under the Pallas interpreter (CPU
+    # rehearsals); it is only ever reached by name
+    use = {"auto": None, "flash-interpret": "interpret"}.get(mode, "on")
+    return flash_attention(q, k, v, causal=True, use_pallas=use, mesh=mesh)
 
 
 def apply_block_with_aux(x, layer, cfg: TransformerConfig, mesh=None,

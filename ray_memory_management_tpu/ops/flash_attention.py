@@ -1,5 +1,5 @@
 """Flash attention as Pallas TPU kernels (forward AND backward), with a
-pure-jnp fallback.
+pure-jnp reference.
 
 Net-new versus the reference (SURVEY.md §2.4: the reference has NO attention
 kernels — GPU attention lives inside user torch code). Here the hot op is a
@@ -38,12 +38,21 @@ DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 
 
-def _pick_block(n: int, target: int) -> int:
-    """Largest divisor of n that is <= target (TPU wants aligned blocks; for
-    odd sizes we fall back to the full dimension)."""
+def _pick_block(n: int, target: int, interpret: bool) -> int:
+    """Largest divisor of n that is <= target. The compiled TPU lowering
+    takes a block whose row count is a multiple of 8 or the whole
+    dimension; a length whose largest divisor is neither is refused here,
+    by name, rather than in the compiler."""
     b = min(n, target)
     while n % b:
         b -= 1
+    if not interpret and b % 8 and b != n:
+        raise ValueError(
+            f"flash attention cannot tile a sequence of length {n} for the "
+            f"TPU: its largest divisor <= {target} is {b}, and a block must "
+            f"have a multiple of 8 rows or span the whole sequence. Pad the "
+            f"sequence to a multiple of 8, or ask for attention='ref' by "
+            f"name")
     return b
 
 
@@ -129,8 +138,8 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     BH, S, D = q.shape
     Skv = k.shape[1]
     off = Skv - S
-    block_q = _pick_block(S, block_q)
-    block_k = _pick_block(Skv, block_k)
+    block_q = _pick_block(S, block_q, interpret)
+    block_k = _pick_block(Skv, block_k, interpret)
     grid = (BH, S // block_q, Skv // block_k)
     kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale,
                                block_q=block_q, block_k=block_k, off=off)
@@ -262,8 +271,8 @@ def _flash_bwd(q, k, v, o, lse, g, causal, scale, block_q, block_k,
     BH, S, D = q.shape
     Skv = k.shape[1]
     off = Skv - S
-    block_q = _pick_block(S, block_q)
-    block_k = _pick_block(Skv, block_k)
+    block_q = _pick_block(S, block_q, interpret)
+    block_k = _pick_block(Skv, block_k, interpret)
     # delta_i = rowsum(dO_i * O_i) — tiny elementwise pass XLA fuses
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[..., None]  # (BH, S, 1)
@@ -321,13 +330,10 @@ def _on_tpu() -> bool:
     """Is default computation placed on TPU? jax_default_device (set by CPU
     test harnesses) wins over the default backend, because compiled Pallas
     only lowers on the TPU backend."""
-    try:
-        dd = jax.config.jax_default_device
-        if dd is not None:
-            return dd.platform == "tpu"
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    dd = jax.config.jax_default_device
+    if dd is not None:
+        return (dd if isinstance(dd, str) else dd.platform) == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -366,17 +372,39 @@ def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None,
                     use_pallas: Optional[str] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K):
+                    block_k: int = DEFAULT_BLOCK_K,
+                    mesh=None):
     """Multi-head attention over [B, H, S, D] (or [BH, S, D]) inputs.
 
     ``use_pallas``: "on" (compiled kernel), "interpret" (kernel under the
-    Pallas interpreter — CPU testing), "off" (jnp reference), or None =
-    auto: "on" when running on TPU, "off" elsewhere (interpret mode is too
-    slow to be a default). Differentiable either way: the Pallas path uses
-    the blockwise backward kernels.
+    Pallas interpreter — CPU testing, only ever by name), "off" (jnp
+    reference), or None = auto: "on" when running on TPU, "off" elsewhere.
+    Auto decides by the platform alone: on a TPU a shape the kernel cannot
+    take raises (``_pick_block``), it never gives way to the reference.
+    Differentiable either way: the Pallas path uses the blockwise backward
+    kernels.
+
+    ``mesh``: inside a jit sharded over a mesh of several devices the
+    compiler refuses to partition a Mosaic kernel ("wrap the call in a
+    shard_map"), so pass the mesh and [B, H, S, D] inputs: each device
+    then runs the kernel on its own block — batch over the data axes
+    (dp, fsdp), heads over tp, which is how column-parallel wq/wk/wv
+    leave q/k/v laid out anyway.
     """
     if use_pallas is None:
         use_pallas = "on" if _on_tpu() else "off"
+    if use_pallas != "off" and mesh is not None and mesh.size > 1:
+        from jax.sharding import PartitionSpec as P
+
+        batch_axes = tuple(a for a in ("dp", "fsdp") if a in mesh.shape)
+        spec = P(batch_axes or None, "tp" if "tp" in mesh.shape else None,
+                 None, None)
+        return jax.shard_map(
+            functools.partial(flash_attention, causal=causal, scale=scale,
+                              use_pallas=use_pallas, block_q=block_q,
+                              block_k=block_k),
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False)(q, k, v)
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     squeeze = q.ndim == 4
     if squeeze:

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..utils.jax_compat import shard_map
 
 _NEG_INF = -1e30
 
@@ -87,8 +86,9 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
         return (acc / jnp.maximum(l, 1e-30)).astype(q_loc.dtype)
 
     spec = P(None, None, axis, None)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
     )
     sharding = NamedSharding(mesh, spec)
     return mapped(
@@ -126,8 +126,9 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "sp",
         return a2a(o_h, 1, 2)  # back to sequence-sharded
 
     spec = P(None, None, axis, None)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
     )
     sharding = NamedSharding(mesh, spec)
     return mapped(

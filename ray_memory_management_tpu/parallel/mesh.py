@@ -54,9 +54,9 @@ def cpu_mesh(axes: Dict[str, int]) -> Mesh:
 
 def local_tpu_mesh(axes: Optional[Dict[str, int]] = None) -> Mesh:
     """Mesh over this host's TPU chips (the host-process model: one process
-    owns 4-8 chips)."""
-    devices = jax.devices("tpu") if any(
-        d.platform == "tpu" for d in jax.devices()) else jax.devices()
+    owns 4-8 chips). Raises where this process has no TPU backend; a CPU
+    mesh is asked for by name (``cpu_mesh``)."""
+    devices = jax.devices("tpu")
     if axes is None:
         axes = {"dp": len(devices)}
     return make_mesh(axes, devices=devices)

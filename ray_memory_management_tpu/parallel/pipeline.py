@@ -37,7 +37,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..utils.jax_compat import shard_map
 
 
 def stage_pspec(n_dims: int, axis: str = "pp") -> P:
@@ -103,9 +102,9 @@ def pipeline_blocks(
     out_specs = (bspec, P()) if with_aux else bspec
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(param_specs, bspec),
-        out_specs=out_specs,
+        out_specs=out_specs, check_vma=False,
     )
     def run(params_local, x_local):
         stage = lax.axis_index(axis)

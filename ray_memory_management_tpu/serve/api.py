@@ -11,7 +11,8 @@ import threading
 from typing import Dict, Optional
 
 from .. import api as core_api
-from .controller import CONTROLLER_NAME, get_or_create_controller
+from .controller import (CONTROLLER_NAME, REPLICA_READY_TIMEOUT_S,
+                         get_or_create_controller)
 from .deployment import Application, Deployment, deployment  # noqa: F401
 from .handle import DeploymentHandle
 
@@ -44,7 +45,8 @@ def _ctrl():
 
 def _deploy(d: Deployment) -> DeploymentHandle:
     ctrl = _ctrl()
-    core_api.get(ctrl.deploy.remote(d.name, d.to_config()), timeout=120)
+    core_api.get(ctrl.deploy.remote(d.name, d.to_config()),
+                 timeout=REPLICA_READY_TIMEOUT_S + 60)
     return get_deployment_handle(d.name)
 
 

@@ -32,6 +32,9 @@ from .. import api
 from ..utils import events, structlog
 
 CONTROLLER_NAME = "SERVE_CONTROLLER"
+# how long a new replica may take to answer ready(); serve.run waits on the
+# deploy call for longer than this (serve/api.py)
+REPLICA_READY_TIMEOUT_S = 180
 
 log = structlog.get_logger(__name__)
 
@@ -257,11 +260,13 @@ class ServeController:
             info.replicas[tag] = handle
             new_tags.append(tag)
         # wait for readiness so the routing table only ever lists live
-        # replicas (deployment_state reconciler waits for replica startup)
+        # replicas (deployment_state reconciler waits for replica startup).
+        # A replica that leases a chip cold-spawns, opens the chip and
+        # builds its model on it before it is ready
         ready_refs = [info.replicas[t].ready.remote() for t in new_tags]
         for tag, ref in zip(new_tags, ready_refs):
             try:
-                await self._aget(ref, timeout=60)
+                await self._aget(ref, timeout=REPLICA_READY_TIMEOUT_S)
             except Exception:
                 # failed/hung startup: remove AND kill, or the actor would
                 # finish init later and sit leaked holding its resources

@@ -75,6 +75,7 @@ class KVPagePool:
             DeviceObjectStore(capacity_bytes=-1)
         self._lock = threading.Lock()
         self._row_pages: Dict[int, int] = {}  # guarded-by: _lock
+        self._peak_store_bytes = 0  # guarded-by: _lock
 
     # -- accounting -----------------------------------------------------------
     def pages_for(self, tokens: int) -> int:
@@ -124,6 +125,9 @@ class KVPagePool:
         self.store.put(void, cache["v"])
         self.store.pin(koid)
         self.store.pin(void)
+        pinned = self.store.total_bytes()
+        with self._lock:
+            self._peak_store_bytes = max(self._peak_store_bytes, pinned)
 
     def take_row(self, row: int) -> Optional[Dict[str, Any]]:
         """Consume a slot's KV arrays out of the store (donation read:
@@ -151,6 +155,7 @@ class KVPagePool:
     def stats(self) -> Dict[str, int]:
         with self._lock:
             pages = sum(self._row_pages.values())
+            peak = self._peak_store_bytes
         return {
             "page_tokens": self.page_tokens,
             "page_bytes": self.page_bytes,
@@ -158,6 +163,7 @@ class KVPagePool:
             "pages_in_use": pages,
             "bytes_in_use": pages * self.page_bytes,
             "store_bytes": self.store.total_bytes(),
+            "peak_store_bytes": peak,
         }
 
     @staticmethod

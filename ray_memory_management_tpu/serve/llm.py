@@ -635,7 +635,10 @@ class LLMServer:
         import jax
 
         from ..models import gpt
+        from ..utils.compile_cache import CompileCounter
 
+        # every program this replica compiles from here on (stats())
+        self._compiles = CompileCounter()
         self.cfg = gpt.PRESETS[preset]
         if max_new_tokens + pad_multiple > self.cfg.max_seq:
             raise ValueError(
@@ -753,9 +756,18 @@ class LLMServer:
         return self._batcher.submit(list(tokens))
 
     def stats(self) -> dict:
+        """Request counters, the KV pool's occupancy, the device this
+        replica computes on as jax reports it, and how many programs it
+        has compiled (a request shape that keeps compiling shows here)."""
+        import jax
+
         out = dict(self._stats)
         if self._engine is not None:
             out["kv"] = self._engine.kv_stats()
+        dev = jax.devices()[0]
+        out["device"] = {"platform": dev.platform, "kind": dev.device_kind,
+                         "count": len(jax.devices())}
+        out["compile"] = self._compiles.snapshot()
         return out
 
     # -- batched model call ---------------------------------------------------

@@ -9,7 +9,8 @@ loop (:275,356-360), and drain per-worker result queues
 (train/_internal/session.py:144 → get_next_results, backend_executor.py:362).
 
 TPU mapping: each TrainWorker is a host-process actor; ``chips_per_worker``
-TPU chips are leased to it (TPU_VISIBLE_CHIPS), and inside the loop the user
+TPU chips are leased to it (its process is spawned with exactly those in
+TPU_VISIBLE_CHIPS and exits with the gang), and inside the loop the user
 builds meshes over the worker's local chips with parallel.make_mesh. Data
 parallelism ACROSS workers rides the collective group exposed via
 ``session_collective_group_name``.
@@ -70,28 +71,41 @@ def placeable_world_size(bundle: Dict[str, Any], cap: int,
     return count
 
 
-def partition_chips_for_host(n_chips: int, n_workers: int,
-                             exclude: Optional[set] = None) -> List[str]:
-    """Split a host's chips into ``n_workers`` DISJOINT contiguous slices
-    covering every available chip (sizes differ by at most one when the
-    count does not divide evenly). One process per host is the preferred
-    TPU layout (SURVEY §7); when a gang does co-locate processes, each
-    must own its slice outright — TPU runtimes cannot time-share a chip
-    between jax.distributed processes. ``exclude`` removes chips already
-    leased to sibling workers through the scheduler."""
-    chips = [c for c in range(n_chips) if not exclude or c not in exclude]
-    if n_workers > len(chips):
+# libtpu's process grid and per-process slice for W worker processes of c
+# chips each that share one four-chip host (the layouts jax's own
+# multi-process tests use): (W, c) -> (TPU_PROCESS_BOUNDS,
+# TPU_CHIPS_PER_PROCESS_BOUNDS)
+_ONE_HOST_WORLDS = {(4, 1): ("2,2,1", "1,1,1"), (2, 2): ("2,1,1", "1,2,1")}
+
+
+def _one_host_tpu_world(leases: List[dict]) -> List[Optional[Dict[str, str]]]:
+    """Per-rank libtpu environment for an xla world whose workers leased
+    chips. A lease makes each worker an isolated one-process slice
+    (node_manager.chip_lease_env); to form ONE world the processes must
+    instead be told the grid they make up and where to find each other.
+    Workers without leases (CPU worlds) need nothing. Anything but the
+    layouts in ``_ONE_HOST_WORLDS`` has not been brought up and raises."""
+    if not any(l["chips"] for l in leases):
+        return [None] * len(leases)
+    counts = {len(l["chips"].split(",")) if l["chips"] else 0
+              for l in leases}
+    shape = (len(leases), counts.pop()) if len(counts) == 1 else None
+    if shape not in _ONE_HOST_WORLDS or \
+            len({l["node_id"] for l in leases}) != 1:
         raise TrainingFailedError(
-            f"{n_workers} xla-mode workers share a host with only "
-            f"{len(chips)} free chips; use at most one worker per chip "
-            "(or one worker per host controlling all its chips)")
-    base, extra = divmod(len(chips), n_workers)
-    out, pos = [], 0
-    for i in range(n_workers):
-        take = base + (1 if i < extra else 0)
-        out.append(",".join(str(c) for c in chips[pos:pos + take]))
-        pos += take
-    return out
+            "an xla world over leased TPU workers is brought up only for "
+            f"{sorted(_ONE_HOST_WORLDS)} (workers, chips each) on one "
+            f"four-chip host; got leases {[l['chips'] for l in leases]} on "
+            f"{len({l['node_id'] for l in leases})} node(s). Use one "
+            "worker that leases all the host's chips")
+    process_bounds, chip_bounds = _ONE_HOST_WORLDS[shape]
+    addresses = ",".join(f"localhost:{l['port']}" for l in leases)
+    return [{"TPU_PROCESS_BOUNDS": process_bounds,
+             "TPU_CHIPS_PER_PROCESS_BOUNDS": chip_bounds,
+             "TPU_PROCESS_ADDRESSES": addresses,
+             "TPU_PROCESS_PORT": str(l["port"]),
+             "CLOUD_TPU_TASK_ID": str(rank)}
+            for rank, l in enumerate(leases)]
 
 
 class _TrainWorkerImpl:
@@ -113,28 +127,34 @@ class _TrainWorkerImpl:
         init_collective_group(world_size, rank, backend, group_name)
         return True
 
-    def _rmt_host_info(self) -> dict:
-        """Where this worker runs and what chips it already leased — the
-        input to the head's per-host chip partitioning."""
+    def _rmt_require_tpu(self) -> str:
+        """``ScalingConfig(use_tpu=True)`` asked for the chip: a worker
+        whose default backend is anything else fails here, before the
+        user's loop can run on the CPU by accident."""
+        import jax
+
+        backend = jax.default_backend()
+        if backend != "tpu":
+            import os
+
+            raise RuntimeError(
+                f"train worker {self.rank} was asked to use the TPU but "
+                f"jax's default backend is {backend!r} (JAX_PLATFORMS="
+                f"{os.environ.get('JAX_PLATFORMS')!r}, TPU_VISIBLE_CHIPS="
+                f"{os.environ.get('TPU_VISIBLE_CHIPS')!r})")
+        return jax.devices()[0].device_kind
+
+    def _rmt_chip_lease(self) -> dict:
+        """What this worker leased and where, plus a free port on its host
+        for libtpu's own process rendezvous (``_one_host_tpu_world``)."""
         import os
+        import socket
 
-        return {
-            "node_id": os.environ.get("RMT_NODE_ID", ""),
-            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
-        }
-
-    def _rmt_set_visible_chips(self, chips_csv: str) -> bool:
-        """Pin this worker to a disjoint chip subset BEFORE any jax backend
-        initializes (the torch _share_cuda_visible_devices analog,
-        train/backend_executor.py:195 + torch/config.py:108-156 — except
-        TPU processes must own DISJOINT chips, so the head partitions
-        rather than shares)."""
-        import os
-
-        os.environ["TPU_VISIBLE_CHIPS"] = chips_csv
-        if os.environ.get("JAX_PLATFORMS") == "cpu":
-            del os.environ["JAX_PLATFORMS"]
-        return True
+        with socket.socket() as s:
+            s.bind(("127.0.0.1", 0))
+            port = s.getsockname()[1]
+        return {"node_id": os.environ.get("RMT_NODE_ID", ""),
+                "chips": os.environ.get("TPU_VISIBLE_CHIPS"), "port": port}
 
     def _rmt_pick_coordinator(self) -> str:
         """Rank-0 hook: choose the jax.distributed coordinator address on
@@ -158,23 +178,26 @@ class _TrainWorkerImpl:
         return f"{host}:{port}"
 
     def _rmt_init_jax_world(self, coordinator: str, world: int,
-                            rank: int) -> int:
+                            rank: int,
+                            tpu_env: Optional[Dict[str, str]] = None) -> int:
         """Form one global jax world across the worker processes
         (jax.distributed.initialize — the NCCLUniqueID-rendezvous /
         _setup_torch_process_group analog, SURVEY §2.3). Must run before
         this process initializes any jax backend; afterwards jax.devices()
-        is the GLOBAL device list and one jit program spans every worker."""
-        import sys
+        is the GLOBAL device list and one jit program spans every worker.
+        ``tpu_env`` is this rank's place among the processes that share a
+        TPU host; libtpu reads it when the backend is created."""
+        import os
 
-        jax_mod = sys.modules.get("jax")
-        if jax_mod is not None:
-            try:
-                if jax_mod._src.xla_bridge._backends:  # noqa: SLF001
-                    raise RuntimeError(
-                        "jax backends already initialized in this worker; "
-                        "xla cross-worker mode requires a fresh process")
-            except AttributeError:
-                pass
+        from ..utils.jax_backend import initialized_platforms
+
+        os.environ.update(tpu_env or {})
+
+        live = initialized_platforms()
+        if live:
+            raise RuntimeError(
+                f"jax backends {live} already initialized in this worker; "
+                "xla cross-worker mode requires a fresh process")
         import jax
 
         jax.distributed.initialize(coordinator_address=coordinator,
@@ -287,61 +310,20 @@ class WorkerGroup:
             backend="objstore", group_name=self.group_name,
         )
 
-    def partition_chips(self) -> None:
-        """Give xla-mode workers sharing a host DISJOINT TPU_VISIBLE_CHIPS.
-
-        Workers that leased chips through the scheduler (num_tpus>0)
-        already hold disjoint sets; this covers the bare-CPU-request case
-        where two xla workers on one TPU host would otherwise both claim
-        every local chip when jax.distributed initializes (VERDICT r2
-        item 7; reference analog _share_cuda_visible_devices,
-        train/backend_executor.py:195)."""
-        from ..state.api import list_nodes
-
-        infos = api.get([a._rmt_host_info.remote() for a in self.actors],
-                        timeout=120)
-        totals = {row["node_id"]: int(
-            row["resources_total"].get("TPU", 0) or 0)
-            for row in list_nodes()}
-        by_node: Dict[str, List[int]] = {}
-        for rank, info in enumerate(infos):
-            by_node.setdefault(info["node_id"], []).append(rank)
-        calls = []
-        for node_id, ranks in by_node.items():
-            n_chips = totals.get(node_id, 0)
-            if n_chips <= 0:
-                continue  # CPU-only host: nothing to partition
-            # workers whose scheduler lease already pinned chips keep
-            # them; the UNLEASED siblings must still be fenced off those
-            # chips, or their jax.distributed init claims the whole host
-            leased_chips: set = set()
-            unleased: List[int] = []
-            for r in ranks:
-                csv = infos[r]["visible_chips"]
-                if csv:
-                    leased_chips.update(int(c) for c in csv.split(","))
-                else:
-                    unleased.append(r)
-            if not unleased:
-                continue
-            slices = partition_chips_for_host(n_chips, len(unleased),
-                                              exclude=leased_chips)
-            for csv, rank in zip(slices, sorted(unleased)):
-                calls.append(
-                    self.actors[rank]._rmt_set_visible_chips.remote(csv))
-        if calls:
-            api.get(calls, timeout=120)
-
     def setup_xla_world(self) -> int:
         """Cross-worker XLA mode: every worker process joins one
         jax.distributed world so the user loop jits over ONE global mesh —
         gradients sync through XLA collectives (ICI/DCN), never the object
-        plane. Returns the global device count."""
-        self.partition_chips()
+        plane. Returns the global device count. On a TPU host each
+        worker sees the chips it leased (``use_tpu``, ``chips_per_worker``)
+        and no others; a worker without a lease is a CPU process."""
+        tpu_envs = _one_host_tpu_world(api.get(
+            [a._rmt_chip_lease.remote() for a in self.actors], timeout=120))
         coordinator = api.get(
             self.actors[0]._rmt_pick_coordinator.remote(), timeout=120)
         counts = api.get(
-            [a._rmt_init_jax_world.remote(coordinator, self.num_workers, r)
+            [a._rmt_init_jax_world.remote(coordinator, self.num_workers, r,
+                                          tpu_envs[r])
              for r, a in enumerate(self.actors)],
             timeout=300,
         )
@@ -372,12 +354,14 @@ class BackendExecutor:
                  resources_per_worker: Optional[Dict[str, Any]] = None,
                  placement_strategy: str = "PACK",
                  use_collective: bool = True,
-                 collective_backend: str = "objstore"):
+                 collective_backend: str = "objstore",
+                 use_tpu: bool = False):
         self.num_workers = num_workers
         self.resources_per_worker = resources_per_worker or {"CPU": 1}
         self.placement_strategy = placement_strategy
         self.use_collective = use_collective and num_workers > 1
         self.collective_backend = collective_backend
+        self.use_tpu = use_tpu
         self.group: Optional[WorkerGroup] = None
 
     def start(self) -> None:
@@ -390,6 +374,10 @@ class BackendExecutor:
                 self.group.setup_xla_world()
             else:
                 self.group.setup_collective()
+        if self.use_tpu:
+            # after the xla world, which must form before any backend
+            api.get([a._rmt_require_tpu.remote() for a in self.group.actors],
+                    timeout=300)
 
     def run(
         self,

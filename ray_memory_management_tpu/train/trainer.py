@@ -345,6 +345,7 @@ class JaxTrainer:
                     bundle,
                     self.scaling.placement_strategy,
                     collective_backend=self.scaling.collective_backend,
+                    use_tpu=self.scaling.use_tpu,
                 )
                 try:
                     executor.start()

@@ -197,3 +197,30 @@ def test_many_actor_tasks_blocked_on_one_dep(rmt_start_regular):
     assert rmt.get(nested_probe.remote(), timeout=60) == "alive"
     assert time.monotonic() - t0 < 1.9, "request pool starved by dep waits"
     assert rmt.get(blocked, timeout=120) == [8] * 12
+
+
+def test_methods_submitted_across_actor_startup_keep_order(rmt_start_regular):
+    """Submits that straddle the moment the actor turns ALIVE: the ones
+    queued while it was starting are sent first, and a later one never
+    overtakes them by going straight to the worker."""
+    import time
+
+    @rmt.remote(num_cpus=0)
+    class Counter:
+        def __init__(self):
+            time.sleep(0.2)
+            self.n = 0
+
+        def inc(self):
+            self.n += 1
+            return self.n
+
+    for _ in range(4):
+        c = Counter.remote()
+        refs = []
+        until = time.monotonic() + 0.8
+        while time.monotonic() < until:
+            refs.append(c.inc.remote())
+            time.sleep(0.001)
+        assert rmt.get(refs, timeout=120) == list(range(1, len(refs) + 1))
+        rmt.kill(c)

@@ -18,10 +18,6 @@ from ray_memory_management_tpu.core import metrics_defs as mdefs
 def mesh_group():
     import jax
 
-    if not col.HAS_SHARD_MAP:
-        pytest.skip("this jax provides no shard_map (neither jax.shard_map "
-                    "nor jax.experimental.shard_map) — xla-backend "
-                    "collectives are unavailable")
     devices = jax.devices("cpu")
     assert len(devices) >= 8, "conftest must force 8 CPU devices"
     return col.MeshCollectives(devices[:8])

@@ -94,9 +94,11 @@ def test_ring_attention_training(setup):
 
 def test_graft_entry_points():
     import importlib.util
+    import os
 
     spec = importlib.util.spec_from_file_location(
-        "graft", "/root/repo/__graft_entry__.py"
+        "graft", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "__graft_entry__.py")
     )
     m = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(m)

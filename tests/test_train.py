@@ -266,36 +266,6 @@ def test_xla_cross_worker_global_mesh(rmt_start_regular, tmp_path):
     np.testing.assert_allclose(rep["grad"], expected, rtol=1e-5)
 
 
-def test_chip_partitioning_unit():
-    """xla-mode workers sharing a host must receive DISJOINT chip slices
-    covering the host (VERDICT r2 item 7)."""
-    from ray_memory_management_tpu.train.backend_executor import (
-        TrainingFailedError, partition_chips_for_host,
-    )
-
-    assert partition_chips_for_host(4, 2) == ["0,1", "2,3"]
-    assert partition_chips_for_host(8, 4) == ["0,1", "2,3", "4,5", "6,7"]
-    assert partition_chips_for_host(4, 1) == ["0,1,2,3"]
-    slices = partition_chips_for_host(8, 2)
-    seen = [c for s in slices for c in s.split(",")]
-    assert len(seen) == len(set(seen)) == 8  # disjoint and covering
-    with pytest.raises(TrainingFailedError):
-        partition_chips_for_host(2, 3)
-
-
-def test_chip_env_applied_before_jax_init(monkeypatch):
-    from ray_memory_management_tpu.train.backend_executor import (
-        _TrainWorkerImpl,
-    )
-
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
-    w = _TrainWorkerImpl(0, 2, "g")
-    assert w._rmt_set_visible_chips("2,3")
-    assert os.environ["TPU_VISIBLE_CHIPS"] == "2,3"
-    assert "JAX_PLATFORMS" not in os.environ  # cpu pin lifted for the chip
-
-
 def test_xla_world_across_two_agent_nodes(tmp_path):
     """The global-mesh xla train runs with its two worker processes on two
     AGENT nodes (separate OS processes joined over TCP), not bare local
