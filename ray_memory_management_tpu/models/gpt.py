@@ -371,25 +371,33 @@ def forward_with_cache_rows(params, tokens, cache, offsets,
     def scan_body(x, layer_and_cache):
         layer, k_cache, v_cache = layer_and_cache
 
+        # the named scopes are what xprof's op view groups by; they are
+        # debug info, which jax leaves out of the compile cache's key. No
+        # line above this function may move for their sake: the Mosaic
+        # kernels of the train step carry their callers' line numbers
+        # inside the program, and so inside its cache key
         def cached_attn(q, k, v):
-            kt = k.transpose(0, 2, 1, 3)                      # [B,Hkv,S,Dh]
-            vt = v.transpose(0, 2, 1, 3)
-            write = jax.vmap(
-                lambda c, u, o: lax.dynamic_update_slice(c, u, (0, o, 0)))
-            kc = write(k_cache, kt, offsets)
-            vc = write(v_cache, vt, offsets)
-            kk, vv = kc, vc
-            if Hkv != H:
-                rep = H // Hkv
-                kk = jnp.repeat(kk, rep, axis=1)
-                vv = jnp.repeat(vv, rep, axis=1)
-            qh = q.transpose(0, 2, 1, 3)                      # [B, H, S, Dh]
-            scores = jnp.einsum(
-                "bhsd,bhtd->bhst", qh, kk,
-                preferred_element_type=jnp.float32) * (Dh ** -0.5)
-            scores = jnp.where(mask[:, None], scores, -jnp.inf)
-            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            o = jnp.einsum("bhst,bhtd->bhsd", probs, vv)
+            with jax.named_scope("kv_write"):
+                kt = k.transpose(0, 2, 1, 3)                  # [B,Hkv,S,Dh]
+                vt = v.transpose(0, 2, 1, 3)
+                write = jax.vmap(
+                    lambda c, u, o: lax.dynamic_update_slice(
+                        c, u, (0, o, 0)))
+                kc = write(k_cache, kt, offsets)
+                vc = write(v_cache, vt, offsets)
+            with jax.named_scope("decode_attention"):
+                kk, vv = kc, vc
+                if Hkv != H:
+                    rep = H // Hkv
+                    kk = jnp.repeat(kk, rep, axis=1)
+                    vv = jnp.repeat(vv, rep, axis=1)
+                qh = q.transpose(0, 2, 1, 3)                  # [B, H, S, Dh]
+                scores = jnp.einsum(
+                    "bhsd,bhtd->bhst", qh, kk,
+                    preferred_element_type=jnp.float32) * (Dh ** -0.5)
+                scores = jnp.where(mask[:, None], scores, -jnp.inf)
+                probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+                o = jnp.einsum("bhst,bhtd->bhsd", probs, vv)
             return o.transpose(0, 2, 1, 3), (kc, vc)
 
         x, (kc, vc) = apply_block(x, layer, cfg, attn_fn=cached_attn,
@@ -398,12 +406,13 @@ def forward_with_cache_rows(params, tokens, cache, offsets,
 
     x, (k_new, v_new) = lax.scan(
         scan_body, x, (params["layers"], cache["k"], cache["v"]))
-    x = _rmsnorm(x, params["final_ln"])
-    logits = lax.dot_general(
-        x, params["lm_head"].astype(cfg.dtype),
-        (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("head_sample"):  # the engine's sampler joins it
+        x = _rmsnorm(x, params["final_ln"])
+        logits = lax.dot_general(
+            x, params["lm_head"].astype(cfg.dtype),
+            (((x.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
     return logits, {"k": k_new, "v": v_new}
 
 

@@ -25,20 +25,32 @@ from __future__ import annotations
 
 import threading
 import time
+from collections import deque
 from typing import Any, Dict, List, Optional, Sequence
 
-from ..utils import faults
+from ..utils import faults, timeline, tracing
+from ..utils.profiling import phase
 from .deployment import deployment
+
+# the engine thread is in exactly one of these at every instant (see
+# ContinuousBatcher._loop)
+ENGINE_PHASES = ("idle_wait", "gate", "prefill", "assemble",
+                 "step_dispatch", "step_wait", "emit", "disassemble")
 
 
 class _Pending:
-    __slots__ = ("item", "event", "result", "error")
+    __slots__ = ("item", "event", "result", "error", "trace",
+                 "t_submit", "t_admit", "t_first", "t_done")
 
     def __init__(self, item):
         self.item = item
         self.event = threading.Event()
         self.result = None
         self.error: Optional[BaseException] = None
+        # the continuous engine's stamps (time.time()) and the submitting
+        # thread's trace context
+        self.trace = None
+        self.t_submit = self.t_admit = self.t_first = self.t_done = 0.0
 
 
 class DynamicBatcher:
@@ -219,7 +231,8 @@ class ContinuousBatcher:
                 key, sub = jax.random.split(key)
                 logits, cache = gpt.forward_with_cache_rows(
                     params, last[:, None], cache, offsets + t, cfg)
-                nxt = _sample(logits[:, 0], sub)
+                with jax.named_scope("head_sample"):
+                    nxt = _sample(logits[:, 0], sub)
                 return (cache, nxt, key), nxt
 
             (cache, _, _), toks = jax.lax.scan(
@@ -244,6 +257,15 @@ class ContinuousBatcher:
         self._cond = threading.Condition()
         self._stop = False
         self.steps = 0  # decode steps executed (the "batches" analog)
+        # what the engine measures of itself, cumulative since it started.
+        # The engine thread is the only writer of these three; it publishes
+        # a copy once per iteration (one reference assignment), and that
+        # copy is all engine_stats() reads
+        self._phase = {name: [0.0, 0.0] for name in ENGINE_PHASES}
+        self._counts = {"iterations": 0, "slab_positions": 0,
+                        "live_positions": 0, "admitted": 0}
+        self._recent: deque = deque(maxlen=512)  # (queue_wait_s, prefill_s)
+        self._publish()
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="llm-engine")
         self._thread.start()
@@ -258,6 +280,10 @@ class ContinuousBatcher:
         budget = self.max_new_tokens if max_new_tokens is None else \
             max(1, min(int(max_new_tokens), self.max_new_tokens))
         p = _Pending((list(tokens), budget))
+        # this is the request's own exec thread: its context is the
+        # replica's exec span, which the engine's spans hang under
+        p.trace = tracing.get_current()
+        p.t_submit = time.time()
         with self._cond:
             if self._stop:
                 raise RuntimeError("engine closed")
@@ -398,7 +424,33 @@ class ContinuousBatcher:
             self._slot_cap[row] = 0
         if p is not None:
             p.result = self._slot_out[row]
+            p.t_done = time.time()
             p.event.set()
+            self._record_request(p, row)
+
+    def _record_request(self, p: _Pending, row: int) -> None:
+        """The request's three spans in the timeline ring, each a child of
+        the context ``submit()`` ran under (the replica's exec span), so
+        ``rmt trace <id>`` walks one request from the handle into queue,
+        prefill and decode. Three spans a request, nothing per iteration."""
+        toks = self._clip_tokens(p.item[0])
+        extra = {"row": row, "prompt_tokens": len(toks),
+                 "bucket": self._bucket_for(toks),
+                 "output_tokens": len(p.result)}
+        for name, start, end in (
+                ("serve.engine.queue", p.t_submit, p.t_admit),
+                ("serve.engine.prefill", p.t_admit, p.t_first),
+                ("serve.engine.decode", p.t_first, p.t_done)):
+            timeline.record_event(
+                name, "serve", start, end, extra=extra,
+                trace=tracing.child_of(p.trace) if p.trace else None)
+
+    def _slab_len(self, active: List[int]) -> int:
+        """Sequence capacity of the slab the decode step runs over: the
+        longest reservation among the live slots, or the whole cache."""
+        if self.kv_pool is None:
+            return self.cfg.max_seq
+        return max(int(self._slot_cap[r]) for r in active)
 
     def _assemble(self, active: List[int]):
         """Consume every active slot's pooled KV rows (``take`` — the
@@ -407,7 +459,7 @@ class ContinuousBatcher:
         aligned max over LIVE slots — not ``max_seq``. Batch dim stays
         ``max_slots`` so the compiled step only re-specializes on S."""
         jnp, cfg = self._jnp, self.cfg
-        S = max(int(self._slot_cap[r]) for r in active)
+        S = self._slab_len(active)
         active_set = set(active)
         zeros = None
         parts_k, parts_v = [], []
@@ -481,15 +533,25 @@ class ContinuousBatcher:
                 break
             self._slot_cap[row] = need
             admits.append((self._q.pop(0), row))
+        now = time.time()
+        for p, _ in admits:
+            p.t_admit = now
         return admits
 
     def _loop(self) -> None:
+        """The engine thread. Every instant of it lies in exactly one
+        ``phase`` (ENGINE_PHASES), so the phases' wall seconds add up to the
+        thread's, and each is a ``rmt.engine.<name>`` span on this thread's
+        line of a profiler trace."""
         jnp, np = self._jnp, self._np
+        acc, counts = self._phase, self._counts
         while True:
             with self._cond:
                 while (not self._stop and not self._q
                        and all(p is None for p in self._slot_pending)):
-                    self._cond.wait(timeout=1.0)
+                    with phase(acc, "idle_wait"):
+                        self._cond.wait(timeout=1.0)
+                    self._publish()
                 if self._stop:
                     # fail slot-resident requests too: close() cannot
                     # touch slot state (it races this thread), so the
@@ -504,69 +566,117 @@ class ContinuousBatcher:
                         p.error = RuntimeError("engine closed")
                         p.event.set()
                     return
-                admits = self._admit_gate()
+                with phase(acc, "gate"):
+                    admits = self._admit_gate()
             try:
                 for p, row in admits:
-                    try:
-                        self._admit(p, row)
-                    except faults.FaultInjected as e:
-                        # injected admit failure takes down ONE request,
-                        # not the engine: release the reservation and
-                        # keep admitting
-                        if self.kv_pool is not None:
-                            self.kv_pool.free(row)
-                            self._slot_cap[row] = 0
-                        self._slot_pending[row] = None
-                        p.error = e
-                        p.event.set()
-                        continue
-                    if self._slot_budget[row] <= 0:
-                        self._retire(row)  # max_new_tokens == 1
+                    with phase(acc, "prefill",
+                               bucket=self._bucket_for(
+                                   self._clip_tokens(p.item[0])),
+                               cap=int(self._slot_cap[row])):
+                        try:
+                            self._admit(p, row)
+                        except faults.FaultInjected as e:
+                            # injected admit failure takes down ONE
+                            # request, not the engine: release the
+                            # reservation and keep admitting
+                            if self.kv_pool is not None:
+                                self.kv_pool.free(row)
+                                self._slot_cap[row] = 0
+                            self._slot_pending[row] = None
+                            p.error = e
+                            p.event.set()
+                            continue
+                        # the first token exists
+                        p.t_first = time.time()
+                        counts["admitted"] += 1
+                        self._recent.append((p.t_admit - p.t_submit,
+                                             p.t_first - p.t_admit))
+                        if self._slot_budget[row] <= 0:
+                            self._retire(row)  # max_new_tokens == 1
                 active = [r for r in range(self.max_slots)
                           if self._slot_pending[r] is not None]
                 if not active:
+                    self._publish()
                     continue
-                self._key, sub = self._jax.random.split(self._key)
-                cache = self._assemble(active) if self.kv_pool is not None \
-                    else self._cache
-                cache, toks = self._step(
-                    self.params, cache,
-                    jnp.asarray(self._slot_last),
-                    jnp.asarray(self._slot_offset), sub)
-                toks = np.asarray(toks)  # [K, B]
-                self.steps += self.steps_per_iter
-                for r in active:
-                    # a row finishing mid-iteration consumes only what its
-                    # budget allows; the surplus decoded junk wrote into
-                    # its OWN cache rows beyond its end, which the per-row
-                    # mask keeps invisible and retire/prefill discards
-                    take = min(self.steps_per_iter,
-                               int(self._slot_budget[r]))
-                    self._slot_out[r].extend(
-                        int(toks[t, r]) for t in range(take))
-                    self._slot_last[r] = int(toks[take - 1, r])
-                    self._slot_offset[r] += take
-                    self._slot_budget[r] -= take
-                    if self._slot_budget[r] <= 0:
-                        self._retire(r)
-                if self.kv_pool is not None:
-                    self._disassemble(cache, [
-                        r for r in active
-                        if self._slot_pending[r] is not None])
-                else:
-                    self._cache = cache
+                S = self._slab_len(active)
+                with phase(acc, "assemble", rows=len(active), S=S):
+                    self._key, sub = self._jax.random.split(self._key)
+                    cache = self._assemble(active) \
+                        if self.kv_pool is not None else self._cache
+                    # what the step attends over, and how much of it is live
+                    counts["iterations"] += 1
+                    counts["slab_positions"] += self.max_slots * S
+                    counts["live_positions"] += int(
+                        self._slot_offset[active].sum())
+                with phase(acc, "step_dispatch"):
+                    cache, toks = self._step(
+                        self.params, cache,
+                        jnp.asarray(self._slot_last),
+                        jnp.asarray(self._slot_offset), sub)
+                with phase(acc, "step_wait"):
+                    toks = np.asarray(toks)  # [K, B]
+                with phase(acc, "emit"):
+                    self.steps += self.steps_per_iter
+                    for r in active:
+                        # a row finishing mid-iteration consumes only what
+                        # its budget allows; the surplus decoded junk wrote
+                        # into its OWN cache rows beyond its end, which the
+                        # per-row mask keeps invisible and retire/prefill
+                        # discards
+                        take = min(self.steps_per_iter,
+                                   int(self._slot_budget[r]))
+                        self._slot_out[r].extend(
+                            int(toks[t, r]) for t in range(take))
+                        self._slot_last[r] = int(toks[take - 1, r])
+                        self._slot_offset[r] += take
+                        self._slot_budget[r] -= take
+                        if self._slot_budget[r] <= 0:
+                            self._retire(r)
+                with phase(acc, "disassemble"):
+                    if self.kv_pool is not None:
+                        self._disassemble(cache, [
+                            r for r in active
+                            if self._slot_pending[r] is not None])
+                    else:
+                        self._cache = cache
             except BaseException as e:  # noqa: BLE001 — fail loudly to
-                with self._cond:        # every parked caller, keep serving
-                    victims = ([p for p in self._slot_pending
-                                if p is not None] + self._q)
-                    self._slot_pending = [None] * self.max_slots
-                    self._q.clear()
-                if self.kv_pool is not None:
-                    self.kv_pool.free_all()
-                    self._slot_cap[:] = 0
-                for p in victims:
-                    p.error = e
-                    p.event.set()
+                # every parked caller, keep serving
+                with phase(acc, "emit"):
+                    with self._cond:
+                        victims = ([p for p in self._slot_pending
+                                    if p is not None] + self._q)
+                        self._slot_pending = [None] * self.max_slots
+                        self._q.clear()
+                    if self.kv_pool is not None:
+                        self.kv_pool.free_all()
+                        self._slot_cap[:] = 0
+                    for p in victims:
+                        p.error = e
+                        p.event.set()
+            self._publish()
+
+    def _publish(self) -> None:
+        """Engine thread (and the constructor, before it starts): put a
+        copy of the accumulators where ``engine_stats`` finds it. Taken
+        between iterations, so the copy's numbers belong to one instant."""
+        self._published = {
+            "phase_s": {k: v[0] for k, v in self._phase.items()},
+            "phase_cpu_s": {k: v[1] for k, v in self._phase.items()},
+            **self._counts, "recent": list(self._recent)}
+
+    def engine_stats(self) -> Dict[str, Any]:
+        """Where the engine thread's time went and what the decode step
+        attended over, cumulative since the engine started (a reader
+        subtracts two snapshots): wall and thread-CPU seconds by phase,
+        iterations, KV positions of the padded slab and the live ones among
+        them (both summed at assembly), requests admitted, and
+        ``(queue_wait_s, prefill_s)`` of the newest 512 of them, oldest
+        first. Any thread may call it; the copy is the caller's."""
+        snap = self._published
+        return {**snap, "phase_s": dict(snap["phase_s"]),
+                "phase_cpu_s": dict(snap["phase_cpu_s"]),
+                "recent": list(snap["recent"])}
 
     def kv_stats(self) -> Dict[str, Any]:
         """Pool occupancy snapshot (paged mode) for metrics/benchmarks."""
@@ -756,14 +866,16 @@ class LLMServer:
         return self._batcher.submit(list(tokens))
 
     def stats(self) -> dict:
-        """Request counters, the KV pool's occupancy, the device this
-        replica computes on as jax reports it, and how many programs it
-        has compiled (a request shape that keeps compiling shows here)."""
+        """Request counters, the KV pool's occupancy, the engine's own
+        phases and counts (continuous mode), the device this replica
+        computes on as jax reports it, and how many programs it has
+        compiled (a request shape that keeps compiling shows here)."""
         import jax
 
         out = dict(self._stats)
         if self._engine is not None:
             out["kv"] = self._engine.kv_stats()
+            out["engine"] = self._engine.engine_stats()
         dev = jax.devices()[0]
         out["device"] = {"platform": dev.platform, "kind": dev.device_kind,
                          "count": len(jax.devices())}

@@ -14,6 +14,10 @@ Perfetto. This module is the thin, dependency-gated bridge:
                                 and device traces line up;
   - ``annotate(name)``          a TraceAnnotation visible in xprof AND a
                                 timeline span — one annotation, both views;
+  - ``phase(acc, name)``        a TraceAnnotation ``rmt.engine.<name>`` plus
+                                wall and thread-CPU seconds added to the
+                                caller's accumulator; no timeline span (for
+                                loops that run many times a second);
   - ``start_server(port)``      live-capture endpoint (connect TensorBoard's
                                 profile tab to localhost:<port>);
   - ``save_device_memory_profile(path)``  HBM allocation snapshot (pprof
@@ -32,13 +36,19 @@ from typing import Optional
 from . import timeline
 
 
-def _profiler():
-    try:
-        import jax
+_resolved = None  # jax.profiler, or False without jax: looked up once
 
-        return jax.profiler
-    except Exception:
-        return None
+
+def _profiler():
+    global _resolved
+    if _resolved is None:
+        try:
+            import jax
+
+            _resolved = jax.profiler
+        except Exception:
+            _resolved = False
+    return _resolved or None
 
 
 @contextlib.contextmanager
@@ -72,6 +82,44 @@ def annotate(name: str):
             yield
     finally:
         timeline.record_event(name, "annotation", start, time.time())
+
+
+class phase:
+    """One phase of a hot loop: ``with phase(acc, "emit"):`` adds the
+    block's wall seconds (``time.perf_counter``) and the calling thread's
+    CPU seconds (``time.thread_time``) to ``acc["emit"]``, a two-item list
+    ``[wall_s, cpu_s]`` the caller owns, and wraps the block in
+    ``TraceAnnotation("rmt.engine.emit", **meta)``. Under a profiler
+    session the annotation lands on the calling thread's line of the host
+    plane, in the same ``.xplane.pb`` and on the same clock as the device
+    planes; without a session it is a flag test. Wall minus CPU is what the
+    thread spent off a core: waiting for the device, a lock or the GIL.
+
+    Nothing goes to the timeline ring: a loop that runs tens of phases a
+    second would only age out the task spans there."""
+
+    __slots__ = ("_slot", "_ann", "_t0", "_c0")
+
+    def __init__(self, acc, name: str, **meta):
+        self._slot = acc[name]
+        prof = _profiler()
+        self._ann = prof.TraceAnnotation("rmt.engine." + name, **meta) \
+            if prof is not None else None
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        self._c0 = time.thread_time()
+        if self._ann is not None:
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        slot = self._slot
+        slot[1] += time.thread_time() - self._c0
+        slot[0] += time.perf_counter() - self._t0
+        return False
 
 
 _server = None

@@ -1,0 +1,291 @@
+"""The serve engine measured from inside (``ContinuousBatcher``): loop
+phases that add up to the engine thread's wall time, counts that only grow
+and read consistently from other threads, a request's queue / prefill /
+decode spans under the caller's trace id, and the phases as
+``rmt.engine.*`` spans on the profiler's own clock.
+
+CPU, toy preset: what is checked is the accounting, not a speed.
+"""
+
+import glob
+import sys
+import threading
+import time
+
+import pytest
+
+import ray_memory_management_tpu as rmt
+from ray_memory_management_tpu import serve
+from ray_memory_management_tpu.serve.llm import (
+    ENGINE_PHASES, ContinuousBatcher, llm_deployment,
+)
+from ray_memory_management_tpu.utils import profiling, timeline, tracing
+
+MAX_SLOTS, PAGE = 4, 16
+
+
+@pytest.fixture(scope="module")
+def model():
+    import jax
+
+    from ray_memory_management_tpu.models import gpt
+
+    cfg = gpt.PRESETS["test"]
+    return cfg, gpt.init_params(jax.random.PRNGKey(0), cfg)
+
+
+def engine(model, mode):
+    cfg, params = model
+    return ContinuousBatcher(
+        params, cfg, max_slots=MAX_SLOTS, max_new_tokens=24,
+        pad_multiple=PAGE, steps_per_iter=4, kv_cache=mode,
+        kv_page_tokens=PAGE)
+
+
+def wave(eng, n=12):
+    """``n`` concurrent requests of mixed lengths; returns when all are
+    answered."""
+    errors = []
+
+    def one(i):
+        try:
+            out = eng.submit(list(range(2, 8 + 3 * i)),
+                             max_new_tokens=6 + 2 * (i % 8), timeout=120)
+            assert len(out) == 6 + 2 * (i % 8)
+        except BaseException as e:  # noqa: BLE001 — reported below
+            errors.append(e)
+
+    threads = [threading.Thread(target=one, args=(i,)) for i in range(n)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(150)
+    assert not errors and not any(t.is_alive() for t in threads), errors
+
+
+def close(eng):
+    eng.close()
+    eng._thread.join(10)
+    assert not eng._thread.is_alive()
+
+
+# ------------------------------------------------------------- (a) accounting
+@pytest.mark.parametrize("mode", ["paged", "slab"])
+def test_phases_add_up_to_the_engine_threads_wall_time(model, mode):
+    eng = engine(model, mode)
+    t0 = time.perf_counter()  # the constructor started the thread
+    try:
+        while eng.engine_stats()["iterations"] < 50:
+            wave(eng)
+    finally:
+        close(eng)
+    wall = time.perf_counter() - t0
+    st = eng.engine_stats()
+    assert set(st["phase_s"]) == set(st["phase_cpu_s"]) == set(ENGINE_PHASES)
+    assert st["iterations"] >= 50
+    # every instant of the thread lies in one phase: what is outside (loop
+    # control between two phases, the exit path) stays under 1%
+    assert sum(st["phase_s"].values()) == pytest.approx(wall, rel=0.01)
+    for name in ENGINE_PHASES:
+        assert 0.0 <= st["phase_cpu_s"][name] <= st["phase_s"][name] + 0.05
+    # the step is waited for, not spun on; the glue is the host's own work
+    assert st["phase_cpu_s"]["step_wait"] < st["phase_s"]["step_wait"]
+    assert st["phase_s"]["prefill"] > 0 and st["phase_s"]["emit"] > 0
+    assert st["admitted"] == len(st["recent"]) > 0
+    assert all(q >= 0 and p > 0 for q, p in st["recent"])
+
+
+@pytest.mark.parametrize("mode", ["paged", "slab"])
+def test_counts_only_grow_and_read_whole_from_eight_threads(model, mode):
+    cfg, _ = model
+    eng = engine(model, mode)
+    stop, errors, reads = threading.Event(), [], [0] * 8
+
+    def reader(k):
+        last = eng.engine_stats()
+        try:
+            while not stop.is_set():
+                st = eng.engine_stats()
+                reads[k] += 1
+                for key in ("iterations", "slab_positions",
+                            "live_positions", "admitted"):
+                    assert st[key] >= last[key], key
+                for name in ENGINE_PHASES:
+                    assert st["phase_s"][name] >= last["phase_s"][name]
+                # one copy, from one instant: counts that move together
+                # are never seen apart
+                assert len(st["recent"]) == min(st["admitted"], 512)
+                assert st["live_positions"] <= st["slab_positions"]
+                per_row = st["slab_positions"] // MAX_SLOTS
+                if mode == "slab":
+                    assert per_row == st["iterations"] * cfg.max_seq
+                else:
+                    assert per_row % PAGE == 0
+                    assert per_row >= st["iterations"] * PAGE
+                st["phase_s"].clear()  # the caller's own copy
+                last = eng.engine_stats()
+        except BaseException as e:  # noqa: BLE001 — reported below
+            errors.append(e)
+
+    threads = [threading.Thread(target=reader, args=(k,)) for k in range(8)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    try:
+        for t in threads:
+            t.start()
+        for _ in range(2):
+            wave(eng)
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(30)
+        sys.setswitchinterval(interval)
+        close(eng)
+    assert not errors, errors
+    assert not any(t.is_alive() for t in threads) and min(reads) > 0
+    st = eng.engine_stats()
+    assert st["admitted"] == 24 and st["iterations"] > 0
+
+
+# ------------------------------------------------------ (b) a request's spans
+SPANS = ("serve.engine.queue", "serve.engine.prefill", "serve.engine.decode")
+
+
+def _poll(pred, timeout=30.0):
+    """Replica-side spans ride the worker's 1 s profile flush."""
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        out = pred()
+        if out:
+            return out
+        time.sleep(0.2)
+    return pred()
+
+
+@pytest.fixture(scope="module")
+def traced_request():
+    """One request sent through ``serve.run`` and a handle under a root
+    context of the test's own: (the context, the trace's slices as the
+    head's ring holds them)."""
+    rmt.init(num_cpus=4, ignore_reinit_error=True)
+    timeline.clear()
+    try:
+        serve.start(http_port=None)
+        handle = serve.run(llm_deployment(
+            "test", max_new_tokens=8, max_batch_size=2, pad_multiple=16))
+        rmt.get(handle.remote({"tokens": [1, 2, 3]}), timeout=300)  # compile
+        root = tracing.new_root()
+        token = tracing.set_current(root)
+        try:
+            out = rmt.get(handle.remote(
+                {"tokens": [5, 6, 7, 8, 9], "max_new_tokens": 6}),
+                timeout=120)
+        finally:
+            tracing.reset(token)
+        assert len(out["tokens"]) == 6
+
+        def spans():
+            evs = timeline.chrome_trace_events(trace_id=root[0],
+                                               flows=False)
+            return evs if {e["name"] for e in evs} >= set(SPANS) else None
+
+        evs = _poll(spans)
+        assert evs, "the engine's spans never reached the head"
+        # the filter the CLI and /api/timeline use
+        filtered = timeline.chrome_trace_events(
+            trace_id=root[0], cat="serve", flows=False)
+        yield root, evs, filtered
+    finally:
+        serve.shutdown()
+        rmt.shutdown()
+        timeline.clear()
+
+
+@pytest.mark.parametrize("span", SPANS)
+def test_a_request_carries_its_trace_into_the_engine(traced_request, span):
+    root, evs, filtered = traced_request
+    by_name = {e["name"]: e for e in evs if e["cat"] == "serve"}
+    assert set(by_name) == set(SPANS)
+    assert sorted(e["name"] for e in filtered) == sorted(SPANS)  # once each
+    ev = by_name[span]
+    assert ev["args"]["trace_id"] == root[0]
+    assert ev["args"]["prompt_tokens"] == 5 and ev["args"]["bucket"] == 16
+    assert ev["args"]["output_tokens"] == 6 and ev["args"]["row"] in (0, 1)
+    # the parent chain: engine span -> the replica's exec span -> the
+    # caller's context
+    execs = [e for e in evs if e["cat"] == "task"
+             and e["args"].get("span_id") == ev["args"]["parent_span_id"]]
+    assert execs and "handle_request" in execs[0]["name"]
+    assert execs[0]["args"]["parent_span_id"] == root[1]
+    assert execs[0]["ts"] <= ev["ts"] + 1e3  # us; same host clock
+    assert ev["ts"] + ev["dur"] <= execs[0]["ts"] + execs[0]["dur"] + 1e3
+    # queue, prefill, decode follow each other without a hole
+    q, p, d = (by_name[n] for n in SPANS)
+    assert q["ts"] + q["dur"] == pytest.approx(p["ts"], abs=1.0)
+    assert p["ts"] + p["dur"] == pytest.approx(d["ts"], abs=1.0)
+
+
+def test_a_request_without_a_context_records_spans_without_ids(model):
+    timeline.clear()
+    eng = engine(model, "paged")
+    try:
+        assert tracing.get_current() is None
+        eng.submit([3, 4, 5], max_new_tokens=5, timeout=120)
+    finally:
+        close(eng)
+    evs = [e for e in timeline.chrome_trace_events(cat="serve", flows=False)]
+    timeline.clear()
+    assert sorted(e["name"] for e in evs) == sorted(SPANS)
+    assert all("trace_id" not in e["args"] for e in evs)
+    assert all(e["args"]["output_tokens"] == 5 for e in evs)
+
+
+# ------------------------------------------- (c) on the profiler's own clock
+@pytest.fixture(scope="module")
+def engine_line(model, tmp_path_factory):
+    """The host-plane line of a profiler capture that holds the engine
+    thread's spans, as (its events, every event's (start, end) of the
+    trace)."""
+    from jax.profiler import ProfileData
+
+    logdir = str(tmp_path_factory.mktemp("xprof"))
+    eng = engine(model, "paged")
+    try:
+        wave(eng, 6)  # compile outside the capture
+        with profiling.xprof_trace(logdir):
+            wave(eng, 6)
+    finally:
+        close(eng)
+    path, = glob.glob(logdir + "/plugins/profile/*/*.xplane.pb")
+    lines, extent = [], []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events]
+            extent += [(a, b) for _, a, b in evs]
+            if any(n.startswith("rmt.engine.") for n, _, _ in evs):
+                lines.append((plane.name, [e for e in evs if e[0].startswith(
+                    "rmt.engine.")]))
+    return lines, extent
+
+
+@pytest.mark.parametrize("name", ["step_wait", "assemble", "emit"])
+def test_phases_are_spans_on_one_line_of_the_host_plane(engine_line, name):
+    lines, extent = engine_line
+    # one thread, one line: all of the engine's spans lie together
+    assert len(lines) == 1, [p for p, _ in lines]
+    plane, evs = lines[0]
+    assert plane.startswith("/host:")
+    mine = [e for e in evs if e[0] == "rmt.engine." + name]
+    assert mine
+    lo, hi = min(a for a, _ in extent), max(b for _, b in extent)
+    assert all(lo <= a <= b <= hi for _, a, b in mine)
+    assert all(b > a for _, a, b in mine)
+    # no two phases overlap: each instant belongs to one of them
+    ordered = sorted(evs, key=lambda e: e[1])
+    for (_, _, end), (nxt, start, _) in zip(ordered, ordered[1:]):
+        assert end <= start, nxt
+    # in an iteration, assemble comes before the wait and the emit after
+    names = [n.rsplit(".", 1)[1] for n, _, _ in ordered]
+    at = names.index("step_wait")
+    assert "assemble" in names[:at] and names[at + 1] == "emit"
