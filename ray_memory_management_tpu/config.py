@@ -332,9 +332,9 @@ _flag("serve_backpressure_timeout_s", float, 60.0,
       "rmt_serve_shed_total{reason=backpressure_timeout}).")
 _flag("kv_page_tokens", int, 64,
       "KV-cache page size in tokens for the serve engine's paged "
-      "device cache: a slot's KV rows grow in pages of this many "
-      "positions instead of reserving max_seq up front, so HBM held by "
-      "a replica scales with live tokens.")
+      "device cache: a request reserves pages of this many positions "
+      "from one resident pool instead of max_seq up front, and the "
+      "decode step fetches only the pages a row's length reaches.")
 _flag("serve_kv_pool_bytes", int, 0,
       "Per-replica KV page-pool budget in bytes. 0 sizes the pool to "
       "the monolithic slab's footprint (max_slots x max_seq), so the "

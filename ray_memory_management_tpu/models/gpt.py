@@ -416,6 +416,85 @@ def forward_with_cache_rows(params, tokens, cache, offsets,
     return logits, {"k": k_new, "v": v_new}
 
 
+def forward_paged_decode(params, tokens, pool, positions, lengths,
+                         page_table, cfg: TransformerConfig):
+    """One decode token a row against a paged KV pool, read and written in
+    place (serve/kv_cache.py): ``pool`` is {"k", "v"} of
+    [L, Hkv, P, page_tokens, Dh] whose last page is the sink, ``page_table``
+    int32 [B, pages_per_row]. Row ``i``'s token ``tokens[i]`` sits at
+    absolute position ``positions[i]`` and attends over its first
+    ``lengths[i]`` cached positions and itself; an idle row has length 0 and
+    a table row of sink entries, so it reads nothing and writes the sink.
+
+    The layer loop only READS the pool (ops/paged_attention.py takes the new
+    token's K and V beside the pages), so nothing of the pool's size is
+    carried through it; the new K and V of all layers are written after it,
+    one position a row, at ``(page_table[i, positions[i] // page_tokens],
+    positions[i] % page_tokens)``, or in the sink where that lies beyond the
+    table. Returns (logits [B, V] fp32, updated pool).
+    """
+    from ..ops.paged_attention import paged_attention
+
+    page, width = pool["k"].shape[3], page_table.shape[1]
+    sink = pool["k"].shape[2] - 1
+    x = params["tok_embed"][tokens[:, None]].astype(cfg.dtype)   # [B, 1, D]
+
+    def scan_body(x, layer_and_index):
+        layer, index = layer_and_index
+
+        def paged_attn(q, k, v):                     # [B, 1, H(kv), Dh]
+            k, v = k[:, 0], v[:, 0]
+            with jax.named_scope("decode_attention"):
+                o = paged_attention(q[:, 0], pool["k"], pool["v"], lengths,
+                                    page_table, layer=index, k_cur=k,
+                                    v_cur=v)
+            return o[:, None], (k, v)
+
+        return apply_block(x, layer, cfg, attn_fn=paged_attn,
+                           positions=positions[:, None])
+
+    x, (k_new, v_new) = lax.scan(
+        scan_body, x, (params["layers"], jnp.arange(cfg.n_layers)))
+    with jax.named_scope("kv_write"):
+        at = positions // page
+        inside = jnp.minimum(at, width - 1)[:, None]
+        pages = jnp.where(
+            at < width,
+            jnp.take_along_axis(page_table, inside, axis=1)[:, 0], sink)
+        offs = positions % page
+
+        def write(pages_of, new):  # new [L, B, Hkv, Dh]
+            # one position a row, every layer and head of it, by reading
+            # the tile of 16 positions around it, patching and writing it
+            # back. Not one scatter, nor an update of the one position: for
+            # either the TPU compiler picks a layout of its own for the
+            # whole pool and copies the pool into it and back, every step
+            # (tests/test_chip_compile.py holds the layout)
+            new = new.transpose(1, 0, 2, 3)[:, :, :, None, None, :]
+            L, Hkv, Dh = new.shape[1], new.shape[2], new.shape[-1]
+            tile = 16 if page % 16 == 0 else 1
+            rows = jnp.arange(tile)[None, None, None, :, None]
+
+            def one(b, c):
+                base = offs[b] // tile * tile
+                at = (0, 0, pages[b], base, 0)
+                old = lax.dynamic_slice(c, at, (L, Hkv, 1, tile, Dh))
+                return lax.dynamic_update_slice(
+                    c, jnp.where(rows == offs[b] - base, new[b], old), at)
+
+            return lax.fori_loop(0, new.shape[0], one, pages_of)
+
+        pool = {"k": write(pool["k"], k_new), "v": write(pool["v"], v_new)}
+    with jax.named_scope("head_sample"):  # the engine's sampler joins it
+        x = _rmsnorm(x[:, 0], params["final_ln"])
+        logits = lax.dot_general(
+            x, params["lm_head"].astype(cfg.dtype),
+            (((x.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+    return logits, pool
+
+
 import functools
 
 

@@ -1,43 +1,38 @@
-"""Paged KV-cache page pool for the serve engine.
+"""Paged KV cache for the serve engine: one resident pool, a block table.
 
-The monolithic engine cache reserved ``max_slots x max_seq`` KV
-positions in HBM up front — a replica serving short requests paid the
-full worst case forever, and the only failure mode past that budget was
-an allocator OOM. This module is the paged replacement (the vLLM paged-
-attention memory-management idea, TPU-shaped): a slot's KV rows are
-allocated in pages of ``kv_page_tokens`` positions from a per-replica
-pool, held as **pinned device objects** in a dedicated
-:class:`~..core.device_store.DeviceObjectStore` so the HBM they occupy
-is first-class observable (``rmt_device_bytes_pinned`` /
-``rmt_serve_kv_pages_in_use`` move with every reserve/free):
+The KV cache of a replica is two device arrays, K and V, of shape
+``[L, Hkv, P, page_tokens, Dh]``: ``P - 1`` pages that requests reserve and
+one sink. They are allocated once (:meth:`KVPagePool.allocate`, by the engine
+thread before its first admission) and stay where they are: prefill scatters a
+prompt's K and V into the row's pages, the decode step writes one position a
+live row and attends through the block table
+(ops/paged_attention.py), both on the donated arrays, and nothing copies KV
+between iterations. The pool's bytes are therefore constant; what tracks the
+live requests is its *pages* (``rmt_serve_kv_pages_in_use``).
 
-  - :meth:`reserve` claims the pages a request's full lifetime needs
-    (prompt + token budget, page-aligned) at admission time; a ``False``
-    return is the engine's admission-backpressure signal — the request
-    stays queued until a retiring slot frees pages. The pool NEVER
-    overcommits, so decode can never hit an allocation failure mid-
-    request.
-  - :meth:`put_row` / :meth:`take_row` move a slot's live KV arrays in
-    and out of the device store between engine iterations; ``take_row``
-    uses the store's consume path (``take``) so the engine owns the sole
-    reference and can donate the buffers into its compiled step
-    (``donate_argnums`` aliases them instead of copying).
-  - :meth:`free` at retire deletes the slot's KV objects and returns its
-    pages — HBM held by a replica's cache scales with LIVE tokens, not
-    with ``max_slots x max_seq``.
+:class:`KVPagePool` is the allocator, all on the host:
 
-The pool's budget is enforced by page accounting, not by store
-eviction: the backing store runs with eviction disabled (demoting a
-live KV page to host shm would break the donation contract and stall
-decode); pressure surfaces as queueing, never as data movement.
+  - :meth:`reserve` claims the page ids a request's whole lifetime needs
+    (prompt bucket or prompt + token budget, page-aligned) at admission and
+    writes them into the row of the block table; a ``False`` return is the
+    engine's admission-backpressure signal — the request stays queued until
+    a retiring slot frees pages. The pool NEVER overcommits, so a decode
+    step can never run out of pages mid-request.
+  - :meth:`free` at retire returns the row's pages to the free list and
+    points its table entries back at the sink.
+  - the sink is one page beyond the budgeted ``capacity_pages`` that no live
+    row ever reads: every unreserved table entry points at it, so the writes
+    of idle rows, and of a row that overshoots its budget inside an
+    iteration of ``steps_per_iter``, land there and never in another
+    request's page.
 """
 
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List
 
-from ..core.device_store import DeviceObjectStore
+import numpy as np
 
 
 def row_token_bytes(cfg) -> int:
@@ -50,16 +45,17 @@ def row_token_bytes(cfg) -> int:
 
 
 class KVPagePool:
-    """Page-granular KV allocator over a device-object store.
+    """Page-granular KV allocator: a free list of page ids and the block
+    table ``int32 [max_slots, ceil(max_seq / page_tokens)]``.
 
     ``pool_bytes <= 0`` sizes the pool to the monolithic slab it
     replaces (``max_slots x max_seq`` positions), so the paged engine
-    can never hold more HBM than the old design's constant footprint.
+    can never hold more HBM than the old design's constant footprint
+    (plus the sink page).
     """
 
     def __init__(self, cfg, max_slots: int, page_tokens: int,
-                 pool_bytes: int = 0,
-                 store: Optional[DeviceObjectStore] = None):
+                 pool_bytes: int = 0):
         self.cfg = cfg
         self.page_tokens = max(1, int(page_tokens))
         self.token_bytes = row_token_bytes(cfg)
@@ -69,13 +65,32 @@ class KVPagePool:
         else:
             budget = max_slots * cfg.max_seq * self.token_bytes
         self.capacity_pages = max(1, budget // self.page_bytes)
-        # eviction disabled: the pool budget is enforced by page
-        # accounting and admission backpressure, never by demotion
-        self.store = store if store is not None else \
-            DeviceObjectStore(capacity_bytes=-1)
+        self.sink_page = self.capacity_pages  # the arrays' last page
+        self.table_width = -(-cfg.max_seq // self.page_tokens)
+        # the engine thread's: it alone reserves, frees and reads the table
+        self.table = np.full((max_slots, self.table_width), self.sink_page,
+                             np.int32)
         self._lock = threading.Lock()
-        self._row_pages: Dict[int, int] = {}  # guarded-by: _lock
-        self._peak_store_bytes = 0  # guarded-by: _lock
+        self._free: List[int] = list(  # guarded-by: _lock
+            range(self.capacity_pages - 1, -1, -1))  # pop() -> lowest id
+        self._row_pages: Dict[int, List[int]] = {}  # guarded-by: _lock
+        self._array_bytes = 0  # guarded-by: _lock
+
+    # -- the device arrays ----------------------------------------------------
+    def allocate(self) -> Dict[str, Any]:
+        """The pool's K and V arrays, zeroed. The caller (the engine) owns
+        them: they are donated to every prefill and decode program, and a
+        buffer that is donated cannot also be pinned in a store."""
+        import jax.numpy as jnp
+
+        cfg = self.cfg
+        shape = (cfg.n_layers, cfg.kv_heads, self.capacity_pages + 1,
+                 self.page_tokens, cfg.head_dim)
+        pool = {"k": jnp.zeros(shape, cfg.dtype),
+                "v": jnp.zeros(shape, cfg.dtype)}
+        with self._lock:
+            self._array_bytes = (self.capacity_pages + 1) * self.page_bytes
+        return pool
 
     # -- accounting -----------------------------------------------------------
     def pages_for(self, tokens: int) -> int:
@@ -86,89 +101,65 @@ class KVPagePool:
         return self.pages_for(tokens) * self.page_tokens
 
     def reserve(self, row: int, tokens: int) -> bool:
-        """Claim the pages ``row`` needs for ``tokens`` KV positions.
-        False = pool exhausted (admission backpressure)."""
+        """Claim the pages ``row`` needs for ``tokens`` KV positions and
+        put their ids in its table row. False = pool exhausted (admission
+        backpressure)."""
         need = self.pages_for(tokens)
+        if need > self.table_width:
+            raise ValueError(
+                f"{tokens} KV tokens need {need} pages, the block table "
+                f"holds {self.table_width} a row")
         with self._lock:
-            in_use = sum(self._row_pages.values()) \
-                - self._row_pages.get(row, 0)
-            if in_use + need > self.capacity_pages:
+            held = self._row_pages.get(row, [])
+            if need > len(self._free) + len(held):
                 return False
-            self._row_pages[row] = need
+            self._free.extend(reversed(held))
+            pages = [self._free.pop() for _ in range(need)]
+            self._row_pages[row] = pages
+        self.table[row] = self.sink_page
+        self.table[row, :need] = pages
         self._publish()
         return True
 
     def free(self, row: int) -> None:
-        """Return ``row``'s pages and drop its KV objects (the retire
-        path: the gauges fall by exactly this slot's live footprint)."""
+        """Return ``row``'s pages (the retire path); what they hold stays
+        in the arrays until the next owner's prefill overwrites it."""
         with self._lock:
-            self._row_pages.pop(row, None)
-        self.store.delete(self._oid(row, "k"))
-        self.store.delete(self._oid(row, "v"))
+            self._free.extend(reversed(self._row_pages.pop(row, [])))
+        self.table[row] = self.sink_page
         self._publish()
 
     def free_all(self) -> None:
         with self._lock:
-            rows = list(self._row_pages)
             self._row_pages.clear()
-        for row in rows:
-            self.store.delete(self._oid(row, "k"))
-            self.store.delete(self._oid(row, "v"))
+            self._free = list(range(self.capacity_pages - 1, -1, -1))
+        self.table[:] = self.sink_page
         self._publish()
-
-    # -- KV row movement ------------------------------------------------------
-    def put_row(self, row: int, cache: Dict[str, Any]) -> None:
-        """Pin a slot's live KV arrays in the device tier (between
-        engine iterations the store is the owner)."""
-        koid, void = self._oid(row, "k"), self._oid(row, "v")
-        self.store.put(koid, cache["k"])
-        self.store.put(void, cache["v"])
-        self.store.pin(koid)
-        self.store.pin(void)
-        pinned = self.store.total_bytes()
-        with self._lock:
-            self._peak_store_bytes = max(self._peak_store_bytes, pinned)
-
-    def take_row(self, row: int) -> Optional[Dict[str, Any]]:
-        """Consume a slot's KV arrays out of the store (donation read:
-        the engine gets the sole reference and feeds the buffers to its
-        ``donate_argnums`` step)."""
-        k = self.store.take(self._oid(row, "k"))
-        v = self.store.take(self._oid(row, "v"))
-        if k is None or v is None:
-            return None
-        return {"k": k, "v": v}
 
     # -- introspection --------------------------------------------------------
     @property
     def pages_in_use(self) -> int:
         with self._lock:
-            return sum(self._row_pages.values())
+            return self.capacity_pages - len(self._free)
 
     def row_tokens(self, row: int) -> int:
         with self._lock:
-            return self._row_pages.get(row, 0) * self.page_tokens
-
-    def bytes_in_use(self) -> int:
-        return self.pages_in_use * self.page_bytes
+            return len(self._row_pages.get(row, ())) * self.page_tokens
 
     def stats(self) -> Dict[str, int]:
         with self._lock:
-            pages = sum(self._row_pages.values())
-            peak = self._peak_store_bytes
+            pages = self.capacity_pages - len(self._free)
+            array_bytes = self._array_bytes
         return {
             "page_tokens": self.page_tokens,
             "page_bytes": self.page_bytes,
             "capacity_pages": self.capacity_pages,
             "pages_in_use": pages,
             "bytes_in_use": pages * self.page_bytes,
-            "store_bytes": self.store.total_bytes(),
-            "peak_store_bytes": peak,
+            # the resident arrays, sink included: constant once allocated
+            "store_bytes": array_bytes,
+            "peak_store_bytes": array_bytes,
         }
-
-    @staticmethod
-    def _oid(row: int, part: str) -> bytes:
-        return f"serve.kv.{part}.{row}".encode()
 
     def _publish(self) -> None:
         try:

@@ -33,7 +33,8 @@ from ..utils.profiling import phase
 from .deployment import deployment
 
 # the engine thread is in exactly one of these at every instant (see
-# ContinuousBatcher._loop)
+# ContinuousBatcher._loop). "disassemble" reads 0 since the programs write
+# the KV in place; readers index the phases by name, so the name stays
 ENGINE_PHASES = ("idle_wait", "gate", "prefill", "assemble",
                  "step_dispatch", "step_wait", "emit", "disassemble")
 
@@ -156,19 +157,21 @@ class ContinuousBatcher:
     batch approximation (a short row conditioning on its repeated final
     token) is gone.
 
-    KV memory is PAGED by default (``kv_cache="paged"``): instead of a
-    monolithic ``max_slots x max_seq`` slab pinned forever, each admitted
-    request reserves page-aligned KV capacity for its own lifetime
-    (prompt + budget) from a :class:`~.kv_cache.KVPagePool` of pinned
-    device objects. Between iterations the pool's device store owns every
-    live slot's KV rows; each iteration consumes them (``take`` — a
-    donation read), packs them into one working slab whose sequence
-    capacity is the page-aligned max over LIVE slots (not ``max_seq``),
-    runs the donated compiled step, and pins the surviving rows back.
-    ``_retire`` frees the slot's pages, so a replica's HBM tracks live
-    tokens; pool exhaustion defers admission (backpressure) instead of
-    OOMing. ``kv_cache="slab"`` keeps the old monolithic layout for A/B
-    benchmarking.
+    KV memory is PAGED by default (``kv_cache="paged"``): the cache is one
+    resident pool of pages on the device (:class:`~.kv_cache.KVPagePool`:
+    K and V as ``[L, Hkv, P, page_tokens, Dh]``, allocated once by the
+    engine thread) addressed through a block table. Each admitted request
+    reserves the page ids of its own lifetime (prompt + budget, page
+    aligned); prefill scatters the prompt's K and V into those pages, and
+    every iteration runs ONE compiled decode program, the same for the
+    engine's lifetime, that attends through the table
+    (ops/paged_attention.py) and writes one position a live row, all on the
+    donated pool: nothing copies KV between iterations, and the host's part
+    of an iteration is the table and the lengths of the live slots.
+    ``_retire`` returns the slot's pages, so the pool's *pages* track live
+    requests while its bytes stay constant; pool exhaustion defers
+    admission (backpressure) instead of OOMing. ``kv_cache="slab"`` keeps
+    the old monolithic ``max_slots x max_seq`` layout for A/B benchmarking.
     """
 
     def __init__(self, params, cfg, max_slots: int = 8,
@@ -216,7 +219,10 @@ class ContinuousBatcher:
         else:
             self.kv_pool = None
             self._cache = gpt.init_kv_cache(cfg, max_slots, cfg.max_seq)
-        self._prefill_cache: Dict[Any, Any] = {}  # bucket[, cap] -> fn
+        # paged: the pool's K and V arrays, the engine thread's between
+        # programs (allocated at its first admission, donated to each)
+        self._pool: Optional[Dict[str, Any]] = None
+        self._prefill_cache: Dict[Any, Any] = {}  # bucket -> fn
 
         def _sample(logits, key):
             if self.temperature > 0:
@@ -244,13 +250,36 @@ class ContinuousBatcher:
         self._step = jax.jit(step_fn, donate_argnums=(1,))
         self._sample = _sample
 
+        def paged_step_fn(params, pool, last, offsets, table, key):
+            # a slot with nothing in its cache is idle: it reads nothing
+            # and its writes go to the sink its table row points at
+            live = offsets > 0
+
+            def body(carry, t):
+                pool, last, key = carry
+                key, sub = jax.random.split(key)
+                logits, pool = gpt.forward_paged_decode(
+                    params, last, pool, offsets + t,
+                    jnp.where(live, offsets + t, 0), table, cfg)
+                with jax.named_scope("head_sample"):
+                    nxt = _sample(logits, sub)
+                return (pool, nxt, key), nxt
+
+            (pool, _, _), toks = jax.lax.scan(
+                body, (pool, last, key), jnp.arange(K))
+            return pool, toks  # [K, B]
+
+        # the paged mode's one decode program: its shapes are the
+        # constructor's (max_slots, the table's width, the pool's size),
+        # whichever rows are live and however long they are
+        self._paged_step = jax.jit(paged_step_fn, donate_argnums=(1,))
+
         # slot state (host side)
         self._slot_pending: List[Optional[_Pending]] = [None] * max_slots
         self._slot_offset = np.zeros(max_slots, np.int32)
         self._slot_last = np.ones(max_slots, np.int32)
         self._slot_out: List[List[int]] = [[] for _ in range(max_slots)]
         self._slot_budget = np.zeros(max_slots, np.int32)
-        self._slot_cap = np.zeros(max_slots, np.int32)  # paged: reserved
         self.kv_backpressure = 0  # admissions deferred on pool exhaustion
 
         self._q: List[_Pending] = []
@@ -358,27 +387,35 @@ class ContinuousBatcher:
         self._prefill_cache[bucket] = fn
         return fn
 
-    def _paged_prefill_fn(self, bucket: int, cap: int):
-        """Prefill into a FRESH single-row cache of seq capacity ``cap``
-        (the slot's page-aligned reservation) — no slab to splice into;
-        the row cache becomes the slot's pooled KV object. Compiled per
-        (bucket, cap) pair; both are page/pad-aligned so the variant set
-        stays small."""
+    def _paged_prefill_fn(self, bucket: int):
+        """Prefill one prompt of ``bucket`` tokens and scatter its K and V
+        into the row's pages of the donated pool. Compiled per bucket: the
+        reservation's size does not enter, only the table row does."""
         jax, jnp, gpt, cfg = self._jax, self._jnp, self._gpt, self.cfg
-        key_ = ("paged", bucket, cap)
-        fn = self._prefill_cache.get(key_)
+        fn = self._prefill_cache.get(bucket)
         if fn is not None:
             return fn
+        page = self.kv_pool.page_tokens
+        n_pages = self.kv_pool.pages_for(bucket)
 
-        def prefill(params, tokens, true_len, key):
-            row_cache = gpt.init_kv_cache(cfg, 1, cap)
+        def prefill(params, pool, tokens, table_row, true_len, key):
+            row_cache = gpt.init_kv_cache(cfg, 1, n_pages * page)
             logits, row_cache = gpt.forward_with_cache_rows(
                 params, tokens, row_cache, jnp.zeros((1,), jnp.int32), cfg)
-            first = self._sample(logits[0, true_len - 1][None], key)[0]
-            return row_cache, first
 
-        fn = jax.jit(prefill)
-        self._prefill_cache[key_] = fn
+            def paged(c):  # [L, 1, Hkv, n * page, Dh] -> whole pages
+                return c[:, 0].reshape(c.shape[0], c.shape[2], n_pages,
+                                       page, c.shape[4])
+
+            pages = table_row[:n_pages]
+            pool = jax.tree.map(
+                lambda whole, c: whole.at[:, :, pages].set(paged(c)),
+                pool, row_cache)
+            first = self._sample(logits[0, true_len - 1][None], key)[0]
+            return pool, first
+
+        fn = jax.jit(prefill, donate_argnums=(1,))
+        self._prefill_cache[bucket] = fn
         return fn
 
     def _admit(self, p: _Pending, row: int) -> None:
@@ -397,10 +434,13 @@ class ContinuousBatcher:
         # per-row mask stops at true_len and decode overwrites those slots
         self._key, sub = self._jax.random.split(self._key)
         if self.kv_pool is not None:
-            cap = int(self._slot_cap[row])  # reserved by the admit gate
-            row_cache, first = self._paged_prefill_fn(bucket, cap)(
-                self.params, jnp.asarray(arr), jnp.int32(len(toks)), sub)
-            self.kv_pool.put_row(row, row_cache)
+            if self._pool is None:  # the engine's first admission
+                self._pool = self.kv_pool.allocate()
+            # the row's pages were reserved by the admit gate
+            self._pool, first = self._paged_prefill_fn(bucket)(
+                self.params, self._pool, jnp.asarray(arr),
+                jnp.asarray(self.kv_pool.table[row]),
+                jnp.int32(len(toks)), sub)
         else:
             self._cache, first = self._prefill_fn(bucket)(
                 self.params, self._cache, jnp.asarray(arr),
@@ -417,11 +457,9 @@ class ContinuousBatcher:
         self._slot_offset[row] = 0
         self._slot_last[row] = 1
         if self.kv_pool is not None:
-            # pages return to the pool and the slot's KV objects drop out
-            # of the device tier: rmt_device_bytes_pinned falls by this
-            # slot's live footprint, and a queued request can now reserve
+            # the slot's pages return to the free list and its table row
+            # to the sink: a queued request can now reserve them
             self.kv_pool.free(row)
-            self._slot_cap[row] = 0
         if p is not None:
             p.result = self._slot_out[row]
             p.t_done = time.time()
@@ -445,54 +483,15 @@ class ContinuousBatcher:
                 name, "serve", start, end, extra=extra,
                 trace=tracing.child_of(p.trace) if p.trace else None)
 
-    def _slab_len(self, active: List[int]) -> int:
-        """Sequence capacity of the slab the decode step runs over: the
-        longest reservation among the live slots, or the whole cache."""
+    def _fetched_positions(self, active: List[int]) -> int:
+        """KV positions the decode step fetches this iteration: each live
+        row's pages up to its last step's length (slab mode: the whole
+        cache, every row)."""
         if self.kv_pool is None:
-            return self.cfg.max_seq
-        return max(int(self._slot_cap[r]) for r in active)
-
-    def _assemble(self, active: List[int]):
-        """Consume every active slot's pooled KV rows (``take`` — the
-        store drops its reference so the step can DONATE the buffers) and
-        pack them into one working slab whose seq capacity is the page-
-        aligned max over LIVE slots — not ``max_seq``. Batch dim stays
-        ``max_slots`` so the compiled step only re-specializes on S."""
-        jnp, cfg = self._jnp, self.cfg
-        S = self._slab_len(active)
-        active_set = set(active)
-        zeros = None
-        parts_k, parts_v = [], []
-        for r in range(self.max_slots):
-            rc = self.kv_pool.take_row(r) if r in active_set else None
-            if rc is None:  # idle slot: a zero row keeps shapes static
-                if zeros is None:
-                    zeros = jnp.zeros(
-                        (cfg.n_layers, 1, cfg.kv_heads, S, cfg.head_dim),
-                        jnp.dtype(cfg.dtype))
-                parts_k.append(zeros)
-                parts_v.append(zeros)
-                continue
-            cap = int(self._slot_cap[r])
-            if cap < S:
-                pad = ((0, 0), (0, 0), (0, 0), (0, S - cap), (0, 0))
-                rc = {"k": jnp.pad(rc["k"], pad),
-                      "v": jnp.pad(rc["v"], pad)}
-            parts_k.append(rc["k"])
-            parts_v.append(rc["v"])
-        return {"k": jnp.concatenate(parts_k, axis=1),
-                "v": jnp.concatenate(parts_v, axis=1)}
-
-    def _disassemble(self, cache, rows: List[int]) -> None:
-        """Slice each surviving slot's reserved capacity back out of the
-        working slab and pin it in the pool; the slab itself is dropped
-        (retired slots' rows simply are not put back — that plus
-        ``_retire``'s free() is how HBM tracks live tokens)."""
-        for r in rows:
-            cap = int(self._slot_cap[r])
-            self.kv_pool.put_row(r, {
-                "k": cache["k"][:, r:r + 1, :, :cap, :],
-                "v": cache["v"][:, r:r + 1, :, :cap, :]})
+            return self.max_slots * self.cfg.max_seq
+        page = self.kv_pool.page_tokens
+        ends = self._slot_offset[active] + self.steps_per_iter
+        return int((-(-ends // page) * page).sum())
 
     def _admit_gate(self) -> List:
         """Pop admissible queued requests (head-of-line FIFO) into free
@@ -531,7 +530,6 @@ class ContinuousBatcher:
                 except Exception:  # noqa: BLE001
                     pass
                 break
-            self._slot_cap[row] = need
             admits.append((self._q.pop(0), row))
         now = time.time()
         for p, _ in admits:
@@ -561,7 +559,7 @@ class ContinuousBatcher:
                     self._slot_pending = [None] * self.max_slots
                     if self.kv_pool is not None:
                         self.kv_pool.free_all()
-                        self._slot_cap[:] = 0
+                        self._pool = None  # the arrays leave the device
                     for p in victims:
                         p.error = RuntimeError("engine closed")
                         p.event.set()
@@ -573,7 +571,8 @@ class ContinuousBatcher:
                     with phase(acc, "prefill",
                                bucket=self._bucket_for(
                                    self._clip_tokens(p.item[0])),
-                               cap=int(self._slot_cap[row])):
+                               cap=self.kv_pool.row_tokens(row)
+                               if self.kv_pool is not None else 0):
                         try:
                             self._admit(p, row)
                         except faults.FaultInjected as e:
@@ -582,7 +581,6 @@ class ContinuousBatcher:
                             # reservation and keep admitting
                             if self.kv_pool is not None:
                                 self.kv_pool.free(row)
-                                self._slot_cap[row] = 0
                             self._slot_pending[row] = None
                             p.error = e
                             p.event.set()
@@ -599,21 +597,28 @@ class ContinuousBatcher:
                 if not active:
                     self._publish()
                     continue
-                S = self._slab_len(active)
-                with phase(acc, "assemble", rows=len(active), S=S):
+                with phase(acc, "assemble", rows=len(active)):
                     self._key, sub = self._jax.random.split(self._key)
-                    cache = self._assemble(active) \
-                        if self.kv_pool is not None else self._cache
-                    # what the step attends over, and how much of it is live
+                    # paged: the host's part is the live slots' lengths and
+                    # table rows; the KV stays where it is
+                    last = jnp.asarray(self._slot_last)
+                    offsets = jnp.asarray(self._slot_offset)
+                    if self.kv_pool is not None:
+                        table = jnp.asarray(self.kv_pool.table)
+                    # what the step fetches, and how much of it is live
                     counts["iterations"] += 1
-                    counts["slab_positions"] += self.max_slots * S
+                    counts["slab_positions"] += self._fetched_positions(
+                        active)
                     counts["live_positions"] += int(
                         self._slot_offset[active].sum())
                 with phase(acc, "step_dispatch"):
-                    cache, toks = self._step(
-                        self.params, cache,
-                        jnp.asarray(self._slot_last),
-                        jnp.asarray(self._slot_offset), sub)
+                    if self.kv_pool is not None:
+                        self._pool, toks = self._paged_step(
+                            self.params, self._pool, last, offsets, table,
+                            sub)
+                    else:
+                        self._cache, toks = self._step(
+                            self.params, self._cache, last, offsets, sub)
                 with phase(acc, "step_wait"):
                     toks = np.asarray(toks)  # [K, B]
                 with phase(acc, "emit"):
@@ -621,9 +626,8 @@ class ContinuousBatcher:
                     for r in active:
                         # a row finishing mid-iteration consumes only what
                         # its budget allows; the surplus decoded junk wrote
-                        # into its OWN cache rows beyond its end, which the
-                        # per-row mask keeps invisible and retire/prefill
-                        # discards
+                        # beyond its end, into its OWN cache row or pages or
+                        # into the sink, where the lengths keep it invisible
                         take = min(self.steps_per_iter,
                                    int(self._slot_budget[r]))
                         self._slot_out[r].extend(
@@ -633,13 +637,6 @@ class ContinuousBatcher:
                         self._slot_budget[r] -= take
                         if self._slot_budget[r] <= 0:
                             self._retire(r)
-                with phase(acc, "disassemble"):
-                    if self.kv_pool is not None:
-                        self._disassemble(cache, [
-                            r for r in active
-                            if self._slot_pending[r] is not None])
-                    else:
-                        self._cache = cache
             except BaseException as e:  # noqa: BLE001 — fail loudly to
                 # every parked caller, keep serving
                 with phase(acc, "emit"):
@@ -650,7 +647,9 @@ class ContinuousBatcher:
                         self._q.clear()
                     if self.kv_pool is not None:
                         self.kv_pool.free_all()
-                        self._slot_cap[:] = 0
+                        # a program that failed may have consumed the
+                        # donated arrays: the next admission allocates anew
+                        self._pool = None
                     for p in victims:
                         p.error = e
                         p.event.set()
@@ -669,8 +668,9 @@ class ContinuousBatcher:
         """Where the engine thread's time went and what the decode step
         attended over, cumulative since the engine started (a reader
         subtracts two snapshots): wall and thread-CPU seconds by phase,
-        iterations, KV positions of the padded slab and the live ones among
-        them (both summed at assembly), requests admitted, and
+        iterations, KV positions the step fetches (paged: each live row's
+        pages up to its last step's length; slab: the whole cache) and the
+        live ones among them (both summed at assembly), requests admitted, and
         ``(queue_wait_s, prefill_s)`` of the newest 512 of them, oldest
         first. Any thread may call it; the copy is the caller's."""
         snap = self._published

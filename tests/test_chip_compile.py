@@ -101,3 +101,87 @@ def test_kernel_in_a_tp_sharded_jit_needs_the_mesh(topo):
         lambda q, k, v: flash_attention(q, k, v, use_pallas="on",
                                         mesh=mesh)).lower(x, x, x).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+# ------------------------------------------------- the paged decode path
+# (Hkv, heads a group, page, table width): Mistral-7B's GQA at chat-online's
+# page, MHA at a small page, a wide group
+PAGED = [(8, 4, 256, 16), (4, 1, 16, 8), (2, 16, 128, 4)]
+
+
+@pytest.mark.parametrize("hkv,group,page,width", PAGED,
+                         ids=lambda v: str(v))
+def test_paged_attention_kernel_compiles_for_v5e(one_chip, hkv, group, page,
+                                                 width):
+    from ray_memory_management_tpu.ops.paged_attention import paged_attention
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    B, L, P_, Dh = 16, 2, 9, 128
+    pool = s((L, hkv, P_, page, Dh))
+    compiled = jax.jit(
+        lambda q, k, v, n, t, layer, kc, vc: paged_attention(
+            q, k, v, n, t, layer=layer, k_cur=kc, v_cur=vc,
+            use_pallas="on")).lower(
+        s((B, hkv * group, Dh)), pool, pool, s((B,), jnp.int32),
+        s((B, width), jnp.int32), s((), jnp.int32), s((B, hkv, Dh)),
+        s((B, hkv, Dh))).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def test_paged_step_keeps_the_pool_where_it_is(one_chip, monkeypatch):
+    """The engine's one decode program at chat-online's size (Mistral-7B
+    widths, 16 layers, 16 slots, 96 pages of 256 and the sink): the kernel
+    is in it, every value of the pool's shape keeps the layout the pool
+    came in, and none is a copy. (A scatter or a one-row
+    dynamic_update_slice of the new K and V made the compiler pick its own
+    layout for the pool and copy 1.5 GiB into it and back every step.)"""
+    import re
+
+    from ray_memory_management_tpu.models import gpt
+    from ray_memory_management_tpu.ops import paged_attention as pa
+    from ray_memory_management_tpu.serve.kv_cache import row_token_bytes
+    from ray_memory_management_tpu.serve.llm import ContinuousBatcher
+
+    # the dispatch asks where default computation lands: steer it here
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    cfg = gpt.TransformerConfig(
+        vocab_size=32_000, d_model=4096, n_layers=16, n_heads=32,
+        n_kv_heads=8, d_ff=14336, max_seq=4096, param_dtype=jnp.bfloat16)
+    slots = 16
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    eng = ContinuousBatcher(
+        None, cfg, max_slots=slots, max_new_tokens=384, pad_multiple=256,
+        steps_per_iter=8, kv_page_tokens=256,
+        kv_pool_bytes=slots * 1536 * row_token_bytes(cfg))
+    try:
+        params = shaped(jax.eval_shape(
+            lambda: gpt.init_params(jax.random.PRNGKey(0), cfg)))
+        pool = shaped(jax.eval_shape(eng.kv_pool.allocate))
+        assert pool["k"].shape == (16, 8, 97, 256, 128)
+        compiled = eng._paged_step.lower(
+            params, pool, arr((slots,)), arr((slots,)),
+            arr((slots, eng.kv_pool.table_width)),
+            arr((2,), jnp.uint32)).compile()
+    finally:
+        eng.close()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    made = re.findall(r"= bf16\[16,8,97,256,128\]\{([\d,]+)[^ ]* (\S+?)\(",
+                      text)
+    assert made and {layout for layout, _ in made} == {"4,3,2,1,0"}
+    assert "copy" not in {op for _, op in made}
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    # 7.0 GiB of weights, 1.5 GiB of pool, under 1 GiB of temporaries
+    assert 8.4 * 2 ** 30 < total < 9.6 * 2 ** 30
+    assert m.alias_size_in_bytes >= 2 * pool["k"].size * 2  # donated

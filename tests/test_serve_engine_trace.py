@@ -116,12 +116,13 @@ def test_counts_only_grow_and_read_whole_from_eight_threads(model, mode):
                 # are never seen apart
                 assert len(st["recent"]) == min(st["admitted"], 512)
                 assert st["live_positions"] <= st["slab_positions"]
-                per_row = st["slab_positions"] // MAX_SLOTS
-                if mode == "slab":
-                    assert per_row == st["iterations"] * cfg.max_seq
-                else:
-                    assert per_row % PAGE == 0
-                    assert per_row >= st["iterations"] * PAGE
+                whole = st["iterations"] * MAX_SLOTS * cfg.max_seq
+                if mode == "slab":  # every row's whole cache, each time
+                    assert st["slab_positions"] == whole
+                else:  # the live rows' pages up to their last step's length
+                    assert st["slab_positions"] % PAGE == 0
+                    assert st["iterations"] * PAGE <= st[
+                        "slab_positions"] <= whole
                 st["phase_s"].clear()  # the caller's own copy
                 last = eng.engine_stats()
         except BaseException as e:  # noqa: BLE001 — reported below

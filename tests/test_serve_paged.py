@@ -48,43 +48,218 @@ def engine_setup():
 
 # --- paged KV accounting -----------------------------------------------------
 
+def _stopped_engine(llm_mod, params, cfg, **kw):
+    """An engine whose thread has exited: the test drives admit, step and
+    retire itself, so the assertions bracket them deterministically."""
+    eng = llm_mod.ContinuousBatcher(params, cfg, kv_cache="paged", **kw)
+    eng.close()
+    eng._thread.join(30)
+    assert not eng._thread.is_alive()
+    return eng
+
+
+def _admit(eng, llm_mod, row, tokens, budget):
+    p = llm_mod._Pending((list(tokens), budget))
+    need = eng._need_tokens(p)
+    assert eng.kv_pool.reserve(row, need)
+    eng._admit(p, row)
+    return p, need
+
+
 class TestPagedKV:
-    def test_retire_frees_pages_and_pinned_bytes(self, engine_setup):
-        """The headline memory contract: a slot's KV pages are pinned
-        device objects while the request lives, and BOTH gauges
-        (rmt_device_bytes_pinned, rmt_serve_kv_pages_in_use) fall back
-        to zero at retire — HBM tracks live tokens, not max_slots x
-        max_seq. Driven directly (engine thread stopped) so admit/retire
-        bracket the assertions deterministically."""
+    def test_retire_frees_pages_and_the_pools_bytes_stay(self, engine_setup):
+        """The memory contract of the resident pool: its arrays are
+        allocated at the first admission and not before, a request holds
+        page ids while it lives, the page gauge
+        (rmt_serve_kv_pages_in_use) falls back to zero at retire, and the
+        pool's bytes are the same before and after: what tracks live
+        requests is pages, not bytes."""
         from ray_memory_management_tpu.serve import llm as llm_mod
 
         gpt, cfg, params = engine_setup
-        eng = llm_mod.ContinuousBatcher(
-            params, cfg, max_slots=2, max_new_tokens=4, pad_multiple=8,
-            kv_cache="paged", kv_page_tokens=16)
-        eng.close()
-        eng._thread.join(30)
-        assert not eng._thread.is_alive()
+        eng = _stopped_engine(llm_mod, params, cfg, max_slots=2,
+                              max_new_tokens=4, pad_multiple=8,
+                              kv_page_tokens=16)
+        pool = eng.kv_pool
+        assert eng._pool is None and eng.kv_stats()["store_bytes"] == 0
+        assert (pool.table == pool.sink_page).all()
 
-        p = llm_mod._Pending(([5, 9, 17, 3], 4))
-        need = eng._need_tokens(p)
-        assert eng.kv_pool.reserve(0, need)
-        eng._slot_cap[0] = need
-        eng._admit(p, 0)
-
-        assert eng.kv_pool.pages_in_use == eng.kv_pool.pages_for(need)
-        live_bytes = eng.kv_pool.store.total_bytes()
-        assert live_bytes > 0
-        assert mdefs.device_bytes_pinned().get() == float(live_bytes)
-        assert mdefs.serve_kv_pages_in_use().get() == \
-            float(eng.kv_pool.pages_for(need))
+        p, need = _admit(eng, llm_mod, 0, [5, 9, 17, 3], 4)
+        pages = pool.pages_for(need)
+        assert pool.pages_in_use == pages
+        assert mdefs.serve_kv_pages_in_use().get() == float(pages)
+        owned = pool.table[0, :pages]
+        assert (owned != pool.sink_page).all() and len(set(owned)) == pages
+        assert (pool.table[0, pages:] == pool.sink_page).all()
+        assert (pool.table[1] == pool.sink_page).all()
+        # K and V, every budgeted page and the sink
+        whole = (pool.capacity_pages + 1) * pool.page_bytes
+        assert eng._pool["k"].nbytes + eng._pool["v"].nbytes == whole
+        kv = eng.kv_stats()
+        assert kv["store_bytes"] == kv["peak_store_bytes"] == whole
+        assert kv["bytes_in_use"] == pages * pool.page_bytes
 
         eng._retire(0)
         assert p.event.is_set() and p.result  # request completed
-        assert eng.kv_pool.pages_in_use == 0
-        assert eng.kv_pool.store.total_bytes() == 0
-        assert mdefs.device_bytes_pinned().get() == 0.0
+        assert pool.pages_in_use == 0
         assert mdefs.serve_kv_pages_in_use().get() == 0.0
+        assert (pool.table == pool.sink_page).all()
+        kv = eng.kv_stats()
+        assert kv["store_bytes"] == kv["peak_store_bytes"] == whole
+        assert kv["bytes_in_use"] == 0
+
+    @pytest.mark.parametrize("kv_heads", [None, 1], ids=["mha", "gqa"])
+    @pytest.mark.parametrize("page", [8, 16])
+    def test_interleaved_on_shuffled_pages_is_token_exact(
+            self, engine_setup, kv_heads, page):
+        """Two requests decode side by side, their pages handed out in a
+        shuffled order (so neither row's pages are contiguous or
+        ascending): each answer is the one ``gpt.generate`` gives alone."""
+        import random
+
+        import jax
+        import numpy as np
+
+        from ray_memory_management_tpu.serve.llm import ContinuousBatcher
+
+        gpt, cfg, params = engine_setup
+        if kv_heads is not None:
+            cfg = gpt.TransformerConfig(
+                vocab_size=128, n_layers=2, n_heads=2, n_kv_heads=kv_heads,
+                d_model=32, max_seq=128)
+            params = gpt.init_params(jax.random.PRNGKey(1), cfg)
+        eng = ContinuousBatcher(params, cfg, max_slots=2, max_new_tokens=24,
+                                pad_multiple=8, steps_per_iter=4,
+                                kv_page_tokens=page)
+        seen = {}
+        reserve = eng.kv_pool.reserve
+
+        def spy(row, tokens):
+            ok = reserve(row, tokens)
+            seen[row] = list(eng.kv_pool.table[row])
+            return ok
+
+        try:
+            # the engine thread is idle until the first submit
+            random.Random(page).shuffle(eng.kv_pool._free)
+            eng.kv_pool.reserve = spy
+            prompts = [list(range(3, 3 + 21)), list(range(40, 40 + 9))]
+            budgets, res = [24, 17], [None, None]
+
+            def go(i):
+                res[i] = eng.submit(prompts[i], max_new_tokens=budgets[i])
+
+            ts = [threading.Thread(target=go, args=(i,)) for i in range(2)]
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(300)
+            for i in range(2):
+                ref = np.asarray(gpt.generate(
+                    params, cfg, np.asarray([prompts[i]], np.int32),
+                    steps=budgets[i]))
+                assert res[i] == ref[0, len(prompts[i]):].tolist(), i
+        finally:
+            eng.close()
+        sink = eng.kv_pool.sink_page
+        owned = [[pg for pg in seen[r] if pg != sink] for r in (0, 1)]
+        assert len(owned[0]) >= 2 and not set(owned[0]) & set(owned[1])
+        assert any(sorted(o) != o or o[-1] - o[0] != len(o) - 1
+                   for o in owned)  # really shuffled
+
+    def test_overshoot_lands_in_the_sink_not_in_a_neighbour(
+            self, engine_setup):
+        """Row 0's budget ends two tokens into an iteration of eight; its
+        reservation ends with them. The six positions it decodes past
+        that go to the sink: row 1's pages, row 0's own history and every
+        free page are bit for bit what they were."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from ray_memory_management_tpu.serve import llm as llm_mod
+
+        gpt, cfg, params = engine_setup
+        K, page = 8, 8
+        eng = _stopped_engine(llm_mod, params, cfg, max_slots=3,
+                              max_new_tokens=16, pad_multiple=8,
+                              steps_per_iter=K, kv_page_tokens=page)
+        pool = eng.kv_pool
+        # prompt 5 + budget 3: one page, full after the two decode steps
+        _admit(eng, llm_mod, 0, range(2, 7), 3)
+        _admit(eng, llm_mod, 1, range(20, 31), 16)
+        assert pool.pages_for(eng.kv_pool.row_tokens(0)) == 1
+        before = {n: np.asarray(a, np.float32)
+                  for n, a in eng._pool.items()}
+        off = eng._slot_offset.copy()
+        eng._pool, toks = eng._paged_step(
+            params, eng._pool, jnp.asarray(eng._slot_last),
+            jnp.asarray(eng._slot_offset), jnp.asarray(pool.table),
+            eng._key)
+        assert np.asarray(toks).shape == (K, 3)
+        sink, mine = pool.sink_page, pool.table[0, 0]
+        theirs = [pg for pg in pool.table[1] if pg != sink]
+        free = [pg for pg in range(pool.capacity_pages)
+                if pg != mine and pg not in theirs]
+        for name, was in before.items():
+            now = np.asarray(eng._pool[name], np.float32)
+            # row 1 wrote its own K positions and nothing else of its pages
+            flat = lambda a: a[:, :, theirs].reshape(  # noqa: E731
+                a.shape[0], a.shape[1], -1, a.shape[-1])
+            wrote = slice(int(off[1]), int(off[1]) + K)
+            keep = np.ones(len(theirs) * page, bool)
+            keep[wrote] = False
+            np.testing.assert_array_equal(flat(now)[:, :, keep],
+                                          flat(was)[:, :, keep])
+            assert np.abs(flat(now)[:, :, wrote]).min(axis=-1).max() > 0
+            # row 0: its history stands, its page took positions 5, 6, 7
+            np.testing.assert_array_equal(now[:, :, mine, :off[0]],
+                                          was[:, :, mine, :off[0]])
+            assert (now[:, :, mine, off[0]:] != was[:, :, mine, off[0]:]
+                    ).any(axis=(0, 1, 3)).all()
+            np.testing.assert_array_equal(now[:, :, free], was[:, :, free])
+            # the overshoot (and the idle row 2) went to the sink
+            assert (now[:, :, sink] != was[:, :, sink]).any()
+
+    def test_one_decode_program_and_one_prefill_a_bucket(self):
+        """``stats()["compile"]``: the first request of a bucket builds
+        that bucket's prefill, the very first the decode step too; after
+        that no length, budget, page count or mix of live rows asks the
+        compiler for anything."""
+        from ray_memory_management_tpu.serve.llm import LLMServer
+
+        srv = LLMServer(preset="test", max_batch_size=4, max_new_tokens=24,
+                        pad_multiple=16, steps_per_iter=4,
+                        kv_page_tokens=16)
+        eng = srv._engine
+        try:
+            def programs():
+                return srv.stats()["compile"]["programs"]
+
+            srv.generate(list(range(2, 12)), max_new_tokens=9)  # bucket 16
+            assert set(eng._prefill_cache) == {16}
+            first = programs()
+            srv.generate(list(range(2, 22)), max_new_tokens=9)  # bucket 32
+            assert set(eng._prefill_cache) == {16, 32}
+            # one program more: the new bucket's prefill, and no new step
+            assert programs() == first + 1
+            warm = programs()
+
+            def go(i):
+                srv.generate(list(range(2, 4 + 3 * i)),
+                             max_new_tokens=3 + 2 * (i % 9))
+
+            ts = [threading.Thread(target=go, args=(i,)) for i in range(10)]
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(300)
+            assert not any(t.is_alive() for t in ts)
+            assert srv.stats()["requests"] == 12
+            assert programs() == warm
+            assert set(eng._prefill_cache) == {16, 32}
+            assert eng._paged_step._cache_size() == 1
+        finally:
+            eng.close()
 
     def test_pool_exhaustion_backpressures_never_fails(self, engine_setup):
         """More concurrent requests than the page pool fits: admissions
