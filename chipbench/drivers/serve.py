@@ -1,10 +1,13 @@
 """The serve driver: one replica on a leased chip behind ``serve.run``.
 
-The benchmark process stays off the chip. It deploys :class:`ChipLLMServer`
-(the program's ``LLMServer`` with two bridges: a configuration taken from the
-cell's file, and the calls only the chip holder can serve: trace, memory,
-the output check), warms the cell's shapes through ``handle.remote``, then
-offers the mix's load from this one thread for the window and drains it.
+The benchmark process stays off the chip. It deploys :func:`chip_server`'s
+class (the server class of the configuration's architecture, the program's
+``LLMServer`` for the dense decoder, under :class:`ChipServer`'s two bridges:
+a configuration taken from the cell's file, and the calls only the chip
+holder can serve: trace, memory, the output check), warms the cell's shapes
+through ``handle.remote``, then offers the mix's load from this one thread
+for the window and drains it. What is particular to a model's layers is its
+architecture's to answer (``chipbench/architectures/``).
 
 Requests go the normal way: ``handle.remote({"tokens", "max_new_tokens"})``
 -> router -> replica -> ``LLMServer.__call__`` -> ``ContinuousBatcher``.
@@ -20,31 +23,11 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from chipbench import flops, traffic
-from ray_memory_management_tpu.serve.llm import LLMServer
+from chipbench import architectures, flops, traffic
 
 DRAIN_S = 60.0      # how long past the window's close an answer may come
+CHECK_WIDTH = 512   # the reference runs a sample at a multiple of this length
 DEPLOYMENT = "chipbench-llm"
-
-
-def transformer_config(cfg: Dict[str, Any], **over):
-    """The program's ``TransformerConfig`` of a configuration file."""
-    import jax.numpy as jnp
-
-    from ray_memory_management_tpu.models import gpt
-
-    if cfg["hidden_size"] != cfg["num_attention_heads"] * cfg["head_dim"]:
-        raise ValueError("the program derives head_dim from hidden_size")
-    fields = dict(
-        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
-        n_layers=cfg["num_hidden_layers"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], d_ff=cfg["intermediate_size"],
-        max_seq=cfg["max_position_embeddings"], rope_theta=cfg["rope_theta"],
-        dtype=jnp.dtype(cfg["activation_dtype"]),
-        param_dtype=jnp.dtype(cfg["param_dtype"]))
-    fields.update(over)
-    return gpt.TransformerConfig(**fields)
 
 
 def device_fields(jax, expect: str, chips: int = 1) -> Dict[str, Any]:
@@ -69,9 +52,11 @@ def memory_peak(jax) -> int:
     return max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
 
 
-class ChipLLMServer(LLMServer):
-    """``LLMServer`` deployed from a configuration file. Runs in the
-    replica process, the only one that holds the chip."""
+class ChipServer:
+    """The benchmark's bridges, laid over the server class of the
+    configuration's architecture (:func:`chip_server`): a configuration
+    taken from the cell's file, and the calls only the chip holder can
+    serve. Runs in the replica process, the only one that holds the chip."""
 
     def __init__(self, config: Dict[str, Any], engine: Dict[str, Any],
                  seed: int, expect_platform: str,
@@ -79,14 +64,12 @@ class ChipLLMServer(LLMServer):
         t0 = time.time()
         import jax
 
-        from ray_memory_management_tpu.models import gpt
-
         self._device = device_fields(jax, expect_platform, chips)
         t_device = time.time()
         self._config, self._seed, self._fault = config, seed, fault
-        tc = transformer_config(config)
-        gpt.PRESETS[config["name"]] = tc
-        super().__init__(preset=config["name"], seed=seed, **engine)
+        self._arch = architectures.of(config)
+        super().__init__(seed=seed, **self._arch.server_kwargs(config),
+                         **engine)
         jax.block_until_ready(self.params)
         self._times = {"init_start": t0, "device_ready": t_device,
                        "weights_ready": time.time()}
@@ -99,7 +82,7 @@ class ChipLLMServer(LLMServer):
             # produced
             out = list(out)
             out[len(out) // 2] = (out[len(out) // 2] + 1) \
-                % self.cfg.vocab_size
+                % self._config["vocab_size"]
         return out
 
     def snapshot(self) -> Dict[str, Any]:
@@ -112,13 +95,16 @@ class ChipLLMServer(LLMServer):
         out["memory_peak_bytes"] = memory_peak(jax)
         return out
 
-    def trace_start(self) -> bool:
+    def trace_start(self) -> Dict[str, float]:
+        """Start the profiler; returns when the call reached this process
+        and when the profiler ran, on the host's clock."""
         import jax
 
+        t_call = time.time()
         self._trace_dir = tempfile.mkdtemp(prefix="chipbench_trace_")
         jax.profiler.start_trace(self._trace_dir)
         self._trace_t0 = time.time()
-        return True
+        return {"called": t_call, "started": self._trace_t0}
 
     def trace_stop(self) -> Dict[str, Any]:
         """Stop the profiler and reduce the trace here, where it lies."""
@@ -152,72 +138,96 @@ class ChipLLMServer(LLMServer):
         return peak
 
     def check(self, samples: List[Dict[str, Any]],
-              control: Optional[str] = None) -> Dict[str, Any]:
-        """The widest gap by which a served token's reference logit lies
-        below the reference's best, over ``samples`` (prompt and served
-        tokens). The reference makes its own weights from the seed. With
-        ``control`` the same reading is also taken of the token a lower
-        precision puts first at each position."""
-        import jax
-        import jax.numpy as jnp
-
-        from chipbench.reference import model
-
+              control: Optional[str] = None,
+              detail: bool = False) -> Dict[str, Any]:
         if self.params is not None:
             raise RuntimeError("free() the weights before the check")
-        cfg = self._config
-        params = model.init_params(jax.random.PRNGKey(self._seed), cfg)
-        longest = max(len(s["prompt"]) + len(s["served"]) for s in samples)
-        width = -(-longest // 128) * 128
-        out_width = -(-max(len(s["served"]) for s in samples) // 128) * 128
-
-        def gaps(p, tokens, served_at, start, compute):
-            ref = model.logits(p, tokens, cfg)
-            best = jnp.max(ref, axis=-1)
-            pos = start + jnp.arange(served_at.shape[0])
-            took = ref[pos, served_at]
-            out = {"served": best[pos] - took}
-            if compute is not None:
-                low = jnp.argmax(model.logits(p, tokens, cfg, compute), -1)
-                out["control"] = best[pos] - ref[pos, low[pos]]
-            return out
-
-        fn = jax.jit(gaps, static_argnames=("compute",))
-        worst = {"served": 0.0, "control": 0.0}
-        tokens_compared = 0
-        for s in samples:
-            n_p, served = len(s["prompt"]), list(s["served"])
-            seq = (list(s["prompt"]) + served)[:-1]
-            toks = np.ones((width,), np.int32)
-            toks[:len(seq)] = seq
-            served_at = np.zeros((out_width,), np.int32)
-            served_at[:len(served)] = served
-            out = fn(params, jnp.asarray(toks), jnp.asarray(served_at),
-                     jnp.int32(n_p - 1), control)
-            for k, v in out.items():
-                worst[k] = max(worst[k],
-                               float(jnp.max(v[:len(served)])))
-            tokens_compared += len(served)
-        del params
-        gc.collect()
-        out = {"gap_max": worst["served"], "tokens": tokens_compared,
-               "requests": len(samples)}
-        if control is not None:
-            out["control_gap_max"] = worst["control"]
-        return out
+        return check_samples(self._config, self._seed, samples, control,
+                             detail)
 
     def reseed(self, seed: int) -> bool:
         """New weights from ``seed`` under the compiled programs (the
         limits tool reads a dozen seeds in one process)."""
         import jax
 
-        from ray_memory_management_tpu.models import gpt
-
         self._seed = seed
         self.free()
-        self.params = gpt.init_params(jax.random.PRNGKey(seed), self.cfg)
+        self.params = self._arch.init_program_params(
+            jax.random.PRNGKey(seed), self.cfg)
         self._engine.params = self.params
         return True
+
+
+def chip_server(cfg: Dict[str, Any]) -> type:
+    """The class that is deployed: the bridges over the server class of
+    the configuration's architecture."""
+    return type("ChipLLMServer",
+                (ChipServer, architectures.of(cfg).server_class()), {})
+
+
+def check_samples(cfg: Dict[str, Any], seed: int,
+                  samples: List[Dict[str, Any]],
+                  control: Optional[str] = None,
+                  detail: bool = False) -> Dict[str, Any]:
+    """The widest gap by which a served token's reference logit lies below
+    the reference's best, over ``samples`` (prompt and served tokens). The
+    reference is the architecture's and makes its own weights from the
+    seed; each sample runs at its own length, rounded up to
+    ``CHECK_WIDTH``. With ``control`` the same reading is also taken, at the
+    same served positions, of the token a lower precision puts first there.
+    ``detail`` adds one row a sample (the limits tool reads a sample size
+    from them): prompt length, served tokens, the two gaps, the control's
+    gap at the first served token, and at how many served positions the
+    control put another token first."""
+    import jax
+    import jax.numpy as jnp
+
+    model = architectures.of(cfg).reference()
+    t0 = time.time()
+    params = model.init_params(jax.random.PRNGKey(seed), cfg)
+    out_width = -(-max(len(s["served"]) for s in samples) // 128) * 128
+
+    def gaps(p, tokens, served_at, start, n_served, compute):
+        ref = model.logits(p, tokens, cfg)
+        best = jnp.max(ref, axis=-1)
+        live = jnp.arange(out_width) < n_served
+        pos = jnp.where(live, start + jnp.arange(out_width), 0)
+        out = {"served": jnp.where(live, best[pos] - ref[pos, served_at], 0.)}
+        if compute is not None:
+            low = jnp.argmax(model.logits(p, tokens, cfg, compute), -1)
+            out["control"] = jnp.where(live, best[pos] - ref[pos, low[pos]],
+                                       0.)
+        return out
+
+    fn = jax.jit(gaps, static_argnames=("compute",))
+    worst = {"served": 0.0, "control": 0.0}
+    tokens_compared, rows = 0, []
+    for s in samples:
+        n_p, served = len(s["prompt"]), list(s["served"])
+        seq = (list(s["prompt"]) + served)[:-1]
+        toks = np.ones((-(-len(seq) // CHECK_WIDTH) * CHECK_WIDTH,), np.int32)
+        toks[:len(seq)] = seq
+        served_at = np.zeros((out_width,), np.int32)
+        served_at[:len(served)] = served
+        out = {k: np.asarray(v) for k, v in fn(
+            params, jnp.asarray(toks), jnp.asarray(served_at),
+            jnp.int32(n_p - 1), jnp.int32(len(served)), control).items()}
+        for k, v in out.items():
+            worst[k] = max(worst[k], float(v.max()))
+        tokens_compared += len(served)
+        if detail:
+            c = out.get("control", np.zeros(1))
+            rows.append([n_p, len(served), float(out["served"].max()),
+                         float(c.max()), float(c[0]), int((c > 0).sum())])
+    del params
+    gc.collect()
+    out = {"gap_max": worst["served"], "tokens": tokens_compared,
+           "requests": len(samples), "seconds": time.time() - t0}
+    if control is not None:
+        out["control_gap_max"] = worst["control"]
+    if detail:
+        out["per_sample"] = rows
+    return out
 
 
 # ---------------------------------------------------------------- the load
@@ -230,21 +240,22 @@ def engine_kwargs(cfg: Dict[str, Any], mix: Dict[str, Any]) -> Dict[str, Any]:
     page = e["kv_page_tokens"]
     need = mix["prompt_tokens"]["max"] + e["max_new_tokens"]
     cap = min(-(-need // page) * page, cfg["max_position_embeddings"])
-    itemsize = 2 if cfg["activation_dtype"] == "bfloat16" else 4
-    token_bytes = (2 * cfg["num_hidden_layers"] * cfg["num_key_value_heads"]
-                   * cfg["head_dim"] * itemsize)
-    e.setdefault("kv_pool_bytes", e["max_batch_size"] * cap * token_bytes)
+    if "kv_pool_bytes" not in e:
+        e["kv_pool_bytes"] = e["max_batch_size"] * cap \
+            * architectures.of(cfg).cache_token_bytes(cfg)
     return e
 
 
 def warm_up_waves(mix: Dict[str, Any]) -> List[List[Dict[str, int]]]:
     """(prompt length, budget) pairs, in waves sent together, that make the
-    engine build every program the mix can reach: each (prefill bucket,
-    reserved capacity) pair, the decode step at each slab length, and the
-    pad / slice programs of every (row capacity, slab length) pair. In the
-    wave of slab length S the requests that reserve S hold the slab there
-    while one request of each shorter capacity sits beside them; every
-    budget lasts three iterations where the clips allow it."""
+    engine build every program the mix can reach. With the paged pool those
+    are one prefill a bucket and the one decode step, which the first
+    request builds. The pairs cover more than that, each (prefill bucket,
+    reserved capacity in whole pages) pair, so that a program keyed on a
+    row's capacity would be built here too and not inside a window. In the
+    wave of capacity S the requests that reserve S sit beside one request of
+    each shorter capacity; every budget lasts three iterations where the
+    clips allow it."""
     e = mix["engine"]
     pad, page, k = e["pad_multiple"], e["kv_page_tokens"], e["steps_per_iter"]
     lo_p, hi_p = mix["prompt_tokens"]["min"], mix["prompt_tokens"]["max"]
@@ -360,18 +371,23 @@ class OpenLoop:
 
 class ClosedLoop:
     """Closed loop: each client sends its next request when its last one
-    returns; a request is due the instant its client is free."""
+    returns; a request is due the instant its client is free. ``dry``
+    counts the clients that had sent all the mix gives them before the
+    window closed: such a run offered less than a closed loop does."""
 
     def __init__(self, load: Load, clients):
         self.load, self.clients = load, clients
         self.nxt = [0] * len(clients)
         self.started = False
+        self.dry = 0
 
     def _send(self, c: int) -> None:
         if self.nxt[c] < len(self.clients[c]):
             self.load.send(self.clients[c][self.nxt[c]], self.load.clock(),
                            client=c)
             self.nxt[c] += 1
+        else:
+            self.dry += 1
 
     def run_until(self, end: float) -> None:
         load = self.load
@@ -410,7 +426,7 @@ class Served:
             serve.start(http_port=None)
             t_deploy = time.time()
             self.handle = serve.run(deployment(
-                ChipLLMServer, name=DEPLOYMENT,
+                chip_server(cfg), name=DEPLOYMENT,
                 ray_actor_options={"num_tpus": cell["chips"]},
                 max_concurrent_queries=mix["engine"].get(
                     "max_concurrent_queries", 100),
@@ -456,22 +472,34 @@ class Served:
         offer = (OpenLoop if open_loop else ClosedLoop)(load, work)
         if trace:
             # the window's last seconds, traced in the chip holder; the
-            # trace is collected and reduced there once the window is shut
+            # trace is collected and reduced there once the window is shut.
+            # The sender does not wait for the call that starts it: every
+            # request due meanwhile would be sent late
             offer.run_until(max(0.0, seconds - float(
                 mix.get("trace_seconds", 5.0))))
-            self.call("trace_start")
+            t_asked = time.time()
+            start_ref = self.handle.trace_start.remote()
         offer.run_until(seconds)
         window_s = load.clock()
+        if trace:
+            began = self.rmt.get(start_ref, timeout=300)
         after = self.call("snapshot")
         # the trace is collected while the last answers come in
         stop_ref = self.handle.trace_stop.remote() if trace else None
         load.drain(seconds + DRAIN_S)
-        reduced = self.rmt.get(stop_ref, timeout=300) if trace else None
+        reduced = None
+        if trace:
+            reduced = self.rmt.get(stop_ref, timeout=300)
+            # what the trace cost: the call's way to the replica, and the
+            # profiler's start there (one host, one clock)
+            reduced.update(dispatch_s=began["called"] - t_asked,
+                           start_s=began["started"] - began["called"])
         return {"t0": t0, "window_s": window_s, "before": before,
                 "after": after, "done": load.done, "trace": reduced,
-                "seconds": seconds}
+                "seconds": seconds, "dry": getattr(offer, "dry", 0)}
 
-    def check(self, seed: int, done, control: Optional[str] = None):
+    def check(self, seed: int, done, control: Optional[str] = None,
+              detail: bool = False):
         """Free the weights, then compare a sample of the answers, drawn
         from the seed with the longest in it, with the reference."""
         peak = self.call("free")
@@ -485,7 +513,7 @@ class Served:
         if sample:
             compared = self.call("check", [
                 {"prompt": r["prompt"], "served": r["served"]}
-                for r in sample], control, timeout=600)
+                for r in sample], control, detail, timeout=600)
         return peak, compared
 
 
@@ -498,6 +526,7 @@ def summarize(w: Dict[str, Any], cfg, mix) -> Dict[str, Any]:
     reads the same. Latency: each request's time from the instant it was due
     over its output tokens (one with no answer counts the whole drain)."""
     done, seconds, close = w["done"], w["seconds"], w["window_s"]
+    arch = architectures.of(cfg)
     ok = [r for r in done if "served" in r]
     lat = [(r["finished"] - r["due"]) / max(1, len(r["served"])) * 1e3
            if "served" in r else
@@ -510,7 +539,7 @@ def summarize(w: Dict[str, Any], cfg, mix) -> Dict[str, Any]:
         "ok": ok, "failed": len(done) - len(ok),
         "tokens": sum(n * c for n, c in zip(sizes, share)),
         "latency_ms_per_token": lat,
-        "model_flops": sum(c * flops.forward_flops(
+        "model_flops": sum(c * arch.forward_flops(
             cfg, n, flops.causal_pairs(n)) for n, c in zip(sizes, share)),
         "late_ms": [(r["sent"] - r["due"]) * 1e3 for r in done],
         "requests_in_window": sum(r["finished"] <= close for r in ok),
@@ -544,6 +573,10 @@ def run(cell: Dict[str, Any], cfg: Dict[str, Any], mix: Dict[str, Any], *,
     }
     correct = (failed == 0 and not s["wrong_length"] and bool(done)
                and compared["tokens"] >= 1 and compared["gap_max"] <= limit)
+    if mix["kind"] == "serve-closed":
+        # a client with nothing left to send: the rate read is not capacity
+        comparisons["clients_out_of_work"] = [w["dry"], 0]
+        correct = correct and w["dry"] == 0
     if control is not None:
         comparisons["control_logit_gap_max"] = [
             compared.get("control_gap_max"), limit]
@@ -554,13 +587,17 @@ def run(cell: Dict[str, Any], cfg: Dict[str, Any], mix: Dict[str, Any], *,
                   lease_to_device_s=served.lease_to_device_s)
     values = {"setup_s": setup_s}
     if window_s > 0 and s["tokens"]:
-        values["serve.tokens_per_s"] = s["tokens"] / window_s
+        # one reading, named for what it is: below the knee of an open loop
+        # the offered load less the window's edge, in a closed loop capacity
+        values["serve.capacity_tokens_per_s" if mix["kind"] == "serve-closed"
+               else "serve.tokens_per_s"] = s["tokens"] / window_s
     if s["latency_ms_per_token"] and mix["kind"] == "serve-open":
         values["serve.norm_latency_p90_ms"] = percentile(
             s["latency_ms_per_token"], 90)
     device = dict(first["device"], memory_peak_bytes=peak)
     return {"correct": correct, "attempted": len(done), "failed": failed,
             "values": values, "device": device, "comparisons": comparisons,
+            "check_s": compared.get("seconds"),
             "context": {"kind": "serve", "cfg": cfg, "mix": mix,
                         "before": w["before"], "after": w["after"],
                         "clocks": clocks, "trace": w["trace"],

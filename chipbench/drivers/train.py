@@ -1,7 +1,8 @@
 """The train driver: ``JaxTrainer.fit`` with one worker on a leased chip.
 
 The loop is user code, as it is for any user of ``JaxTrainer``: it builds the
-step with ``parallel.make_train_step`` and ``gpt.loss_fn``, feeds a new
+step with ``parallel.make_train_step`` and the loss of the configuration's
+architecture (``gpt.loss_fn`` for the dense decoder), feeds a new
 seeded batch from the host every step and reports through
 ``session.report``. Set-up builds the one compiled step with its state,
 drives it through its first steps (which the reference follows) and hands
@@ -82,11 +83,10 @@ def train_loop(config: Dict[str, Any]) -> None:
     import jax.numpy as jnp
     import optax
 
-    from chipbench.drivers.serve import (device_fields, memory_peak,
-                                         transformer_config)
+    from chipbench import architectures
+    from chipbench.drivers.serve import device_fields, memory_peak
     from chipbench.reference import train as ref_train
     from chipbench.trace import xplane
-    from ray_memory_management_tpu.models import gpt
     from ray_memory_management_tpu.parallel import (
         make_mesh, make_train_step, param_pspecs, shard_pytree)
     from ray_memory_management_tpu.train import session
@@ -99,7 +99,8 @@ def train_loop(config: Dict[str, Any]) -> None:
     fault = config.get("fault")
     B, S, V = mix["batch"], mix["seq"], cfg["vocab_size"]
     o = mix["optimizer"]
-    tc = transformer_config(
+    arch = architectures.of(cfg)
+    tc = arch.program_config(
         cfg, attention=config.get("attention", mix["attention"]),
         remat=mix["remat"], max_seq=S,
         scan_unroll=cfg["num_hidden_layers"] if mix["unroll_layers"] else 1)
@@ -110,7 +111,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     def loss_fn(p, b):
         if fault == "half_batch":  # a test's planted fault
             b = jax.tree.map(lambda x: x[: B // 2], b)
-        return gpt.loss_fn(p, b, tc, mesh=mesh)
+        return arch.program_loss(p, b, tc, mesh)
 
     step = make_train_step(loss_fn, opt, mesh)
     if fault == "state_unchanged":
@@ -126,7 +127,7 @@ def train_loop(config: Dict[str, Any]) -> None:
         tree))
     change = jax.jit(lambda p, k: jax.tree.map(
         lambda a, b: jnp.sqrt(jnp.sum(jnp.square(a - b))), p,
-        gpt.init_params(k, tc)))
+        arch.init_program_params(k, tc)))
 
     def feed(i: int, seed=seed):
         batch = traffic.train_batch(mix, seed, i, V)
@@ -138,7 +139,7 @@ def train_loop(config: Dict[str, Any]) -> None:
         """State from the seed, driven through its first steps by the
         window's own call and feed; what the comparison reads of them."""
         key = jax.random.PRNGKey(seed)
-        params = gpt.init_params(key, tc)
+        params = arch.init_program_params(key, tc)
         params = shard_pytree(params, mesh, param_pspecs(params, mesh, "dp"))
         opt_state = opt.init(params)
         got = {"losses": []}

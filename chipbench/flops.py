@@ -3,12 +3,18 @@
 Every count here is what the algorithm needs, not what the program happens
 to execute: recomputation (remat) is not counted in a model's FLOPs, and a
 kernel's bytes are its operands read once and its results written once.
-Configurations are the dicts of ``chipbench/configs/<name>.json``.
+Configurations are the dicts of ``chipbench/configs/<name>.json``; a
+model's own counts (its parameters, its forward FLOPs) are its
+architecture's (``chipbench/architectures/``), and what is here is no
+architecture's: the peaks, causal pairs, a train step of any forward, the
+flash kernels, the roofline.
 """
 
 from __future__ import annotations
 
 from typing import Dict, Tuple
+
+from chipbench import architectures
 
 # Published peaks of one chip, keyed by jax's ``device_kind``.
 # Source: Google Cloud documentation, "TPU v5e" (system architecture):
@@ -31,36 +37,6 @@ def peak(device_kind: str) -> Dict[str, float]:
             f"{sorted(PEAKS)}") from None
 
 
-def matmul_params(cfg: dict) -> Tuple[int, int]:
-    """(parameters of one layer's matmuls, parameters of the output head).
-    The embedding is a lookup and counts no FLOPs."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    h, hkv, dh = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  cfg["head_dim"])
-    layer = d * h * dh + 2 * d * hkv * dh + h * dh * d + 3 * d * f
-    return layer, d * cfg["vocab_size"]
-
-
-def n_params(cfg: dict) -> int:
-    """All parameters: layers, norms, embedding and untied head."""
-    layer, head = matmul_params(cfg)
-    d, n = cfg["hidden_size"], cfg["num_hidden_layers"]
-    return n * (layer + 2 * d) + d + 2 * head
-
-
-def forward_flops(cfg: dict, tokens: int, attended: int) -> float:
-    """Forward FLOPs of ``tokens`` token positions that attend, between
-    them, to ``attended`` (query, key) pairs (causal: position p attends
-    p + 1 keys). Two FLOPs per multiply-add; softmax and norms are not
-    counted."""
-    layer, head = matmul_params(cfg)
-    n = cfg["num_hidden_layers"]
-    dense = 2.0 * tokens * (n * layer + head)
-    # QK^T and PV: 2 matmuls x 2 FLOPs x heads x head_dim per attended pair
-    attn = 4.0 * attended * n * cfg["num_attention_heads"] * cfg["head_dim"]
-    return dense + attn
-
-
 def causal_pairs(length: int, start: int = 0) -> int:
     """(query, key) pairs of positions start..length-1, each attending to
     itself and everything before it."""
@@ -70,7 +46,8 @@ def causal_pairs(length: int, start: int = 0) -> int:
 def train_flops_per_step(cfg: dict, batch: int, seq: int) -> float:
     """Forward plus backward of one step without recomputation: the
     backward pass costs twice the forward."""
-    return 3.0 * forward_flops(cfg, batch * seq, batch * causal_pairs(seq))
+    return 3.0 * architectures.of(cfg).forward_flops(
+        cfg, batch * seq, batch * causal_pairs(seq))
 
 
 # ---------------------------------------------------------------- flash kernels

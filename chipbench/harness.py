@@ -8,12 +8,14 @@ last lines of standard error.
 from __future__ import annotations
 
 import argparse
+import ctypes
 import importlib
 import json
 import os
+import signal
 import sys
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from chipbench import manifest
 
@@ -31,12 +33,77 @@ def prepare_process() -> None:
     """What every entry point does before it touches the runtime: stay off
     the chip, and let the leased process cache the small programs too (the
     engine's pad / slice / concatenate glue), so that a run after the first
-    compiles nothing."""
+    compiles nothing; and be the process that orphans below it fall to, so
+    that ``stop_processes`` finds them."""
     pin_to_cpu()
+    adopt_orphans()
     os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     from ray_memory_management_tpu.utils import compile_cache
 
     compile_cache.adopt()
+
+
+def adopt_orphans() -> None:
+    """Make this process the one that orphans below it fall to (Linux's
+    child subreaper), so that a worker's own children are still found
+    under it, and can be waited for, once the worker is gone."""
+    try:
+        ctypes.CDLL(None, use_errno=True).prctl(36, 1, 0, 0, 0)
+    except (OSError, AttributeError):  # not Linux: ``below`` sees less
+        pass
+
+
+def below(pid: int) -> List[Tuple[int, str]]:
+    """(pid, command) of every process under ``pid`` by ``/proc``'s parent
+    links, zombies included: one of ours is there until it is waited for."""
+    parent, name = {}, {}
+    for entry in os.listdir("/proc"):
+        if not entry.isdigit():
+            continue
+        try:
+            with open(f"/proc/{entry}/stat") as f:
+                comm, rest = f.read().rsplit(")", 1)
+        except OSError:  # gone between the listing and the read
+            continue
+        parent[int(entry)] = int(rest.split()[1])
+        name[int(entry)] = comm.split("(", 1)[1]
+    found, edge = [], [pid]
+    while edge:
+        edge = [p for p, pp in parent.items() if pp in edge]
+        found += [(p, name[p]) for p in edge]
+    return found
+
+
+def stop_processes(grace_s: float = 20.0, kill_s: float = 30.0,
+                   give_up_s: float = 45.0) -> List[Tuple[int, str, str]]:
+    """Leave no process behind: wait for everything below this one to end,
+    tell what outstays ``grace_s`` to stop, kill what outstays ``kill_s``.
+    ``rmt.shutdown`` tells its workers to go and waits a second for them;
+    the worker that holds the chip takes 4.5 to 6 s more to be gone.
+    Returns (pid, command, what it took) of those not gone at once."""
+    me, t0, seen = os.getpid(), time.time(), {}
+    while True:
+        left = below(me)
+        for pid, _ in left:
+            try:  # ours to reap, or someone else's child
+                os.waitpid(pid, os.WNOHANG)
+            except ChildProcessError:
+                pass
+        left = below(me)
+        waited = time.time() - t0
+        if not left or waited > give_up_s:
+            return [(p, c, how) for p, (c, how) in seen.items()]
+        for pid, comm in left:
+            how = ("waited" if waited <= grace_s
+                   else "SIGTERM" if waited <= kill_s else "SIGKILL")
+            if how != seen.get(pid, (comm, "waited"))[1]:
+                try:
+                    os.kill(pid, signal.SIGTERM if how == "SIGTERM"
+                            else signal.SIGKILL)
+                except ProcessLookupError:
+                    pass
+            seen[pid] = (comm, how)
+        time.sleep(0.05)
 
 
 def load_cell(workload: str):
@@ -80,7 +147,11 @@ def result_line(workload: str, trace: bool,
                              "idle_gaps": reduced["idle_gaps"]}
         # what the instrumentation cost this run (the driver ignores it)
         line["trace_cost"] = {k: reduced.get(k)
-                              for k in ("collect_s", "reduce_s")}
+                              for k in ("dispatch_s", "start_s",
+                                        "collect_s", "reduce_s")}
+    if result.get("check_s") is not None:
+        # what the output check cost this run (the driver ignores it)
+        line["check_s"] = result["check_s"]
     line["compared"] = result["comparisons"]
     return line
 
@@ -103,8 +174,13 @@ def main(argv=None, started: Optional[float] = None) -> int:
     args = parser.parse_args(argv)
 
     prepare_process()
-    result = run_cell(args.workload, args.seed, args.seconds,
-                      bool(args.trace), started)
+    try:
+        result = run_cell(args.workload, args.seed, args.seconds,
+                          bool(args.trace), started)
+    finally:  # on every way out
+        for pid, comm, how in stop_processes():
+            print(f"process {pid} ({comm}) outlasted shutdown: {how}",
+                  file=sys.stderr)
     line = result_line(args.workload, bool(args.trace), result)
     for name, pair in line["compared"].items():
         print(f"compared {name}: {json.dumps(pair)}", file=sys.stderr)

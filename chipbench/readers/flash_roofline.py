@@ -12,7 +12,7 @@ tensors, dq one.
 
 import re
 
-from chipbench import flops
+from chipbench import architectures, flops
 
 # the kernel functions' names, should a trace carry them
 NAMES = {"fwd": "_fwd_kernel", "dq": "_dq_kernel", "dkv": "_dkv_kernel"}
@@ -49,14 +49,15 @@ def read(ctx):
     t, cfg, mix = ctx.get("trace"), ctx["cfg"], ctx["mix"]
     if not t or not t.get("ops") or mix.get("attention") != "flash":
         return None
-    bh = mix["batch"] * cfg["num_attention_heads"]
+    heads, head_dim = architectures.of(cfg).attention_shape(cfg)
+    bh = mix["batch"] * heads
     least, spent = 0.0, 0.0
     for name, seconds in t["ops"].items():
         kind = kind_of(t["op_text"].get(name, name), bh, mix["seq"],
-                       cfg["head_dim"])
+                       head_dim)
         if kind is None:
             continue
-        f, b = flops.flash_call(kind, bh, mix["seq"], cfg["head_dim"])
+        f, b = flops.flash_call(kind, bh, mix["seq"], head_dim)
         bound, _ = flops.roofline_seconds(f, b, ctx["device"]["kind"])
         least += bound * t["op_calls"][name]
         spent += seconds

@@ -1,4 +1,6 @@
-"""The plain reference of the train cell: loss, gradients and AdamW by hand.
+"""The plain reference of a train cell: the loss of the configuration's
+architecture (its reference module's ``mean_loss``), gradients and AdamW by
+hand.
 
 ``follow`` drives the first steps from the seed on the batches the window's
 feed makes, and returns what the comparison reads: each step's loss, the norm
@@ -15,7 +17,7 @@ import json
 import jax
 import jax.numpy as jnp
 
-from chipbench.reference import model
+from chipbench import architectures
 
 
 def adamw_update(p, g, m, v, t, opt: dict):
@@ -43,6 +45,7 @@ def _programs(cfg_json: str, opt_json: str, compute: str, dtype: str):
     """The jitted gradient, update and change-norm programs of one configuration,
     compiled once however many seeds follow."""
     cfg, opt = json.loads(cfg_json), json.loads(opt_json)
+    model = architectures.of(cfg).reference()
 
     def grad(params, batch):
         loss, grads = jax.value_and_grad(model.mean_loss)(
@@ -78,7 +81,7 @@ def follow(seed: int, cfg: dict, opt: dict, batches, compute: str = "f32",
         json.dumps(cfg, sort_keys=True), json.dumps(opt, sort_keys=True),
         compute, dtype)
     rng = jax.random.PRNGKey(seed)
-    params = model.init_params(rng, cfg, dtype)
+    params = architectures.of(cfg).reference().init_params(rng, cfg, dtype)
     m = jax.tree.map(jnp.zeros_like, params)
     v = jax.tree.map(jnp.zeros_like, params)
     losses, first = [], None

@@ -23,13 +23,13 @@ import time
 import numpy as np
 import pytest
 
-from chipbench import flops, harness, manifest, traffic
+from chipbench import architectures, flops, harness, manifest, traffic
 from chipbench.drivers import serve as serve_driver
 from chipbench.drivers import train as train_driver
 from chipbench.trace import xplane
+from chipbench_config_checks import NAME, check_config_file
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 FILES = {d: sorted(f for f in os.listdir(os.path.join(manifest.HERE, d))
                    if f.endswith(".json"))
@@ -124,21 +124,11 @@ def test_every_data_file_loads(folder, name):
         data = json.load(f)
     assert data["name"] == name[:-len(".json")] and NAME.match(data["name"])
     if folder == "configs":
-        assert len(data["source"]) <= 200
-        width_keys = ("hidden_size", "intermediate_size", "head_dim",
-                      "num_attention_heads", "num_key_value_heads")
-        assert not set(data["reduced"]) & set(width_keys)
-        for k in ("hidden_size", "intermediate_size", "vocab_size",
-                  "num_attention_heads", "num_key_value_heads"):
-            assert data[k] == data["published"][k], k  # no width is cut
-        for k, v in data["published"].items():
-            if k in data and data[k] != v:
-                assert k in data["reduced"], k
-        serve_driver.transformer_config(data)
+        check_config_file(data)
     elif folder == "traffic":
-        assert data["kind"].split("-")[0] in ("serve", "train")
         assert len(data["why"]) <= 200 and data["who"]
-        harness.driver_for(data)
+        # a kind is served by the driver of its first word, found by name
+        assert callable(harness.driver_for(data).run)
     else:
         assert UNIT.match(data["unit"])
         assert callable(manifest.reader(data["reader"]))
@@ -158,12 +148,32 @@ def test_generators_are_deterministic_and_keep_their_clips(name):
         assert np.array_equal(a["tokens"][:, 1:], a["targets"][:, :-1])
         assert len({r.tobytes() for r in a["tokens"]}) == mix["batch"]
         return
+    p, o = mix["prompt_tokens"], mix["output_tokens"]
+    if mix["kind"] == "serve-closed":
+        n = int(mix["requests_per_client"])
+        one = traffic.closed_clients(mix, SEED, n, 1000)
+        two = traffic.closed_clients(mix, SEED, n, 1000)
+        other = traffic.closed_clients(mix, SEED + 1, n, 1000)
+        assert one == two and one != other
+        assert len(one) == mix["clients"] and {len(c) for c in one} == {n}
+        flat = [r for c in one for r in c]
+        for r in flat:
+            assert p["min"] <= len(r["tokens"]) <= p["max"]
+            assert o["min"] <= r["max_new_tokens"] <= o["max"]
+            assert "due" not in r and min(r["tokens"]) >= 2
+        # every seed offers each client the same sizes in the same order
+        size = lambda cs: [[(len(r["tokens"]), r["max_new_tokens"])  # noqa
+                            for r in c] for c in cs]
+        assert (size(one) == size(other)) == ("schedule_seed" in mix)
+        assert sorted(sum(size(one), [])) == sorted(sum(size(other), []))
+        med = sorted(len(r["tokens"]) for r in flat)[len(flat) // 2]
+        assert abs(med - p["median"]) <= 0.1 * p["median"]
+        return
     mix = dict(mix, rate_per_s=3.0)
     one = traffic.open_schedule(mix, SEED, 40.0, 1000)
     two = traffic.open_schedule(mix, SEED, 40.0, 1000)
     other = traffic.open_schedule(mix, SEED + 1, 40.0, 1000)
     assert one == two and one != other
-    p, o = mix["prompt_tokens"], mix["output_tokens"]
     for r in one:
         assert p["min"] <= len(r["tokens"]) <= p["max"]
         assert o["min"] <= r["max_new_tokens"] <= o["max"]
@@ -179,14 +189,16 @@ def test_generators_are_deterministic_and_keep_their_clips(name):
     assert abs(med - p["median"]) <= 0.1 * p["median"]
 
 
-def test_warm_up_waves_reach_every_program_of_the_mix():
-    mix = manifest.traffic("chat-online")
+@pytest.mark.parametrize("name,mid,pairs", [("chat-online", 100, 12),
+                                            ("longprompt-batch", 40, 14)])
+def test_warm_up_waves_reach_every_program_of_the_mix(name, mid, pairs):
+    mix = manifest.traffic(name)
     e = mix["engine"]
     up = lambda x, m: -(-x // m) * m  # noqa: E731
     reach = set()
     for p in range(mix["prompt_tokens"]["min"],
                    mix["prompt_tokens"]["max"] + 1):
-        for b in (mix["output_tokens"]["min"], 100,
+        for b in (mix["output_tokens"]["min"], mid,
                   mix["output_tokens"]["max"]):
             bucket = up(p, e["pad_multiple"])
             reach.add((bucket, up(max(bucket, p + b), e["kv_page_tokens"])))
@@ -203,7 +215,12 @@ def test_warm_up_waves_reach_every_program_of_the_mix():
             caps.append(cap)
         # the wave's slab length sits beside every shorter capacity
         assert set(caps) >= {c for _, c in reach if c <= max(caps)}
-    assert got == reach and len(reach) == 12
+    assert got == reach and len(reach) == pairs
+    # one prefill program a bucket: every bucket of the mix is among them
+    assert {b for b, _ in got} == set(range(
+        up(mix["prompt_tokens"]["min"], e["pad_multiple"]),
+        up(mix["prompt_tokens"]["max"], e["pad_multiple"]) + 1,
+        e["pad_multiple"]))
 
 
 # --------------------------------------------------------------------- flops
@@ -211,15 +228,16 @@ def test_flops_against_hand_worked_counts():
     m = manifest.config("mistral-7b-d16")
     # one layer: q 4096x4096, k and v 4096x1024, o 4096x4096, three 4096x14336
     layer = 4096 * 4096 * 2 + 4096 * 1024 * 2 + 3 * 4096 * 14336
-    assert flops.matmul_params(m) == (layer, 4096 * 32000)
-    assert flops.n_params(m) == 16 * (layer + 2 * 4096) + 4096 \
+    arch = architectures.of(m)
+    assert arch.matmul_params(m) == (layer, 4096 * 32000)
+    assert arch.n_params(m) == 16 * (layer + 2 * 4096) + 4096 \
         + 2 * 4096 * 32000
-    assert abs(flops.n_params(m) / 1e9 - 3.75) < 0.01
+    assert abs(arch.n_params(m) / 1e9 - 3.75) < 0.01
     # one token attending to 1000 positions
     want = 2 * (16 * layer + 4096 * 32000) + 4 * 1000 * 16 * 32 * 128
-    assert flops.forward_flops(m, 1, 1000) == want
+    assert arch.forward_flops(m, 1, 1000) == want
     i = manifest.config("internlm2-1.8b-d6")
-    assert abs(flops.n_params(i) / 1e6 - 757) < 1
+    assert abs(architectures.of(i).n_params(i) / 1e6 - 757) < 1
     step = flops.train_flops_per_step(i, 2, 4096)
     layer_i = 2048 * 2048 * 2 + 2048 * 1024 * 2 + 3 * 2048 * 8192
     dense = 2 * 8192 * (6 * layer_i + 2048 * 92544)
@@ -343,7 +361,7 @@ def test_serve_driver_at_toy_size_ends_in_a_contract_line(toy_serve, trace):
     assert r["attempted"] >= 20 and r["failed"] == 0
     line = json.loads(json.dumps(
         harness.result_line("chat-online", trace, r)))
-    assert set(line) - {"compared", "breakdown", "trace_cost"} \
+    assert set(line) - {"compared", "breakdown", "trace_cost", "check_s"} \
         == CONTRACT_KEYS
     assert list(line)[-1] == "compared"
     assert line["device"]["platform"] == "cpu"
@@ -370,7 +388,7 @@ def test_train_driver_at_toy_size_ends_in_a_contract_line(toy_train, trace):
     assert r["correct"], r["comparisons"]
     line = json.loads(json.dumps(
         harness.result_line("pretrain-1chip", trace, r)))
-    assert set(line) - {"compared", "breakdown", "trace_cost"} \
+    assert set(line) - {"compared", "breakdown", "trace_cost", "check_s"} \
         == CONTRACT_KEYS
     assert list(line)[-1] == "compared"
     if trace:
@@ -440,11 +458,12 @@ def test_reference_against_gpt_forward_at_toy_size():
     import jax
     import jax.numpy as jnp
 
-    from chipbench.reference import model
     from ray_memory_management_tpu.models import gpt
 
     cfg = dict(TOY, param_dtype="float32")
-    tc = serve_driver.transformer_config(
+    arch = architectures.of(cfg)
+    model = arch.reference()
+    tc = arch.program_config(
         dict(cfg, activation_dtype="float32"), attention="ref")
     key = jax.random.PRNGKey(SEED)
     ours, theirs = model.init_params(key, cfg), gpt.init_params(key, tc)
@@ -467,6 +486,36 @@ def test_command_line_refuses_a_machine_without_a_chip():
     assert proc.returncode != 0
     assert '"correct"' not in proc.stdout
     assert "platform 'cpu'" in proc.stderr
+
+
+LEAVES_BEHIND = """
+import os, subprocess, sys
+from chipbench import harness
+
+harness.adopt_orphans()
+sleeper = "import time; print('up', flush=True); time.sleep(120)"
+# a worker that started a child of its own and went, as a killed worker does
+subprocess.Popen([sys.executable, "-c",
+                  "import subprocess, sys; subprocess.Popen("
+                  "[sys.executable, '-c', %r])" % sleeper]).wait()
+# and one that takes no notice of being told to stop
+deaf = subprocess.Popen(
+    [sys.executable, "-c", "import signal; signal.signal(signal.SIGTERM, "
+     "signal.SIG_IGN); " + sleeper], stdout=subprocess.PIPE)
+deaf.stdout.readline()
+before = harness.below(os.getpid())
+stopped = harness.stop_processes(grace_s=0.3, kill_s=1.0)
+print(len(before), sorted(how for _, _, how in stopped),
+      len(harness.below(os.getpid())))
+"""
+
+
+def test_a_run_waits_for_every_process_below_it():
+    proc = subprocess.run([sys.executable, "-c", LEAVES_BEHIND],
+                          cwd=manifest.ROOT, capture_output=True, text=True,
+                          timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.split("\n")[-2] == "2 ['SIGKILL', 'SIGTERM'] 0"
 
 
 # ------------------------------------------------ described-chip compilation
@@ -494,12 +543,14 @@ def _total_bytes(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-@pytest.mark.parametrize("program", ["decode", "train"])
-def test_the_largest_programs_fit_a_described_v5e(one_chip, program):
+@pytest.mark.parametrize("program,mix_name", [
+    ("decode", "chat-online"), ("train", "pretrain-4k"),
+    ("prefill", "longprompt-batch"), ("decode", "longprompt-batch")],
+    ids=["decode", "train", "prefill", "decode-longprompt"])
+def test_the_largest_programs_fit_a_described_v5e(one_chip, program,
+                                                  mix_name, monkeypatch):
     import jax
     import jax.numpy as jnp
-
-    from ray_memory_management_tpu.models import gpt
 
     def shaped(tree):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
@@ -509,35 +560,61 @@ def test_the_largest_programs_fit_a_described_v5e(one_chip, program):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     hbm = flops.peak("TPU v5 lite")["hbm_bytes"]
-    if program == "decode":
+    if program in ("decode", "prefill"):
+        from ray_memory_management_tpu.ops import paged_attention as pa
         from ray_memory_management_tpu.serve.llm import ContinuousBatcher
 
+        # the kernel's dispatch asks where computation lands: steer it here
+        monkeypatch.setattr(pa, "_on_tpu", lambda: True)
         cfg = manifest.config("mistral-7b-d16")
-        mix = manifest.traffic("chat-online")
+        arch = architectures.of(cfg)
+        mix = manifest.traffic(mix_name)
         e = serve_driver.engine_kwargs(cfg, mix)
-        tc = serve_driver.transformer_config(cfg)
-        slots = e["max_batch_size"]
-        longest = e["kv_pool_bytes"] // slots // (
-            2 * 16 * 8 * 128 * 2)  # one slot's share, in positions
-        assert longest == 1536
+        tc = arch.program_config(cfg)
+        slots, page = e["max_batch_size"], e["kv_page_tokens"]
+        # one slot's share of the pool, in positions: 16 x 1,536 are 1.5
+        # GiB, 8 x 4,096 are 2 GiB
+        longest = e["kv_pool_bytes"] // slots // arch.cache_token_bytes(cfg)
+        assert (e["kv_pool_bytes"], longest) == {
+            "chat-online": (3 * 2 ** 29, 1536),
+            "longprompt-batch": (2 ** 31, 4096)}[mix_name]
         params = shaped(jax.eval_shape(
-            lambda: gpt.init_params(jax.random.PRNGKey(0), tc)))
-        cache = shaped(jax.eval_shape(
-            lambda: gpt.init_kv_cache(tc, slots, longest)))
+            lambda: arch.init_program_params(jax.random.PRNGKey(0), tc)))
         eng = ContinuousBatcher(
             None, tc, max_slots=slots, max_new_tokens=e["max_new_tokens"],
             pad_multiple=e["pad_multiple"],
             steps_per_iter=e["steps_per_iter"],
-            kv_page_tokens=e["kv_page_tokens"],
-            kv_pool_bytes=e["kv_pool_bytes"])
+            kv_page_tokens=page, kv_pool_bytes=e["kv_pool_bytes"])
         try:
-            compiled = eng._step.lower(
-                params, cache, arr((slots,)), arr((slots,)),
-                arr((2,), jnp.uint32)).compile()
+            pool = shaped(jax.eval_shape(eng.kv_pool.allocate))
+            assert pool["k"].shape[2] == slots * longest // page + 1  # sink
+            width = eng.kv_pool.table_width
+            if program == "decode":
+                # the one decode program of the engine's lifetime
+                compiled = eng._paged_step.lower(
+                    params, pool, arr((slots,)), arr((slots,)),
+                    arr((slots, width)), arr((2,), jnp.uint32)).compile()
+            else:
+                # the mix's largest prefill bucket
+                bucket = -(-mix["prompt_tokens"]["max"] // e[
+                    "pad_multiple"]) * e["pad_multiple"]
+                assert bucket == 3584
+                compiled = eng._paged_prefill_fn(bucket).lower(
+                    params, pool, arr((1, bucket)), arr((width,)),
+                    arr(()), arr((2,), jnp.uint32)).compile()
         finally:
             eng.close()
-        # the issue's reckoning: 11.2 GiB in the program
-        assert 10.5 * 2 ** 30 < _total_bytes(compiled) < 12 * 2 ** 30
+        assert compiled.as_text().count("tpu_custom_call") == (
+            1 if program == "decode" else 0)
+        # 7.0 GiB of weights and the pool in each (1.5 GiB, longprompt-batch
+        # 2 GiB); by the compiler 9.13 GiB and 9.65 GiB in the decode
+        # programs (PERF.md), 12.58 GiB in the prefill of 3,584 positions
+        # (its row cache, activations, [32, S, S] scores and logits)
+        lo, hi = {("decode", "chat-online"): (8.4, 9.6),
+                  ("decode", "longprompt-batch"): (8.9, 10.1),
+                  ("prefill", "longprompt-batch"): (12.0, 13.2)}[
+                      program, mix_name]
+        assert lo * 2 ** 30 < _total_bytes(compiled) < hi * 2 ** 30
     else:
         import optax
         from jax.sharding import Mesh
@@ -545,17 +622,18 @@ def test_the_largest_programs_fit_a_described_v5e(one_chip, program):
         from ray_memory_management_tpu.parallel import make_train_step
 
         cfg = manifest.config("internlm2-1.8b-d6")
-        mix = manifest.traffic("pretrain-4k")
-        tc = serve_driver.transformer_config(
+        arch = architectures.of(cfg)
+        mix = manifest.traffic(mix_name)
+        tc = arch.program_config(
             cfg, attention=mix["attention"], remat=mix["remat"],
             max_seq=mix["seq"], scan_unroll=cfg["num_hidden_layers"])
         mesh = Mesh(np.array([one_chip._device]), ("dp",))
         params = shaped(jax.eval_shape(
-            lambda: gpt.init_params(jax.random.PRNGKey(0), tc)))
+            lambda: arch.init_program_params(jax.random.PRNGKey(0), tc)))
         opt = optax.adamw(mix["optimizer"]["learning_rate"])
         state = shaped(jax.eval_shape(opt.init, params))
         step = make_train_step(
-            lambda p, b: gpt.loss_fn(p, b, tc, mesh=mesh), opt, mesh)
+            lambda p, b: arch.program_loss(p, b, tc, mesh), opt, mesh)
         shape = (mix["batch"], mix["seq"])
         compiled = step.lower(params, state, {
             "tokens": arr(shape), "targets": arr(shape)}).compile()

@@ -83,7 +83,7 @@ def read(name, ctx):
 def test_reader_on_hand_made_snapshots(name):
     entry = manifest.metric_files()[name]
     assert entry["reader"] == METRICS[name]
-    assert entry["workloads"] == ["chat-online"]
+    assert "chat-online" in entry["workloads"]
     assert entry["layer"] == "serve engine"
     assert read(name, CTX) == pytest.approx(EXPECT[name], rel=1e-12)
     # a program without the engine's counts (the parent, barrier mode):
