@@ -1,0 +1,17 @@
+"""The models. :func:`serving_model` is how the serve engine finds the one a
+configuration object belongs to."""
+
+
+def serving_model(cfg):
+    """The module that serves ``cfg``: it offers ``init_params(key, cfg)``,
+    ``cache_spec(cfg)``, ``prefill_row(params, tokens, cfg, n_positions,
+    true_len)`` and ``paged_decode(params, tokens, pool, positions, lengths,
+    page_table, cfg)`` (models/gpt.py and models/latent_moe.py say what
+    each returns)."""
+    from . import gpt, latent_moe
+
+    for module, kind in ((gpt, gpt.TransformerConfig),
+                         (latent_moe, latent_moe.LatentMoEConfig)):
+        if isinstance(cfg, kind):
+            return module
+    raise TypeError(f"no model serves a {type(cfg).__name__}")

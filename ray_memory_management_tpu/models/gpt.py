@@ -543,3 +543,33 @@ def generate(params, cfg: TransformerConfig, prompt, steps: int,
     new_tokens = _decode_program(cfg, float(temperature), int(steps))(
         params, prompt, key)
     return jnp.concatenate([prompt, new_tokens], axis=1)
+
+
+# ------------------------------------------- what the serve engine asks for
+# (serve/llm.py asks a configuration's model for ``init_params`` and these
+# three; models/latent_moe.py offers the same. Below everything else: no
+# line above may move, see forward_with_cache_rows)
+def cache_spec(cfg: TransformerConfig):
+    """What a token leaves in the cache, as the page pool lays it out: name
+    -> (dims before the pages, dims after a page's positions, dtype). K and
+    V, each [L, Hkv, pages, page_tokens, Dh]."""
+    one = ((cfg.n_layers, cfg.kv_heads), (cfg.head_dim,), cfg.dtype)
+    return {"k": one, "v": one}
+
+
+def prefill_row(params, tokens, cfg: TransformerConfig, n_positions: int,
+                true_len):
+    """Prefill one row: tokens [1, S], of which the first ``true_len`` are
+    the prompt -> (logits [V] fp32 at the prompt's last token, the row's
+    cache {"k", "v"} of [L, Hkv, n_positions, Dh])."""
+    row_cache = init_kv_cache(cfg, 1, n_positions)
+    logits, row_cache = forward_with_cache_rows(
+        params, tokens, row_cache, jnp.zeros((1,), jnp.int32), cfg)
+    return logits[0, true_len - 1], {k: c[:, 0] for k, c in row_cache.items()}
+
+
+def paged_decode(params, tokens, pool, positions, lengths, page_table,
+                 cfg: TransformerConfig):
+    """:func:`forward_paged_decode`, and no counts of its own."""
+    return forward_paged_decode(params, tokens, pool, positions, lengths,
+                                page_table, cfg) + ({},)

@@ -20,6 +20,10 @@ Capacity: each expert processes at most C = ceil(k * T / E) x
 capacity_factor tokens per batch; overflow tokens fall through the
 residual connection (their combine weights are zero), the Switch
 "token dropping" behavior.
+
+:func:`moe_dropless` is the layer without a capacity (the serve path's:
+models/latent_moe.py): every token gets every expert it chose, whatever
+else is in the batch.
 """
 
 from __future__ import annotations
@@ -140,3 +144,63 @@ def moe_ffn(x, layer, cfg, mesh: Optional[Mesh] = None):
     aux = E * jnp.sum(f * p)
 
     return out.reshape(B, S, D), aux
+
+
+# ------------------------------------------------------------ without drops
+def route_sigmoid_top_k(x, router, bias, top_k: int, scale: float,
+                        normalize: bool = True):
+    """The router of the DeepSeek-V3 family (``noaux_tc`` with one group):
+    scores ``s = sigmoid(x . router)`` in float32; a token takes the
+    ``top_k`` experts largest in ``s + bias``; the bias chooses and does
+    not weigh: the weights are ``s`` at the chosen experts, over their sum
+    where ``normalize``, times ``scale``. x [T, D] -> (experts int32
+    [T, k], weights float32 [T, k])."""
+    with jax.named_scope("moe_route"):
+        s = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+        w = jnp.take_along_axis(s, chosen, axis=-1)
+        if normalize:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        return chosen.astype(jnp.int32), w * scale
+
+
+def moe_dropless(x, layer, top_k: int, scale: float, normalize: bool = True,
+                 live=None):
+    """Routed SwiGLU experts without a capacity: x [T, D] -> (y [T, D],
+    expert_tokens int32 [E]).
+
+    ``layer``: router [D, E], bias [E], w1 / w3 [E, D, F], w2 [E, F, D].
+    One algorithm for any T: the T x k (token, expert) assignments are
+    sorted by expert, the three matmuls run grouped over the sorted rows
+    (``jax.lax.ragged_dot``: on a TPU a grouped-matmul kernel that visits
+    only the experts that have rows, so an expert no token chose is not
+    read), and each token's k results are weighed and summed where the token
+    lies. No row is dropped or reweighed for room, so a token's result does
+    not depend on what else is in the batch. A row of ``live`` (bool [T])
+    that is False is routed to no expert: it sorts past every group, reads
+    0 and is counted nowhere. ``expert_tokens[e]`` is how many live rows
+    chose expert ``e``."""
+    T, D = x.shape
+    E = layer["router"].shape[-1]
+    chosen, w = route_sigmoid_top_k(x, layer["router"], layer["bias"],
+                                    top_k, scale, normalize)
+    with jax.named_scope("moe_experts"):
+        if live is not None:
+            chosen = jnp.where(live[:, None], chosen, E)  # past every group
+            w = jnp.where(live[:, None], w, 0.0)
+        flat = chosen.reshape(-1)                         # [T * k]
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+        xs = x[order // top_k]                            # rows by expert
+
+        def grouped(a, b):
+            return jax.lax.ragged_dot(a, b.astype(a.dtype), sizes,
+                                      preferred_element_type=jnp.float32)
+
+        h = jax.nn.silu(grouped(xs, layer["w1"])) * grouped(xs, layer["w3"])
+        ys = grouped(h.astype(x.dtype), layer["w2"])      # [T * k, D] f32
+        back = jnp.argsort(order)                         # where each lies
+        y = jnp.sum(ys[back].reshape(T, top_k, D) * w[..., None], axis=1)
+    return y.astype(x.dtype), sizes

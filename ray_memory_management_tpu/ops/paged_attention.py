@@ -23,6 +23,10 @@ the layer loop.
 
 Which one runs is decided from the platform and the shape, never by a flag;
 ``"interpret"`` (the kernel under the Pallas interpreter) only by name.
+
+:func:`latent_attention`, below the K/V kernel, is the same reading of a pool
+of latent-attention pages: one vector a token that is key and value of every
+head (``name="latent_decode_attention"``).
 """
 
 from __future__ import annotations
@@ -235,3 +239,152 @@ def paged_attention(q, k_pages, v_pages, lengths, page_indices, layer=0,
     return _paged_attention_pallas(q, k_pages, v_pages, lengths,
                                    page_indices, layer, k_cur, v_cur, scale,
                                    interpret=(use_pallas == "interpret"))
+
+
+# ------------------------------------------------------------ latent pages
+# A latent-attention (MLA) cache holds one vector a token and layer, shared by
+# every head: the normed compressed KV and the rotary key side by side
+# (models/latent_moe.py). In absorbed form a head's query has the vector's
+# width, the vector is the key, and its first ``value_width`` columns are the
+# value: one page fetch serves as both, for all heads.
+def latent_attention_reference(q, pages, lengths, page_indices, cur, *,
+                               layer=0, value_width: int, scale: float):
+    """Plain jnp reading of the block table; see :func:`latent_attention`."""
+    B, H, W = q.shape
+    page = pages.shape[2]
+    T = page_indices.shape[1] * page
+    rows = pages[layer][page_indices].reshape(B, T, W)  # in table order
+    seen = jnp.arange(T)[None, :] < lengths[:, None]            # [B, T]
+    s = jnp.einsum("bhw,btw->bht", q, rows,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(seen[:, None, :], s, _NEG_INF)
+    # what lies past a row's length is never read, whatever it holds
+    v = jnp.where(seen[:, :, None], rows[..., :value_width], 0)
+    s_cur = jnp.einsum("bhw,bw->bh", q, cur,
+                       preferred_element_type=jnp.float32) * scale
+    s = jnp.concatenate([s, s_cur[..., None]], axis=-1)
+    v = jnp.concatenate([v, cur[:, None, :value_width]], axis=1)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bht,btv->bhv", p.astype(v.dtype), v).astype(q.dtype)
+
+
+def _latent_kernel(lengths_ref, table_ref, layer_ref, q_ref, cur_ref,
+                   pool_hbm, o_ref, buf, sem, *, page, width, value_width,
+                   scale):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    length = lengths_ref[b]
+    layer = layer_ref[0]
+    n_pages = lax.div(length + (page - 1), page)
+
+    def copy(i, slot):
+        """The DMA of the row's i-th page: key and value of every head."""
+        return pltpu.make_async_copy(
+            pool_hbm.at[layer, table_ref[b * width + i]], buf.at[slot],
+            sem.at[slot])
+
+    @pl.when(n_pages > 0)
+    def _first():
+        copy(0, 0).start()
+
+    q = q_ref[...]                                   # [rows, W]
+    cur = cur_ref[...].astype(jnp.float32)
+    # the token being computed: one more position, seen by every row
+    m0 = jnp.sum(q.astype(jnp.float32) * cur, axis=-1, keepdims=True) * scale
+    l0 = jnp.ones_like(m0)
+    acc0 = cur[:, :value_width]
+
+    def body(i, carry):
+        m, l, acc = carry
+        slot = lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_pages)
+        def _next():
+            copy(i + 1, 1 - slot).start()
+
+        copy(i, slot).wait()
+        kv = buf[slot]                               # [page, W]
+        s = lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        pos = i * page + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < length, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc = alpha * acc + jnp.dot(p.astype(kv.dtype), kv[:, :value_width],
+                                    preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    _, l, acc = lax.fori_loop(0, n_pages, body, (m0, l0, acc0))
+    o_ref[...] = (acc / l).astype(o_ref.dtype)
+
+
+def _latent_attention_pallas(q, pages, lengths, page_indices, cur, layer,
+                             value_width, scale, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, W = q.shape
+    page, width = pages.shape[2], page_indices.shape[1]
+    rows = -(-H // _GROUP_ROWS) * _GROUP_ROWS
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, page=page, width=width,
+                          value_width=value_width, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((None, rows, W), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec((None, rows, W), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, rows, value_width),
+                                   lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, page, W), pages.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((B, rows, value_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="latent_decode_attention",
+    )(lengths.astype(jnp.int32), page_indices.reshape(-1).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      jnp.pad(q, ((0, 0), (0, rows - H), (0, 0))),   # zero rows below H
+      jnp.broadcast_to(cur[:, None, :], (B, rows, W)), pages)
+    return out[:, :H]
+
+
+def latent_kernel_takes(q, pages, value_width: int) -> bool:
+    """Can the compiled latent kernel tile these shapes on a TPU? The
+    vector and its value part fill whole lanes, a page whole sublanes."""
+    sublanes = 8 * 4 // jnp.dtype(pages.dtype).itemsize
+    return (q.shape[-1] % 128 == 0 and value_width % 128 == 0
+            and pages.shape[2] % sublanes == 0 and q.dtype == pages.dtype)
+
+
+def latent_attention(q, pages, lengths, page_indices, cur, *, layer=0,
+                     value_width: int, scale: float,
+                     use_pallas: Optional[str] = None):
+    """One decode token a row against a pool of latent pages, read in place.
+
+    ``q`` [B, H, W]: every head's absorbed query at the cached vector's
+    width; ``pages`` [L, P, page_tokens, W], of which ``layer`` is read
+    where it lies; ``lengths`` int32 [B]; ``page_indices`` int32
+    [B, pages_per_row], of which the row's first ``ceil(lengths[b] /
+    page_tokens)`` entries are fetched, each once, as key (all ``W``
+    columns) and value (the first ``value_width``) of all ``H`` heads;
+    ``cur`` [B, W], the vector of the token being computed, which every row
+    sees beside its cache. Returns [B, H, value_width] in ``q``'s dtype.
+    ``use_pallas`` as :func:`paged_attention`'s: None = the kernel on a TPU
+    for a shape it can tile, the plain reading anywhere else."""
+    if use_pallas is None:
+        use_pallas = "on" if _on_tpu() and latent_kernel_takes(
+            q, pages, value_width) else "off"
+    if use_pallas == "off":
+        return latent_attention_reference(
+            q, pages, lengths, page_indices, cur, layer=layer,
+            value_width=value_width, scale=scale)
+    return _latent_attention_pallas(q, pages, lengths, page_indices, cur,
+                                    layer, value_width, scale,
+                                    interpret=(use_pallas == "interpret"))

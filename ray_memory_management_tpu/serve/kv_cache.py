@@ -1,14 +1,17 @@
 """Paged KV cache for the serve engine: one resident pool, a block table.
 
-The KV cache of a replica is two device arrays, K and V, of shape
-``[L, Hkv, P, page_tokens, Dh]``: ``P - 1`` pages that requests reserve and
-one sink. They are allocated once (:meth:`KVPagePool.allocate`, by the engine
-thread before its first admission) and stay where they are: prefill scatters a
-prompt's K and V into the row's pages, the decode step writes one position a
-live row and attends through the block table
-(ops/paged_attention.py), both on the donated arrays, and nothing copies KV
-between iterations. The pool's bytes are therefore constant; what tracks the
-live requests is its *pages* (``rmt_serve_kv_pages_in_use``).
+The cache of a replica is the device arrays its model's cache specification
+names (``models.serving_model(cfg).cache_spec(cfg)``: name -> dims before the
+pages, dims after a page's positions, dtype): for the dense decoder K and V
+of shape ``[L, Hkv, P, page_tokens, Dh]``, for the latent-attention model one
+array ``[L, P, page_tokens, cache_width]``; ``P - 1`` pages that requests
+reserve and one sink. They are allocated once (:meth:`KVPagePool.allocate`,
+by the engine thread before its first admission) and stay where they are:
+prefill scatters a prompt's cache into the row's pages, the decode step
+writes one position a live row and attends through the block table
+(ops/paged_attention.py), both on the donated arrays, and nothing copies the
+cache between iterations. The pool's bytes are therefore constant; what
+tracks the live requests is its *pages* (``rmt_serve_kv_pages_in_use``).
 
 :class:`KVPagePool` is the allocator, all on the host:
 
@@ -36,12 +39,15 @@ import numpy as np
 
 
 def row_token_bytes(cfg) -> int:
-    """HBM bytes one KV position of one slot occupies (k + v across all
-    layers)."""
+    """HBM bytes one cached position of one slot occupies, over all layers
+    and arrays (padding the layout carries counted)."""
     import jax.numpy as jnp
 
-    itemsize = jnp.dtype(cfg.dtype).itemsize
-    return 2 * cfg.n_layers * cfg.kv_heads * cfg.head_dim * itemsize
+    from ..models import serving_model
+
+    return sum(int(np.prod(lead + trail)) * jnp.dtype(dtype).itemsize
+               for lead, trail, dtype
+               in serving_model(cfg).cache_spec(cfg).values())
 
 
 class KVPagePool:
@@ -57,7 +63,10 @@ class KVPagePool:
     def __init__(self, cfg, max_slots: int, page_tokens: int,
                  pool_bytes: int = 0):
         self.cfg = cfg
+        from ..models import serving_model
+
         self.page_tokens = max(1, int(page_tokens))
+        self.spec = serving_model(cfg).cache_spec(cfg)
         self.token_bytes = row_token_bytes(cfg)
         self.page_bytes = self.page_tokens * self.token_bytes
         if pool_bytes and pool_bytes > 0:
@@ -78,16 +87,16 @@ class KVPagePool:
 
     # -- the device arrays ----------------------------------------------------
     def allocate(self) -> Dict[str, Any]:
-        """The pool's K and V arrays, zeroed. The caller (the engine) owns
-        them: they are donated to every prefill and decode program, and a
-        buffer that is donated cannot also be pinned in a store."""
+        """The pool's arrays, zeroed: for each name of the model's cache
+        specification, dims before + (pages and the sink, page_tokens) + dims
+        after. The caller (the engine) owns them: they are donated to every
+        prefill and decode program, and a buffer that is donated cannot also
+        be pinned in a store."""
         import jax.numpy as jnp
 
-        cfg = self.cfg
-        shape = (cfg.n_layers, cfg.kv_heads, self.capacity_pages + 1,
-                 self.page_tokens, cfg.head_dim)
-        pool = {"k": jnp.zeros(shape, cfg.dtype),
-                "v": jnp.zeros(shape, cfg.dtype)}
+        pages = (self.capacity_pages + 1, self.page_tokens)
+        pool = {name: jnp.zeros(lead + pages + trail, dtype)
+                for name, (lead, trail, dtype) in self.spec.items()}
         with self._lock:
             self._array_bytes = (self.capacity_pages + 1) * self.page_bytes
         return pool

@@ -185,9 +185,12 @@ class ContinuousBatcher:
         import jax.numpy as jnp
         import numpy as np
 
-        from ..models import gpt
+        from ..models import serving_model
 
-        self._jax, self._jnp, self._np, self._gpt = jax, jnp, np, gpt
+        self._jax, self._jnp, self._np = jax, jnp, np
+        # the configuration's model: parameters, prefill, the decode step
+        # and what a token leaves in the cache are its to say
+        self._model = model = serving_model(cfg)
         self.cfg = cfg
         self.params = params
         self.max_slots = max_slots
@@ -217,10 +220,14 @@ class ContinuousBatcher:
                 else gcfg.serve_kv_pool_bytes)
             self._cache = None
         else:
+            if not hasattr(model, "forward_with_cache_rows"):
+                raise ValueError(
+                    f"kv_cache='slab' is the dense decoder's; "
+                    f"{model.__name__} serves from pages")
             self.kv_pool = None
-            self._cache = gpt.init_kv_cache(cfg, max_slots, cfg.max_seq)
-        # paged: the pool's K and V arrays, the engine thread's between
-        # programs (allocated at its first admission, donated to each)
+            self._cache = model.init_kv_cache(cfg, max_slots, cfg.max_seq)
+        # paged: the pool's arrays, the engine thread's between programs
+        # (allocated at its first admission, donated to each)
         self._pool: Optional[Dict[str, Any]] = None
         self._prefill_cache: Dict[Any, Any] = {}  # bucket -> fn
 
@@ -235,7 +242,7 @@ class ContinuousBatcher:
             def body(carry, t):
                 cache, last, key = carry
                 key, sub = jax.random.split(key)
-                logits, cache = gpt.forward_with_cache_rows(
+                logits, cache = model.forward_with_cache_rows(
                     params, last[:, None], cache, offsets + t, cfg)
                 with jax.named_scope("head_sample"):
                     nxt = _sample(logits[:, 0], sub)
@@ -258,16 +265,18 @@ class ContinuousBatcher:
             def body(carry, t):
                 pool, last, key = carry
                 key, sub = jax.random.split(key)
-                logits, pool = gpt.forward_paged_decode(
+                logits, pool, counts = model.paged_decode(
                     params, last, pool, offsets + t,
                     jnp.where(live, offsets + t, 0), table, cfg)
                 with jax.named_scope("head_sample"):
                     nxt = _sample(logits, sub)
-                return (pool, nxt, key), nxt
+                return (pool, nxt, key), (nxt, counts)
 
-            (pool, _, _), toks = jax.lax.scan(
+            (pool, _, _), (toks, counts) = jax.lax.scan(
                 body, (pool, last, key), jnp.arange(K))
-            return pool, toks  # [K, B]
+            # toks [K, B]; what the model counted of its step (small
+            # arrays, for the dense decoder none), summed over the K steps
+            return pool, toks, jax.tree.map(lambda c: c.sum(0), counts)
 
         # the paged mode's one decode program: its shapes are the
         # constructor's (max_slots, the table's width, the pool's size),
@@ -294,6 +303,9 @@ class ContinuousBatcher:
         self._counts = {"iterations": 0, "slab_positions": 0,
                         "live_positions": 0, "admitted": 0}
         self._recent: deque = deque(maxlen=512)  # (queue_wait_s, prefill_s)
+        # what the model's decode step counted of itself (paged_decode's
+        # third result), added up by name: arrays, or nothing
+        self._model_counts: Dict[str, Any] = {}
         self._publish()
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="llm-engine")
@@ -361,7 +373,7 @@ class ContinuousBatcher:
         return min(self.kv_pool.round_tokens(need), self.cfg.max_seq)
 
     def _prefill_fn(self, bucket: int):
-        jax, jnp, gpt, cfg = self._jax, self._jnp, self._gpt, self.cfg
+        jax, jnp, gpt, cfg = self._jax, self._jnp, self._model, self.cfg
         fn = self._prefill_cache.get(bucket)
         if fn is not None:
             return fn
@@ -388,30 +400,31 @@ class ContinuousBatcher:
         return fn
 
     def _paged_prefill_fn(self, bucket: int):
-        """Prefill one prompt of ``bucket`` tokens and scatter its K and V
-        into the row's pages of the donated pool. Compiled per bucket: the
-        reservation's size does not enter, only the table row does."""
-        jax, jnp, gpt, cfg = self._jax, self._jnp, self._gpt, self.cfg
+        """Prefill one prompt of ``bucket`` tokens and scatter what it
+        leaves in the cache into the row's pages of the donated pool.
+        Compiled per bucket: the reservation's size does not enter, only
+        the table row does."""
+        jax, model, cfg = self._jax, self._model, self.cfg
         fn = self._prefill_cache.get(bucket)
         if fn is not None:
             return fn
         page = self.kv_pool.page_tokens
         n_pages = self.kv_pool.pages_for(bucket)
+        spec = self.kv_pool.spec
 
         def prefill(params, pool, tokens, table_row, true_len, key):
-            row_cache = gpt.init_kv_cache(cfg, 1, n_pages * page)
-            logits, row_cache = gpt.forward_with_cache_rows(
-                params, tokens, row_cache, jnp.zeros((1,), jnp.int32), cfg)
-
-            def paged(c):  # [L, 1, Hkv, n * page, Dh] -> whole pages
-                return c[:, 0].reshape(c.shape[0], c.shape[2], n_pages,
-                                       page, c.shape[4])
-
+            logits, row_cache = model.prefill_row(
+                params, tokens, cfg, n_pages * page, true_len)
             pages = table_row[:n_pages]
-            pool = jax.tree.map(
-                lambda whole, c: whole.at[:, :, pages].set(paged(c)),
-                pool, row_cache)
-            first = self._sample(logits[0, true_len - 1][None], key)[0]
+
+            def scatter(name):  # the row's positions as whole pages
+                lead, trail, _ = spec[name]
+                at = (slice(None),) * len(lead) + (pages,)
+                return pool[name].at[at].set(row_cache[name].reshape(
+                    lead + (n_pages, page) + trail))
+
+            pool = {name: scatter(name) for name in pool}
+            first = self._sample(logits[None], key)[0]
             return pool, first
 
         fn = jax.jit(prefill, donate_argnums=(1,))
@@ -613,14 +626,19 @@ class ContinuousBatcher:
                         self._slot_offset[active].sum())
                 with phase(acc, "step_dispatch"):
                     if self.kv_pool is not None:
-                        self._pool, toks = self._paged_step(
+                        self._pool, toks, stepped = self._paged_step(
                             self.params, self._pool, last, offsets, table,
                             sub)
                     else:
+                        stepped = {}
                         self._cache, toks = self._step(
                             self.params, self._cache, last, offsets, sub)
                 with phase(acc, "step_wait"):
-                    toks = np.asarray(toks)  # [K, B]
+                    # toks [K, B] and the model's counts, in one readback
+                    toks, stepped = self._jax.device_get((toks, stepped))
+                    for name, c in stepped.items():
+                        self._model_counts[name] = self._model_counts.get(
+                            name, 0) + c.astype(np.int64)
                 with phase(acc, "emit"):
                     self.steps += self.steps_per_iter
                     for r in active:
@@ -662,7 +680,12 @@ class ContinuousBatcher:
         self._published = {
             "phase_s": {k: v[0] for k, v in self._phase.items()},
             "phase_cpu_s": {k: v[1] for k, v in self._phase.items()},
-            **self._counts, "recent": list(self._recent)}
+            **self._counts, "recent": list(self._recent),
+            # bytes a cached position holds (0: no page pool), and the
+            # model's own counts as plain lists
+            "cache_token_bytes": self.kv_pool.token_bytes
+            if self.kv_pool is not None else 0,
+            **{k: v.tolist() for k, v in self._model_counts.items()}}
 
     def engine_stats(self) -> Dict[str, Any]:
         """Where the engine thread's time went and what the decode step
@@ -670,9 +693,12 @@ class ContinuousBatcher:
         subtracts two snapshots): wall and thread-CPU seconds by phase,
         iterations, KV positions the step fetches (paged: each live row's
         pages up to its last step's length; slab: the whole cache) and the
-        live ones among them (both summed at assembly), requests admitted, and
+        live ones among them (both summed at assembly), requests admitted,
         ``(queue_wait_s, prefill_s)`` of the newest 512 of them, oldest
-        first. Any thread may call it; the copy is the caller's."""
+        first, the bytes a cached position holds, and whatever the model's
+        decode step counts of itself, added up by name (the expert model:
+        ``expert_tokens`` [E], ``experts_touched``, ``expert_layer_steps``).
+        Any thread may call it; the copy is the caller's."""
         snap = self._published
         return {**snap, "phase_s": dict(snap["phase_s"]),
                 "phase_cpu_s": dict(snap["phase_cpu_s"]),
@@ -722,6 +748,12 @@ def unpack_weights(payload: Dict[str, Any]):
 class LLMServer:
     """Deployment class: KV-cached batched generation on one chip.
 
+    The model is ``config`` (a configuration object of models/: a
+    ``TransformerConfig``, a ``LatentMoEConfig``) or, without one, the
+    ``TransformerConfig`` that ``preset`` names; the engine asks
+    ``models.serving_model`` for its functions, and for its parameters from
+    ``seed`` unless ``init`` (``init(key, cfg)`` -> the model's parameter
+    tree) makes them on the replica.
     ``user_config`` (reconfigure) can retune ``max_new_tokens`` /
     ``temperature`` without a redeploy. ``weights`` (a
     :func:`pack_weights` payload) skips the replica-side param init —
@@ -729,6 +761,8 @@ class LLMServer:
     init under ``rmt_serve_cold_start_seconds{source=shipped|init}``."""
 
     def __init__(self, preset: str = "gpt2-small",
+                 config: Any = None,
+                 init: Any = None,
                  max_batch_size: int = 8,
                  batch_wait_timeout_s: float = 0.01,
                  max_new_tokens: int = 32,
@@ -744,12 +778,15 @@ class LLMServer:
         t0 = time.monotonic()
         import jax
 
-        from ..models import gpt
+        from ..models import gpt, serving_model
         from ..utils.compile_cache import CompileCounter
 
         # every program this replica compiles from here on (stats())
         self._compiles = CompileCounter()
-        self.cfg = gpt.PRESETS[preset]
+        self.cfg = config if config is not None else gpt.PRESETS[preset]
+        model = serving_model(self.cfg)
+        if batching == "barrier" and model is not gpt:
+            raise ValueError("batching='barrier' is the dense decoder's")
         if max_new_tokens + pad_multiple > self.cfg.max_seq:
             raise ValueError(
                 f"max_new_tokens={max_new_tokens} leaves no room for a "
@@ -760,7 +797,7 @@ class LLMServer:
             self.params = unpack_weights(weights)
             cold_source = "shipped"
         else:
-            self.params = gpt.init_params(
+            self.params = (init or model.init_params)(
                 jax.random.PRNGKey(seed), self.cfg)
             cold_source = "init"
         self.max_new_tokens = max_new_tokens

@@ -191,7 +191,7 @@ class TestPagedKV:
         before = {n: np.asarray(a, np.float32)
                   for n, a in eng._pool.items()}
         off = eng._slot_offset.copy()
-        eng._pool, toks = eng._paged_step(
+        eng._pool, toks, _ = eng._paged_step(
             params, eng._pool, jnp.asarray(eng._slot_last),
             jnp.asarray(eng._slot_offset), jnp.asarray(pool.table),
             eng._key)
