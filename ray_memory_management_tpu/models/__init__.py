@@ -5,9 +5,10 @@ configuration object belongs to."""
 def serving_model(cfg):
     """The module that serves ``cfg``: it offers ``init_params(key, cfg)``,
     ``cache_spec(cfg)``, ``prefill_row(params, tokens, cfg, n_positions,
-    true_len)`` and ``paged_decode(params, tokens, pool, positions, lengths,
-    page_table, cfg)`` (models/gpt.py and models/latent_moe.py say what
-    each returns)."""
+    true_len)``, ``prefill_takes_kernel(cfg, n_tokens)`` and
+    ``paged_decode(params, tokens, pool, positions, lengths, page_table,
+    cfg)`` (models/gpt.py and models/latent_moe.py say what each
+    returns)."""
     from . import gpt, latent_moe
 
     for module, kind in ((gpt, gpt.TransformerConfig),

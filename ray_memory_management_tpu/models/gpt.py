@@ -547,7 +547,7 @@ def generate(params, cfg: TransformerConfig, prompt, steps: int,
 
 # ------------------------------------------- what the serve engine asks for
 # (serve/llm.py asks a configuration's model for ``init_params`` and these
-# three; models/latent_moe.py offers the same. Below everything else: no
+# four; models/latent_moe.py offers the same. Below everything else: no
 # line above may move, see forward_with_cache_rows)
 def cache_spec(cfg: TransformerConfig):
     """What a token leaves in the cache, as the page pool lays it out: name
@@ -557,15 +557,75 @@ def cache_spec(cfg: TransformerConfig):
     return {"k": one, "v": one}
 
 
+def prefill_takes_kernel(cfg: TransformerConfig, n_tokens: int) -> bool:
+    """Whether :func:`prefill_row` over a bucket of ``n_tokens`` computes its
+    attention in the flash forward kernel (ops/flash_attention.py): on a TPU
+    and for a length the kernel can tile, unless ``cfg.attention`` asks for
+    the reference by name; off the TPU only where it asks for the kernel
+    under the interpreter by name (``_attention`` reads it the same way).
+    The serve engine counts its prefill positions by this."""
+    from ..ops.flash_attention import DEFAULT_BLOCK_Q, _on_tpu, _pick_block
+
+    interpret = cfg.attention == "flash-interpret"
+    if cfg.attention == "ref" or not (interpret or _on_tpu()):
+        return False
+    try:
+        _pick_block(n_tokens, DEFAULT_BLOCK_Q, interpret)
+    except ValueError:  # no block of 8 rows divides it: the plain path
+        return False
+    return True
+
+
 def prefill_row(params, tokens, cfg: TransformerConfig, n_positions: int,
                 true_len):
     """Prefill one row: tokens [1, S], of which the first ``true_len`` are
     the prompt -> (logits [V] fp32 at the prompt's last token, the row's
-    cache {"k", "v"} of [L, Hkv, n_positions, Dh])."""
-    row_cache = init_kv_cache(cfg, 1, n_positions)
-    logits, row_cache = forward_with_cache_rows(
-        params, tokens, row_cache, jnp.zeros((1,), jnp.int32), cfg)
-    return logits[0, true_len - 1], {k: c[:, 0] for k, c in row_cache.items()}
+    cache {"k", "v"} of [L, Hkv, n_positions, Dh], zero past S).
+
+    Nothing of size S x S or S x V is written out where
+    :func:`prefill_takes_kernel` holds: the prompt attends blockwise in the
+    flash forward kernel (S = Skv, so its causal offset is 0; the right-pad
+    positions attend backwards only and no prompt position sees them), each
+    layer's K and V leave the layer loop as its second result, and the head
+    runs on the one row at ``true_len - 1``. Elsewhere the attention is the
+    kernel's own reference, the same mathematics with the scores written
+    out."""
+    from ..ops.flash_attention import flash_attention
+
+    S = tokens.shape[1]
+    rep = cfg.n_heads // cfg.kv_heads
+    use = "off"
+    if prefill_takes_kernel(cfg, S):
+        use = "interpret" if cfg.attention == "flash-interpret" else "on"
+    x = params["tok_embed"][tokens].astype(cfg.dtype)
+
+    def scan_body(x, layer):
+
+        def prompt_attn(q, k, v):                    # [1, S, H(kv), Dh]
+            kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+            with jax.named_scope("prefill_attention"):
+                # the kernel wants as many K/V heads as query heads
+                o = flash_attention(
+                    q.transpose(0, 2, 1, 3), jnp.repeat(kt, rep, axis=1),
+                    jnp.repeat(vt, rep, axis=1), causal=True, use_pallas=use)
+            return o.transpose(0, 2, 1, 3), (kt[0], vt[0])
+
+        return apply_block(x, layer, cfg, attn_fn=prompt_attn,
+                           positions=jnp.arange(S)[None, :])
+
+    x, (k_new, v_new) = lax.scan(scan_body, x, params["layers"])
+    with jax.named_scope("kv_write"):
+        pad = ((0, 0), (0, 0), (0, n_positions - S), (0, 0))
+        row_cache = {"k": jnp.pad(k_new, pad), "v": jnp.pad(v_new, pad)}
+    with jax.named_scope("head_sample"):  # the engine's sampler joins it
+        x = _rmsnorm(lax.dynamic_slice_in_dim(x[0], true_len - 1, 1),
+                     params["final_ln"])
+        logits = lax.dot_general(
+            x, params["lm_head"].astype(cfg.dtype),
+            (((x.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+    return logits[0], row_cache
 
 
 def paged_decode(params, tokens, pool, positions, lengths, page_table,

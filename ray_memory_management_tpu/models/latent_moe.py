@@ -371,3 +371,15 @@ def paged_decode(params, tokens, pool, positions, lengths, page_table,
     return logits, pool, {"expert_tokens": expert_tokens,
                           "experts_touched": touched,
                           "expert_layer_steps": steps}
+
+
+def prefill_takes_kernel(cfg: LatentMoEConfig, n_tokens: int) -> bool:
+    """Whether :func:`prefill_row` attends in the flash forward kernel (the
+    choice ``_attend_plain`` makes: one head size for q, k and v, and
+    ``flash_attention`` itself goes by the platform alone). Whatever
+    ``n_tokens``: on a TPU a length the kernel cannot tile is refused, never
+    handed to the reference. At the end of the file so that no line of the
+    programs above moves."""
+    from ..ops.flash_attention import _on_tpu
+
+    return cfg.qk_head_dim == cfg.v_head_dim and _on_tpu()

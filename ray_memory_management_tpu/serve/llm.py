@@ -230,6 +230,8 @@ class ContinuousBatcher:
         # (allocated at its first admission, donated to each)
         self._pool: Optional[Dict[str, Any]] = None
         self._prefill_cache: Dict[Any, Any] = {}  # bucket -> fn
+        # buckets whose program attends in the flash kernel (paged mode)
+        self._prefill_kernel: set = set()
 
         def _sample(logits, key):
             if self.temperature > 0:
@@ -301,7 +303,9 @@ class ContinuousBatcher:
         # copy is all engine_stats() reads
         self._phase = {name: [0.0, 0.0] for name in ENGINE_PHASES}
         self._counts = {"iterations": 0, "slab_positions": 0,
-                        "live_positions": 0, "admitted": 0}
+                        "live_positions": 0, "admitted": 0,
+                        "prefill_positions": 0,
+                        "prefill_kernel_positions": 0}
         self._recent: deque = deque(maxlen=512)  # (queue_wait_s, prefill_s)
         # what the model's decode step counted of itself (paged_decode's
         # third result), added up by name: arrays, or nothing
@@ -429,6 +433,8 @@ class ContinuousBatcher:
 
         fn = jax.jit(prefill, donate_argnums=(1,))
         self._prefill_cache[bucket] = fn
+        if model.prefill_takes_kernel(cfg, bucket):
+            self._prefill_kernel.add(bucket)
         return fn
 
     def _admit(self, p: _Pending, row: int) -> None:
@@ -458,6 +464,11 @@ class ContinuousBatcher:
             self._cache, first = self._prefill_fn(bucket)(
                 self.params, self._cache, jnp.asarray(arr),
                 jnp.int32(row), jnp.int32(len(toks)), sub)
+        # what the prefill programs computed, and how much of it in a
+        # program that holds the kernel
+        self._counts["prefill_positions"] += bucket
+        if bucket in self._prefill_kernel:
+            self._counts["prefill_kernel_positions"] += bucket
         self._slot_pending[row] = p
         self._slot_offset[row] = len(toks)
         self._slot_last[row] = int(first)
@@ -694,6 +705,9 @@ class ContinuousBatcher:
         iterations, KV positions the step fetches (paged: each live row's
         pages up to its last step's length; slab: the whole cache) and the
         live ones among them (both summed at assembly), requests admitted,
+        the positions their prefill programs computed (the sum of the
+        buckets' lengths) and those of buckets whose program attends in the
+        flash kernel (the model's ``prefill_takes_kernel``),
         ``(queue_wait_s, prefill_s)`` of the newest 512 of them, oldest
         first, the bytes a cached position holds, and whatever the model's
         decode step counts of itself, added up by name (the expert model:

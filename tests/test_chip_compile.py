@@ -185,3 +185,92 @@ def test_paged_step_keeps_the_pool_where_it_is(one_chip, monkeypatch):
     # 7.0 GiB of weights, 1.5 GiB of pool, under 1 GiB of temporaries
     assert 8.4 * 2 ** 30 < total < 9.6 * 2 ** 30
     assert m.alias_size_in_bytes >= 2 * pool["k"].size * 2  # donated
+
+
+# ------------------------------------------------- the dense decoder's prefill
+def _old_prefill_row(params, tokens, cfg, n_positions, true_len):
+    """The prefill as it was before the kernel: a fresh row cache through
+    ``forward_with_cache_rows``, the head over every position."""
+    from ray_memory_management_tpu.models import gpt
+
+    row_cache = gpt.init_kv_cache(cfg, 1, n_positions)
+    logits, row_cache = gpt.forward_with_cache_rows(
+        params, tokens, row_cache, jnp.zeros((1,), jnp.int32), cfg)
+    return logits[0, true_len - 1], {k: c[:, 0] for k, c in row_cache.items()}
+
+
+# (bucket, pad_multiple = page): chat-online's one block, its 768 (blocks of
+# 384) and longprompt-batch's shortest and longest
+PREFILLS = [(256, 256), (768, 256), (512, 512), (3584, 512)]
+
+
+@pytest.mark.parametrize("bucket,page", PREFILLS, ids=lambda v: str(v))
+def test_prefill_program_holds_the_kernel_and_no_scores(one_chip, monkeypatch,
+                                                        bucket, page):
+    """The prefill program as the engine builds it (``_paged_prefill_fn``)
+    at ``mistral-7b-d16`` widths: the flash forward kernel is in it, and no
+    array of S x S x heads or S x V elements. At 3,584 it needs at least
+    2 GiB of temporaries less than the old path's program, lowered the same
+    way."""
+    import importlib
+    import re
+
+    from ray_memory_management_tpu.models import gpt
+    from ray_memory_management_tpu.serve.kv_cache import row_token_bytes
+    from ray_memory_management_tpu.serve.llm import ContinuousBatcher
+
+    # the dispatch asks where default computation lands: steer it here
+    # (the package's ``flash_attention`` is the function, so by full name)
+    fa = importlib.import_module(
+        "ray_memory_management_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    cfg = gpt.TransformerConfig(
+        vocab_size=32_000, d_model=4096, n_layers=16, n_heads=32,
+        n_kv_heads=8, d_ff=14336, max_seq=4096, param_dtype=jnp.bfloat16)
+    slots = 8
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = shaped(jax.eval_shape(
+        lambda: gpt.init_params(jax.random.PRNGKey(0), cfg)))
+
+    def compiled():
+        eng = ContinuousBatcher(
+            None, cfg, max_slots=slots, max_new_tokens=256,
+            pad_multiple=page, steps_per_iter=8, kv_page_tokens=page,
+            kv_pool_bytes=slots * 4096 * row_token_bytes(cfg))
+        try:
+            pool = shaped(jax.eval_shape(eng.kv_pool.allocate))
+            program = eng._paged_prefill_fn(bucket).lower(
+                params, pool, arr((1, bucket)),
+                arr((eng.kv_pool.table_width,)), arr(()),
+                arr((2,), jnp.uint32)).compile()
+            return program, bucket in eng._prefill_kernel
+        finally:
+            eng.close()
+
+    new, counted = compiled()
+    text = new.as_text()
+    # scores are [.., S, S] and all positions' logits [.., S, V], whatever
+    # dimensions of one the compiler drops
+    scores = rf"\[(\d+,)*{bucket},{bucket}\]"
+    all_logits = rf"\[(\d+,)*{bucket},32000\]"
+    assert counted and text.count("tpu_custom_call") == 1
+    assert not re.search(scores, text) and not re.search(all_logits, text)
+    if bucket != 3584:
+        return
+    monkeypatch.setattr(gpt, "prefill_row", _old_prefill_row)
+    monkeypatch.setattr(gpt, "prefill_takes_kernel", lambda cfg, n: False)
+    old, counted = compiled()
+    text = old.as_text()
+    assert not counted and "tpu_custom_call" not in text
+    assert re.search("f32" + scores, text)
+    assert re.search("f32" + all_logits, text)
+    saved = (old.memory_analysis().temp_size_in_bytes
+             - new.memory_analysis().temp_size_in_bytes)
+    assert saved >= 2 * 2 ** 30, saved

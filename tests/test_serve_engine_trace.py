@@ -108,8 +108,13 @@ def test_counts_only_grow_and_read_whole_from_eight_threads(model, mode):
                 st = eng.engine_stats()
                 reads[k] += 1
                 for key in ("iterations", "slab_positions",
-                            "live_positions", "admitted"):
+                            "live_positions", "admitted",
+                            "prefill_positions",
+                            "prefill_kernel_positions"):
                     assert st[key] >= last[key], key
+                assert st["admitted"] * PAGE <= st["prefill_positions"]
+                assert st["prefill_kernel_positions"] <= st[
+                    "prefill_positions"]
                 for name in ENGINE_PHASES:
                     assert st["phase_s"][name] >= last["phase_s"][name]
                 # one copy, from one instant: counts that move together
@@ -146,6 +151,42 @@ def test_counts_only_grow_and_read_whole_from_eight_threads(model, mode):
     assert not any(t.is_alive() for t in threads) and min(reads) > 0
     st = eng.engine_stats()
     assert st["admitted"] == 24 and st["iterations"] > 0
+
+
+@pytest.mark.parametrize("mode,attention", [
+    ("paged", "auto"), ("paged", "flash-interpret"), ("slab", "auto")])
+def test_prefill_positions_grow_by_the_bucket_an_admission(model, mode,
+                                                           attention):
+    """``prefill_positions`` adds each admission's bucket length, and
+    ``prefill_kernel_positions`` those of buckets whose program holds the
+    flash kernel: none off the TPU, all where the configuration asks for
+    the kernel under the interpreter by name (paged mode's prefill)."""
+    import dataclasses
+
+    cfg, params = model
+    eng = engine((dataclasses.replace(cfg, attention=attention), params),
+                 mode)
+    try:
+        seen = []
+
+        def published():  # the answer leaves in ``emit``, the copy after it
+            st = eng.engine_stats()
+            return st if st["admitted"] > len(seen) else None
+
+        for n_prompt in (3, 16, 17, 40, 16):
+            eng.submit(list(range(2, 2 + n_prompt)), max_new_tokens=2,
+                       timeout=120)
+            st = _poll(published)
+            seen.append((st["admitted"], st["prefill_positions"],
+                         st["prefill_kernel_positions"]))
+    finally:
+        close(eng)
+    buckets = [16, 16, 32, 48, 16]
+    totals = [sum(buckets[:i + 1]) for i in range(5)]
+    assert [a for a, _, _ in seen] == [1, 2, 3, 4, 5]
+    assert [p for _, p, _ in seen] == totals
+    assert [k for _, _, k in seen] == (
+        totals if attention == "flash-interpret" else [0] * 5)
 
 
 # ------------------------------------------------------ (b) a request's spans
