@@ -21,7 +21,7 @@ the window and parsed as null). vs_baseline > 1.0 means this runtime beats
 the reference's published single-node numbers on the geometric mean across
 the metric suite. The ``tpu`` dict carries the north-star rows BASELINE.md
 mandates: single-chip TransformerLM MFU, flash-kernel speedup at long S,
-serve decode tokens/s, and allreduce bus-bw when >1 chip is attached —
+and allreduce bus-bw when >1 chip is attached —
 measured in this run or absent: with no chip the section is an error that
 says so. Human-readable per-metric rows go to stderr.
 """
@@ -35,15 +35,13 @@ def _tpu_suite():
     process at a time and this process stays off it (``main`` pins its jax
     to the CPU platform), so every row leases: the compute rows run
     ``utils/tpu_bench`` functions inside a ``num_tpus`` task, whose worker
-    is spawned for that lease and gone after it; the serve row starts a
-    cluster whose replica leases the chip. With no chip the section is an
-    error that says so. Nothing is carried over from an earlier run, and
+    is spawned for that lease and gone after it. With no chip the section
+    is an error that says so. Nothing is carried over from an earlier run, and
     the rows, their sizes and their metrics are not yet a benchmark
     (ROADMAP A0). The RL-learner row is not among them: its learner lives
     in the driver, which this layout keeps off the chip."""
     import ray_memory_management_tpu as rmt
     from ray_memory_management_tpu.api import _detect_tpu_chips
-    from ray_memory_management_tpu.utils import tpu_bench
 
     chips = _detect_tpu_chips()
     if chips == 0:
@@ -129,23 +127,6 @@ def _tpu_suite():
                 out["allreduce_busbw_gbps"] = round(bw["busbw_gbps"], 2)
     finally:
         rmt.shutdown()
-    try:
-        sv = tpu_bench.llm_serving_bench()
-    except Exception as e:  # noqa: BLE001 — reported like any failed row
-        sv, last_err = None, repr(e)[-300:]
-        print(f"  tpu serve bench failed: {last_err}", file=sys.stderr)
-    if sv is not None:
-        ratio = sv.get("continuous_vs_barrier")
-        print(
-            f"  tpu serve-LM decode: {sv['decode_tokens_per_s']:,.0f} tok/s"
-            f"  ({sv['requests_per_s']:.1f} req/s, "
-            f"{sv.get('decode_steps', '?')} steps"
-            + (f"; {ratio:.2f}x over batch-barrier" if ratio else "")
-            + ")", file=sys.stderr)
-        out["serve_decode_tokens_per_s"] = round(
-            sv["decode_tokens_per_s"], 1)
-        if ratio:
-            out["serve_continuous_vs_barrier"] = round(ratio, 2)
     if not any(k for k in out if k != "device"):
         return {"error": f"all tpu rows failed; last: {last_err}"}
     return out
@@ -519,54 +500,6 @@ def _elastic_suite():
         return {"error": repr(e)}
 
 
-# Serving-data-plane fields every BENCH_DETAIL.json must carry
-# (tests/test_bench_format.py enforces the set): open-loop p50/p99 +
-# SLO-violation curve through the real stack, paged-vs-monolithic KV
-# concurrent-slot capacity at equal HBM budget (ISSUE floor: >= 1.5x),
-# continuous-vs-barrier tokens/s on staggered arrivals, tokens/s/chip,
-# shed counts, and cold-start seconds for init vs shipped weights.
-REQUIRED_SERVE_FIELDS = (
-    "p50_ms", "p99_ms", "slo_ms", "slo_violation_pct", "latency_curve",
-    "offered_rps", "n_requests", "shed_total",
-    "paged_slots", "slab_slots", "paged_slots_ratio", "kv_backpressure",
-    "continuous_tokens_per_s", "barrier_tokens_per_s",
-    "continuous_vs_barrier", "tokens_per_s_per_chip", "n_chips",
-    "cold_start_init_s", "cold_start_shipped_s",
-)
-
-
-def _serve_suite():
-    """Serving data plane (utils/serve_bench.py); fault-isolated so a
-    failure still reports the rest of the run."""
-    try:
-        from ray_memory_management_tpu.utils.serve_bench import (
-            run_serve_suite,
-        )
-
-        out = run_serve_suite()
-        print(
-            f"  serve paged KV: {out['paged_slots']} concurrent slots vs "
-            f"{out['slab_slots']} monolithic at equal HBM budget "
-            f"({out['paged_slots_ratio']:.1f}x), "
-            f"{out['tokens_per_s_per_chip']:,.0f} tok/s/chip",
-            file=sys.stderr)
-        print(
-            f"  serve open-loop @ {out['offered_rps']:.0f} rps: "
-            f"p50 {out['p50_ms']:.0f} ms, p99 {out['p99_ms']:.0f} ms, "
-            f"{out['slo_violation_pct']:.1f}% over SLO; continuous vs "
-            f"barrier {out['continuous_vs_barrier']:.2f}x; cold start "
-            f"{out['cold_start_shipped_s']:.2f}s shipped vs "
-            f"{out['cold_start_init_s']:.2f}s init",
-            file=sys.stderr)
-        missing = [k for k in REQUIRED_SERVE_FIELDS if k not in out]
-        if missing:
-            out["error"] = f"missing fields: {missing}"
-        return out
-    except Exception as e:  # pragma: no cover - keep the headline alive
-        print(f"  serve suite failed: {e!r}", file=sys.stderr)
-        return {"error": repr(e)}
-
-
 # Multi-tenant job-plane fields every BENCH_DETAIL.json must carry
 # (tests/test_bench_format.py enforces the set): submit-path tasks/s
 # with one ledger vs four quota'd jobs and the overhead between them,
@@ -821,7 +754,6 @@ def main() -> None:
     profile = _profile_suite()
     health = _health_suite()
     elastic = _elastic_suite()
-    serve = _serve_suite()
     jobs = _jobs_suite()
     scale = _scale_suite()
     scale_curve = _scale_curve_suite()
@@ -838,7 +770,7 @@ def main() -> None:
               "locality": locality, "device": device,
               "tracing": tracing, "logging": logging_out,
               "profile": profile, "health": health, "elastic": elastic,
-              "serve": serve, "jobs": jobs, "metrics": obs_metrics}
+              "jobs": jobs, "metrics": obs_metrics}
     import os
     detail_path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                "BENCH_DETAIL.json")
@@ -850,7 +782,7 @@ def main() -> None:
     for section in ("micro_stats", "scale", "scale_curve", "pod", "tpu",
                     "transfer", "compression", "locality", "device",
                     "tracing", "logging", "profile", "health", "elastic",
-                    "serve", "jobs", "metrics"):
+                    "jobs", "metrics"):
         if detail.get(section):
             print(json.dumps({"detail": section, **{
                 section: detail[section]}}))
@@ -859,15 +791,14 @@ def main() -> None:
                         tpu, transfer, locality, tracing, elastic,
                         compression, logging=logging_out, device=device,
                         profile=profile, health=health,
-                        scale_curve=scale_curve,
-                        serve=serve, jobs=jobs, pod=pod))
+                        scale_curve=scale_curve, jobs=jobs, pod=pod))
 
 
 def headline_line(results, stats, ratios, gm, memcpy_gbps, scale, tpu,
                   transfer=None, locality=None, tracing=None,
                   elastic=None, compression=None, logging=None,
                   device=None, profile=None, health=None,
-                  scale_curve=None, serve=None, jobs=None, pod=None):
+                  scale_curve=None, jobs=None, pod=None):
     """The ONE machine-facing stdout line: compact (<1 KB guaranteed)
     JSON carrying the geomean, the hw ceiling ratio, the mandated micro/
     scale rows, and the TPU north-star numbers."""
@@ -1003,17 +934,6 @@ def headline_line(results, stats, ratios, gm, memcpy_gbps, scale, tpu,
             "async_vs_sync_pct": elastic["async_blocking_vs_sync_pct"],
             "recovery_s": elastic["recovery_s"],
         }
-    if serve and "error" not in serve:
-        # the serving-data-plane acceptance numbers: paged-KV concurrent
-        # slots vs the monolithic slab at equal HBM budget (>= 1.5x),
-        # open-loop tail latency, per-chip decode rate, and the
-        # continuous-batching win over the whole-batch barrier
-        line["serve"] = {
-            "p99_ms": serve["p99_ms"],
-            "tokens_per_s_per_chip": serve["tokens_per_s_per_chip"],
-            "paged_slots_ratio": serve["paged_slots_ratio"],
-            "continuous_vs_barrier": serve["continuous_vs_barrier"],
-        }
     if jobs and "error" not in jobs:
         # the job-plane acceptance numbers: multi-tenant submit overhead
         # (quota admission + fair ordering), sweep latency at 1000
@@ -1030,8 +950,7 @@ def headline_line(results, stats, ratios, gm, memcpy_gbps, scale, tpu,
             line["tpu"] = {"error": tpu["error"][:120]}
         else:
             t = {k: tpu[k] for k in
-                 ("train_mfu", "train_tokens_per_s",
-                  "serve_decode_tokens_per_s") if k in tpu}
+                 ("train_mfu", "train_tokens_per_s") if k in tpu}
             if "device" in tpu:
                 t["device_kind"] = tpu["device"]["kind"]
             rows = tpu.get("train_rows", {})
@@ -1045,7 +964,7 @@ def headline_line(results, stats, ratios, gm, memcpy_gbps, scale, tpu,
             line["tpu"] = t
     payload = json.dumps(line)
     if len(payload) > 1000:  # hard guarantee: never outgrow the tail window
-        for k in ("jobs", "serve", "health", "profile", "compression",
+        for k in ("jobs", "health", "profile", "compression",
                   "elastic", "logging", "tracing", "device", "locality",
                   "transfer", "micro", "pod_curve", "scale_curve",
                   "scale"):

@@ -65,12 +65,6 @@ FIELD_SPECS: Tuple[Tuple[str, str, float], ...] = (
     ("pod_curve.rows_rss_mb", "down", 768.0),
     ("tpu.train_tokens_per_s", "up", 0.35),
     ("tpu.train_mfu", "up", 0.35),
-    # serving data plane (ISSUE 17): tail latency must not creep, the
-    # paged-KV capacity win and per-chip decode rate must not erode
-    ("serve.p99_ms", "down", 200.0),
-    ("serve.tokens_per_s_per_chip", "up", 0.40),
-    ("serve.paged_slots_ratio", "up", 0.25),
-    ("serve.continuous_vs_barrier", "up", 0.30),
     # multi-tenant job plane (ISSUE 18): the quota/attribution machinery
     # must not tax the submit hot path (overhead is a percentage, so the
     # band is absolute points), sweeps must stay milliseconds-fast, and

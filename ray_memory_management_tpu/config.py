@@ -337,10 +337,9 @@ _flag("kv_page_tokens", int, 64,
       "decode step fetches only the pages a row's length reaches.")
 _flag("serve_kv_pool_bytes", int, 0,
       "Per-replica KV page-pool budget in bytes. 0 sizes the pool to "
-      "the monolithic slab's footprint (max_slots x max_seq), so the "
-      "paged engine can never hold more HBM than the slab it replaced; "
-      "exhaustion causes admission backpressure, never an allocation "
-      "failure.")
+      "max_slots x max_seq positions, so every slot can hold a request "
+      "of the model's full length at once; exhaustion causes admission "
+      "backpressure, never an allocation failure.")
 _flag("serve_shed_queue_factor", float, 2.0,
       "HTTP proxy load-shed threshold as a multiple of the deployment's "
       "total capacity (replicas x max_concurrent_queries): when the "

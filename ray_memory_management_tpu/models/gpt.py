@@ -347,77 +347,8 @@ def forward_with_cache(params, tokens, cache, offset, cfg: TransformerConfig):
     return logits, {"k": k_new, "v": v_new}
 
 
-def forward_with_cache_rows(params, tokens, cache, offsets,
-                            cfg: TransformerConfig):
-    """Incremental forward with PER-ROW positions: row ``i`` of ``tokens``
-    [B, S] occupies absolute positions [offsets[i], offsets[i]+S) of its
-    cache row. This is the kernel continuous batching needs — rows of one
-    decode batch sit at different sequence depths (one request is 900
-    tokens in, its neighbor just prefilled) — and it is also the exact
-    fix for the padded-batch approximation: each row attends only to its
-    own true history (mask per row), with rope/positional phases taken
-    from its own offset. Returns (logits [B, S, V] fp32, updated cache).
-    """
-    B, S = tokens.shape
-    H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    T = cache["k"].shape[3]
-    x = params["tok_embed"][tokens].astype(cfg.dtype)
-    positions = offsets[:, None] + jnp.arange(S)[None, :]     # [B, S]
-    key_pos = jnp.arange(T)                                   # [T]
-    # per-row causal-vs-cache mask: row i's query at absolute pos p sees
-    # key slots <= p of row i's cache only
-    mask = key_pos[None, None, :] <= positions[:, :, None]    # [B, S, T]
-
-    def scan_body(x, layer_and_cache):
-        layer, k_cache, v_cache = layer_and_cache
-
-        # the named scopes are what xprof's op view groups by; they are
-        # debug info, which jax leaves out of the compile cache's key. No
-        # line above this function may move for their sake: the Mosaic
-        # kernels of the train step carry their callers' line numbers
-        # inside the program, and so inside its cache key
-        def cached_attn(q, k, v):
-            with jax.named_scope("kv_write"):
-                kt = k.transpose(0, 2, 1, 3)                  # [B,Hkv,S,Dh]
-                vt = v.transpose(0, 2, 1, 3)
-                write = jax.vmap(
-                    lambda c, u, o: lax.dynamic_update_slice(
-                        c, u, (0, o, 0)))
-                kc = write(k_cache, kt, offsets)
-                vc = write(v_cache, vt, offsets)
-            with jax.named_scope("decode_attention"):
-                kk, vv = kc, vc
-                if Hkv != H:
-                    rep = H // Hkv
-                    kk = jnp.repeat(kk, rep, axis=1)
-                    vv = jnp.repeat(vv, rep, axis=1)
-                qh = q.transpose(0, 2, 1, 3)                  # [B, H, S, Dh]
-                scores = jnp.einsum(
-                    "bhsd,bhtd->bhst", qh, kk,
-                    preferred_element_type=jnp.float32) * (Dh ** -0.5)
-                scores = jnp.where(mask[:, None], scores, -jnp.inf)
-                probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-                o = jnp.einsum("bhst,bhtd->bhsd", probs, vv)
-            return o.transpose(0, 2, 1, 3), (kc, vc)
-
-        x, (kc, vc) = apply_block(x, layer, cfg, attn_fn=cached_attn,
-                                  positions=positions)
-        return x, (kc, vc)
-
-    x, (k_new, v_new) = lax.scan(
-        scan_body, x, (params["layers"], cache["k"], cache["v"]))
-    with jax.named_scope("head_sample"):  # the engine's sampler joins it
-        x = _rmsnorm(x, params["final_ln"])
-        logits = lax.dot_general(
-            x, params["lm_head"].astype(cfg.dtype),
-            (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-    return logits, {"k": k_new, "v": v_new}
-
-
-def forward_paged_decode(params, tokens, pool, positions, lengths,
-                         page_table, cfg: TransformerConfig):
+def paged_decode(params, tokens, pool, positions, lengths, page_table,
+                 cfg: TransformerConfig):
     """One decode token a row against a paged KV pool, read and written in
     place (serve/kv_cache.py): ``pool`` is {"k", "v"} of
     [L, Hkv, P, page_tokens, Dh] whose last page is the sink, ``page_table``
@@ -431,7 +362,8 @@ def forward_paged_decode(params, tokens, pool, positions, lengths,
     carried through it; the new K and V of all layers are written after it,
     one position a row, at ``(page_table[i, positions[i] // page_tokens],
     positions[i] % page_tokens)``, or in the sink where that lies beyond the
-    table. Returns (logits [B, V] fp32, updated pool).
+    table. Returns (logits [B, V] fp32, updated pool, the step's own counts:
+    none).
     """
     from ..ops.paged_attention import paged_attention
 
@@ -492,7 +424,7 @@ def forward_paged_decode(params, tokens, pool, positions, lengths,
             (((x.ndim - 1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-    return logits, pool
+    return logits, pool, {}
 
 
 import functools
@@ -546,9 +478,13 @@ def generate(params, cfg: TransformerConfig, prompt, steps: int,
 
 
 # ------------------------------------------- what the serve engine asks for
-# (serve/llm.py asks a configuration's model for ``init_params`` and these
-# four; models/latent_moe.py offers the same. Below everything else: no
-# line above may move, see forward_with_cache_rows)
+# (serve/llm.py asks a configuration's model for ``init_params``,
+# ``paged_decode`` above and these three; models/latent_moe.py offers the
+# same. Below everything else, for no line above ``init_kv_cache`` may move:
+# the Mosaic kernels of the train step carry their callers' line numbers
+# inside the program, and so inside its compile-cache key. The named scopes
+# are what xprof's op view groups by; they are debug info, which jax leaves
+# out of that key)
 def cache_spec(cfg: TransformerConfig):
     """What a token leaves in the cache, as the page pool lays it out: name
     -> (dims before the pages, dims after a page's positions, dtype). K and
@@ -626,10 +562,3 @@ def prefill_row(params, tokens, cfg: TransformerConfig, n_positions: int,
             preferred_element_type=jnp.float32,
         )
     return logits[0], row_cache
-
-
-def paged_decode(params, tokens, pool, positions, lengths, page_table,
-                 cfg: TransformerConfig):
-    """:func:`forward_paged_decode`, and no counts of its own."""
-    return forward_paged_decode(params, tokens, pool, positions, lengths,
-                                page_table, cfg) + ({},)

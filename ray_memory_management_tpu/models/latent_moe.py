@@ -307,7 +307,7 @@ def paged_decode(params, tokens, pool, positions, lengths, page_table,
     ``i``'s token sits at ``positions[i]`` and attends over its first
     ``lengths[i]`` cached positions and itself; an idle row has length 0 and
     a table row of sink entries: it reads nothing, writes the sink, and is
-    routed to no expert. As in models/gpt.py::forward_paged_decode the layer
+    routed to no expert. As in models/gpt.py::paged_decode the layer
     loop only reads the pool and the new vectors of all layers are written
     after it. Returns (logits [B, V] fp32, pool, counts), the counts int32:
     ``expert_tokens`` [E], the live rows' assignments summed over the expert

@@ -633,7 +633,7 @@ Client::Client(const std::string& host, int port, const std::string& authkey) {
     // version-checked ping (the server raises on wire-protocol mismatch)
     std::map<std::string, PyVal> ping;
     ping["type"] = PvStr("ping");
-    ping["proto"] = PvInt(1);  // config.WIRE_PROTOCOL_VERSION
+    ping["proto"] = PvInt(2);  // config.WIRE_PROTOCOL_VERSION
     Request(std::move(ping));
   } catch (...) {
     // the destructor never runs for a partially constructed object:

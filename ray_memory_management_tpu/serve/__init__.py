@@ -29,7 +29,6 @@ from .handle import (  # noqa: F401
 from .kv_cache import KVPagePool  # noqa: F401
 from .llm import (  # noqa: F401
     ContinuousBatcher,
-    DynamicBatcher,
     LLMServer,
     llm_deployment,
     pack_weights,

@@ -54,10 +54,9 @@ class KVPagePool:
     """Page-granular KV allocator: a free list of page ids and the block
     table ``int32 [max_slots, ceil(max_seq / page_tokens)]``.
 
-    ``pool_bytes <= 0`` sizes the pool to the monolithic slab it
-    replaces (``max_slots x max_seq`` positions), so the paged engine
-    can never hold more HBM than the old design's constant footprint
-    (plus the sink page).
+    ``pool_bytes <= 0`` sizes the pool to ``max_slots x max_seq``
+    positions (plus the sink page): every slot can then hold a request of
+    the model's full length at once.
     """
 
     def __init__(self, cfg, max_slots: int, page_tokens: int,

@@ -1,20 +1,19 @@
-"""LM serving: a deployment wrapping the KV-cached decode path.
+"""LM serving: a deployment around one continuous-batching engine.
 
 The reference serves models through generic deployments plus the
 ``serve.batch`` request coalescer (python/ray/serve/batching.py:279;
-replica loop serve/_private/replica.py:250). Here the same two pieces are
+replica loop serve/_private/replica.py:250). Here the coalescer is
 TPU-shaped:
 
-  - :class:`DynamicBatcher` — a thread-based request coalescer: callers
-    block, a background thread collects up to ``max_batch_size`` requests
-    within ``batch_wait_timeout_s`` and runs them as ONE model call. On a
-    TPU the batch dimension is nearly free (MXU width), so coalescing is
+  - :class:`ContinuousBatcher` — the engine: callers block in ``submit``,
+    one thread admits each into a free slot of ``max_slots`` (a prefill
+    program per prompt bucket, a multiple of ``pad_multiple``) and decodes
+    every occupied slot together, ``steps_per_iter`` tokens an iteration,
+    in ONE compiled program over a paged KV pool. On a TPU the batch
+    dimension is nearly free (MXU width), so sharing decode iterations is
     the difference between 1x and Nx decode throughput under load.
-  - :class:`LLMServer` — the deployment class: holds params on device,
-    pads each batch to a fixed shape bucket (batch -> ``max_batch_size``
-    rows, prompt -> multiple of ``pad_multiple``), so XLA compiles ONE
-    prefill+decode program per bucket and reuses it forever
-    (models/gpt.py generate's compile-once contract).
+  - :class:`LLMServer` — the deployment class: holds the parameters on the
+    device, owns the engine, and answers requests and ``stats()``.
 
 Requests carry token ids (``{"tokens": [...]}``) or plain text
 (``{"text": ...}``, byte-level fallback tokenizer) — the deployment is
@@ -48,83 +47,10 @@ class _Pending:
         self.event = threading.Event()
         self.result = None
         self.error: Optional[BaseException] = None
-        # the continuous engine's stamps (time.time()) and the submitting
-        # thread's trace context
+        # the engine's stamps (time.time()) and the submitting thread's
+        # trace context
         self.trace = None
         self.t_submit = self.t_admit = self.t_first = self.t_done = 0.0
-
-
-class DynamicBatcher:
-    """Coalesce concurrent blocking calls into batched ``fn`` invocations.
-
-    ``fn(items: list) -> list`` runs on the batcher thread; callers park
-    in :meth:`submit` until their result is ready. The first arrival opens
-    a window of ``batch_wait_timeout_s``; the batch launches when the
-    window closes or ``max_batch_size`` is reached, whichever is first
-    (the reference's @serve.batch semantics, batching.py:279)."""
-
-    def __init__(self, fn, max_batch_size: int = 8,
-                 batch_wait_timeout_s: float = 0.01):
-        self._fn = fn
-        self.max_batch_size = max_batch_size
-        self.batch_wait_timeout_s = batch_wait_timeout_s
-        self._q: List[_Pending] = []
-        self._cond = threading.Condition()
-        self._stop = False
-        self._thread = threading.Thread(target=self._loop, daemon=True,
-                                        name="llm-batcher")
-        self._thread.start()
-
-    def submit(self, item, timeout: float = 300.0):
-        p = _Pending(item)
-        with self._cond:
-            if self._stop:
-                raise RuntimeError("batcher closed")
-            self._q.append(p)
-            self._cond.notify()
-        if not p.event.wait(timeout):
-            raise TimeoutError("batched call timed out")
-        if p.error is not None:
-            raise p.error
-        return p.result
-
-    def _loop(self) -> None:
-        while not self._stop:
-            with self._cond:
-                while not self._q and not self._stop:
-                    self._cond.wait(timeout=1.0)
-                if self._stop:
-                    return
-                deadline = time.monotonic() + self.batch_wait_timeout_s
-                while (len(self._q) < self.max_batch_size
-                       and time.monotonic() < deadline):
-                    self._cond.wait(timeout=max(
-                        0.0, deadline - time.monotonic()))
-                batch = self._q[: self.max_batch_size]
-                del self._q[: self.max_batch_size]
-            try:
-                results = self._fn([p.item for p in batch])
-                if len(results) != len(batch):
-                    raise ValueError(
-                        f"batch fn returned {len(results)} results for "
-                        f"{len(batch)} items")
-                for p, r in zip(batch, results):
-                    p.result = r
-                    p.event.set()
-            except BaseException as e:  # noqa: BLE001 — deliver to callers
-                for p in batch:
-                    p.error = e
-                    p.event.set()
-
-    def close(self) -> None:
-        with self._cond:
-            self._stop = True
-            drained = list(self._q)
-            self._q.clear()
-            self._cond.notify_all()
-        for p in drained:  # fail parked callers promptly, not by timeout
-            p.error = RuntimeError("batcher closed")
-            p.event.set()
 
 
 def _bytes_tokenize(text: str, vocab_size: int) -> List[int]:
@@ -135,57 +61,51 @@ def _bytes_tokenize(text: str, vocab_size: int) -> List[int]:
 class ContinuousBatcher:
     """Decode-step-granular request scheduler (continuous batching).
 
-    The DynamicBatcher above is a whole-batch barrier: every request in a
-    batch decodes the full ``max_new_tokens`` before ANY new request joins,
-    so under streaming arrivals the chip idles on retired rows and new
-    arrivals queue behind the stragglers. This engine schedules at decode-
-    step granularity over a fixed slot table (the vLLM/Orca iteration-level
-    scheduling idea, TPU-shaped):
+    Requests join and leave at decode-step granularity over a fixed slot
+    table (the vLLM/Orca iteration-level scheduling idea, TPU-shaped), so
+    under streaming arrivals no request waits for a batch of strangers to
+    finish and no slot idles on a retired row:
 
-      - a KV cache of ``max_slots`` rows lives across requests; a new
-        request is PREFILLED into a free row the moment one exists
+      - a new request is PREFILLED into a free slot the moment one exists
         (per-bucket compiled prefill writes its prompt's KV at positions
         [0, len));
-      - every engine iteration runs ONE single-token decode step over all
-        occupied rows (one compiled program, static [max_slots, 1] shape,
-        per-row offsets via models/gpt.forward_with_cache_rows);
+      - every engine iteration runs ONE decode program over all occupied
+        slots (static [max_slots] shape, each row at its own position and
+        length);
       - a row that reaches its request's token budget retires immediately
         and its slot admits the next queued request at the very next step.
 
-    Per-row offsets also make mixed-length batches EXACT: each row attends
-    only to its own true history with its own rope phases — the padded-
-    batch approximation (a short row conditioning on its repeated final
-    token) is gone.
+    Per-row positions also make mixed-length batches EXACT: each row
+    attends only to its own true history with its own rope phases.
 
-    KV memory is PAGED by default (``kv_cache="paged"``): the cache is one
-    resident pool of pages on the device (:class:`~.kv_cache.KVPagePool`:
-    K and V as ``[L, Hkv, P, page_tokens, Dh]``, allocated once by the
-    engine thread) addressed through a block table. Each admitted request
-    reserves the page ids of its own lifetime (prompt + budget, page
-    aligned); prefill scatters the prompt's K and V into those pages, and
-    every iteration runs ONE compiled decode program, the same for the
-    engine's lifetime, that attends through the table
-    (ops/paged_attention.py) and writes one position a live row, all on the
-    donated pool: nothing copies KV between iterations, and the host's part
-    of an iteration is the table and the lengths of the live slots.
-    ``_retire`` returns the slot's pages, so the pool's *pages* track live
-    requests while its bytes stay constant; pool exhaustion defers
-    admission (backpressure) instead of OOMing. ``kv_cache="slab"`` keeps
-    the old monolithic ``max_slots x max_seq`` layout for A/B benchmarking.
+    KV memory is PAGED: the cache is one resident pool of pages on the
+    device (:class:`~.kv_cache.KVPagePool`: K and V as
+    ``[L, Hkv, P, page_tokens, Dh]``, allocated once by the engine thread)
+    addressed through a block table. Each admitted request reserves the
+    page ids of its own lifetime (prompt + budget, page aligned); prefill
+    scatters the prompt's K and V into those pages, and every iteration
+    runs ONE compiled decode program, the same for the engine's lifetime,
+    that attends through the table (ops/paged_attention.py) and writes one
+    position a live row, all on the donated pool: nothing copies KV between
+    iterations, and the host's part of an iteration is the table and the
+    lengths of the live slots. ``_retire`` returns the slot's pages, so the
+    pool's *pages* track live requests while its bytes stay constant; pool
+    exhaustion defers admission (backpressure) instead of OOMing.
     """
 
     def __init__(self, params, cfg, max_slots: int = 8,
                  max_new_tokens: int = 32, temperature: float = 0.0,
                  pad_multiple: int = 64, seed: int = 0,
                  steps_per_iter: int = 8,
-                 kv_cache: str = "paged",
                  kv_page_tokens: Optional[int] = None,
                  kv_pool_bytes: Optional[int] = None):
         import jax
         import jax.numpy as jnp
         import numpy as np
 
+        from ..config import global_config
         from ..models import serving_model
+        from .kv_cache import KVPagePool
 
         self._jax, self._jnp, self._np = jax, jnp, np
         # the configuration's model: parameters, prefill, the decode step
@@ -200,37 +120,21 @@ class ContinuousBatcher:
         # scheduling quantum: each engine iteration decodes K tokens for
         # every occupied row inside ONE compiled lax.scan — per-step
         # Python dispatch would otherwise eat the step-granularity win
-        # (the barrier mode scans its whole budget in one program; K
-        # amortizes dispatch K-fold while arrivals still join within K
+        # (K amortizes dispatch K-fold while arrivals still join within K
         # steps and finished rows retire within K steps)
         self.steps_per_iter = max(1, min(steps_per_iter, max_new_tokens))
         self._key = jax.random.PRNGKey(seed)
-        if kv_cache not in ("paged", "slab"):
-            raise ValueError(f"unknown kv_cache mode: {kv_cache!r}")
-        self.kv_cache_mode = kv_cache
-        if kv_cache == "paged":
-            from ..config import global_config
-            from .kv_cache import KVPagePool
-
-            gcfg = global_config()
-            self.kv_pool: Optional[KVPagePool] = KVPagePool(
-                cfg, max_slots=max_slots,
-                page_tokens=kv_page_tokens or gcfg.kv_page_tokens,
-                pool_bytes=kv_pool_bytes if kv_pool_bytes is not None
-                else gcfg.serve_kv_pool_bytes)
-            self._cache = None
-        else:
-            if not hasattr(model, "forward_with_cache_rows"):
-                raise ValueError(
-                    f"kv_cache='slab' is the dense decoder's; "
-                    f"{model.__name__} serves from pages")
-            self.kv_pool = None
-            self._cache = model.init_kv_cache(cfg, max_slots, cfg.max_seq)
-        # paged: the pool's arrays, the engine thread's between programs
+        gcfg = global_config()
+        self.kv_pool = KVPagePool(
+            cfg, max_slots=max_slots,
+            page_tokens=kv_page_tokens or gcfg.kv_page_tokens,
+            pool_bytes=kv_pool_bytes if kv_pool_bytes is not None
+            else gcfg.serve_kv_pool_bytes)
+        # the pool's arrays, the engine thread's between programs
         # (allocated at its first admission, donated to each)
         self._pool: Optional[Dict[str, Any]] = None
         self._prefill_cache: Dict[Any, Any] = {}  # bucket -> fn
-        # buckets whose program attends in the flash kernel (paged mode)
+        # buckets whose program attends in the flash kernel
         self._prefill_kernel: set = set()
 
         def _sample(logits, key):
@@ -240,23 +144,6 @@ class ContinuousBatcher:
 
         K = self.steps_per_iter
 
-        def step_fn(params, cache, last, offsets, key):
-            def body(carry, t):
-                cache, last, key = carry
-                key, sub = jax.random.split(key)
-                logits, cache = model.forward_with_cache_rows(
-                    params, last[:, None], cache, offsets + t, cfg)
-                with jax.named_scope("head_sample"):
-                    nxt = _sample(logits[:, 0], sub)
-                return (cache, nxt, key), nxt
-
-            (cache, _, _), toks = jax.lax.scan(
-                body, (cache, last, key), jnp.arange(K))
-            return cache, toks  # [K, B]
-
-        # donate the cache so each iteration updates it in place on device
-        # instead of allocating a fresh multi-hundred-MB copy
-        self._step = jax.jit(step_fn, donate_argnums=(1,))
         self._sample = _sample
 
         def paged_step_fn(params, pool, last, offsets, table, key):
@@ -280,9 +167,9 @@ class ContinuousBatcher:
             # arrays, for the dense decoder none), summed over the K steps
             return pool, toks, jax.tree.map(lambda c: c.sum(0), counts)
 
-        # the paged mode's one decode program: its shapes are the
-        # constructor's (max_slots, the table's width, the pool's size),
-        # whichever rows are live and however long they are
+        # the one decode program: its shapes are the constructor's
+        # (max_slots, the table's width, the pool's size), whichever rows
+        # are live and however long they are
         self._paged_step = jax.jit(paged_step_fn, donate_argnums=(1,))
 
         # slot state (host side)
@@ -320,8 +207,7 @@ class ContinuousBatcher:
                max_new_tokens: Optional[int] = None):
         """Blocking generate. ``max_new_tokens`` may be set PER REQUEST
         (capped by the engine default): with step-granular scheduling a
-        short request retires early and frees its slot — under the old
-        whole-batch barrier every request paid the longest budget."""
+        short request retires early and frees its slot."""
         budget = self.max_new_tokens if max_new_tokens is None else \
             max(1, min(int(max_new_tokens), self.max_new_tokens))
         p = _Pending((list(tokens), budget))
@@ -376,33 +262,6 @@ class ContinuousBatcher:
         need = max(self._bucket_for(toks), len(toks) + budget)
         return min(self.kv_pool.round_tokens(need), self.cfg.max_seq)
 
-    def _prefill_fn(self, bucket: int):
-        jax, jnp, gpt, cfg = self._jax, self._jnp, self._model, self.cfg
-        fn = self._prefill_cache.get(bucket)
-        if fn is not None:
-            return fn
-
-        def prefill(params, cache, tokens, row, true_len, key):
-            lax = jax.lax
-            row_cache = {
-                "k": lax.dynamic_slice_in_dim(cache["k"], row, 1, axis=1),
-                "v": lax.dynamic_slice_in_dim(cache["v"], row, 1, axis=1),
-            }
-            logits, row_cache = gpt.forward_with_cache_rows(
-                params, tokens, row_cache, jnp.zeros((1,), jnp.int32), cfg)
-            cache = {
-                "k": lax.dynamic_update_slice_in_dim(
-                    cache["k"], row_cache["k"], row, axis=1),
-                "v": lax.dynamic_update_slice_in_dim(
-                    cache["v"], row_cache["v"], row, axis=1),
-            }
-            first = self._sample(logits[0, true_len - 1][None], key)[0]
-            return cache, first
-
-        fn = jax.jit(prefill, donate_argnums=(1,))
-        self._prefill_cache[bucket] = fn
-        return fn
-
     def _paged_prefill_fn(self, bucket: int):
         """Prefill one prompt of ``bucket`` tokens and scatter what it
         leaves in the cache into the row's pages of the donated pool.
@@ -452,18 +311,13 @@ class ContinuousBatcher:
         arr[0, : len(toks)] = toks  # right-pad junk is invisible: the
         # per-row mask stops at true_len and decode overwrites those slots
         self._key, sub = self._jax.random.split(self._key)
-        if self.kv_pool is not None:
-            if self._pool is None:  # the engine's first admission
-                self._pool = self.kv_pool.allocate()
-            # the row's pages were reserved by the admit gate
-            self._pool, first = self._paged_prefill_fn(bucket)(
-                self.params, self._pool, jnp.asarray(arr),
-                jnp.asarray(self.kv_pool.table[row]),
-                jnp.int32(len(toks)), sub)
-        else:
-            self._cache, first = self._prefill_fn(bucket)(
-                self.params, self._cache, jnp.asarray(arr),
-                jnp.int32(row), jnp.int32(len(toks)), sub)
+        if self._pool is None:  # the engine's first admission
+            self._pool = self.kv_pool.allocate()
+        # the row's pages were reserved by the admit gate
+        self._pool, first = self._paged_prefill_fn(bucket)(
+            self.params, self._pool, jnp.asarray(arr),
+            jnp.asarray(self.kv_pool.table[row]),
+            jnp.int32(len(toks)), sub)
         # what the prefill programs computed, and how much of it in a
         # program that holds the kernel
         self._counts["prefill_positions"] += bucket
@@ -480,10 +334,9 @@ class ContinuousBatcher:
         self._slot_pending[row] = None
         self._slot_offset[row] = 0
         self._slot_last[row] = 1
-        if self.kv_pool is not None:
-            # the slot's pages return to the free list and its table row
-            # to the sink: a queued request can now reserve them
-            self.kv_pool.free(row)
+        # the slot's pages return to the free list and its table row to
+        # the sink: a queued request can now reserve them
+        self.kv_pool.free(row)
         if p is not None:
             p.result = self._slot_out[row]
             p.t_done = time.time()
@@ -509,28 +362,22 @@ class ContinuousBatcher:
 
     def _fetched_positions(self, active: List[int]) -> int:
         """KV positions the decode step fetches this iteration: each live
-        row's pages up to its last step's length (slab mode: the whole
-        cache, every row)."""
-        if self.kv_pool is None:
-            return self.max_slots * self.cfg.max_seq
+        row's pages up to its last step's length."""
         page = self.kv_pool.page_tokens
         ends = self._slot_offset[active] + self.steps_per_iter
         return int((-(-ends // page) * page).sum())
 
     def _admit_gate(self) -> List:
         """Pop admissible queued requests (head-of-line FIFO) into free
-        slots. Paged mode reserves each request's lifetime pages FIRST —
-        a failed reserve defers admission (backpressure) until a retiring
-        slot frees pages, so decode can never OOM mid-request. Caller
-        holds ``_cond``."""
+        slots, reserving each request's lifetime pages FIRST: a failed
+        reserve defers admission (backpressure) until a retiring slot frees
+        pages, so decode can never OOM mid-request. Caller holds
+        ``_cond``."""
         admits = []
         for row in range(self.max_slots):
             if not self._q:
                 break
             if self._slot_pending[row] is not None:
-                continue
-            if self.kv_pool is None:
-                admits.append((self._q.pop(0), row))
                 continue
             p = self._q[0]
             need = self._need_tokens(p)
@@ -581,9 +428,8 @@ class ContinuousBatcher:
                     victims = [p for p in self._slot_pending
                                if p is not None]
                     self._slot_pending = [None] * self.max_slots
-                    if self.kv_pool is not None:
-                        self.kv_pool.free_all()
-                        self._pool = None  # the arrays leave the device
+                    self.kv_pool.free_all()
+                    self._pool = None  # the arrays leave the device
                     for p in victims:
                         p.error = RuntimeError("engine closed")
                         p.event.set()
@@ -595,16 +441,14 @@ class ContinuousBatcher:
                     with phase(acc, "prefill",
                                bucket=self._bucket_for(
                                    self._clip_tokens(p.item[0])),
-                               cap=self.kv_pool.row_tokens(row)
-                               if self.kv_pool is not None else 0):
+                               cap=self.kv_pool.row_tokens(row)):
                         try:
                             self._admit(p, row)
                         except faults.FaultInjected as e:
                             # injected admit failure takes down ONE
                             # request, not the engine: release the
                             # reservation and keep admitting
-                            if self.kv_pool is not None:
-                                self.kv_pool.free(row)
+                            self.kv_pool.free(row)
                             self._slot_pending[row] = None
                             p.error = e
                             p.event.set()
@@ -623,12 +467,11 @@ class ContinuousBatcher:
                     continue
                 with phase(acc, "assemble", rows=len(active)):
                     self._key, sub = self._jax.random.split(self._key)
-                    # paged: the host's part is the live slots' lengths and
-                    # table rows; the KV stays where it is
+                    # the host's part is the live slots' lengths and table
+                    # rows; the KV stays where it is
                     last = jnp.asarray(self._slot_last)
                     offsets = jnp.asarray(self._slot_offset)
-                    if self.kv_pool is not None:
-                        table = jnp.asarray(self.kv_pool.table)
+                    table = jnp.asarray(self.kv_pool.table)
                     # what the step fetches, and how much of it is live
                     counts["iterations"] += 1
                     counts["slab_positions"] += self._fetched_positions(
@@ -636,14 +479,8 @@ class ContinuousBatcher:
                     counts["live_positions"] += int(
                         self._slot_offset[active].sum())
                 with phase(acc, "step_dispatch"):
-                    if self.kv_pool is not None:
-                        self._pool, toks, stepped = self._paged_step(
-                            self.params, self._pool, last, offsets, table,
-                            sub)
-                    else:
-                        stepped = {}
-                        self._cache, toks = self._step(
-                            self.params, self._cache, last, offsets, sub)
+                    self._pool, toks, stepped = self._paged_step(
+                        self.params, self._pool, last, offsets, table, sub)
                 with phase(acc, "step_wait"):
                     # toks [K, B] and the model's counts, in one readback
                     toks, stepped = self._jax.device_get((toks, stepped))
@@ -655,8 +492,8 @@ class ContinuousBatcher:
                     for r in active:
                         # a row finishing mid-iteration consumes only what
                         # its budget allows; the surplus decoded junk wrote
-                        # beyond its end, into its OWN cache row or pages or
-                        # into the sink, where the lengths keep it invisible
+                        # beyond its end, into its OWN pages or into the
+                        # sink, where the lengths keep it invisible
                         take = min(self.steps_per_iter,
                                    int(self._slot_budget[r]))
                         self._slot_out[r].extend(
@@ -674,11 +511,10 @@ class ContinuousBatcher:
                                     if p is not None] + self._q)
                         self._slot_pending = [None] * self.max_slots
                         self._q.clear()
-                    if self.kv_pool is not None:
-                        self.kv_pool.free_all()
-                        # a program that failed may have consumed the
-                        # donated arrays: the next admission allocates anew
-                        self._pool = None
+                    self.kv_pool.free_all()
+                    # a program that failed may have consumed the donated
+                    # arrays: the next admission allocates anew
+                    self._pool = None
                     for p in victims:
                         p.error = e
                         p.event.set()
@@ -692,19 +528,18 @@ class ContinuousBatcher:
             "phase_s": {k: v[0] for k, v in self._phase.items()},
             "phase_cpu_s": {k: v[1] for k, v in self._phase.items()},
             **self._counts, "recent": list(self._recent),
-            # bytes a cached position holds (0: no page pool), and the
-            # model's own counts as plain lists
-            "cache_token_bytes": self.kv_pool.token_bytes
-            if self.kv_pool is not None else 0,
+            # bytes a cached position holds, and the model's own counts as
+            # plain lists
+            "cache_token_bytes": self.kv_pool.token_bytes,
             **{k: v.tolist() for k, v in self._model_counts.items()}}
 
     def engine_stats(self) -> Dict[str, Any]:
         """Where the engine thread's time went and what the decode step
         attended over, cumulative since the engine started (a reader
         subtracts two snapshots): wall and thread-CPU seconds by phase,
-        iterations, KV positions the step fetches (paged: each live row's
-        pages up to its last step's length; slab: the whole cache) and the
-        live ones among them (both summed at assembly), requests admitted,
+        iterations, KV positions the step fetches (each live row's pages up
+        to its last step's length) and the live ones among them (both
+        summed at assembly), requests admitted,
         the positions their prefill programs computed (the sum of the
         buckets' lengths) and those of buckets whose program attends in the
         flash kernel (the model's ``prefill_takes_kernel``),
@@ -719,13 +554,9 @@ class ContinuousBatcher:
                 "recent": list(snap["recent"])}
 
     def kv_stats(self) -> Dict[str, Any]:
-        """Pool occupancy snapshot (paged mode) for metrics/benchmarks."""
-        if self.kv_pool is None:
-            return {"mode": "slab", "kv_backpressure": 0}
-        out = dict(self.kv_pool.stats())
-        out["mode"] = "paged"
-        out["kv_backpressure"] = self.kv_backpressure
-        return out
+        """Pool occupancy snapshot for metrics/benchmarks."""
+        return {**self.kv_pool.stats(),
+                "kv_backpressure": self.kv_backpressure}
 
 
 def pack_weights(params, precision: str = "bf16") -> Dict[str, Any]:
@@ -778,14 +609,11 @@ class LLMServer:
                  config: Any = None,
                  init: Any = None,
                  max_batch_size: int = 8,
-                 batch_wait_timeout_s: float = 0.01,
                  max_new_tokens: int = 32,
                  temperature: float = 0.0,
                  pad_multiple: int = 64,
                  seed: int = 0,
-                 batching: str = "continuous",
                  steps_per_iter: int = 8,
-                 kv_cache: str = "paged",
                  kv_page_tokens: Optional[int] = None,
                  kv_pool_bytes: Optional[int] = None,
                  weights: Optional[Dict[str, Any]] = None):
@@ -799,14 +627,11 @@ class LLMServer:
         self._compiles = CompileCounter()
         self.cfg = config if config is not None else gpt.PRESETS[preset]
         model = serving_model(self.cfg)
-        if batching == "barrier" and model is not gpt:
-            raise ValueError("batching='barrier' is the dense decoder's")
         if max_new_tokens + pad_multiple > self.cfg.max_seq:
             raise ValueError(
                 f"max_new_tokens={max_new_tokens} leaves no room for a "
                 f"{pad_multiple}-token prompt bucket within the model's "
                 f"max_seq={self.cfg.max_seq}")
-        self.gpt = gpt
         if weights is not None:
             self.params = unpack_weights(weights)
             cold_source = "shipped"
@@ -819,36 +644,26 @@ class LLMServer:
         self.pad_multiple = pad_multiple
         self.max_batch_size = max_batch_size
         self.seed = seed
-        self._key = jax.random.PRNGKey(seed + 1)
         self._stats = {"requests": 0, "batches": 0, "generated_tokens": 0}
-        self.batching = batching
         self.steps_per_iter = steps_per_iter
-        self.kv_cache = kv_cache
         self.kv_page_tokens = kv_page_tokens
         self.kv_pool_bytes = kv_pool_bytes
-        if batching == "continuous":
-            # decode-step-granular join/leave + exact per-row positions
-            self._engine = ContinuousBatcher(
-                self.params, self.cfg, max_slots=max_batch_size,
-                max_new_tokens=max_new_tokens, temperature=temperature,
-                pad_multiple=pad_multiple, seed=seed + 1,
-                steps_per_iter=steps_per_iter, kv_cache=kv_cache,
-                kv_page_tokens=kv_page_tokens, kv_pool_bytes=kv_pool_bytes)
-            self._batcher = None
-        elif batching == "barrier":
-            # legacy whole-batch mode (kept for A/B benchmarking)
-            self._engine = None
-            self._batcher = DynamicBatcher(
-                self._run_batch, max_batch_size=max_batch_size,
-                batch_wait_timeout_s=batch_wait_timeout_s)
-        else:
-            raise ValueError(f"unknown batching mode: {batching!r}")
+        self._engine = self._new_engine()
         try:
             from ..core import metrics_defs as mdefs
             mdefs.serve_cold_start_seconds().observe(
                 time.monotonic() - t0, tags={"source": cold_source})
         except Exception:  # noqa: BLE001 — metrics never fail init
             pass
+
+    def _new_engine(self) -> ContinuousBatcher:
+        return ContinuousBatcher(
+            self.params, self.cfg, max_slots=self.max_batch_size,
+            max_new_tokens=self.max_new_tokens,
+            temperature=self.temperature, pad_multiple=self.pad_multiple,
+            seed=self.seed + 1, steps_per_iter=self.steps_per_iter,
+            kv_page_tokens=self.kv_page_tokens,
+            kv_pool_bytes=self.kv_pool_bytes)
 
     # -- config ---------------------------------------------------------------
     def reconfigure(self, user_config: Optional[dict]) -> None:
@@ -866,28 +681,19 @@ class LLMServer:
                    or new_temp != self.temperature)
         self.max_new_tokens = new_tokens
         self.temperature = new_temp
-        if self._engine is not None and changed:
+        if changed:
             # temperature is baked into the engine's compiled sampler at
             # trace time (and the token budget into its slot accounting):
             # swap in a fresh engine rather than mutating a live one
-            old = self._engine
-            self._engine = ContinuousBatcher(
-                self.params, self.cfg, max_slots=self.max_batch_size,
-                max_new_tokens=new_tokens, temperature=new_temp,
-                pad_multiple=self.pad_multiple, seed=self.seed + 1,
-                steps_per_iter=self.steps_per_iter,
-                kv_cache=self.kv_cache,
-                kv_page_tokens=self.kv_page_tokens,
-                kv_pool_bytes=self.kv_pool_bytes)
+            old, self._engine = self._engine, self._new_engine()
             old.close()
 
     # -- request surface ------------------------------------------------------
     def __call__(self, request: Any = None) -> Dict[str, Any]:
         """HTTP entrypoint: {"tokens": [...]} or {"text": "..."}. Returns
         {"tokens": [...]}. An optional per-request "max_new_tokens"
-        (capped by the deployment default) is honored in continuous mode —
-        step-granular scheduling makes short requests retire early; in
-        barrier mode the whole batch decodes the deployment default."""
+        (capped by the deployment default) is honored: step-granular
+        scheduling makes short requests retire early."""
         if isinstance(request, str):
             request = {"text": request}
         request = request or {}
@@ -905,78 +711,28 @@ class LLMServer:
                  max_new_tokens: Optional[int] = None) -> List[int]:
         """Generate continuation ids for one prompt (batched under the
         hood with whatever arrives concurrently). ``max_new_tokens`` can
-        be set per request in continuous mode (capped by the deployment
-        default); barrier mode always decodes the full default."""
-        if self._engine is not None:
-            out = self._engine.submit(list(tokens),
-                                      max_new_tokens=max_new_tokens)
-            self._stats["requests"] += 1
-            self._stats["generated_tokens"] += len(out)
-            self._stats["batches"] = self._engine.steps
-            return out
-        return self._batcher.submit(list(tokens))
+        be set per request (capped by the deployment default)."""
+        out = self._engine.submit(list(tokens),
+                                  max_new_tokens=max_new_tokens)
+        self._stats["requests"] += 1
+        self._stats["generated_tokens"] += len(out)
+        self._stats["batches"] = self._engine.steps
+        return out
 
     def stats(self) -> dict:
         """Request counters, the KV pool's occupancy, the engine's own
-        phases and counts (continuous mode), the device this replica
-        computes on as jax reports it, and how many programs it has
-        compiled (a request shape that keeps compiling shows here)."""
+        phases and counts, the device this replica computes on as jax
+        reports it, and how many programs it has compiled (a request shape
+        that keeps compiling shows here)."""
         import jax
 
-        out = dict(self._stats)
-        if self._engine is not None:
-            out["kv"] = self._engine.kv_stats()
-            out["engine"] = self._engine.engine_stats()
+        out = dict(self._stats, kv=self._engine.kv_stats(),
+                   engine=self._engine.engine_stats())
         dev = jax.devices()[0]
         out["device"] = {"platform": dev.platform, "kind": dev.device_kind,
                          "count": len(jax.devices())}
         out["compile"] = self._compiles.snapshot()
         return out
-
-    # -- batched model call ---------------------------------------------------
-    def _run_batch(self, prompts: List[List[int]]) -> List[List[int]]:
-        """One prefill+decode for a batch of prompts. Shapes are bucketed:
-        batch padded to max_batch_size rows, prompt length to the next
-        pad_multiple — one compiled program per (bucket, steps), reused
-        across calls.
-
-        Rows shorter than the bucket are right-padded by repeating their
-        own final token. Equal-length batches (the common serving shape)
-        are exact; a shorter row in a mixed batch conditions on those
-        repeats — the standard padded-batch approximation (exact handling
-        would need per-row position masks through prefill)."""
-        import jax.numpy as jnp
-        import numpy as np
-
-        import jax
-
-        n = len(prompts)
-        lens = [len(p) for p in prompts]
-        s0 = max(lens)
-        bucket = ((s0 + self.pad_multiple - 1)
-                  // self.pad_multiple) * self.pad_multiple
-        bucket = min(bucket, self.cfg.max_seq - self.max_new_tokens)
-        B = self.max_batch_size
-        arr = np.ones((B, bucket), np.int32)  # dummy rows: token 1
-        for i, p in enumerate(prompts):
-            p = p[-bucket:]  # truncate over-long prompts from the left
-            arr[i, : len(p)] = p
-            if len(p) < bucket:
-                # right-pad with the row's final token: with causal
-                # attention the FINAL position's logits (which seed the
-                # decode) see the true prompt plus harmless repeats
-                arr[i, len(p):] = p[-1]
-        self._key, sub = jax.random.split(self._key)
-        out = self.gpt.generate(
-            self.params, self.cfg, jnp.asarray(arr),
-            steps=self.max_new_tokens, temperature=self.temperature,
-            key=sub)
-        out_np = np.asarray(out)
-        self._stats["requests"] += n
-        self._stats["batches"] += 1
-        self._stats["generated_tokens"] += n * self.max_new_tokens
-        return [out_np[i, bucket: bucket + self.max_new_tokens].tolist()
-                for i in range(n)]
 
 
 def llm_deployment(preset: str = "gpt2-small",
@@ -1023,5 +779,5 @@ def llm_deployment(preset: str = "gpt2-small",
     ).bind(preset=preset, **kwargs)
 
 
-__all__ = ["ContinuousBatcher", "DynamicBatcher", "LLMServer",
-           "llm_deployment", "pack_weights", "unpack_weights"]
+__all__ = ["ContinuousBatcher", "LLMServer", "llm_deployment",
+           "pack_weights", "unpack_weights"]

@@ -13,9 +13,7 @@ device→host readback as its completion barrier.
 Who holds the chip: ``train_step_mfu``, ``flash_attention_bench``,
 ``allreduce_busbw`` and ``rl_learner_bench`` compute in the calling process,
 which therefore holds the chip (call them from a ``num_tpus`` task, or from
-a driver that starts no chip-leasing worker). ``llm_serving_bench`` is the
-other layout: its caller must be OFF the chip, because the replica it
-starts leases it.
+a driver that starts no chip-leasing worker).
 """
 
 from __future__ import annotations
@@ -184,100 +182,6 @@ def flash_attention_bench(seq_lens=(1024, 4096, 8192), bh: int = 4,
         out[S] = {"flash_ms": flash_ms, "ref_ms": ref_ms,
                   "speedup": ref_ms / flash_ms}
     return out
-
-
-def llm_serving_bench(preset: str = "gpt2-small", n_requests: int = 32,
-                      prompt_len: int = 128, max_new_tokens: int = 64,
-                      max_batch_size: int = 8) -> Dict[str, float]:
-    """Decode goodput (REQUESTED tokens/s) through the FULL serve stack
-    on the chip: handle -> router -> replica (num_tpus=1 chip lease) ->
-    batching engine -> the KV-cached decode programs (serve/llm.py).
-    Runs BOTH batching modes over the same Poisson arrival schedule of a
-    MIXED workload (budgets alternate max_new_tokens and a quarter of
-    it) — "continuous" (decode-step join/leave, per-request budgets
-    honored, the default) vs the legacy "barrier" (whole-batch: every
-    request pays the full deployment budget and new arrivals park behind
-    the longest running batch) — and reports the speedup."""
-    import threading
-
-    import numpy as np
-
-    import ray_memory_management_tpu as rmt
-    from ray_memory_management_tpu import serve
-    from ray_memory_management_tpu.serve.llm import llm_deployment
-
-    rmt.init(num_cpus=4, num_tpus=1)
-    try:
-        out: Dict[str, float] = {}
-        prompt = list(range(2, 2 + prompt_len))
-        # Poisson arrivals at ~2x the barrier's drain rate so queueing
-        # pressure is real; same arrival schedule for both modes
-        rng = np.random.default_rng(0)
-        gaps = rng.exponential(0.05, n_requests)  # drawn ONCE: both
-        # mixed budgets: half the requests want a quarter the tokens
-        budgets = [max_new_tokens if i % 2 == 0 else
-                   max(1, max_new_tokens // 4)
-                   for i in range(n_requests)]
-        requested = sum(budgets)
-        for mode in ("continuous", "barrier"):    # modes see the same
-            # arrival schedule, so the ratio measures the batching
-            # mode, not arrival-pattern noise
-            serve.start(http_port=None)
-            handle = serve.run(llm_deployment(
-                preset, ray_actor_options={"num_tpus": 1},
-                max_new_tokens=max_new_tokens,
-                max_batch_size=max_batch_size,
-                batch_wait_timeout_s=0.02,
-                batching=mode))
-            # warm: compiles the decode programs on the chip
-            warm = rmt.get(handle.remote({"tokens": prompt}),
-                           timeout=900)
-            assert len(warm["tokens"]) == max_new_tokens
-
-            results: list = []
-
-            def one(budget):
-                r = rmt.get(handle.remote(
-                    {"tokens": prompt, "max_new_tokens": budget}),
-                    timeout=900)
-                results.append(len(r["tokens"]))
-
-            t0 = time.perf_counter()
-            threads = []
-            for i in range(n_requests):
-                th = threading.Thread(target=one, args=(budgets[i],))
-                th.start()
-                threads.append(th)
-                time.sleep(float(gaps[i]))
-            for th in threads:
-                th.join()
-            dt = time.perf_counter() - t0
-            assert len(results) == n_requests
-            # goodput: tokens the CLIENTS asked for per second
-            # (barrier mode over-generates for short requests; those
-            # surplus tokens are waste, not throughput)
-            key = ("decode_tokens_per_s" if mode == "continuous"
-                   else "decode_tokens_per_s_barrier")
-            out[key] = requested / dt
-            if mode == "continuous":
-                out["requests_per_s"] = n_requests / dt
-                try:
-                    stats = rmt.get(handle.stats.remote(), timeout=60)
-                    out["decode_steps"] = stats["batches"]
-                except Exception:
-                    pass
-            serve.shutdown()
-        if out.get("decode_tokens_per_s_barrier"):
-            out["continuous_vs_barrier"] = (
-                out["decode_tokens_per_s"]
-                / out["decode_tokens_per_s_barrier"])
-        return out
-    finally:
-        try:
-            serve.shutdown()
-        except Exception:
-            pass
-        rmt.shutdown()
 
 
 def rl_learner_bench(n_workers: int = 2, iters: int = 4,

@@ -26,6 +26,27 @@ import pytest  # noqa: E402
 import ray_memory_management_tpu as rmt  # noqa: E402
 
 
+@pytest.fixture(scope="session")
+def cpp_client_dir():
+    """The C++ client's directory with its Makefile's targets built (make
+    caches them). Two test files need them and xdist gives the files to
+    two workers at once: one ``make`` at a time, under a file lock, so that
+    neither links what the other is still writing."""
+    import fcntl
+    import subprocess
+
+    client_dir = os.path.join(os.path.dirname(os.path.abspath(rmt.__file__)),
+                              "native", "client")
+    with open(os.path.join(client_dir, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)  # released when it closes
+        try:
+            subprocess.run(["make", "-C", client_dir], check=True,
+                           capture_output=True, text=True, timeout=300)
+        except subprocess.CalledProcessError as e:  # pragma: no cover
+            pytest.fail(f"C++ client build failed:\n{e.stderr}")
+    return client_dir
+
+
 @pytest.fixture
 def rmt_start_regular():
     rt = rmt.init(num_cpus=4, ignore_reinit_error=True)

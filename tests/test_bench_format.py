@@ -39,7 +39,6 @@ def _bloated_inputs():
              "stats": {k: {"median": 1.0, "min": 0.5, "max": 2.0}
                        for k in range(20)}}
     tpu = {"train_mfu": 0.532, "train_tokens_per_s": 101786.0,
-           "serve_decode_tokens_per_s": 2345.6,
            "train_rows": {
                "llama-1b S=2048": {"tokens_per_s": 17356.0,
                                    "mfu": 0.4795},
@@ -64,7 +63,6 @@ def test_headline_line_stays_under_1kb(bench):
     assert line["tpu"]["train_mfu"] == 0.532
     assert line["tpu"]["llama1b_mfu"] == 0.4795
     assert line["tpu"]["flash_speedup_8192"] == 2.4
-    assert line["tpu"]["serve_decode_tokens_per_s"] == 2345.6
     assert line["tpu"]["device_kind"] == "TPU v5 lite"
     assert line["scale"]["many_actors_per_s"] == 86.54
     assert line["micro"]["single_client_tasks_async"] == 11912.5
@@ -667,61 +665,3 @@ def test_pod_curve_required_fields(bench):
     assert rows["total"] >= rows["target"] == 3000
     assert rows["cold"] > 0  # the hot cap engaged during the flood
     assert rows["rss_mb_at_rows"] > 0
-
-
-def test_headline_line_carries_serve_summary(bench):
-    results, stats, ratios, scale, tpu = _bloated_inputs()
-    serve = {"p99_ms": 41.7, "tokens_per_s_per_chip": 512.3,
-             "paged_slots_ratio": 4.0, "continuous_vs_barrier": 1.31,
-             "p50_ms": 18.2, "slo_violation_pct": 0.0}
-    payload = bench.headline_line(results, stats, ratios, 3.02, 11.56,
-                                  scale, tpu, serve=serve)
-    assert len(payload) <= 1000
-    line = json.loads(payload)
-    if "serve" in line:  # may be popped only by the <1KB guard
-        assert line["serve"]["p99_ms"] == 41.7
-        assert line["serve"]["paged_slots_ratio"] == 4.0
-        assert line["serve"]["continuous_vs_barrier"] == 1.31
-
-
-def test_headline_line_drops_errored_serve(bench):
-    results, stats, ratios, scale, tpu = _bloated_inputs()
-    payload = bench.headline_line(results, stats, ratios, 3.02, 11.56,
-                                  scale, tpu, serve={"error": "boom"})
-    assert "serve" not in json.loads(payload)
-
-
-def test_bench_detail_snapshot_has_serve_section(bench):
-    """An existing BENCH_DETAIL.json snapshot (written by a full bench
-    run) must carry the serve section with the required fields."""
-    path = os.path.join(os.path.dirname(_BENCH), "BENCH_DETAIL.json")
-    if not os.path.exists(path):
-        pytest.skip("no BENCH_DETAIL.json snapshot in repo")
-    with open(path) as f:
-        detail = json.load(f)
-    serve = detail.get("serve")
-    if serve is None:
-        pytest.skip("snapshot predates the serve section")
-    if "error" not in serve:
-        missing = [k for k in bench.REQUIRED_SERVE_FIELDS
-                   if k not in serve]
-        assert not missing, missing
-
-
-@pytest.mark.slow
-def test_serve_suite_required_fields(bench):
-    """A mini open-loop serve pass end-to-end (real handle -> p2c router
-    -> replica -> paged engine stack): every field the BENCH_DETAIL.json
-    contract names must be present, the paged engine must beat the
-    monolithic slab's slot count at equal HBM budget, and exhaustion
-    must surface as backpressure counts, not errors."""
-    from ray_memory_management_tpu.utils.serve_bench import run_serve_suite
-
-    out = run_serve_suite(mini=True)
-    missing = [k for k in bench.REQUIRED_SERVE_FIELDS if k not in out]
-    assert not missing, missing
-    assert out["paged_slots"] > out["slab_slots"]
-    assert out["paged_slots_ratio"] >= 1.5
-    assert out["continuous_tokens_per_s"] > 0
-    assert out["cold_start_shipped_s"] > 0
-    assert out["n_requests"] > 0

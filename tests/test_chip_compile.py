@@ -190,12 +190,12 @@ def test_paged_step_keeps_the_pool_where_it_is(one_chip, monkeypatch):
 # ------------------------------------------------- the dense decoder's prefill
 def _old_prefill_row(params, tokens, cfg, n_positions, true_len):
     """The prefill as it was before the kernel: a fresh row cache through
-    ``forward_with_cache_rows``, the head over every position."""
+    ``forward_with_cache``, the head over every position."""
     from ray_memory_management_tpu.models import gpt
 
     row_cache = gpt.init_kv_cache(cfg, 1, n_positions)
-    logits, row_cache = gpt.forward_with_cache_rows(
-        params, tokens, row_cache, jnp.zeros((1,), jnp.int32), cfg)
+    logits, row_cache = gpt.forward_with_cache(
+        params, tokens, row_cache, 0, cfg)
     return logits[0, true_len - 1], {k: c[:, 0] for k, c in row_cache.items()}
 
 

@@ -12,20 +12,10 @@ import pytest
 
 import ray_memory_management_tpu as rmt
 
-CLIENT_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "ray_memory_management_tpu", "native", "client")
-
 
 @pytest.fixture(scope="module")
-def rmt_demo_binary():
-    """Build the C++ client + demo via its Makefile (cached by make)."""
-    try:
-        subprocess.run(["make", "-C", CLIENT_DIR], check=True,
-                       capture_output=True, text=True, timeout=300)
-    except subprocess.CalledProcessError as e:  # pragma: no cover
-        pytest.fail(f"C++ client build failed:\n{e.stderr}")
-    return os.path.join(CLIENT_DIR, "rmt_demo")
+def rmt_demo_binary(cpp_client_dir):
+    return os.path.join(cpp_client_dir, "rmt_demo")
 
 
 class TestCppClient:

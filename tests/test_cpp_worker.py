@@ -15,19 +15,10 @@ import pytest
 import ray_memory_management_tpu as rmt
 from ray_memory_management_tpu.exceptions import TaskError
 
-CLIENT_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "ray_memory_management_tpu", "native", "client")
-
 
 @pytest.fixture(scope="module")
-def executor_binary():
-    try:
-        subprocess.run(["make", "-C", CLIENT_DIR], check=True,
-                       capture_output=True, text=True, timeout=300)
-    except subprocess.CalledProcessError as e:  # pragma: no cover
-        pytest.fail(f"C++ executor build failed:\n{e.stderr}")
-    return os.path.join(CLIENT_DIR, "rmt_executor_demo")
+def executor_binary(cpp_client_dir):
+    return os.path.join(cpp_client_dir, "rmt_executor_demo")
 
 
 def _wait_registered(name: str, timeout: float = 30.0) -> None:

@@ -355,7 +355,3 @@ def test_the_engine_serves_the_model_and_counts_live_rows_only(params):
         assert srv.stats()["kv"]["pages_in_use"] == 0
     finally:
         srv._engine.close()
-    with pytest.raises(ValueError, match="slab"):
-        LLMServer(config=CFG, kv_cache="slab")
-    with pytest.raises(ValueError, match="barrier"):
-        LLMServer(config=CFG, batching="barrier")

@@ -115,14 +115,21 @@ def test_resnet_trains():
     assert losses[-1] < losses[0]
 
 
-def test_cached_decode_matches_full_forward(small_lm):
+@pytest.mark.parametrize("n_kv_heads", [None, 2], ids=["mha", "gqa"])
+def test_cached_decode_matches_full_forward(small_lm, n_kv_heads):
     """forward_with_cache must reproduce forward's logits exactly: prefill
     logits == full-forward logits on the prompt, and each decode step's
     logits == full-forward logits at that position (VERDICT r1 weak 7 —
-    the old generate() recomputed the whole prefix per token)."""
+    the old generate() recomputed the whole prefix per token). With fewer
+    K/V heads than query heads too: the cache holds the K/V heads and the
+    oracle repeats them."""
     import numpy as np
 
     cfg, params = small_lm
+    if n_kv_heads is not None:
+        cfg = dataclasses.replace(cfg, n_kv_heads=n_kv_heads)
+        params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+        assert cfg.kv_heads < cfg.n_heads
     prompt = jax.random.randint(jax.random.PRNGKey(3), (2, 7), 0,
                                 cfg.vocab_size)
     T = 10

@@ -1,9 +1,9 @@
 """The dense decoder's prefill of one row (``models/gpt.py::prefill_row``:
 the prompt's attention through the flash forward kernel, each layer's K and V
 out of the layer loop, the head at the prompt's last token only) against the
-path it replaced: ``forward_with_cache_rows`` on a fresh row cache, which
-slab mode and ``generate()`` keep. CPU, toy GQA widths, float32: what is
-checked is the arithmetic and the dispatch, not a speed.
+path it replaced: ``forward_with_cache`` on a fresh row cache, which
+``generate()`` keeps. CPU, toy GQA widths, float32: what is checked is the
+arithmetic and the dispatch, not a speed.
 """
 
 import dataclasses
@@ -31,8 +31,8 @@ def params():
 
 def old_prefill_row(params, tokens, cfg, n_positions, true_len):
     row_cache = gpt.init_kv_cache(cfg, 1, n_positions)
-    logits, row_cache = gpt.forward_with_cache_rows(
-        params, tokens, row_cache, jnp.zeros((1,), jnp.int32), cfg)
+    logits, row_cache = gpt.forward_with_cache(
+        params, tokens, row_cache, 0, cfg)
     return logits[0, true_len - 1], {k: c[:, 0] for k, c in row_cache.items()}
 
 

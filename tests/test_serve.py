@@ -187,50 +187,6 @@ class TestHTTP:
 
 
 class TestLLMServing:
-    def test_dynamic_batcher_coalesces(self):
-        import threading
-
-        from ray_memory_management_tpu.serve.llm import DynamicBatcher
-
-        sizes = []
-
-        def fn(items):
-            sizes.append(len(items))
-            return [i * 10 for i in items]
-
-        b = DynamicBatcher(fn, max_batch_size=4, batch_wait_timeout_s=0.1)
-        try:
-            results = {}
-
-            def call(i):
-                results[i] = b.submit(i)
-
-            threads = [threading.Thread(target=call, args=(i,))
-                       for i in range(4)]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join(timeout=30)
-            assert results == {i: i * 10 for i in range(4)}
-            # 4 concurrent callers within one window -> ONE model call
-            assert max(sizes) >= 2, sizes
-        finally:
-            b.close()
-
-    def test_batcher_error_propagates(self):
-        from ray_memory_management_tpu.serve.llm import DynamicBatcher
-
-        def boom(items):
-            raise RuntimeError("model fell over")
-
-        b = DynamicBatcher(boom, max_batch_size=2,
-                           batch_wait_timeout_s=0.01)
-        try:
-            with pytest.raises(RuntimeError, match="fell over"):
-                b.submit(1)
-        finally:
-            b.close()
-
     def test_llm_deployment_end_to_end(self, serve_instance):
         """HTTP request -> batched KV-cached generate -> tokens back
         (tiny preset on CPU; the TPU path is the same program)."""
@@ -238,7 +194,6 @@ class TestLLMServing:
 
         serve.run(llm_deployment("test", max_new_tokens=4,
                                  max_batch_size=2,
-                                 batch_wait_timeout_s=0.005,
                                  pad_multiple=16))
         handle = serve.get_handle("LLM")
 
@@ -271,7 +226,6 @@ class TestLLMServing:
             port = start_proxy(_ctrl(), 0)
             h = serve.run(llm_deployment("test", max_new_tokens=3,
                                          max_batch_size=2,
-                                         batch_wait_timeout_s=0.005,
                                          pad_multiple=16))
             rmt.get(h.remote({"tokens": [1]}), timeout=300)  # warm compile
             req = rq.Request(
@@ -286,9 +240,8 @@ class TestLLMServing:
 
 class TestContinuousBatching:
     """Decode-step-granular scheduling (serve/llm.ContinuousBatcher):
-    join/leave at step granularity and EXACT mixed-length batches via
-    per-row positions (models/gpt.forward_with_cache_rows) — the two
-    properties the whole-batch DynamicBatcher path lacks."""
+    join/leave at step granularity, shared decode iterations and EXACT
+    mixed-length batches via per-row positions (models/gpt.paged_decode)."""
 
     @pytest.fixture(scope="class")
     def engine_setup(self):
@@ -321,9 +274,8 @@ class TestContinuousBatching:
 
     def test_mixed_length_batch_is_exact(self, engine_setup):
         """Two different-length prompts decoded CONCURRENTLY must each
-        equal their solo greedy decode — the padded-batch approximation
-        (a short row conditioning on its repeated final token) would
-        diverge here."""
+        equal their solo greedy decode: a short row that conditioned on
+        its padding would diverge here."""
         import threading
 
         import numpy as np
@@ -357,8 +309,7 @@ class TestContinuousBatching:
     def test_short_request_completes_while_long_mid_decode(
             self, engine_setup):
         """Step-granular leave: a 1-token request submitted AFTER a
-        96-token request must finish first (the barrier design would park
-        it behind the whole batch)."""
+        96-token request must finish first, not park behind it."""
         import threading
         import time as _time
 
@@ -434,10 +385,65 @@ class TestContinuousBatching:
 
         srv = LLMServer(preset="test", max_new_tokens=4, max_batch_size=2,
                         pad_multiple=16)
-        assert srv.batching == "continuous"
         out = srv({"tokens": [5, 6, 7]})
         assert len(out["tokens"]) == 4
         # per-request budget honored
         out1 = srv({"tokens": [5, 6, 7], "max_new_tokens": 1})
         assert len(out1["tokens"]) == 1
         srv._engine.close()
+
+    def test_concurrent_callers_share_decode_iterations(self, engine_setup):
+        """Four callers at once decode in the SAME iterations: the engine
+        runs about as many for the four as for one of them alone, nowhere
+        near four times as many, and each answer is still its own."""
+        import threading
+
+        import numpy as np
+
+        from ray_memory_management_tpu.serve.llm import ContinuousBatcher
+
+        gpt, cfg, params, _ = engine_setup
+        eng = ContinuousBatcher(params, cfg, max_slots=4, max_new_tokens=32,
+                                pad_multiple=8, steps_per_iter=4)
+        try:
+            prompts = [[5 + i, 9, 17, 3] for i in range(4)]
+            solo = eng.submit(prompts[0])  # also compiles both programs
+            alone = eng.engine_stats()["iterations"]
+            assert alone == 8  # 31 tokens after the prefill's, 4 a time
+            res = [None] * 4
+
+            def go(i):
+                res[i] = eng.submit(prompts[i])
+
+            ts = [threading.Thread(target=go, args=(i,)) for i in range(4)]
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(300)
+            together = eng.engine_stats()["iterations"] - alone
+            assert res[0] == solo
+            for i in range(4):
+                ref = np.asarray(gpt.generate(
+                    params, cfg, np.asarray([prompts[i]], np.int32),
+                    steps=32))
+                assert res[i] == ref[0, len(prompts[i]):].tolist(), i
+            assert together < 2 * alone, (together, alone)
+        finally:
+            eng.close()
+
+    @pytest.mark.parametrize("knob,value", [
+        ("batching", "barrier"), ("kv_cache", "slab"),
+        ("batch_wait_timeout_s", 0.01)])
+    def test_deleted_knobs_are_refused(self, engine_setup, knob, value):
+        """One engine, one cache layout: the options that chose between
+        them are gone from both constructors, not silently accepted."""
+        from ray_memory_management_tpu.serve.llm import (
+            ContinuousBatcher,
+            LLMServer,
+        )
+
+        _, cfg, params, _ = engine_setup
+        with pytest.raises(TypeError, match=knob):
+            LLMServer(preset="test", **{knob: value})
+        with pytest.raises(TypeError, match=knob):
+            ContinuousBatcher(params, cfg, **{knob: value})

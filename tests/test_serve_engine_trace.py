@@ -34,12 +34,11 @@ def model():
     return cfg, gpt.init_params(jax.random.PRNGKey(0), cfg)
 
 
-def engine(model, mode):
+def engine(model):
     cfg, params = model
     return ContinuousBatcher(
         params, cfg, max_slots=MAX_SLOTS, max_new_tokens=24,
-        pad_multiple=PAGE, steps_per_iter=4, kv_cache=mode,
-        kv_page_tokens=PAGE)
+        pad_multiple=PAGE, steps_per_iter=4, kv_page_tokens=PAGE)
 
 
 def wave(eng, n=12):
@@ -70,9 +69,8 @@ def close(eng):
 
 
 # ------------------------------------------------------------- (a) accounting
-@pytest.mark.parametrize("mode", ["paged", "slab"])
-def test_phases_add_up_to_the_engine_threads_wall_time(model, mode):
-    eng = engine(model, mode)
+def test_phases_add_up_to_the_engine_threads_wall_time(model):
+    eng = engine(model)
     t0 = time.perf_counter()  # the constructor started the thread
     try:
         while eng.engine_stats()["iterations"] < 50:
@@ -95,10 +93,9 @@ def test_phases_add_up_to_the_engine_threads_wall_time(model, mode):
     assert all(q >= 0 and p > 0 for q, p in st["recent"])
 
 
-@pytest.mark.parametrize("mode", ["paged", "slab"])
-def test_counts_only_grow_and_read_whole_from_eight_threads(model, mode):
+def test_counts_only_grow_and_read_whole_from_eight_threads(model):
     cfg, _ = model
-    eng = engine(model, mode)
+    eng = engine(model)
     stop, errors, reads = threading.Event(), [], [0] * 8
 
     def reader(k):
@@ -121,13 +118,10 @@ def test_counts_only_grow_and_read_whole_from_eight_threads(model, mode):
                 # are never seen apart
                 assert len(st["recent"]) == min(st["admitted"], 512)
                 assert st["live_positions"] <= st["slab_positions"]
-                whole = st["iterations"] * MAX_SLOTS * cfg.max_seq
-                if mode == "slab":  # every row's whole cache, each time
-                    assert st["slab_positions"] == whole
-                else:  # the live rows' pages up to their last step's length
-                    assert st["slab_positions"] % PAGE == 0
-                    assert st["iterations"] * PAGE <= st[
-                        "slab_positions"] <= whole
+                # the live rows' pages up to their last step's length
+                assert st["slab_positions"] % PAGE == 0
+                assert st["iterations"] * PAGE <= st["slab_positions"] <= (
+                    st["iterations"] * MAX_SLOTS * cfg.max_seq)
                 st["phase_s"].clear()  # the caller's own copy
                 last = eng.engine_stats()
         except BaseException as e:  # noqa: BLE001 — reported below
@@ -153,19 +147,16 @@ def test_counts_only_grow_and_read_whole_from_eight_threads(model, mode):
     assert st["admitted"] == 24 and st["iterations"] > 0
 
 
-@pytest.mark.parametrize("mode,attention", [
-    ("paged", "auto"), ("paged", "flash-interpret"), ("slab", "auto")])
-def test_prefill_positions_grow_by_the_bucket_an_admission(model, mode,
-                                                           attention):
+@pytest.mark.parametrize("attention", ["auto", "flash-interpret"])
+def test_prefill_positions_grow_by_the_bucket_an_admission(model, attention):
     """``prefill_positions`` adds each admission's bucket length, and
     ``prefill_kernel_positions`` those of buckets whose program holds the
     flash kernel: none off the TPU, all where the configuration asks for
-    the kernel under the interpreter by name (paged mode's prefill)."""
+    the kernel under the interpreter by name."""
     import dataclasses
 
     cfg, params = model
-    eng = engine((dataclasses.replace(cfg, attention=attention), params),
-                 mode)
+    eng = engine((dataclasses.replace(cfg, attention=attention), params))
     try:
         seen = []
 
@@ -269,7 +260,7 @@ def test_a_request_carries_its_trace_into_the_engine(traced_request, span):
 
 def test_a_request_without_a_context_records_spans_without_ids(model):
     timeline.clear()
-    eng = engine(model, "paged")
+    eng = engine(model)
     try:
         assert tracing.get_current() is None
         eng.submit([3, 4, 5], max_new_tokens=5, timeout=120)
@@ -291,7 +282,7 @@ def engine_line(model, tmp_path_factory):
     from jax.profiler import ProfileData
 
     logdir = str(tmp_path_factory.mktemp("xprof"))
-    eng = engine(model, "paged")
+    eng = engine(model)
     try:
         wave(eng, 6)  # compile outside the capture
         with profiling.xprof_trace(logdir):

@@ -51,7 +51,7 @@ def engine_setup():
 def _stopped_engine(llm_mod, params, cfg, **kw):
     """An engine whose thread has exited: the test drives admit, step and
     retire itself, so the assertions bracket them deterministically."""
-    eng = llm_mod.ContinuousBatcher(params, cfg, kv_cache="paged", **kw)
+    eng = llm_mod.ContinuousBatcher(params, cfg, **kw)
     eng.close()
     eng._thread.join(30)
     assert not eng._thread.is_alive()
@@ -276,8 +276,7 @@ class TestPagedKV:
         pool_bytes = 2 * 16 * row_token_bytes(cfg)
         eng = ContinuousBatcher(
             params, cfg, max_slots=4, max_new_tokens=8, pad_multiple=8,
-            steps_per_iter=4, kv_cache="paged", kv_page_tokens=16,
-            kv_pool_bytes=pool_bytes)
+            steps_per_iter=4, kv_page_tokens=16, kv_pool_bytes=pool_bytes)
         try:
             prompts = [[2 + i, 5, 7, 11] for i in range(6)]
             res = [None] * 6
@@ -310,11 +309,119 @@ class TestPagedKV:
         gpt, cfg, params = engine_setup
         eng = ContinuousBatcher(
             params, cfg, max_slots=2, max_new_tokens=8, pad_multiple=8,
-            kv_cache="paged", kv_page_tokens=16,
+            kv_page_tokens=16,
             kv_pool_bytes=16 * row_token_bytes(cfg))  # one page total
         try:
             with pytest.raises(RuntimeError, match="pool capacity"):
                 eng.submit(list(range(2, 32)), timeout=30)
+        finally:
+            eng.close()
+
+    @staticmethod
+    def _resident_and_queued(eng, prompts, budget):
+        """Two callers on a one-slot engine, parked in ``submit``; returns
+        their threads and what each raised, once the first is in the slot
+        and the second in the queue."""
+        raised = [None, None]
+
+        def go(i):
+            try:
+                eng.submit(prompts[i], max_new_tokens=budget, timeout=300)
+            except BaseException as e:  # noqa: BLE001 (the test reads it)
+                raised[i] = e
+
+        ts = [threading.Thread(target=go, args=(i,)) for i in range(2)]
+        ts[0].start()
+        deadline = time.time() + 120
+        while eng._slot_pending[0] is None and time.time() < deadline:
+            time.sleep(0.005)
+        ts[1].start()
+        while not eng._q and time.time() < deadline:
+            time.sleep(0.005)
+        assert eng._slot_pending[0] is not None and len(eng._q) == 1
+        return ts, raised
+
+    def test_failed_decode_program_fails_all_and_the_engine_recovers(
+            self, engine_setup):
+        """The decode program raises once: the resident caller and the
+        queued one both get that error, every page goes back, the pool's
+        arrays are dropped (a failed program may have consumed them), and
+        the next request is token-exact on a pool allocated anew."""
+        import numpy as np
+
+        from ray_memory_management_tpu.serve.llm import ContinuousBatcher
+
+        gpt, cfg, params = engine_setup
+        eng = ContinuousBatcher(params, cfg, max_slots=1, max_new_tokens=8,
+                                pad_multiple=8, steps_per_iter=4,
+                                kv_page_tokens=16)
+        step, calls, go_on = eng._paged_step, [], threading.Event()
+
+        def falls_over_once(*args):
+            calls.append(1)
+            if len(calls) == 1:
+                go_on.wait(120)  # until the second caller is queued
+                raise RuntimeError("decode program fell over")
+            return step(*args)
+
+        eng._paged_step = falls_over_once
+        try:
+            prompts = [[5, 9, 17, 3], [2, 4, 6, 8, 10]]
+            ts, raised = self._resident_and_queued(eng, prompts, 8)
+            assert eng.kv_pool.pages_in_use > 0
+            go_on.set()
+            for t in ts:
+                t.join(60)
+            assert not any(t.is_alive() for t in ts)
+            for e in raised:
+                assert isinstance(e, RuntimeError)
+                assert "decode program fell over" in str(e)
+            assert eng.kv_pool.pages_in_use == 0 and eng._pool is None
+            assert eng._thread.is_alive()  # it keeps serving
+            out = eng.submit(prompts[1], timeout=120)
+            ref = np.asarray(gpt.generate(
+                params, cfg, np.asarray([prompts[1]], np.int32), steps=8))
+            assert out == ref[0, len(prompts[1]):].tolist()
+            assert eng.kv_pool.pages_in_use == 0
+        finally:
+            eng.close()
+
+    def test_close_fails_resident_and_queued_callers_promptly(
+            self, engine_setup):
+        """``close()`` while one request decodes and one waits: both get
+        "engine closed" within seconds of it, not after their submit
+        timeout of 300 s, the engine thread exits, and no page stays
+        reserved."""
+        from ray_memory_management_tpu.serve.llm import ContinuousBatcher
+
+        gpt, cfg, params = engine_setup
+        eng = ContinuousBatcher(params, cfg, max_slots=1,
+                                max_new_tokens=100, pad_multiple=8,
+                                steps_per_iter=1, kv_page_tokens=16)
+        step = eng._paged_step
+
+        def slow_step(*args):  # 99 of them: resident for five seconds
+            time.sleep(0.05)
+            return step(*args)
+
+        eng._paged_step = slow_step
+        try:
+            ts, raised = self._resident_and_queued(
+                eng, [[5, 9, 17, 3], [2, 4, 6, 8, 10]], 100)
+            t0 = time.monotonic()
+            eng.close()
+            for t in ts:
+                t.join(30)
+            eng._thread.join(30)
+            assert time.monotonic() - t0 < 30
+            assert not any(t.is_alive() for t in ts)
+            assert not eng._thread.is_alive()
+            for e in raised:
+                assert isinstance(e, RuntimeError)
+                assert "engine closed" in str(e)
+            assert eng.kv_pool.pages_in_use == 0 and eng._pool is None
+            with pytest.raises(RuntimeError, match="engine closed"):
+                eng.submit([1, 2, 3])
         finally:
             eng.close()
 
