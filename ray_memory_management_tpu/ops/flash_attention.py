@@ -3,23 +3,48 @@ pure-jnp reference.
 
 Net-new versus the reference (SURVEY.md §2.4: the reference has NO attention
 kernels — GPU attention lives inside user torch code). Here the hot op is a
-first-class TPU kernel:
+first-class TPU kernel; three ``pallas_call``s over [BH, S, D] operands:
 
-  - forward: online-softmax blockwise attention. Grid is (BH, n_q, n_k): the
-    K/V sequence streams through VMEM one (block_k, D) tile per grid step —
-    VMEM stays O(block), so S is bounded by HBM, not VMEM. Running max /
-    denominator / output accumulate in VMEM scratch across the innermost
-    grid dimension; the logsumexp is saved for the backward in a (BH, S, 1)
-    layout — blocks of (1, block_q, 1) are legal on TPU because the last
-    block dim equals the array dim, so the per-row vector costs S fp32
-    words, not a lane-replicated tile.
-  - backward: two Pallas kernels, both O(block) VMEM: a dq kernel on grid
-    (BH, n_q, n_k) and a dk/dv kernel on grid (BH, n_k, n_q), each
-    recomputing the p tile from q, k and the saved lse (rematerialisation:
-    trades one extra QK^T matmul for never materialising the S×S matrix —
-    training memory is O(S·D), not O(S²)).
-  - causal masking skips fully-masked tiles via pl.when on both passes, so
-    the causal schedule does ~half the tile work.
+  - forward: online-softmax blockwise attention, grid (BH, n_q, groups of
+    K/V blocks); returns the output and, for the backward, the logsumexp
+    as a (BH, S, 1) column.
+  - backward: a dq kernel on the same grid and a dk/dv kernel on grid
+    (BH, n_k, groups of q/dO blocks), each recomputing the p tile from q, k
+    and the saved lse (rematerialisation: one extra QK^T product for never
+    materialising the S×S matrix — training memory is O(S·D), not O(S²)).
+    The dk/dv kernel holds its tile transposed (s^T = k q^T), so dv += p^T dO
+    and dk += ds^T q are plain products and nothing the size of a tile is
+    transposed; lse and delta reach it as rows.
+
+What a score tile costs around its MXU products (measured on a v5e,
+PERF.md §6, PR 32):
+
+  - operands reach the MXU in the dtype they arrive in, with float32
+    accumulation; the second operands the kernels make themselves (p, ds)
+    are cast to that dtype before their product, which is what the MXU did
+    to float32 operands anyway. float32 inputs stay float32 throughout.
+    ``scale`` is folded into the resident (block, D) tile (q, or k in the
+    dk/dv kernel) and into dq / dk at the end, never into a score tile.
+    Softmax statistics, accumulators, lse and delta are float32.
+  - the streamed operand pair (K and V; q and dO for dk/dv) is resident a
+    group of blocks at a time (``_pick_group``: as many as fit
+    ``_STREAM_BYTES``, the whole head at the lengths served and trained),
+    seen as [BH, n_blocks, block, D], and a ``fori_loop`` inside the grid
+    step walks the blocks by index: first those wholly below the diagonal,
+    with no mask built, then the ones the diagonal crosses, where alone the
+    two iotas, the compare and the select are made. Blocks wholly above
+    the diagonal are never touched. Where a head needs more than one group,
+    the index map clamps a step past the diagonal to the group already
+    resident, so it fetches nothing.
+  - the forward keeps the running max in all 128 lanes and the denominator
+    as 128 lane partial sums a row: the row max is the one reduction across
+    lanes a tile costs, the sum's waits for the last step, and no per-row
+    value is broadcast across lanes inside the loop.
+  - tile shape: ``_pick_block`` (the largest divisor of the length up to
+    512 rows, from S and Skv alone) and ``_pick_group`` (from the block's
+    bytes: D and dtype). No flag, environment variable or model decides it.
+  - the calls are jitted (``_fwd_call``, ``_bwd_calls``), so a program of
+    many layers traces and lowers each kernel once.
   - CPU/testing: the same kernels run under interpret mode; tests compare
     against the jnp reference on a virtual device.
 """
@@ -38,14 +63,19 @@ DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 
 
+def _largest_divisor(n: int, at_most: int) -> int:
+    d = max(1, min(n, at_most))
+    while n % d:
+        d -= 1
+    return d
+
+
 def _pick_block(n: int, target: int, interpret: bool) -> int:
     """Largest divisor of n that is <= target. The compiled TPU lowering
     takes a block whose row count is a multiple of 8 or the whole
     dimension; a length whose largest divisor is neither is refused here,
     by name, rather than in the compiler."""
-    b = min(n, target)
-    while n % b:
-        b -= 1
+    b = _largest_divisor(n, target)
     if not interpret and b % 8 and b != n:
         raise ValueError(
             f"flash attention cannot tile a sequence of length {n} for the "
@@ -71,60 +101,187 @@ def reference_attention(q, k, v, causal: bool = True,
     return jnp.einsum("...qk,...kd->...qd", p.astype(v.dtype), v)
 
 
-def _causal_mask(s, qi, ki, block_q, block_k, off):
-    """Mask the (block_q, block_k) score tile: col <= row + off survives
-    (off = Skv - S supports cross/prefix attention like the reference)."""
-    rows = qi * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0) + off
-    cols = ki * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(cols <= rows, s, _NEG_INF)
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+_LANES = 128
+# what one grid step may hold of the streamed operand pair (K and V, or q
+# and dO); the pipeline keeps two such buffers
+_STREAM_BYTES = 4 << 20
+_VMEM_LIMIT_BYTES = 64 << 20
+
+
+def _pick_group(n_blocks: int, pair_bytes: int, stream_bytes: int) -> int:
+    """How many blocks of the streamed pair one grid step holds: the largest
+    divisor of n_blocks whose pair fits stream_bytes (at least one)."""
+    return _largest_divisor(n_blocks, stream_bytes // pair_bytes)
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _causal_mask(s, limit, q_axis):
+    """Mask a score tile the diagonal crosses: key position - query
+    position <= limit survives, both counted from the tile's corner (limit
+    = first query row + off - first key column, a scalar). ``q_axis`` is
+    the tile's query axis: 0 for (block_q, block_k), 1 for the transposed
+    tile of the dk/dv kernel."""
+    d = (lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+         - lax.broadcasted_iota(jnp.int32, s.shape, q_axis))
+    return jnp.where(d <= limit, s, _NEG_INF)
+
+
+def _div_from_zero(x, d):
+    """max(x, 0) // d for a traced scalar: every caller clips the quotient
+    to a range that starts at 0, and truncating division is floor there."""
+    return lax.div(jnp.maximum(x, 0), d)
+
+
+def _key_slices(reach, block_q, block_k, group, causal):
+    """Of a step's ``group`` resident key slices, for a tile of block_q
+    query rows whose first sees ``reach`` columns past the step's first:
+    (how many lie wholly at or below the diagonal, how many it sees at
+    all). Without a mask all of them are the first kind."""
+    if not causal:
+        return group, group
+    return (jnp.minimum(_div_from_zero(reach + 1, block_k), group),
+            jnp.minimum(_div_from_zero(reach + block_q - 1 + block_k, block_k),
+                        group))
+
+
+def _key_group_index(causal, block_q, off, span):
+    """Index map of the K and V groups on grid (bh, qi, kj): a step past
+    the diagonal names the group already resident, so nothing is fetched
+    for it."""
+    def index(bh, qi, kj):
+        if causal:
+            kj = jnp.minimum(
+                kj, jnp.maximum(qi * block_q + block_q - 1 + off, 0) // span)
+        return (bh, kj, 0, 0)
+
+    return index
+
+
+def _lanes(x, n):
+    """A per-row value kept in all 128 lanes, (rows, 128), at the width of
+    an (rows, n) tile it is combined with: whole vregs side by side where n
+    is a multiple of 128, so that nothing is broadcast across lanes in the
+    loop."""
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    if n < _LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _lane_sums(p):
+    """Row sums of p left as 128 partial sums a row (plain adds of whole
+    vregs); the one reduction across lanes waits for the kernel's end. A
+    width that is no multiple of 128 puts its sum in lane 0."""
+    rows, n = p.shape
+    if n % _LANES == 0:
+        part = p[:, :_LANES]
+        for c in range(1, n // _LANES):
+            part = part + p[:, c * _LANES:(c + 1) * _LANES]
+        return part
+    lane = lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+    return jnp.where(lane == 0, jnp.sum(p, axis=-1, keepdims=True), 0.0)
+
+
+def _each_slice(tile, lo, mid, hi, masked_first):
+    """Run ``tile(j, masked)`` over the resident slices lo..hi: the ones
+    the diagonal crosses (masked) on one side of ``mid``, the ones wholly
+    below it on the other, two to a loop step, so that one slice's
+    products can be scheduled beside the other's vector work."""
+
+    def loop(a, b, masked, width):
+        def body(i, carry):
+            for j in range(width):
+                tile(a + width * i + j, masked)
+            return carry
+
+        steps = lax.div(b - a, width)
+        lax.fori_loop(0, steps, body, 0)
+        return a + width * steps
+
+    def unmasked(a, b):
+        loop(loop(a, b, False, 2), b, False, 1)
+
+    if masked_first:
+        loop(lo, mid, True, 1)
+        unmasked(mid, hi)
+    else:
+        unmasked(lo, mid)
+        loop(mid, hi, True, 1)
 
 
 # --------------------------------------------------------------------- forward
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, causal, scale, block_q, block_k, off):
+                *, causal, scale, off):
     import jax.experimental.pallas as pl
 
+    block_q = q_ref.shape[1]
+    group, block_k = k_ref.shape[1:3]
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    n_k = pl.num_programs(2)
+    kj = pl.program_id(2)
 
-    @pl.when(ki == 0)
+    @pl.when(kj == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # a tile is fully masked iff its smallest col exceeds its largest row+off
-    run_pred = (ki * block_k <= qi * block_q + (block_q - 1) + off
-                if causal else True)
+    q = q_ref[0] * scale
+    # the last key column the tile's first query row sees, counted from the
+    # first column resident in this step
+    reach = qi * block_q + off - kj * (group * block_k)
 
-    @pl.when(run_pred)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, off)
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
+    def tile(j, masked):
+        k = k_ref[0, j]
+        v = v_ref[0, j]
+        s = _dot(q, k, _NT)
+        if masked:
+            s = _causal_mask(s, reach - j * block_k, 0)
+        # m in every lane and l as lane partial sums, both (block_q, 128):
+        # the row max is the one reduction across lanes a slice costs
+        m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        p = jnp.exp(s - _lanes(m_new, block_k))
+        l_scr[...] = l_scr[...] * alpha + _lane_sums(p)
+        acc_scr[...] = acc_scr[...] * _lanes(alpha, acc_scr.shape[1]) \
+            + _dot(p.astype(v.dtype), v, _NN)
+        m_scr[...] = m_new
 
-    @pl.when(ki == n_k - 1)
+    # slices wholly at or below the diagonal, then those it crosses; the
+    # ones wholly above it are never touched
+    n_full, n_run = _key_slices(reach, block_q, block_k, group, causal)
+    _each_slice(tile, 0, n_full, n_run, masked_first=False)
+
+    @pl.when(kj == pl.num_programs(2) - 1)
     def _finish():
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
+        l = jnp.maximum(jnp.sum(l_scr[...], axis=-1, keepdims=True), 1e-30)
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
         if lse_ref is not None:
             lse_ref[0] = m_scr[:, :1] + jnp.log(l)
+
+
+def _blocked(x, block):
+    """[BH, S, D] seen as [BH, S // block, block, D]: a grid step holds a
+    group of blocks and the kernel takes one by its index, so no slice of a
+    ref ever starts at a row the tiling cannot take."""
+    BH, S, D = x.shape
+    return x.reshape(BH, S // block, block, D)
+
+
+def _compiler_params(interpret):
+    if interpret:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT_BYTES)}
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
@@ -132,6 +289,16 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     """Returns (out, lse) when save_lse else out; lse is (BH, S, 1) fp32.
     Inference callers pass save_lse=False so the kernel never writes the
     lse array (pallas outputs are not dead-code-eliminated)."""
+    return _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
+                     save_lse, _STREAM_BYTES)
+
+
+# jitted with everything but the arrays static, so that a program's layers
+# trace and lower each kernel once and not once a layer (a train step holds
+# 24 calls of three kernels)
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret, save_lse,
+              stream_bytes):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -140,131 +307,141 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     off = Skv - S
     block_q = _pick_block(S, block_q, interpret)
     block_k = _pick_block(Skv, block_k, interpret)
-    grid = (BH, S // block_q, Skv // block_k)
+    n_k = Skv // block_k
+    group = _pick_group(n_k, 2 * block_k * D * k.dtype.itemsize,
+                        stream_bytes)
+    grid = (BH, S // block_q, n_k // group)
     kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale,
-                               block_q=block_q, block_k=block_k, off=off)
+                               off=off)
     if not save_lse:
         def kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
                    _inner=kernel):
             _inner(q_ref, k_ref, v_ref, o_ref, None, m_scr, l_scr, acc_scr)
+
+    kv_index = _key_group_index(causal, block_q, off, group * block_k)
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
-    out_specs = [pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0))]
+    out_specs = [pl.BlockSpec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0))]
     if save_lse:
         out_shape.append(jax.ShapeDtypeStruct((BH, S, 1), jnp.float32))
         out_specs.append(
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)))
+            pl.BlockSpec((1, block_q, 1), lambda bh, qi, kj: (bh, qi, 0)))
     res = pl.pallas_call(
         kernel,
         out_shape=tuple(out_shape),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
+            pl.BlockSpec((1, group, block_k, D), kv_index),
+            pl.BlockSpec((1, group, block_k, D), kv_index),
         ],
         out_specs=tuple(out_specs),
         scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+        **_compiler_params(interpret),
+    )(q, _blocked(k, block_k), _blocked(v, block_k))
     return res if save_lse else res[0]
 
 
 # -------------------------------------------------------------------- backward
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, causal, scale, block_q, block_k, off):
+               dq_scr, *, causal, scale, off):
     import jax.experimental.pallas as pl
 
+    block_q = q_ref.shape[1]
+    group, block_k = k_ref.shape[1:3]
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    n_k = pl.num_programs(2)
+    kj = pl.program_id(2)
 
-    @pl.when(ki == 0)
+    @pl.when(kj == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    run_pred = (ki * block_k <= qi * block_q + (block_q - 1) + off
-                if causal else True)
+    q = q_ref[0] * scale
+    do = do_ref[0]
+    lse = lse_ref[0]
+    delta = delta_ref[0]
+    reach = qi * block_q + off - kj * (group * block_k)
 
-    @pl.when(run_pred)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, off)
+    def tile(j, masked):
+        k = k_ref[0, j]
+        v = v_ref[0, j]
+        s = _dot(q, k, _NT)
+        if masked:
+            s = _causal_mask(s, reach - j * block_k, 0)
         p = jnp.exp(s - lse)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dq_scr[...] += lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        ds = p * (_dot(do, v, _NT) - delta)
+        dq_scr[...] += _dot(ds.astype(k.dtype), k, _NN)
 
-    @pl.when(ki == n_k - 1)
+    n_full, n_run = _key_slices(reach, block_q, block_k, group, causal)
+    _each_slice(tile, 0, n_full, n_run, masked_first=False)
+
+    @pl.when(kj == pl.num_programs(2) - 1)
     def _finish():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr,
-                *, causal, scale, block_q, block_k, off):
+                dk_ref, dv_ref, dk_scr, dv_scr, *, causal, scale, off):
+    """The tile is held transposed, (block_k, block_q): s^T = k q^T, so that
+    dv += p^T dO and dk += ds^T q are plain products and nothing the size
+    of the tile is transposed; lse and delta come as rows for it."""
     import jax.experimental.pallas as pl
 
+    block_k = k_ref.shape[1]
+    group, block_q = q_ref.shape[1:3]
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    n_q = pl.num_programs(2)
+    qj = pl.program_id(2)
 
-    @pl.when(qi == 0)
+    @pl.when(qj == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    # fully masked iff the tile's largest row+off is below its smallest col
-    run_pred = (qi * block_q + (block_q - 1) + off >= ki * block_k
-                if causal else True)
+    k = k_ref[0] * scale
+    v = v_ref[0]
+    # the first query row resident in this step sees key columns up to
+    # reach, counted from the tile's first column
+    reach = qj * (group * block_q) + off - ki * block_k
 
-    @pl.when(run_pred)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, off)
-        p = jnp.exp(s - lse)  # (block_q, block_k)
-        # dv += p^T @ do; dk += ds^T @ q — contract over the q rows so no
-        # explicit transpose materialises
-        dv_scr[...] += lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dk_scr[...] += lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def tile(j, masked):
+        q = q_ref[0, j]
+        do = do_ref[0, j]
+        st = _dot(k, q, _NT)
+        if masked:
+            st = _causal_mask(st, reach + j * block_q, 1)
+        pt = jnp.exp(st - lse_ref[0, j])
+        dv_scr[...] += _dot(pt.astype(do.dtype), do, _NN)
+        dst = pt * (_dot(v, do, _NT) - delta_ref[0, j])
+        dk_scr[...] += _dot(dst.astype(q.dtype), q, _NN)
 
-    @pl.when(qi == n_q - 1)
+    j_run = j_full = 0
+    if causal:
+        # slices wholly above the diagonal are never touched; then the
+        # ones it crosses, then those wholly below it
+        j_run = jnp.minimum(_div_from_zero(-reach, block_q), group)
+        j_full = jnp.clip(_div_from_zero(block_k - 1 - reach + block_q - 1,
+                                    block_q), j_run, group)
+    _each_slice(tile, j_run, j_full, group, masked_first=True)
+
+    @pl.when(qj == pl.num_programs(2) - 1)
     def _finish():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd(q, k, v, o, lse, g, causal, scale, block_q, block_k,
                interpret):
+    return _bwd_calls(q, k, v, o, lse, g, causal, scale, block_q, block_k,
+                      interpret, _STREAM_BYTES)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+def _bwd_calls(q, k, v, o, lse, g, causal, scale, block_q, block_k,
+               interpret, stream_bytes):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -273,55 +450,59 @@ def _flash_bwd(q, k, v, o, lse, g, causal, scale, block_q, block_k,
     off = Skv - S
     block_q = _pick_block(S, block_q, interpret)
     block_k = _pick_block(Skv, block_k, interpret)
+    n_q, n_k = S // block_q, Skv // block_k
     # delta_i = rowsum(dO_i * O_i) — tiny elementwise pass XLA fuses
-    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)[..., None]  # (BH, S, 1)
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
 
+    k_group = _pick_group(n_k, 2 * block_k * D * k.dtype.itemsize,
+                          stream_bytes)
+    kv_index = _key_group_index(causal, block_q, off, k_group * block_k)
+    q_tile = pl.BlockSpec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0))
+    q_column = pl.BlockSpec((1, block_q, 1), lambda bh, qi, kj: (bh, qi, 0))
+    kv_group = pl.BlockSpec((1, k_group, block_k, D), kv_index)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, scale=scale,
-                          block_q=block_q, block_k=block_k, off=off),
+        functools.partial(_dq_kernel, causal=causal, scale=scale, off=off),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        grid=(BH, S // block_q, Skv // block_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D),
-                               lambda bh, qi, ki: (bh, qi, 0)),
+        grid=(BH, n_q, n_k // k_group),
+        in_specs=[q_tile, kv_group, kv_group, q_tile, q_column, q_column],
+        out_specs=q_tile,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, g, lse, delta)
+        **_compiler_params(interpret),
+    )(q, _blocked(k, block_k), _blocked(v, block_k), g, lse,
+      delta[..., None])
 
+    q_group = _pick_group(n_q, 2 * block_q * D * q.dtype.itemsize,
+                          stream_bytes)
+    q_span = q_group * block_q
+
+    def q_index(bh, ki, qj):
+        if causal:  # a step before the diagonal names the first group needed
+            qj = jnp.maximum(
+                qj, jnp.minimum(jnp.maximum(ki * block_k - off, 0) // q_span,
+                                n_q // q_group - 1))
+        return (bh, qj, 0, 0)
+
+    k_tile = pl.BlockSpec((1, block_k, D), lambda bh, ki, qj: (bh, ki, 0))
+    q_rows = pl.BlockSpec((1, q_group, block_q, D), q_index)
+    q_row = pl.BlockSpec((1, q_group, 1, block_q), q_index)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, scale=scale,
-                          block_q=block_q, block_k=block_k, off=off),
+        functools.partial(_dkv_kernel, causal=causal, scale=scale, off=off),
         out_shape=(
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ),
-        grid=(BH, Skv // block_k, S // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, ki, qi: (bh, qi, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-        ),
+        grid=(BH, n_k, n_q // q_group),
+        in_specs=[k_tile, k_tile, q_rows, q_rows, q_row, q_row],
+        out_specs=(k_tile, k_tile),
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
-    )(k, v, q, g, lse, delta)
+        **_compiler_params(interpret),
+    )(k, v, _blocked(q, block_q), _blocked(g, block_q),
+      lse.reshape(BH, n_q, 1, block_q), delta.reshape(BH, n_q, 1, block_q))
     return dq, dk, dv
 
 

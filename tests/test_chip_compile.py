@@ -58,20 +58,45 @@ def _backward(q, k, v):
 
 # (BH, S, D): gpt2-small at B=16 S=1024; 128 heads-by-batch at S=2048;
 # long context at head_dim 128; a length whose only tile is an odd
-# multiple of 8 rows (1032 -> 344); a short one the kernel spans whole
+# multiple of 8 rows (1032 -> 344); a short one the kernel spans whole;
+# pretrain-1chip's step (B=2 x 16 heads, S=4096)
 SHAPES = [(192, 1024, 64), (128, 2048, 64), (4, 8192, 128), (4, 1032, 64),
-          (4, 40, 64)]
+          (4, 40, 64), (32, 4096, 128)]
+# forward only, the serve cells' prompts: longprompt-batch's longest bucket
+# (mistral-7b-d16, 32 heads) and reasoning-batch's (glm-4.7-flash-d7, 20
+# heads of 256)
+PROMPTS = [(32, 3584, 128), (20, 4096, 256)]
+CASES = [(s, fn) for s in SHAPES for fn in ("fwd", "bwd")] \
+    + [(s, "fwd") for s in PROMPTS]
 
 
-@pytest.mark.parametrize("shape", SHAPES, ids=lambda s: "x".join(map(str, s)))
-@pytest.mark.parametrize("fn,n_kernels", [(_forward, 1), (_backward, 3)],
-                         ids=["fwd", "bwd"])
-def test_flash_kernels_compile_for_v5e(one_chip, shape, fn, n_kernels):
+@pytest.mark.parametrize(
+    "shape,fn", CASES, ids=lambda v: v if isinstance(v, str)
+    else "x".join(map(str, v)))
+def test_flash_kernels_compile_for_v5e(one_chip, shape, fn):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    fn, n_kernels = {"fwd": (_forward, 1), "bwd": (_backward, 3)}[fn]
     compiled = jax.jit(fn).lower(x, x, x).compile()
     # the kernels themselves, not the jnp reference: fwd is one Mosaic
     # call; bwd is the fwd recompute plus the dq and dk/dv kernels
     assert compiled.as_text().count("tpu_custom_call") == n_kernels
+
+
+def test_the_roofline_reader_still_tells_the_three_kernels_apart(one_chip):
+    """``train.flash_roofline`` finds the kernels in a trace by their
+    shapes (``chipbench.readers.flash_roofline.kind_of``): first operand
+    [BH, S, D]; the forward returns that and a [BH, S, 1] column, dk/dv two
+    such tensors, dq one. The compiled backward at pretrain-1chip's shape
+    holds one of each by that reading, whatever else the calls take."""
+    from chipbench.readers.flash_roofline import kind_of
+
+    bh, seq, head_dim = 32, 4096, 128
+    x = jax.ShapeDtypeStruct((bh, seq, head_dim), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(_backward).lower(x, x, x).compile().as_text()
+    kinds = [kind_of(line, bh, seq, head_dim)
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sorted(kinds) == ["dkv", "dq", "fwd"]
 
 
 @pytest.mark.parametrize("seq", [1000, 520])
