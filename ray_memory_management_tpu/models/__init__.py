@@ -8,11 +8,13 @@ def serving_model(cfg):
     true_len)``, ``prefill_takes_kernel(cfg, n_tokens)`` and
     ``paged_decode(params, tokens, pool, positions, lengths, page_table,
     cfg)`` (models/gpt.py and models/latent_moe.py say what each
-    returns)."""
-    from . import gpt, latent_moe
+    returns), and where a slot holds a state besides its pages also
+    ``state_spec(cfg)`` (models/hybrid_ssm.py)."""
+    from . import gpt, hybrid_ssm, latent_moe
 
     for module, kind in ((gpt, gpt.TransformerConfig),
-                         (latent_moe, latent_moe.LatentMoEConfig)):
+                         (latent_moe, latent_moe.LatentMoEConfig),
+                         (hybrid_ssm, hybrid_ssm.HybridSSMConfig)):
         if isinstance(cfg, kind):
             return module
     raise TypeError(f"no model serves a {type(cfg).__name__}")

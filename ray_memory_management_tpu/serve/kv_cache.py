@@ -28,6 +28,18 @@ tracks the live requests is its *pages* (``rmt_serve_kv_pages_in_use``).
     of idle rows, and of a row that overshoots its budget inside an
     iteration of ``steps_per_iter``, land there and never in another
     request's page.
+
+A model may also name arrays that a *slot* holds whatever its length
+(``state_spec(cfg)``: name -> dims before the slots, dims after, dtype; the
+hybrid state-space model's recurrent state and convolution tail,
+models/hybrid_ssm.py): a **state entry**, ``[lead..., max_slots, trail...]``,
+sized by slots and not by tokens. It is allocated with the pages, donated
+with them and counted beside them (``state_bytes``, ``store_bytes``); no page
+id and no reservation refers to it, since slot ``i``'s entry is row ``i``.
+Nobody zeroes it between requests: the prefill of an admission overwrites its
+slot's entry whole, with the state of the prompt's last real token, and the
+decode step moves only the entries of live slots. A model without
+``state_spec`` (or with an empty one) gets the pages alone, as before.
 """
 
 from __future__ import annotations
@@ -41,13 +53,18 @@ import numpy as np
 def row_token_bytes(cfg) -> int:
     """HBM bytes one cached position of one slot occupies, over all layers
     and arrays (padding the layout carries counted)."""
-    import jax.numpy as jnp
-
     from ..models import serving_model
 
+    return _spec_bytes(serving_model(cfg).cache_spec(cfg))
+
+
+def _spec_bytes(spec) -> int:
+    """Bytes of one entry (a position, or a slot) over a specification's
+    arrays."""
+    import jax.numpy as jnp
+
     return sum(int(np.prod(lead + trail)) * jnp.dtype(dtype).itemsize
-               for lead, trail, dtype
-               in serving_model(cfg).cache_spec(cfg).values())
+               for lead, trail, dtype in spec.values())
 
 
 class KVPagePool:
@@ -64,10 +81,16 @@ class KVPagePool:
         self.cfg = cfg
         from ..models import serving_model
 
+        model = serving_model(cfg)
         self.page_tokens = max(1, int(page_tokens))
-        self.spec = serving_model(cfg).cache_spec(cfg)
-        self.token_bytes = row_token_bytes(cfg)
+        self.spec = model.cache_spec(cfg)
+        self.token_bytes = _spec_bytes(self.spec)
         self.page_bytes = self.page_tokens * self.token_bytes
+        # what a slot holds whatever its length (most models: nothing)
+        self.max_slots = int(max_slots)
+        self.state_spec = model.state_spec(cfg) \
+            if hasattr(model, "state_spec") else {}
+        self.state_row_bytes = _spec_bytes(self.state_spec)
         if pool_bytes and pool_bytes > 0:
             budget = int(pool_bytes)
         else:
@@ -88,17 +111,27 @@ class KVPagePool:
     def allocate(self) -> Dict[str, Any]:
         """The pool's arrays, zeroed: for each name of the model's cache
         specification, dims before + (pages and the sink, page_tokens) + dims
-        after. The caller (the engine) owns them: they are donated to every
-        prefill and decode program, and a buffer that is donated cannot also
-        be pinned in a store."""
+        after; and for each name of its state specification, dims before +
+        (max_slots,) + dims after. The caller (the engine) owns them: they
+        are donated to every prefill and decode program, and a buffer that is
+        donated cannot also be pinned in a store."""
         import jax.numpy as jnp
 
         pages = (self.capacity_pages + 1, self.page_tokens)
         pool = {name: jnp.zeros(lead + pages + trail, dtype)
                 for name, (lead, trail, dtype) in self.spec.items()}
+        pool.update({name: jnp.zeros(lead + (self.max_slots,) + trail, dtype)
+                     for name, (lead, trail, dtype)
+                     in self.state_spec.items()})
         with self._lock:
-            self._array_bytes = (self.capacity_pages + 1) * self.page_bytes
+            self._array_bytes = (self.capacity_pages + 1) * self.page_bytes \
+                + self.state_bytes
         return pool
+
+    @property
+    def state_bytes(self) -> int:
+        """The state entries' bytes: every slot's, live or not."""
+        return self.max_slots * self.state_row_bytes
 
     # -- accounting -----------------------------------------------------------
     def pages_for(self, tokens: int) -> int:
@@ -164,7 +197,11 @@ class KVPagePool:
             "capacity_pages": self.capacity_pages,
             "pages_in_use": pages,
             "bytes_in_use": pages * self.page_bytes,
-            # the resident arrays, sink included: constant once allocated
+            # what the slots hold whatever their length (0: no state entry)
+            "state_row_bytes": self.state_row_bytes,
+            "state_bytes": self.state_bytes,
+            # the resident arrays, sink and state included: constant once
+            # allocated
             "store_bytes": array_bytes,
             "peak_store_bytes": array_bytes,
         }

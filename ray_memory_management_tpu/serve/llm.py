@@ -202,6 +202,22 @@ class ContinuousBatcher:
                                         name="llm-engine")
         self._thread.start()
 
+    @property
+    def params(self):
+        """The model's parameters, passed to every program. Whoever owns the
+        replica may take them off the device by setting ``None`` while no
+        request is in flight (and put others back before the next one): the
+        engine thread is woken and lets its pool go with them."""
+        return self._params
+
+    @params.setter
+    def params(self, value) -> None:
+        self._params = value
+        cond = getattr(self, "_cond", None)  # None: the constructor's call
+        if value is None and cond is not None:
+            with cond:
+                cond.notify_all()
+
     # -- client side ----------------------------------------------------------
     def submit(self, tokens: List[int], timeout: float = 300.0,
                max_new_tokens: Optional[int] = None):
@@ -264,18 +280,21 @@ class ContinuousBatcher:
 
     def _paged_prefill_fn(self, bucket: int):
         """Prefill one prompt of ``bucket`` tokens and scatter what it
-        leaves in the cache into the row's pages of the donated pool.
-        Compiled per bucket: the reservation's size does not enter, only
-        the table row does."""
+        leaves in the cache into the row's pages of the donated pool, and,
+        where the model keeps a state a slot (``state_spec``), the row's
+        state into the slot's entry: whatever an earlier request left there
+        is overwritten whole. Compiled per bucket: the reservation's size
+        does not enter, only the table row and the slot's index do."""
         jax, model, cfg = self._jax, self._model, self.cfg
         fn = self._prefill_cache.get(bucket)
         if fn is not None:
             return fn
         page = self.kv_pool.page_tokens
         n_pages = self.kv_pool.pages_for(bucket)
-        spec = self.kv_pool.spec
+        spec, state_spec = self.kv_pool.spec, self.kv_pool.state_spec
 
-        def prefill(params, pool, tokens, table_row, true_len, key):
+        def prefill(params, pool, tokens, table_row, true_len, key,
+                    slot=None):
             logits, row_cache = model.prefill_row(
                 params, tokens, cfg, n_pages * page, true_len)
             pages = table_row[:n_pages]
@@ -286,7 +305,12 @@ class ContinuousBatcher:
                 return pool[name].at[at].set(row_cache[name].reshape(
                     lead + (n_pages, page) + trail))
 
-            pool = {name: scatter(name) for name in pool}
+            def put(name):  # the slot's entry, every layer of it
+                at = (slice(None),) * len(state_spec[name][0]) + (slot,)
+                return pool[name].at[at].set(row_cache[name])
+
+            pool = {name: (put if name in state_spec else scatter)(name)
+                    for name in pool}
             first = self._sample(logits[None], key)[0]
             return pool, first
 
@@ -314,10 +338,12 @@ class ContinuousBatcher:
         if self._pool is None:  # the engine's first admission
             self._pool = self.kv_pool.allocate()
         # the row's pages were reserved by the admit gate
+        # and where the model keeps a state a slot, its entry is the row's
+        slot = (jnp.int32(row),) if self.kv_pool.state_spec else ()
         self._pool, first = self._paged_prefill_fn(bucket)(
             self.params, self._pool, jnp.asarray(arr),
             jnp.asarray(self.kv_pool.table[row]),
-            jnp.int32(len(toks)), sub)
+            jnp.int32(len(toks)), sub, *slot)
         # what the prefill programs computed, and how much of it in a
         # program that holds the kernel
         self._counts["prefill_positions"] += bucket
@@ -418,6 +444,11 @@ class ContinuousBatcher:
             with self._cond:
                 while (not self._stop and not self._q
                        and all(p is None for p in self._slot_pending)):
+                    if self.params is None:
+                        # the weights left the device (see ``params``): the
+                        # cache is of no use without them and goes too; the
+                        # next admission allocates it anew
+                        self._pool = None
                     with phase(acc, "idle_wait"):
                         self._cond.wait(timeout=1.0)
                     self._publish()
@@ -528,9 +559,11 @@ class ContinuousBatcher:
             "phase_s": {k: v[0] for k, v in self._phase.items()},
             "phase_cpu_s": {k: v[1] for k, v in self._phase.items()},
             **self._counts, "recent": list(self._recent),
-            # bytes a cached position holds, and the model's own counts as
-            # plain lists
+            # bytes a cached position holds, bytes a slot's state holds
+            # (0: the model keeps none), and the model's own counts as plain
+            # lists
             "cache_token_bytes": self.kv_pool.token_bytes,
+            "state_row_bytes": self.kv_pool.state_row_bytes,
             **{k: v.tolist() for k, v in self._model_counts.items()}}
 
     def engine_stats(self) -> Dict[str, Any]:
@@ -594,7 +627,8 @@ class LLMServer:
     """Deployment class: KV-cached batched generation on one chip.
 
     The model is ``config`` (a configuration object of models/: a
-    ``TransformerConfig``, a ``LatentMoEConfig``) or, without one, the
+    ``TransformerConfig``, a ``LatentMoEConfig``, a ``HybridSSMConfig``) or,
+    without one, the
     ``TransformerConfig`` that ``preset`` names; the engine asks
     ``models.serving_model`` for its functions, and for its parameters from
     ``seed`` unless ``init`` (``init(key, cfg)`` -> the model's parameter

@@ -130,8 +130,9 @@ def test_kernel_in_a_tp_sharded_jit_needs_the_mesh(topo):
 
 # ------------------------------------------------- the paged decode path
 # (Hkv, heads a group, page, table width): Mistral-7B's GQA at chat-online's
-# page, MHA at a small page, a wide group
-PAGED = [(8, 4, 256, 16), (4, 1, 16, 8), (2, 16, 128, 4)]
+# page, MHA at a small page, a wide group, and conversation-batch's (the
+# hybrid state-space model's 20 query heads on 4 K/V heads, pages of 512)
+PAGED = [(8, 4, 256, 16), (4, 1, 16, 8), (2, 16, 128, 4), (4, 5, 512, 8)]
 
 
 @pytest.mark.parametrize("hkv,group,page,width", PAGED,
@@ -153,6 +154,54 @@ def test_paged_attention_kernel_compiles_for_v5e(one_chip, hkv, group, page,
         s((B, width), jnp.int32), s((), jnp.int32), s((B, hkv, Dh)),
         s((B, hkv, Dh))).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def test_state_update_kernel_compiles_for_v5e(one_chip):
+    """``ssm_decode_update`` at conversation-batch's shape (5 layers, 64
+    slots, 32 heads of a [256, 128] float32 state in 2 groups): one Mosaic
+    call, and the 1.34 GB state aliased in and out, not copied."""
+    from ray_memory_management_tpu.ops.ssm import ssm_decode_update
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    L, S, H, N, P_, G = 5, 64, 32, 256, 128, 2
+    f32 = jnp.float32
+    compiled = jax.jit(
+        lambda st, x, dt, A, B, C, D, live, layer: ssm_decode_update(
+            st, x, dt, A, B, C, D, live, layer=layer, use_pallas="on"),
+        donate_argnums=(0,)).lower(
+        s((L, S, H, N, P_), f32), s((S, H, P_)), s((S, H), f32),
+        s((H,), f32), s((S, G, N)), s((S, G, N)), s((H,), f32),
+        s((S,), jnp.bool_), s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "ssm_decode_update" in text
+    m = compiled.memory_analysis()
+    state = L * S * H * N * P_ * 4
+    assert m.alias_size_in_bytes >= state
+    assert m.temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("T", [512, 3072])
+def test_chunked_scan_kernel_compiles_for_v5e(one_chip, T):
+    """``ssd_chunk_scan`` at conversation-batch's shortest and longest
+    bucket (32 heads of 128 channels, a state of 256, 2 groups, bf16
+    operands): one Mosaic call."""
+    from ray_memory_management_tpu.ops.ssm import ssd_scan
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    H, P_, G, N = 32, 128, 2, 256
+    f32 = jnp.float32
+    compiled = jax.jit(lambda x, dt, A, B, C, D, n: ssd_scan(
+        x, dt, A, B, C, D, true_len=n, use_pallas="on")).lower(
+        s((T, H, P_)), s((T, H), f32), s((H,), f32), s((T, G, N)),
+        s((T, G, N)), s((H,), f32), s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "ssd_chunk_scan" in text
 
 
 def test_paged_step_keeps_the_pool_where_it_is(one_chip, monkeypatch):
