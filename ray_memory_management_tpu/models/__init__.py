@@ -8,8 +8,14 @@ def serving_model(cfg):
     true_len)``, ``prefill_takes_kernel(cfg, n_tokens)`` and
     ``paged_decode(params, tokens, pool, positions, lengths, page_table,
     cfg)`` (models/gpt.py and models/latent_moe.py say what each
-    returns), and where a slot holds a state besides its pages also
-    ``state_spec(cfg)`` (models/hybrid_ssm.py)."""
+    returns), where a slot holds a state besides its pages also
+    ``state_spec(cfg)`` (models/hybrid_ssm.py), and, optionally,
+    ``mixed_step(params, pool, chunk_tokens, chunk_pages, chunk_last,
+    tokens, positions, lengths, page_table, cfg, *, chunk_index)``: one
+    chunk of a prompt and one decode token a live row in one pass over the
+    layers (models/gpt.py). The engine prefills in chunks that ride its
+    decode steps where a model offers it, and whole prompts through
+    ``prefill_row`` where it does not."""
     from . import gpt, hybrid_ssm, latent_moe
 
     for module, kind in ((gpt, gpt.TransformerConfig),

@@ -367,8 +367,6 @@ def paged_decode(params, tokens, pool, positions, lengths, page_table,
     """
     from ..ops.paged_attention import paged_attention
 
-    page, width = pool["k"].shape[3], page_table.shape[1]
-    sink = pool["k"].shape[2] - 1
     x = params["tok_embed"][tokens[:, None]].astype(cfg.dtype)   # [B, 1, D]
 
     def scan_body(x, layer_and_index):
@@ -388,42 +386,9 @@ def paged_decode(params, tokens, pool, positions, lengths, page_table,
     x, (k_new, v_new) = lax.scan(
         scan_body, x, (params["layers"], jnp.arange(cfg.n_layers)))
     with jax.named_scope("kv_write"):
-        at = positions // page
-        inside = jnp.minimum(at, width - 1)[:, None]
-        pages = jnp.where(
-            at < width,
-            jnp.take_along_axis(page_table, inside, axis=1)[:, 0], sink)
-        offs = positions % page
-
-        def write(pages_of, new):  # new [L, B, Hkv, Dh]
-            # one position a row, every layer and head of it, by reading
-            # the tile of 16 positions around it, patching and writing it
-            # back. Not one scatter, nor an update of the one position: for
-            # either the TPU compiler picks a layout of its own for the
-            # whole pool and copies the pool into it and back, every step
-            # (tests/test_chip_compile.py holds the layout)
-            new = new.transpose(1, 0, 2, 3)[:, :, :, None, None, :]
-            L, Hkv, Dh = new.shape[1], new.shape[2], new.shape[-1]
-            tile = 16 if page % 16 == 0 else 1
-            rows = jnp.arange(tile)[None, None, None, :, None]
-
-            def one(b, c):
-                base = offs[b] // tile * tile
-                at = (0, 0, pages[b], base, 0)
-                old = lax.dynamic_slice(c, at, (L, Hkv, 1, tile, Dh))
-                return lax.dynamic_update_slice(
-                    c, jnp.where(rows == offs[b] - base, new[b], old), at)
-
-            return lax.fori_loop(0, new.shape[0], one, pages_of)
-
-        pool = {"k": write(pool["k"], k_new), "v": write(pool["v"], v_new)}
+        pool = _write_rows(pool, k_new, v_new, positions, page_table)
     with jax.named_scope("head_sample"):  # the engine's sampler joins it
-        x = _rmsnorm(x[:, 0], params["final_ln"])
-        logits = lax.dot_general(
-            x, params["lm_head"].astype(cfg.dtype),
-            (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        logits = _head(params, x[:, 0], cfg)
     return logits, pool, {}
 
 
@@ -530,9 +495,7 @@ def prefill_row(params, tokens, cfg: TransformerConfig, n_positions: int,
 
     S = tokens.shape[1]
     rep = cfg.n_heads // cfg.kv_heads
-    use = "off"
-    if prefill_takes_kernel(cfg, S):
-        use = "interpret" if cfg.attention == "flash-interpret" else "on"
+    use = _prompt_attention_mode(cfg, S)
     x = params["tok_embed"][tokens].astype(cfg.dtype)
 
     def scan_body(x, layer):
@@ -554,11 +517,182 @@ def prefill_row(params, tokens, cfg: TransformerConfig, n_positions: int,
         pad = ((0, 0), (0, 0), (0, n_positions - S), (0, 0))
         row_cache = {"k": jnp.pad(k_new, pad), "v": jnp.pad(v_new, pad)}
     with jax.named_scope("head_sample"):  # the engine's sampler joins it
-        x = _rmsnorm(lax.dynamic_slice_in_dim(x[0], true_len - 1, 1),
-                     params["final_ln"])
-        logits = lax.dot_general(
-            x, params["lm_head"].astype(cfg.dtype),
-            (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        logits = _head(
+            params, lax.dynamic_slice_in_dim(x[0], true_len - 1, 1), cfg)
     return logits[0], row_cache
+
+
+def _head(params, x, cfg: TransformerConfig):
+    """Final norm and output head over rows ``x`` [N, D] -> logits [N, V]:
+    bf16 operands on the MXU, fp32 accumulation and output."""
+    x = _rmsnorm(x, params["final_ln"])
+    return lax.dot_general(
+        x, params["lm_head"].astype(cfg.dtype),
+        (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _prompt_attention_mode(cfg: TransformerConfig, n_tokens: int) -> str:
+    """``flash_attention``'s ``use_pallas`` for ``n_tokens`` prompt
+    positions: the kernel where :func:`prefill_takes_kernel` holds (under
+    the interpreter where the configuration asks for that by name), else
+    its plain reference."""
+    if not prefill_takes_kernel(cfg, n_tokens):
+        return "off"
+    return "interpret" if cfg.attention == "flash-interpret" else "on"
+
+
+def _write_rows(pool, k_new, v_new, positions, page_table):
+    """One decode position a row into the pool: ``k_new`` / ``v_new``
+    [L, B, Hkv, Dh] go to ``(page_table[i, positions[i] // page_tokens],
+    positions[i] % page_tokens)``, or to the sink where that lies beyond the
+    table. What :func:`paged_decode` and :func:`mixed_step` do after their
+    layer loops."""
+    page, width = pool["k"].shape[3], page_table.shape[1]
+    sink = pool["k"].shape[2] - 1
+    at = positions // page
+    inside = jnp.minimum(at, width - 1)[:, None]
+    pages = jnp.where(
+        at < width,
+        jnp.take_along_axis(page_table, inside, axis=1)[:, 0], sink)
+    offs = positions % page
+
+    def write(pages_of, new):  # new [L, B, Hkv, Dh]
+        # one position a row, every layer and head of it, by reading
+        # the tile of 16 positions around it, patching and writing it
+        # back. Not one scatter, nor an update of the one position: for
+        # either the TPU compiler picks a layout of its own for the
+        # whole pool and copies the pool into it and back, every step
+        # (tests/test_chip_compile.py holds the layout)
+        new = new.transpose(1, 0, 2, 3)[:, :, :, None, None, :]
+        L, Hkv, Dh = new.shape[1], new.shape[2], new.shape[-1]
+        tile = 16 if page % 16 == 0 else 1
+        rows = jnp.arange(tile)[None, None, None, :, None]
+
+        def one(b, c):
+            base = offs[b] // tile * tile
+            at = (0, 0, pages[b], base, 0)
+            old = lax.dynamic_slice(c, at, (L, Hkv, 1, tile, Dh))
+            return lax.dynamic_update_slice(
+                c, jnp.where(rows == offs[b] - base, new[b], old), at)
+
+        return lax.fori_loop(0, new.shape[0], one, pages_of)
+
+    return {"k": write(pool["k"], k_new), "v": write(pool["v"], v_new)}
+
+
+def mixed_step(params, pool, chunk_tokens, chunk_pages, chunk_last, tokens,
+               positions, lengths, page_table, cfg: TransformerConfig, *,
+               chunk_index):
+    """One chunk of one row's prompt and one decode token a live row, in one
+    pass over the layers: the decode rows' weights are the chunk's.
+
+    ``chunk_tokens`` int32 [C] are the prompt's positions
+    ``[chunk_index * C, (chunk_index + 1) * C)`` (``C`` a whole number of
+    pages; past the prompt's end, padding that no later position sees),
+    ``chunk_pages`` int32 the prompt's row of the block table, as many
+    entries as the longest prompt has pages in whole chunks (sink entries
+    past the row's own), ``chunk_last`` the position inside the chunk whose
+    logits are wanted (the prompt's last token where this is its last
+    chunk). ``tokens``, ``positions``, ``lengths`` and ``page_table`` are
+    :func:`paged_decode`'s; the row being prefilled is idle among them
+    (length 0, a table row of sink entries), so its decode write cannot land
+    in the pages the chunks fill.
+
+    Every projection and the MLP run once over the ``C + B`` rows. The
+    attention splits them: the chunk attends causally, in the flash forward
+    kernel where :func:`prefill_takes_kernel` holds for ``C``, over the
+    ``chunk_index * C`` positions earlier chunks left in the row's pages
+    and over itself; the decode rows attend through the block table as in
+    :func:`paged_decode`. The layer loop only reads the pool; after it the
+    chunk's K and V go to whole pages and each decode row's to its one
+    position. Returns (logits [B + 1, V] fp32: the decode rows', then the
+    chunk's at ``chunk_last``; the updated pool; the step's own counts:
+    none).
+
+    ``chunk_index`` is a run-time value (an int32 scalar): ONE program for
+    every chunk of every prompt, as the decode step is one. The kernel's
+    causal offset ``Skv - S`` is static, so the chunk's attention is a
+    ``lax.switch`` over the prefix lengths a prompt can have, each branch
+    the kernel at its own ``Skv`` (it touches no block above the diagonal;
+    branch 0 is :func:`prefill_row` of one bucket). The pages before the
+    chunk are gathered OUTSIDE the switch, all the table holds, with the
+    chunk's own K and V laid over them where the chunk starts, and a branch
+    takes the leading part it sees: a branch that slices the pool itself
+    makes the TPU compiler copy the whole pool into a layout of its own,
+    once a page, and one that joins prefix and chunk itself writes both
+    once more."""
+    from ..ops.flash_attention import flash_attention
+    from ..ops.paged_attention import paged_attention
+
+    C, page = chunk_tokens.shape[0], pool["k"].shape[3]
+    Hkv, Dh, rep = cfg.kv_heads, cfg.head_dim, cfg.n_heads // cfg.kv_heads
+    n_pages = C // page
+    n_chunks = chunk_pages.shape[0] // n_pages  # the longest prompt's
+    chunk_index = jnp.asarray(chunk_index, jnp.int32)
+    before = chunk_pages[:(n_chunks - 1) * n_pages]
+    use = _prompt_attention_mode(cfg, C)
+    x = params["tok_embed"][jnp.concatenate([chunk_tokens, tokens])]
+    x = x.astype(cfg.dtype)[None]                            # [1, C + B, D]
+    at = jnp.concatenate([chunk_index * C + jnp.arange(C), positions])[None]
+
+    def over(n_before):  # the chunk over ``n_before`` earlier chunks + itself
+        def attend(q, ks, vs):
+            # the kernel wants as many K/V heads as query heads
+            return flash_attention(
+                q, jnp.repeat(ks[:, :(n_before + 1) * C], rep, axis=0)[None],
+                jnp.repeat(vs[:, :(n_before + 1) * C], rep, axis=0)[None],
+                causal=True, use_pallas=use)
+        return attend
+
+    def scan_body(x, layer_and_index):
+        layer, index = layer_and_index
+
+        def row_so_far(pages_of, own):
+            # the row's pages before its last chunk, then the chunk's own
+            # positions laid over them where the chunk starts: the first
+            # ``(chunk_index + 1) * C`` positions are what the chunk sees
+            so_far = jnp.concatenate([
+                lax.dynamic_slice(pages_of, (index, 0, before[i], 0, 0),
+                                  (1, Hkv, 1, page, Dh)
+                                  ).reshape(Hkv, page, Dh)
+                for i in range(before.shape[0])] + [own], axis=1)
+            return lax.dynamic_update_slice(so_far, own,
+                                            (0, chunk_index * C, 0))
+
+        def attn(q, k, v):                          # [1, C + B, H(kv), Dh]
+            kt, vt = k[0, :C].transpose(1, 0, 2), v[0, :C].transpose(1, 0, 2)
+            with jax.named_scope("prefix_gather"):
+                ks, vs = row_so_far(pool["k"], kt), row_so_far(pool["v"], vt)
+            with jax.named_scope("prefill_attention"):
+                o_chunk = lax.switch(
+                    chunk_index, [over(n) for n in range(n_chunks)],
+                    q[:, :C].transpose(0, 2, 1, 3), ks, vs)
+            with jax.named_scope("decode_attention"):
+                o_rows = paged_attention(
+                    q[0, C:], pool["k"], pool["v"], lengths, page_table,
+                    layer=index, k_cur=k[0, C:], v_cur=v[0, C:])
+            o = jnp.concatenate([o_chunk.transpose(0, 2, 1, 3),
+                                 o_rows[None]], axis=1)
+            return o, (kt, vt, k[0, C:], v[0, C:])
+
+        return apply_block(x, layer, cfg, attn_fn=attn, positions=at)
+
+    x, (k_chunk, v_chunk, k_rows, v_rows) = lax.scan(
+        scan_body, x, (params["layers"], jnp.arange(cfg.n_layers)))
+    with jax.named_scope("kv_write"):
+        now = lax.dynamic_slice_in_dim(chunk_pages, chunk_index * n_pages,
+                                       n_pages)
+
+        def whole_pages(pages_of, new):  # new [L, Hkv, C, Dh]
+            return pages_of.at[:, :, now].set(
+                new.reshape(new.shape[:2] + (n_pages, page, Dh)))
+
+        pool = {"k": whole_pages(pool["k"], k_chunk),
+                "v": whole_pages(pool["v"], v_chunk)}
+        pool = _write_rows(pool, k_rows, v_rows, positions, page_table)
+    with jax.named_scope("head_sample"):  # the engine's sampler joins it
+        logits = _head(params, jnp.concatenate(
+            [x[0, C:], lax.dynamic_slice_in_dim(x[0], chunk_last, 1)]), cfg)
+    return logits, pool, {}

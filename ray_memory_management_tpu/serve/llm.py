@@ -6,12 +6,16 @@ replica loop serve/_private/replica.py:250). Here the coalescer is
 TPU-shaped:
 
   - :class:`ContinuousBatcher` — the engine: callers block in ``submit``,
-    one thread admits each into a free slot of ``max_slots`` (a prefill
-    program per prompt bucket, a multiple of ``pad_multiple``) and decodes
+    one thread admits each into a free slot of ``max_slots`` and decodes
     every occupied slot together, ``steps_per_iter`` tokens an iteration,
     in ONE compiled program over a paged KV pool. On a TPU the batch
     dimension is nearly free (MXU width), so sharing decode iterations is
-    the difference between 1x and Nx decode throughput under load.
+    the difference between 1x and Nx decode throughput under load. A
+    prompt is prefilled in chunks of ``pad_multiple`` positions, each in
+    the same program as one decode token-step of the live rows (the
+    model's ``mixed_step``: the chunk's matmuls are compute-bound, so the
+    decode rows' weight stream is the chunk's); a model that offers none
+    gets a prefill program per prompt bucket while the loop stands.
   - :class:`LLMServer` — the deployment class: holds the parameters on the
     device, owns the engine, and answers requests and ``stats()``.
 
@@ -66,9 +70,13 @@ class ContinuousBatcher:
     under streaming arrivals no request waits for a batch of strangers to
     finish and no slot idles on a retired row:
 
-      - a new request is PREFILLED into a free slot the moment one exists
-        (per-bucket compiled prefill writes its prompt's KV at positions
-        [0, len));
+      - a new request is PREFILLED into a free slot the moment one exists:
+        chunk by chunk beside the live rows' decode steps where the model
+        offers ``mixed_step`` (``_iterate_mixed``; the row is idle in the
+        decode half until its last chunk has run), else by a per-bucket
+        compiled prefill that writes its prompt's KV at positions [0, len)
+        while the loop stands (``_iterate``). The engine chooses by what
+        the model offers, nothing else;
       - every engine iteration runs ONE decode program over all occupied
         slots (static [max_slots] shape, each row at its own position and
         length);
@@ -172,6 +180,23 @@ class ContinuousBatcher:
         # are live and however long they are
         self._paged_step = jax.jit(paged_step_fn, donate_argnums=(1,))
 
+        # where the model offers ``mixed_step``, a prompt is prefilled in
+        # chunks of ``_chunk`` positions (the bucket step in whole pages),
+        # each in the same program as one decode token-step of the live
+        # rows: ONE program, whichever chunk of whichever prompt it carries
+        self._mixed = hasattr(model, "mixed_step")
+        page = self.kv_pool.page_tokens
+        self._chunk = -(-pad_multiple // page) * page
+        if self._mixed:
+            self._mixed_step = self._mixed_step_program()
+            if model.prefill_takes_kernel(cfg, self._chunk):
+                self._prefill_kernel.add(self._chunk)
+        # rows with chunks to go, oldest first; each row's prompt padded to
+        # whole chunks with its true length, and how many chunks are done
+        self._prefilling: List[int] = []
+        self._slot_prompt: List[Any] = [None] * max_slots
+        self._slot_chunks = np.zeros(max_slots, np.int32)
+
         # slot state (host side)
         self._slot_pending: List[Optional[_Pending]] = [None] * max_slots
         self._slot_offset = np.zeros(max_slots, np.int32)
@@ -192,7 +217,8 @@ class ContinuousBatcher:
         self._counts = {"iterations": 0, "slab_positions": 0,
                         "live_positions": 0, "admitted": 0,
                         "prefill_positions": 0,
-                        "prefill_kernel_positions": 0}
+                        "prefill_kernel_positions": 0,
+                        "mixed_steps": 0, "chunk_positions_live": 0}
         self._recent: deque = deque(maxlen=512)  # (queue_wait_s, prefill_s)
         # what the model's decode step counted of itself (paged_decode's
         # third result), added up by name: arrays, or nothing
@@ -386,13 +412,6 @@ class ContinuousBatcher:
                 name, "serve", start, end, extra=extra,
                 trace=tracing.child_of(p.trace) if p.trace else None)
 
-    def _fetched_positions(self, active: List[int]) -> int:
-        """KV positions the decode step fetches this iteration: each live
-        row's pages up to its last step's length."""
-        page = self.kv_pool.page_tokens
-        ends = self._slot_offset[active] + self.steps_per_iter
-        return int((-(-ends // page) * page).sum())
-
     def _admit_gate(self) -> List:
         """Pop admissible queued requests (head-of-line FIFO) into free
         slots, reserving each request's lifetime pages FIRST: a failed
@@ -468,72 +487,8 @@ class ContinuousBatcher:
                 with phase(acc, "gate"):
                     admits = self._admit_gate()
             try:
-                for p, row in admits:
-                    with phase(acc, "prefill",
-                               bucket=self._bucket_for(
-                                   self._clip_tokens(p.item[0])),
-                               cap=self.kv_pool.row_tokens(row)):
-                        try:
-                            self._admit(p, row)
-                        except faults.FaultInjected as e:
-                            # injected admit failure takes down ONE
-                            # request, not the engine: release the
-                            # reservation and keep admitting
-                            self.kv_pool.free(row)
-                            self._slot_pending[row] = None
-                            p.error = e
-                            p.event.set()
-                            continue
-                        # the first token exists
-                        p.t_first = time.time()
-                        counts["admitted"] += 1
-                        self._recent.append((p.t_admit - p.t_submit,
-                                             p.t_first - p.t_admit))
-                        if self._slot_budget[row] <= 0:
-                            self._retire(row)  # max_new_tokens == 1
-                active = [r for r in range(self.max_slots)
-                          if self._slot_pending[r] is not None]
-                if not active:
-                    self._publish()
-                    continue
-                with phase(acc, "assemble", rows=len(active)):
-                    self._key, sub = self._jax.random.split(self._key)
-                    # the host's part is the live slots' lengths and table
-                    # rows; the KV stays where it is
-                    last = jnp.asarray(self._slot_last)
-                    offsets = jnp.asarray(self._slot_offset)
-                    table = jnp.asarray(self.kv_pool.table)
-                    # what the step fetches, and how much of it is live
-                    counts["iterations"] += 1
-                    counts["slab_positions"] += self._fetched_positions(
-                        active)
-                    counts["live_positions"] += int(
-                        self._slot_offset[active].sum())
-                with phase(acc, "step_dispatch"):
-                    self._pool, toks, stepped = self._paged_step(
-                        self.params, self._pool, last, offsets, table, sub)
-                with phase(acc, "step_wait"):
-                    # toks [K, B] and the model's counts, in one readback
-                    toks, stepped = self._jax.device_get((toks, stepped))
-                    for name, c in stepped.items():
-                        self._model_counts[name] = self._model_counts.get(
-                            name, 0) + c.astype(np.int64)
-                with phase(acc, "emit"):
-                    self.steps += self.steps_per_iter
-                    for r in active:
-                        # a row finishing mid-iteration consumes only what
-                        # its budget allows; the surplus decoded junk wrote
-                        # beyond its end, into its OWN pages or into the
-                        # sink, where the lengths keep it invisible
-                        take = min(self.steps_per_iter,
-                                   int(self._slot_budget[r]))
-                        self._slot_out[r].extend(
-                            int(toks[t, r]) for t in range(take))
-                        self._slot_last[r] = int(toks[take - 1, r])
-                        self._slot_offset[r] += take
-                        self._slot_budget[r] -= take
-                        if self._slot_budget[r] <= 0:
-                            self._retire(r)
+                (self._iterate_mixed if self._mixed else self._iterate)(
+                    admits)
             except BaseException as e:  # noqa: BLE001 — fail loudly to
                 # every parked caller, keep serving
                 with phase(acc, "emit"):
@@ -542,6 +497,8 @@ class ContinuousBatcher:
                                     if p is not None] + self._q)
                         self._slot_pending = [None] * self.max_slots
                         self._q.clear()
+                    self._prefilling.clear()
+                    self._slot_offset[:] = 0
                     self.kv_pool.free_all()
                     # a program that failed may have consumed the donated
                     # arrays: the next admission allocates anew
@@ -550,6 +507,290 @@ class ContinuousBatcher:
                         p.error = e
                         p.event.set()
             self._publish()
+
+    def _iterate(self, admits) -> None:
+        """One iteration where a prompt is prefilled whole (the model offers
+        no ``mixed_step``): a prefill program an admission, each with its
+        first token read back while the loop stands, then the K token-steps
+        of every live row."""
+        acc, counts = self._phase, self._counts
+        for p, row in admits:
+            with phase(acc, "prefill",
+                       bucket=self._bucket_for(
+                           self._clip_tokens(p.item[0])),
+                       cap=self.kv_pool.row_tokens(row)):
+                try:
+                    self._admit(p, row)
+                except faults.FaultInjected as e:
+                    # injected admit failure takes down ONE
+                    # request, not the engine: release the
+                    # reservation and keep admitting
+                    self.kv_pool.free(row)
+                    self._slot_pending[row] = None
+                    p.error = e
+                    p.event.set()
+                    continue
+                # the first token exists
+                p.t_first = time.time()
+                counts["admitted"] += 1
+                self._recent.append((p.t_admit - p.t_submit,
+                                     p.t_first - p.t_admit))
+                if self._slot_budget[row] <= 0:
+                    self._retire(row)  # max_new_tokens == 1
+        active = [r for r in range(self.max_slots)
+                  if self._slot_pending[r] is not None]
+        if not active:
+            return
+        toks, stepped = self._dispatch_decode(active)
+        with phase(acc, "step_wait"):
+            # toks [K, B] and the model's counts, in one readback
+            toks, stepped = self._jax.device_get((toks, stepped))
+            self._add_model_counts(stepped)
+        with phase(acc, "emit"):
+            self.steps += self.steps_per_iter
+            self._emit(toks, active, {})
+
+    def _dispatch_decode(self, rows):
+        """Assemble and dispatch the K token-steps of the live ``rows`` (the
+        one decode program); its tokens [K, B] and the model's counts, still
+        on the device."""
+        jnp = self._jnp
+        acc, counts = self._phase, self._counts
+        with phase(acc, "assemble", rows=len(rows)):
+            sub = self._iteration_key()
+            # the host's part is the live slots' lengths and table
+            # rows; the KV stays where it is
+            last = jnp.asarray(self._slot_last)
+            offsets = jnp.asarray(self._slot_offset)
+            table = jnp.asarray(self.kv_pool.table)
+            # what the step fetches, and how much of it is live
+            counts["iterations"] += 1
+            self._count_positions(self._slot_offset[rows],
+                                  self.steps_per_iter)
+        with phase(acc, "step_dispatch"):
+            self._pool, toks, stepped = self._paged_step(
+                self.params, self._pool, last, offsets, table, sub)
+        return toks, stepped
+
+    def _mixed_step_program(self):
+        """One chunk of one row's prompt and one decode token-step of the
+        live rows, sampled, on the donated pool (the model's
+        ``mixed_step``): ONE program, whichever chunk of whichever prompt
+        it carries. The mixed steps of an iteration chain on the device:
+        each takes the last one's tokens and the rest of its key, so none
+        waits for the host."""
+        jax, jnp, model, cfg = self._jax, self._jnp, self._model, self.cfg
+
+        def mixed_step(params, pool, chunk_tokens, chunk_pages, chunk_index,
+                       chunk_last, first_row, last, offsets, table, key):
+            key, sub = jax.random.split(key)
+            logits, pool, counts = model.mixed_step(
+                params, pool, chunk_tokens, chunk_pages, chunk_last, last,
+                offsets, offsets, table, cfg, chunk_index=chunk_index)
+            with jax.named_scope("head_sample"):
+                toks = self._sample(logits, sub)
+            # the row whose prompt ends in this chunk (``first_row``; -1:
+            # none does) is live from the next token-step on, and the
+            # chunk's token is its first
+            nxt = jnp.where(jnp.arange(last.shape[0]) == first_row,
+                            toks[-1], toks[:-1])
+            return pool, nxt, key, counts
+
+        return jax.jit(mixed_step, donate_argnums=(1,))
+
+    def _begin_prefill(self, p: _Pending, row: int) -> None:
+        """Admit ``p`` into ``row`` as a prefilling row: the host keeps its
+        prompt, padded to whole chunks (the junk is invisible: every mask
+        stops at the true length and decode overwrites those positions), and
+        the mixed steps to come carry it chunk by chunk. Its pages were
+        reserved by the admit gate; nothing is dispatched here."""
+        act = faults.fire("serve.admit")
+        if act is not None:
+            if act.mode == "stall":
+                act.sleep()
+            else:  # error/drop: fail ONLY this request, engine keeps going
+                act.raise_()
+        np = self._np
+        toks, budget = p.item
+        toks = self._clip_tokens(toks) or [1]
+        arr = np.ones(-(-len(toks) // self._chunk) * self._chunk, np.int32)
+        arr[: len(toks)] = toks
+        self._slot_pending[row] = p
+        self._slot_prompt[row] = (arr, len(toks))
+        self._slot_chunks[row] = 0
+        # idle in the decode half until its last chunk has run
+        self._slot_offset[row] = 0
+        self._slot_last[row] = 1
+        self._slot_out[row] = []
+        self._slot_budget[row] = budget
+        self._prefilling.append(row)
+
+    def _iterate_mixed(self, admits) -> None:
+        """One iteration where the model offers ``mixed_step``: the chunks
+        waiting, oldest row first and at most K of them, each in one program
+        with a decode token-step of the live rows, or, where no chunk waits,
+        the K token-steps of the decode program; then ONE readback of every
+        token the iteration made."""
+        np = self._np
+        acc, counts = self._phase, self._counts
+        for p, row in admits:
+            with phase(acc, "prefill", cap=self.kv_pool.row_tokens(row)):
+                try:
+                    self._begin_prefill(p, row)
+                except faults.FaultInjected as e:
+                    # injected admit failure takes down ONE request, not
+                    # the engine: release the reservation, keep admitting
+                    self.kv_pool.free(row)
+                    p.error = e
+                    p.event.set()
+        active = [r for r in range(self.max_slots)
+                  if self._slot_pending[r] is not None]
+        if not active:
+            return
+        if self._prefilling:
+            outs, began = self._dispatch_mixed(len(active))
+        else:
+            # the decode program runs only where no chunk waits: a chunk
+            # carries the live rows' token-step for nothing, and after the
+            # last one that waits the readback comes at once, so that rows
+            # which ended retire and the prompts queued behind them bring
+            # the next chunks
+            outs, began = [self._dispatch_decode(active)], {}
+        with phase(acc, "step_wait"):
+            # every token-step's tokens [B] (the decode program's [K, B])
+            # and the model's counts, in one readback
+            outs = self._jax.device_get(outs)
+            for _, stepped in outs:
+                self._add_model_counts(stepped)
+            toks = np.concatenate([np.atleast_2d(t) for t, _ in outs])
+        with phase(acc, "emit"):
+            self.steps += len(toks)
+            now = time.time()
+            for row in began:  # the first token exists
+                p = self._slot_pending[row]
+                p.t_first = now
+                counts["admitted"] += 1
+                self._recent.append((p.t_admit - p.t_submit,
+                                     p.t_first - p.t_admit))
+            self._emit(toks, [r for r in active
+                              if r not in self._prefilling], began)
+
+    def _dispatch_mixed(self, rows: int):
+        """Dispatch the chunks that wait, oldest row first and at most K of
+        them, each with a decode token-step of the live rows, and return
+        their tokens and counts still on the device ([(tokens, counts)], a
+        mixed step each) and, for each row whose prompt ended, the
+        token-step that made its first token. Mixed steps chain on the
+        device: each takes the last one's tokens and the rest of its key,
+        and a row whose prompt ends is live from the next one on with its
+        first token left there, so all a program is handed (chunk tokens,
+        offsets, tables) is known without reading anything back, and none
+        waits for the host."""
+        jnp, np = self._jnp, self._np
+        acc, counts = self._phase, self._counts
+        K, C = self.steps_per_iter, self._chunk
+        pool, sink = self.kv_pool, self.kv_pool.sink_page
+        with phase(acc, "assemble", rows=rows):
+            if self._pool is None:  # the engine's first admission
+                self._pool = pool.allocate()
+            key = self._iteration_key()
+            last = jnp.asarray(self._slot_last)
+            # a row with chunks to go is idle in the decode half: offset 0
+            off = self._slot_offset.copy()
+            counts["iterations"] += 1
+        # the most whole chunks a prompt can have
+        per = C // pool.page_tokens
+        most = -(-(self.cfg.max_seq - self.max_new_tokens) // C)
+        began, outs = {}, []
+        while self._prefilling and len(outs) < K:
+            row = self._prefilling[0]
+            arr, true_len = self._slot_prompt[row]
+            index = int(self._slot_chunks[row])
+            ends = (index + 1) * C >= len(arr)
+            with phase(acc, "prefill", chunk_index=index,
+                       cap=pool.row_tokens(row)):
+                live = off > 0
+                # the chunk's table: the row's pages, sink entries past
+                # them, as many whole chunks as the prompt has rounded up
+                # to K of them. The program has a branch a chunk of its
+                # table (and jit a program a table length), so prompts of
+                # up to K chunks share one, of up to 2 K the next
+                reach = min(-(-(len(arr) // C) // K) * K, most) * per
+                chunk_pages = np.full(reach, sink, np.int32)
+                mine = pool.table[row, :reach]
+                chunk_pages[:len(mine)] = mine
+                # an idle row's junk write at positions 0, 1, ... must find
+                # the sink: the prefilling row's pages go in as the chunk's
+                # alone
+                table = np.where(live[:, None], pool.table, sink)
+                # the head's row of the chunk: the prompt's last token
+                at = np.int32(true_len - 1 - index * C if ends else 0)
+                self._pool, last, key, stepped = self._mixed_step(
+                    self.params, self._pool, arr[index * C:(index + 1) * C],
+                    chunk_pages, np.int32(index), at,
+                    np.int32(row if ends else -1), last, off.copy(), table,
+                    key)
+                counts["mixed_steps"] += 1
+                counts["prefill_positions"] += C
+                if C in self._prefill_kernel:
+                    counts["prefill_kernel_positions"] += C
+                counts["chunk_positions_live"] += min(
+                    C, true_len - index * C)
+                self._count_positions(off[live], 1)
+                off[live] += 1
+                self._slot_chunks[row] += 1
+                if ends:  # live from the next token-step on
+                    self._prefilling.pop(0)
+                    off[row] = self._slot_offset[row] = true_len
+                    began[row] = len(outs)
+                outs.append((last, stepped))
+        return outs, began
+
+    def _iteration_key(self):
+        """The key of an iteration's programs, which split it further on
+        the device. Greedy decoding draws nothing: no program is dispatched
+        for a key it would not read."""
+        if self.temperature > 0:
+            self._key, sub = self._jax.random.split(self._key)
+            return sub
+        return self._key
+
+    def _count_positions(self, offsets, steps: int) -> None:
+        """The KV positions a decode program of ``steps`` token-steps over
+        live rows at ``offsets`` fetches (each row's pages up to its last
+        step's length), and how many of them are live."""
+        page = self.kv_pool.page_tokens
+        self._counts["slab_positions"] += int(
+            (-(-(offsets + steps) // page) * page).sum())
+        self._counts["live_positions"] += int(offsets.sum())
+
+    def _add_model_counts(self, stepped) -> None:
+        np = self._np
+        for name, c in stepped.items():
+            self._model_counts[name] = self._model_counts.get(
+                name, 0) + c.astype(np.int64)
+
+    def _emit(self, toks, rows, began) -> None:
+        """Hand each of ``rows`` what its budget allows of the iteration's
+        tokens ``toks`` [T, B]. ``began`` maps a row whose prompt ended in
+        this iteration to the token-step that made its first token: its
+        tokens start there, and its cache holds the prompt and every token
+        but the last."""
+        T = len(toks)
+        for r in rows:
+            start = began.get(r, 0)
+            # a row finishing mid-iteration consumes only what
+            # its budget allows; the surplus decoded junk wrote
+            # beyond its end, into its OWN pages or into the
+            # sink, where the lengths keep it invisible
+            take = min(T - start, int(self._slot_budget[r]))
+            self._slot_out[r].extend(
+                int(t) for t in toks[start:start + take, r])
+            self._slot_last[r] = int(toks[start + take - 1, r])
+            self._slot_offset[r] += take - (r in began)
+            self._slot_budget[r] -= take
+            if self._slot_budget[r] <= 0:
+                self._retire(r)
 
     def _publish(self) -> None:
         """Engine thread (and the constructor, before it starts): put a
@@ -572,10 +813,15 @@ class ContinuousBatcher:
         subtracts two snapshots): wall and thread-CPU seconds by phase,
         iterations, KV positions the step fetches (each live row's pages up
         to its last step's length) and the live ones among them (both
-        summed at assembly), requests admitted,
+        summed at assembly, once a decode program: a mixed step is one
+        token-step), requests admitted,
         the positions their prefill programs computed (the sum of the
-        buckets' lengths) and those of buckets whose program attends in the
-        flash kernel (the model's ``prefill_takes_kernel``),
+        buckets' lengths, or of the chunks') and those of buckets whose
+        program attends in the flash kernel (the model's
+        ``prefill_takes_kernel``), ``mixed_steps`` (token-steps that carried
+        a chunk of a prompt) and ``chunk_positions_live`` (prompt positions
+        in them that were not padding; both 0 where prompts are prefilled
+        whole),
         ``(queue_wait_s, prefill_s)`` of the newest 512 of them, oldest
         first, the bytes a cached position holds, and whatever the model's
         decode step counts of itself, added up by name (the expert model:

@@ -348,3 +348,78 @@ def test_prefill_program_holds_the_kernel_and_no_scores(one_chip, monkeypatch,
     saved = (old.memory_analysis().temp_size_in_bytes
              - new.memory_analysis().temp_size_in_bytes)
     assert saved >= 2 * 2 ** 30, saved
+
+
+# ------------------------------------- a prompt's chunk on the decode step
+def test_the_mixed_step_fits_and_keeps_the_pool_where_it_is(one_chip,
+                                                            monkeypatch):
+    """``longprompt-batch``'s mixed-step program (``mistral-7b-d16`` widths,
+    8 rows, pages of 512, a chunk table of 8 chunks: 512 prompt positions
+    over up to 4,096 keys beside one decode token a row): ONE switch over
+    the prefix lengths in the layer loop, a flash Mosaic call a branch and
+    one paged-attention call, every value of the pool's shape in the layout
+    the pool came in and none a copy (the pages before the chunk are sliced
+    out of the pool outside the switch), and the whole program beside the
+    2 GiB pool inside the chip's memory."""
+    import importlib
+    import re
+
+    from ray_memory_management_tpu.models import gpt
+    from ray_memory_management_tpu.ops import paged_attention as pa
+    from ray_memory_management_tpu.serve.kv_cache import row_token_bytes
+    from ray_memory_management_tpu.serve.llm import ContinuousBatcher
+
+    # the dispatches ask where default computation lands: steer them here
+    fa = importlib.import_module(
+        "ray_memory_management_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    cfg = gpt.TransformerConfig(
+        vocab_size=32_000, d_model=4096, n_layers=16, n_heads=32,
+        n_kv_heads=8, d_ff=14336, max_seq=4096, param_dtype=jnp.bfloat16)
+    slots, page, reach = 8, 512, 8
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    eng = ContinuousBatcher(
+        None, cfg, max_slots=slots, max_new_tokens=256, pad_multiple=page,
+        steps_per_iter=8, kv_page_tokens=page,
+        kv_pool_bytes=slots * 4096 * row_token_bytes(cfg))
+    try:
+        params = shaped(jax.eval_shape(
+            lambda: gpt.init_params(jax.random.PRNGKey(0), cfg)))
+        pool = shaped(jax.eval_shape(eng.kv_pool.allocate))
+        assert pool["k"].shape == (16, 8, 65, 512, 128)
+        width = eng.kv_pool.table_width
+        assert eng._mixed and eng._chunk == page and width == 8
+        compiled = eng._mixed_step.lower(
+            params, pool, arr((page,)), arr((reach,)), arr(()), arr(()),
+            arr(()), arr((slots,)), arr((slots,)), arr((slots, width)),
+            arr((2,), jnp.uint32)).compile()
+        assert page in eng._prefill_kernel
+    finally:
+        eng.close()
+    text = compiled.as_text()
+    # the layer loop is one body: the chunk's flash call in each branch of
+    # one switch, the rows' paged one
+    assert text.count("tpu_custom_call") == reach + 1
+    assert len(re.findall(r" conditional\(", text)) == 1
+    assert "prefill_attention" in text and "paged_decode_attention" in text
+    made = re.findall(r"= bf16\[16,8,65,512,128\]\{([\d,]+)[^ ]* (\S+?)\(",
+                      text)
+    assert made and {layout for layout, _ in made} == {"4,3,2,1,0"}
+    assert "copy" not in {op for _, op in made}
+    # no scores: the chunk's are [32, 512, 512] to [32, 512, 4096]
+    assert not re.search(r"\[(\d+,)*32,512,(512|1024|2048|3584|4096)\]",
+                         text)
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    # 7.0 GiB of weights, 2 GiB of pool, under 1 GiB of temporaries
+    assert 8.9 * 2 ** 30 < total < 10.1 * 2 ** 30
+    assert m.alias_size_in_bytes >= 2 * pool["k"].size * 2  # donated

@@ -409,7 +409,8 @@ class TestContinuousBatching:
             prompts = [[5 + i, 9, 17, 3] for i in range(4)]
             solo = eng.submit(prompts[0])  # also compiles both programs
             alone = eng.engine_stats()["iterations"]
-            assert alone == 8  # 31 tokens after the prefill's, 4 a time
+            # the prompt's chunk with its first token, then 31, 4 a time
+            assert alone == 9
             res = [None] * 4
 
             def go(i):

@@ -59,10 +59,13 @@ def _stopped_engine(llm_mod, params, cfg, **kw):
 
 
 def _admit(eng, llm_mod, row, tokens, budget):
+    """Reserve ``row``'s pages and run the iteration that admits the
+    request (the engine's own, driven by hand): the prompt's chunks, each
+    beside a token-step of the rows already live."""
     p = llm_mod._Pending((list(tokens), budget))
     need = eng._need_tokens(p)
     assert eng.kv_pool.reserve(row, need)
-    eng._admit(p, row)
+    eng._iterate_mixed([(p, row)])
     return p, need
 
 
@@ -78,13 +81,15 @@ class TestPagedKV:
 
         gpt, cfg, params = engine_setup
         eng = _stopped_engine(llm_mod, params, cfg, max_slots=2,
-                              max_new_tokens=4, pad_multiple=8,
-                              kv_page_tokens=16)
+                              max_new_tokens=24, pad_multiple=8,
+                              steps_per_iter=4, kv_page_tokens=16)
         pool = eng.kv_pool
         assert eng._pool is None and eng.kv_stats()["store_bytes"] == 0
         assert (pool.table == pool.sink_page).all()
 
-        p, need = _admit(eng, llm_mod, 0, [5, 9, 17, 3], 4)
+        # the admitting iteration makes the first of its 24 tokens
+        p, need = _admit(eng, llm_mod, 0, [5, 9, 17, 3], 24)
+        assert not p.event.is_set() and len(eng._slot_out[0]) == 1
         pages = pool.pages_for(need)
         assert pool.pages_in_use == pages
         assert mdefs.serve_kv_pages_in_use().get() == float(pages)
@@ -173,7 +178,6 @@ class TestPagedKV:
         reservation ends with them. The six positions it decodes past
         that go to the sink: row 1's pages, row 0's own history and every
         free page are bit for bit what they were."""
-        import jax.numpy as jnp
         import numpy as np
 
         from ray_memory_management_tpu.serve import llm as llm_mod
@@ -184,22 +188,27 @@ class TestPagedKV:
                               max_new_tokens=16, pad_multiple=8,
                               steps_per_iter=K, kv_page_tokens=page)
         pool = eng.kv_pool
-        # prompt 5 + budget 3: one page, full after the two decode steps
-        _admit(eng, llm_mod, 0, range(2, 7), 3)
+        # two chunks, the first token in the second
         _admit(eng, llm_mod, 1, range(20, 31), 16)
+        # prompt 5 + budget 3: one page, full after the two decode steps;
+        # row 1 rode its chunk
+        p0, _ = _admit(eng, llm_mod, 0, range(2, 7), 3)
         assert pool.pages_for(eng.kv_pool.row_tokens(0)) == 1
+        assert eng._slot_offset.tolist() == [5, 11 + 1, 0]
+        assert not p0.event.is_set() and eng._slot_budget[0] == 2
         before = {n: np.asarray(a, np.float32)
                   for n, a in eng._pool.items()}
         off = eng._slot_offset.copy()
-        eng._pool, toks, _ = eng._paged_step(
-            params, eng._pool, jnp.asarray(eng._slot_last),
-            jnp.asarray(eng._slot_offset), jnp.asarray(pool.table),
-            eng._key)
-        assert np.asarray(toks).shape == (K, 3)
         sink, mine = pool.sink_page, pool.table[0, 0]
         theirs = [pg for pg in pool.table[1] if pg != sink]
         free = [pg for pg in range(pool.capacity_pages)
                 if pg != mine and pg not in theirs]
+        steps = eng.steps
+        eng._iterate_mixed([])  # no chunk waits: the decode program
+        assert eng.steps - steps == K and p0.event.is_set()
+        assert p0.result == np.asarray(gpt.generate(
+            params, cfg, np.asarray([list(range(2, 7))], np.int32),
+            steps=3))[0, 5:].tolist()
         for name, was in before.items():
             now = np.asarray(eng._pool[name], np.float32)
             # row 1 wrote its own K positions and nothing else of its pages
@@ -220,11 +229,12 @@ class TestPagedKV:
             # the overshoot (and the idle row 2) went to the sink
             assert (now[:, :, sink] != was[:, :, sink]).any()
 
-    def test_one_decode_program_and_one_prefill_a_bucket(self):
-        """``stats()["compile"]``: the first request of a bucket builds
-        that bucket's prefill, the very first the decode step too; after
-        that no length, budget, page count or mix of live rows asks the
-        compiler for anything."""
+    def test_one_decode_program_and_one_mixed_step(self):
+        """``stats()["compile"]``: the first request builds the mixed step
+        and the decode program, a prompt of more chunks nothing more; after
+        that no length, budget, page count or mix of
+        live rows asks the compiler for anything, and no whole-prompt
+        prefill is ever built."""
         from ray_memory_management_tpu.serve.llm import LLMServer
 
         srv = LLMServer(preset="test", max_batch_size=4, max_new_tokens=24,
@@ -235,14 +245,12 @@ class TestPagedKV:
             def programs():
                 return srv.stats()["compile"]["programs"]
 
-            srv.generate(list(range(2, 12)), max_new_tokens=9)  # bucket 16
-            assert set(eng._prefill_cache) == {16}
-            first = programs()
-            srv.generate(list(range(2, 22)), max_new_tokens=9)  # bucket 32
-            assert set(eng._prefill_cache) == {16, 32}
-            # one program more: the new bucket's prefill, and no new step
-            assert programs() == first + 1
+            srv.generate(list(range(2, 12)), max_new_tokens=9)  # one chunk
+            assert eng._mixed_step._cache_size() == 1
             warm = programs()
+            srv.generate(list(range(2, 22)), max_new_tokens=9)  # two
+            # the second chunk rides the same program, and no new decode
+            assert programs() == warm
 
             def go(i):
                 srv.generate(list(range(2, 4 + 3 * i)),
@@ -256,8 +264,9 @@ class TestPagedKV:
             assert not any(t.is_alive() for t in ts)
             assert srv.stats()["requests"] == 12
             assert programs() == warm
-            assert set(eng._prefill_cache) == {16, 32}
+            assert not eng._prefill_cache
             assert eng._paged_step._cache_size() == 1
+            assert eng._mixed_step._cache_size() == 1
         finally:
             eng.close()
 
@@ -341,12 +350,22 @@ class TestPagedKV:
         assert eng._slot_pending[0] is not None and len(eng._q) == 1
         return ts, raised
 
+    @staticmethod
+    def _wrap_program(eng, which, wrap):
+        """Lay ``wrap(program) -> callable`` over the decode program
+        (``which`` "decode") or over the mixed step ("mixed": the program
+        that carries a prompt's chunk)."""
+        name = "_paged_step" if which == "decode" else "_mixed_step"
+        setattr(eng, name, wrap(getattr(eng, name)))
+
+    @pytest.mark.parametrize("which", ["decode", "mixed"])
     def test_failed_decode_program_fails_all_and_the_engine_recovers(
-            self, engine_setup):
-        """The decode program raises once: the resident caller and the
-        queued one both get that error, every page goes back, the pool's
-        arrays are dropped (a failed program may have consumed them), and
-        the next request is token-exact on a pool allocated anew."""
+            self, engine_setup, which):
+        """The decode program (or the mixed step that carries the resident
+        caller's chunk) raises once: the resident caller and the queued one
+        both get that error, every page goes back, the pool's arrays are
+        dropped (a failed program may have consumed them), and the next
+        request is token-exact on a pool allocated anew."""
         import numpy as np
 
         from ray_memory_management_tpu.serve.llm import ContinuousBatcher
@@ -355,16 +374,18 @@ class TestPagedKV:
         eng = ContinuousBatcher(params, cfg, max_slots=1, max_new_tokens=8,
                                 pad_multiple=8, steps_per_iter=4,
                                 kv_page_tokens=16)
-        step, calls, go_on = eng._paged_step, [], threading.Event()
+        calls, go_on = [], threading.Event()
 
-        def falls_over_once(*args):
-            calls.append(1)
-            if len(calls) == 1:
-                go_on.wait(120)  # until the second caller is queued
-                raise RuntimeError("decode program fell over")
-            return step(*args)
+        def falls_over_once(step):
+            def call(*args):
+                calls.append(1)
+                if len(calls) == 1:
+                    go_on.wait(120)  # until the second caller is queued
+                    raise RuntimeError("decode program fell over")
+                return step(*args)
+            return call
 
-        eng._paged_step = falls_over_once
+        self._wrap_program(eng, which, falls_over_once)
         try:
             prompts = [[5, 9, 17, 3], [2, 4, 6, 8, 10]]
             ts, raised = self._resident_and_queued(eng, prompts, 8)
@@ -377,6 +398,7 @@ class TestPagedKV:
                 assert isinstance(e, RuntimeError)
                 assert "decode program fell over" in str(e)
             assert eng.kv_pool.pages_in_use == 0 and eng._pool is None
+            assert not eng._prefilling
             assert eng._thread.is_alive()  # it keeps serving
             out = eng.submit(prompts[1], timeout=120)
             ref = np.asarray(gpt.generate(
@@ -386,25 +408,30 @@ class TestPagedKV:
         finally:
             eng.close()
 
+    @pytest.mark.parametrize("which", ["decode", "mixed"])
     def test_close_fails_resident_and_queued_callers_promptly(
-            self, engine_setup):
-        """``close()`` while one request decodes and one waits: both get
-        "engine closed" within seconds of it, not after their submit
-        timeout of 300 s, the engine thread exits, and no page stays
-        reserved."""
+            self, engine_setup, which):
+        """``close()`` while one request decodes (or its chunk rides a
+        mixed step that takes seconds) and one waits: both get "engine
+        closed" within seconds of it, not after their submit timeout of
+        300 s, the engine thread exits, and no page stays reserved."""
         from ray_memory_management_tpu.serve.llm import ContinuousBatcher
 
         gpt, cfg, params = engine_setup
         eng = ContinuousBatcher(params, cfg, max_slots=1,
                                 max_new_tokens=100, pad_multiple=8,
                                 steps_per_iter=1, kv_page_tokens=16)
-        step = eng._paged_step
+        # 99 decode steps: resident for five seconds; or three in the
+        # mixed step alone
+        nap = {"decode": 0.05, "mixed": 3.0}[which]
 
-        def slow_step(*args):  # 99 of them: resident for five seconds
-            time.sleep(0.05)
-            return step(*args)
+        def slow(step):
+            def call(*args):
+                time.sleep(nap)
+                return step(*args)
+            return call
 
-        eng._paged_step = slow_step
+        self._wrap_program(eng, which, slow)
         try:
             ts, raised = self._resident_and_queued(
                 eng, [[5, 9, 17, 3], [2, 4, 6, 8, 10]], 100)
