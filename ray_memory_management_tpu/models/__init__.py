@@ -10,17 +10,24 @@ def serving_model(cfg):
     cfg)`` (models/gpt.py and models/latent_moe.py say what each
     returns), where a slot holds a state besides its pages also
     ``state_spec(cfg)`` (models/hybrid_ssm.py), and, optionally,
+    ``mixed_step`` (below). A model whose layers are of several kinds gives
+    both specifications **by kind** (models/nemotron_h.py): the leading
+    dimension of ``cache_spec``'s arrays counts the layers that leave
+    something in a page, that of ``state_spec``'s the layers that keep a
+    state, and a layer that does neither appears in none; the pool takes
+    any leading dimensions (serve/kv_cache.py). The optional
     ``mixed_step(params, pool, chunk_tokens, chunk_pages, chunk_last,
     tokens, positions, lengths, page_table, cfg, *, chunk_index)``: one
     chunk of a prompt and one decode token a live row in one pass over the
     layers (models/gpt.py). The engine prefills in chunks that ride its
     decode steps where a model offers it, and whole prompts through
     ``prefill_row`` where it does not."""
-    from . import gpt, hybrid_ssm, latent_moe
+    from . import gpt, hybrid_ssm, latent_moe, nemotron_h
 
     for module, kind in ((gpt, gpt.TransformerConfig),
                          (latent_moe, latent_moe.LatentMoEConfig),
-                         (hybrid_ssm, hybrid_ssm.HybridSSMConfig)):
+                         (hybrid_ssm, hybrid_ssm.HybridSSMConfig),
+                         (nemotron_h, nemotron_h.NemotronHConfig)):
         if isinstance(cfg, kind):
             return module
     raise TypeError(f"no model serves a {type(cfg).__name__}")

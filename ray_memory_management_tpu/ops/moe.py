@@ -23,7 +23,19 @@ residual connection (their combine weights are zero), the Switch
 
 :func:`moe_dropless` is the layer without a capacity (the serve path's:
 models/latent_moe.py): every token gets every expert it chose, whatever
-else is in the batch.
+else is in the batch. Its two halves are :func:`route_sigmoid_top_k` and
+:func:`grouped_experts`; the second takes experts in either of two forms
+(SwiGLU on three matrices, or a squared ReLU on two), reading whatever
+vector its caller hands it (the hidden one, or a latent one), and **a share
+of a layer's experts**: told the first id it holds, it computes the part of
+the result that the held experts give and nothing in place of the rest (the
+guide's share cut: a router as wide as published, one chip's experts; the
+exchange between chips is not here: ``parallel/sharding.py`` has none). On a
+TPU the two-matrix form runs in a Pallas kernel of its own,
+``moe_expert_tiles_<tiles>`` (tiles of 128 rows, one expert each, the
+expert's two matrices whole in VMEM; a decode step's rows are every held
+expert's tile as they lie); the SwiGLU form and every other platform take
+``jax.lax.ragged_dot``.
 """
 
 from __future__ import annotations
@@ -34,6 +46,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from .flash_attention import _on_tpu
 
 
 def init_moe_params(key, n_layers: int, d_model: int, d_ff: int,
@@ -168,39 +182,269 @@ def route_sigmoid_top_k(x, router, bias, top_k: int, scale: float,
 
 def moe_dropless(x, layer, top_k: int, scale: float, normalize: bool = True,
                  live=None):
-    """Routed SwiGLU experts without a capacity: x [T, D] -> (y [T, D],
-    expert_tokens int32 [E]).
-
-    ``layer``: router [D, E], bias [E], w1 / w3 [E, D, F], w2 [E, F, D].
-    One algorithm for any T: the T x k (token, expert) assignments are
-    sorted by expert, the three matmuls run grouped over the sorted rows
-    (``jax.lax.ragged_dot``: on a TPU a grouped-matmul kernel that visits
-    only the experts that have rows, so an expert no token chose is not
-    read), and each token's k results are weighed and summed where the token
-    lies. No row is dropped or reweighed for room, so a token's result does
-    not depend on what else is in the batch. A row of ``live`` (bool [T])
-    that is False is routed to no expert: it sorts past every group, reads
-    0 and is counted nowhere. ``expert_tokens[e]`` is how many live rows
-    chose expert ``e``."""
-    T, D = x.shape
-    E = layer["router"].shape[-1]
+    """Routed experts without a capacity: x [T, D] -> (y [T, D],
+    expert_tokens int32 [E]): :func:`route_sigmoid_top_k` on ``x``, then
+    :func:`grouped_experts` on ``x`` with what it chose. ``layer``: router
+    [D, E], bias [E] and the experts' matrices. The layer of a model whose
+    experts read the vector the router reads and are all held
+    (models/latent_moe.py); one whose experts read another vector, or that
+    holds a share of them, calls the two itself (models/nemotron_h.py)."""
     chosen, w = route_sigmoid_top_k(x, layer["router"], layer["bias"],
                                     top_k, scale, normalize)
+    return grouped_experts(x, chosen, w, layer, live)
+
+
+def grouped_experts(x, chosen, w, layer, live=None, first: int = 0,
+                    use_pallas: Optional[str] = None):
+    """The experts a router chose, without a capacity: x [T, D] (what the
+    experts read: the hidden vector, or a latent one), ``chosen`` int32
+    [T, k] (ids among all the experts the router knows) and ``w`` float32
+    [T, k] -> (y [T, D'], expert_tokens int32 [E]).
+
+    ``layer`` holds the matrices of the ``E`` experts that live here, ids
+    ``first`` .. ``first + E - 1``, in one of two forms: SwiGLU (w1 / w3
+    [E, D, F], w2 [E, F, D']: ``w2(silu(w1 x) * w3 x)``) or, without a
+    ``w3``, two matrices and a squared ReLU (``w2(relu(w1 x) ** 2)``).
+
+    One algorithm for any T: the T x k (token, expert) assignments are
+    sorted by expert, the matmuls run grouped over the sorted rows, and each
+    token's k results are weighed and summed where the token lies. No row is
+    dropped or reweighed for room, so a token's result does not depend on
+    what else is in the batch. A row of ``live`` (bool [T]) that is False is
+    routed to no expert: it sorts past every group, reads 0 and is counted
+    nowhere. Two forms of the grouped matmuls:
+
+      - ``jax.lax.ragged_dot`` over the sorted rows as they lie (on a TPU
+        the compiler's own grouped-matmul kernel: an expert no token chose
+        is not read and rows past the last group cost nothing, **but they
+        come back as junk, not as zeros**, and every expert's rows are
+        walked in tiles of 512: at 5 to 90 rows an expert it runs at a
+        third of the bandwidth and a twentieth of the peak);
+      - for the two-matrix form on a TPU, the Pallas kernel
+        ``name="moe_expert_tiles_<tiles>"``: each expert's sorted rows are
+        laid out from a tile boundary of their own (``EXPERT_TILE`` rows: one
+        pass of the MXU), so a tile has one expert; the grid walks the tiles
+        that have rows, in expert order; a tile's two matmuls and the
+        squared ReLU between them run in one visit with the expert's two
+        matrices in VMEM (fetched once an expert, whole and contiguous,
+        double buffered against the tile before); an expert no token chose
+        has no tile and is never fetched. **A step of no more rows than a
+        tile (a decode step) needs no sort at all**: every held expert's
+        tile is the step's own rows, the grid walks the held experts, a
+        column of the [T, E] matrix of weights says which rows count for
+        the expert (0: not its), and the results add up in one block that
+        stays in VMEM. Chosen from the platform and the shapes
+        (:func:`expert_kernel_takes`), never by a flag; ``use_pallas``:
+        "on", "interpret", "off", or None = that choice.
+
+    **A share of the experts** (``E`` less than the router's width, or
+    ``first`` > 0: one chip's part of a layer that several chips share): an
+    assignment to an expert that is not held is computed nowhere and adds
+    nothing. It sorts past every group like an idle row's, its weight stays
+    out of the sum, and the weights of the assignments that are held are
+    what the router gave them (normalised over all k, held or not). ``y`` is
+    then this chip's part of the layer's result, and the parts of all the
+    shares add up to the whole. ``expert_tokens[e]`` is how many live rows
+    chose held expert ``first + e``."""
+    T = x.shape[0]
+    E, top_k = layer["w1"].shape[0], chosen.shape[-1]
+    if use_pallas is None:
+        use_pallas = "on" if _on_tpu() and expert_kernel_takes(x, layer) \
+            else "off"
     with jax.named_scope("moe_experts"):
+        held = None
+        if first or E != layer["router"].shape[-1]:
+            chosen = chosen - first
+            held = (chosen >= 0) & (chosen < E)
         if live is not None:
-            chosen = jnp.where(live[:, None], chosen, E)  # past every group
-            w = jnp.where(live[:, None], w, 0.0)
+            held = live[:, None] if held is None else held & live[:, None]
+        if held is not None:
+            chosen = jnp.where(held, chosen, E)           # past every group
+            w = jnp.where(held, w, 0.0)
+        if use_pallas != "off" and T <= EXPERT_TILE:
+            return _one_tile_experts(x, chosen, w, layer,
+                                     interpret=(use_pallas == "interpret"))
         flat = chosen.reshape(-1)                         # [T * k]
         order = jnp.argsort(flat, stable=True)
         sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+        back = jnp.argsort(order)                         # where each lies
+        if use_pallas != "off":
+            ys = _tiled_experts(x, flat, order, back, sizes, layer, top_k,
+                                interpret=(use_pallas == "interpret"))
+            # a row that lies nowhere reads whatever its index points at
+            ys = jnp.where((flat < E)[:, None], ys, 0.0)
+            y = jnp.sum(ys.reshape(T, top_k, -1) * w[..., None], axis=1)
+            return y.astype(x.dtype), sizes
         xs = x[order // top_k]                            # rows by expert
 
         def grouped(a, b):
             return jax.lax.ragged_dot(a, b.astype(a.dtype), sizes,
                                       preferred_element_type=jnp.float32)
 
-        h = jax.nn.silu(grouped(xs, layer["w1"])) * grouped(xs, layer["w3"])
-        ys = grouped(h.astype(x.dtype), layer["w2"])      # [T * k, D] f32
-        back = jnp.argsort(order)                         # where each lies
-        y = jnp.sum(ys[back].reshape(T, top_k, D) * w[..., None], axis=1)
+        if "w3" in layer:
+            h = jax.nn.silu(grouped(xs, layer["w1"])) \
+                * grouped(xs, layer["w3"])
+        else:
+            h = jnp.square(jax.nn.relu(grouped(xs, layer["w1"])))
+        ys = grouped(h.astype(x.dtype), layer["w2"])      # [T * k, D'] f32
+        y = jnp.sum(ys[back].reshape(T, top_k, -1) * w[..., None], axis=1)
     return y.astype(x.dtype), sizes
+
+
+# rows of a tile of the expert kernel: one pass of the MXU's 128 rows
+EXPERT_TILE = 128
+# what the kernel may hold in VMEM: two experts' two matrices (one computed
+# on, one arriving) and a tile's rows; a v5e core has 128 MiB
+_EXPERT_VMEM = 96 << 20
+
+
+def expert_tiles(rows: int, top_k: int, experts: int) -> int:
+    """Tiles the expert kernel's grid has for ``rows`` tokens: a tile an
+    expert where the rows are no more than a tile; else each assignment's
+    row, and for each expert the last tile's empty rest."""
+    if rows <= EXPERT_TILE:
+        return experts
+    return -(-rows * top_k // EXPERT_TILE) + experts
+
+
+def expert_kernel_takes(x, layer) -> bool:
+    """Can the compiled expert kernel run this layer on a TPU? The
+    two-matrix form (the SwiGLU experts keep the compiler's grouped matmul),
+    widths in whole lanes, rows and matrices of one type, and two experts'
+    matrices within the kernel's VMEM."""
+    w1, w2 = layer["w1"], layer["w2"]
+    return ("w3" not in layer and x.dtype == w1.dtype == w2.dtype
+            and x.dtype in (jnp.bfloat16, jnp.float32)
+            and all(n % 128 == 0 for n in w1.shape[1:] + w2.shape[2:])
+            and 2 * (w1.size + w2.size) // w1.shape[0]
+            * jnp.dtype(x.dtype).itemsize <= _EXPERT_VMEM * 3 // 4)
+
+
+def _expert_rows(x_ref, w1_ref, w2_ref, weigh=None):
+    """A tile's rows through one expert: ``w2(relu(w1 x) ** 2)`` in float32,
+    each row's middle times ``weigh`` [rows, 1] where given."""
+    h = jnp.square(jnp.maximum(jnp.dot(
+        x_ref[...], w1_ref[...], preferred_element_type=jnp.float32), 0.0))
+    if weigh is not None:
+        h = h * weigh
+    return jnp.dot(h.astype(x_ref.dtype), w2_ref[...],
+                   preferred_element_type=jnp.float32)
+
+
+def _tiles_kernel(expert_ref, active_ref, x_ref, w1_ref, w2_ref, o_ref):
+    import jax.experimental.pallas as pl
+
+    @pl.when(pl.program_id(0) < active_ref[0])
+    def _a_tile_with_rows():
+        o_ref[...] = _expert_rows(x_ref, w1_ref, w2_ref)
+
+
+def _one_tile_kernel(fetch_ref, count_ref, x_ref, gate_ref, w1_ref, w2_ref,
+                     o_ref):
+    import jax.experimental.pallas as pl
+
+    e = pl.program_id(0)
+
+    @pl.when(e == 0)
+    def _first_expert():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(count_ref[e] > 0)
+    def _an_expert_with_rows():
+        at = jax.lax.broadcasted_iota(jnp.int32, gate_ref.shape, 1)
+        mine = jnp.sum(jnp.where(at == e, gate_ref[...], 0.0), axis=1,
+                       keepdims=True)                          # [T, 1]
+        o_ref[...] += _expert_rows(x_ref, w1_ref, w2_ref, mine)
+
+
+def _one_tile_experts(x, chosen, w, layer, interpret):
+    """The rows of a step that is no longer than a tile, through every held
+    expert that a row chose: (y [T, D'] in ``x``'s type, expert_tokens)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    T, D = x.shape
+    w1, w2 = layer["w1"], layer["w2"]
+    E, F, Dout = w1.shape[0], w1.shape[2], w2.shape[2]
+    hit = chosen[..., None] == jnp.arange(E)                   # [T, k, E]
+    gate = jnp.sum(jnp.where(hit, w[..., None], 0.0), axis=1)  # [T, E]
+    sizes = jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
+    # an expert without rows is not fetched: the grid stays at the one
+    # before it (experts before the first with rows: at expert 0)
+    fetch = jax.lax.cummax(jnp.where(sizes > 0, jnp.arange(E), 0)).astype(
+        jnp.int32)
+    y = pl.pallas_call(
+        _one_tile_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(E,),
+            in_specs=[
+                pl.BlockSpec((T, D), lambda e, fetch, n: (0, 0)),
+                pl.BlockSpec((T, E), lambda e, fetch, n: (0, 0)),
+                pl.BlockSpec((None, D, F),
+                             lambda e, fetch, n: (fetch[e], 0, 0)),
+                pl.BlockSpec((None, F, Dout),
+                             lambda e, fetch, n: (fetch[e], 0, 0))],
+            out_specs=pl.BlockSpec((T, Dout), lambda e, fetch, n: (0, 0))),
+        out_shape=jax.ShapeDtypeStruct((T, Dout), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_EXPERT_VMEM),
+        interpret=interpret,
+        name=f"moe_expert_tiles_{E}",
+    )(fetch, sizes, x, gate, w1, w2)
+    return y.astype(x.dtype), sizes
+
+
+def _tiled_experts(x, flat, order, back, sizes, layer, top_k, interpret):
+    """Each assignment's result [T * k, D'] float32, in the assignments'
+    own order (an assignment that lies nowhere reads another's)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    T, D = x.shape
+    w1, w2 = layer["w1"], layer["w2"]
+    E, F, Dout = w1.shape[0], w1.shape[2], w2.shape[2]
+    tile, n_tiles = EXPERT_TILE, expert_tiles(T, top_k, E)
+    # an expert's sorted rows start at ``start`` and, laid out from a tile
+    # boundary of their own, at ``tile * first_tile``
+    tiles_of = -(-sizes // tile)
+    tile_end = jnp.cumsum(tiles_of)
+    first_tile, start = tile_end - tiles_of, jnp.cumsum(sizes) - sizes
+    active = tile_end[-1:].astype(jnp.int32)
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        tile_end, jnp.arange(n_tiles), side="right"), E - 1).astype(jnp.int32)
+    # the row each place of the layout holds (a tile's empty rest holds
+    # rows of the experts behind: computed, read by nobody)
+    at = jnp.arange(n_tiles * tile)
+    e_at = tile_expert[at // tile]
+    sorted_at = jnp.clip(start[e_at] + at - tile * first_tile[e_at], 0,
+                         flat.shape[0] - 1)
+    rows = x[order[sorted_at] // top_k]                   # [tiles * tile, D]
+
+    def last(i, active):  # a tile without rows: stay where we are
+        return jnp.minimum(i, jnp.maximum(active[0], 1) - 1)
+
+    ys = pl.pallas_call(
+        _tiles_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_tiles,),
+            in_specs=[
+                pl.BlockSpec((tile, D), lambda i, e, a: (last(i, a), 0)),
+                pl.BlockSpec((None, D, F),
+                             lambda i, e, a: (e[last(i, a)], 0, 0)),
+                pl.BlockSpec((None, F, Dout),
+                             lambda i, e, a: (e[last(i, a)], 0, 0))],
+            out_specs=pl.BlockSpec((tile, Dout),
+                                   lambda i, e, a: (last(i, a), 0))),
+        out_shape=jax.ShapeDtypeStruct((n_tiles * tile, Dout), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_EXPERT_VMEM),
+        interpret=interpret,
+        name=f"moe_expert_tiles_{n_tiles}",
+    )(tile_expert, active, rows, w1, w2)
+    # where each assignment's row lies in the layout
+    e_of = jnp.minimum(flat, E - 1)
+    return ys[tile * first_tile[e_of] + back - start[e_of]]

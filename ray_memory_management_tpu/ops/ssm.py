@@ -10,7 +10,16 @@ heads of a group, a head's state ``h`` [N, P] follows
 The state is held **state dimension first** (``[.., H, N, P]``, not Mamba's
 ``[.., H, P, N]``): the contraction with ``C`` then runs over sublanes, ``x``
 and ``y`` lie along the lanes as they come out of a projection, and the
-decode kernel needs no transposition.
+decode kernel needs no transposition. Heads of fewer channels than a row of
+lanes (``P`` = 64) lie **side by side in the resident state**, ``k = 128 //
+P`` of one group to a row (``[.., H / k, N, k P]``, :func:`pack_heads`): a
+float32 array whose last dimension is 64 is padded to 128 in HBM and in
+VMEM, so held a head a row the state would take twice its bytes and every
+fetch would move half padding; side by side it is dense, and the update is
+the same elementwise pass, the decay and the input taking a head's value in
+that head's lanes. Both kernels take ``P`` = 128 (Falcon-H1: 32 heads,
+state 256, 2 groups) and ``P`` = 64 (Nemotron-H: 128 heads, state 128, 8
+groups of 16).
 
   - :func:`ssd_scan`: the recurrence over a whole prompt in chunks of
     ``SSD_CHUNK`` positions (Mamba-2's state-space duality): inside a chunk a
@@ -26,7 +35,7 @@ decode kernel needs no transposition.
     fusions). :func:`ssd_sequential` is the same recurrence a position at a
     time, the oracle of both.
   - :func:`ssm_decode_update`: one token-step of one layer against the
-    resident state ``[L, slots, H, N, P]``. The Pallas kernel
+    resident state ``[L, slots, H / k, N, k P]``. The Pallas kernel
     (``name="ssm_decode_update"``) leaves the array in HBM and aliases it in
     and out: the live slots, in order, are its grid; a live slot's block of
     heads is fetched, updated, written back and contracted with ``C`` in the
@@ -169,10 +178,14 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int = SSD_CHUNK, true_len=None,
 
 def ssd_kernel_takes(x, B, chunk: int = SSD_CHUNK) -> bool:
     """Can the compiled scan kernel tile these shapes on a TPU? A row of at
-    least one whole chunk of whole lanes, channels and state that fill the
-    lanes, bf16 or float32 operands."""
+    least one whole chunk of whole lanes, a state that fills the lanes, heads
+    of 64 channels or whole lanes of them whose block (``_head_block``) is
+    whole lanes wide, bf16 or float32 operands."""
+    H, P = x.shape[1:]
     return (x.shape[0] >= chunk and chunk % 128 == 0
-            and x.shape[-1] % 128 == 0 and B.shape[-1] % 128 == 0
+            and P % 64 == 0 and B.shape[-1] % 128 == 0
+            and H % B.shape[1] == 0
+            and _head_block(H // B.shape[1], B.shape[-1], P) * P % 128 == 0
             and x.dtype in (jnp.bfloat16, jnp.float32))
 
 
@@ -270,7 +283,8 @@ def ssm_decode_update_reference(state, x, dt, A, B, C, D, live, *, layer=0):
     S, H, P = x.shape
     G = B.shape[1]
     f32 = jnp.float32
-    h = state[layer]                                          # [S, H, N, P]
+    k = H // state.shape[2]                       # heads side by side a row
+    h = unpack_heads(state[layer], k)                         # [S, H, N, P]
     b, c = (jnp.repeat(a.astype(f32), H // G, axis=1) for a in (B, C))
     dt = dt.astype(f32)
     new = jnp.exp(dt * A)[..., None, None] * h \
@@ -280,7 +294,7 @@ def ssm_decode_update_reference(state, x, dt, A, B, C, D, live, *, layer=0):
         + D[:, None] * x.astype(f32)
     keep = live[:, None, None, None]
     state = lax.dynamic_update_index_in_dim(
-        state, jnp.where(keep, new, h), layer, 0)
+        state, pack_heads(jnp.where(keep, new, h), k), layer, 0)
     return (jnp.where(live[:, None, None], y, 0.0), state,
             jnp.int32(S))
 
@@ -372,16 +386,22 @@ def _ssm_decode_update_pallas(state, x, dt, A, B, C, D, live, layer,
     S, H, P = x.shape
     G, N = B.shape[1:]
     f32 = jnp.float32
-    hb = _head_block(H // G, N, P)
-    blocks, a_group = H // hb, H // G // hb
+    # rows of the resident state: ``k`` heads side by side (1: a head a row)
+    rows_h, lanes = state.shape[2], state.shape[-1]
+    hb = _head_block(rows_h // G, N, lanes)
+    blocks, a_group = rows_h // hb, rows_h // G // hb
     dt, x = dt.astype(f32), x.astype(f32)
     # the live slots first, in order: the kernel's work list
     order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
     n_live = jnp.sum(live, dtype=jnp.int32).reshape(1)
-    decay = jnp.broadcast_to(jnp.exp(dt * A)[..., None], (S, H, P))
+    # a head's decay and input in that head's lanes of its row
+    decay = jnp.broadcast_to(jnp.exp(dt * A)[..., None],
+                             (S, H, P)).reshape(S, rows_h, lanes)
+    xdt = (dt[..., None] * x).reshape(S, rows_h, lanes)
     # B and C of a group as columns (the state dimension along the sublanes)
     bc = jnp.stack([B.astype(f32), C.astype(f32)], axis=-1)   # [S, G, N, 2]
-    rows = pl.BlockSpec((None, hb, P), lambda i, j, order, *_: (order[i], j, 0))
+    rows = pl.BlockSpec((None, hb, lanes),
+                        lambda i, j, order, *_: (order[i], j, 0))
     y, state, fetched = pl.pallas_call(
         functools.partial(_update_kernel, blocks=blocks, hb=hb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -394,11 +414,11 @@ def _ssm_decode_update_pallas(state, x, dt, A, B, C, D, live, layer,
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=[rows, pl.BlockSpec(memory_space=pl.ANY),
                        pl.BlockSpec(memory_space=pltpu.SMEM)],
-            scratch_shapes=[pltpu.VMEM((2, hb, N, P), f32),
-                            pltpu.VMEM((2, hb, N, P), f32),
+            scratch_shapes=[pltpu.VMEM((2, hb, N, lanes), f32),
+                            pltpu.VMEM((2, hb, N, lanes), f32),
                             pltpu.SemaphoreType.DMA((2,)),
                             pltpu.SemaphoreType.DMA((2,))]),
-        out_shape=[jax.ShapeDtypeStruct((S, H, P), f32),
+        out_shape=[jax.ShapeDtypeStruct((S, rows_h, lanes), f32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype),
                    jax.ShapeDtypeStruct((1,), jnp.int32)],
         # operands count the three prefetched scalars: the state is the 7th
@@ -407,28 +427,58 @@ def _ssm_decode_update_pallas(state, x, dt, A, B, C, D, live, layer,
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="ssm_decode_update",
-    )(order, n_live, jnp.asarray(layer, jnp.int32).reshape(1), decay,
-      dt[..., None] * x, bc, state)
+    )(order, n_live, jnp.asarray(layer, jnp.int32).reshape(1), decay, xdt,
+      bc, state)
     # an idle slot's block was written as zeros and stays so
-    return (jnp.where(live[:, None, None], y + D[:, None] * x, 0.0), state,
+    return (jnp.where(live[:, None, None],
+                      y.reshape(S, H, P) + D[:, None] * x, 0.0), state,
             fetched[0])
 
 
 def ssm_kernel_takes(state, x) -> bool:
     """Can the compiled kernel tile these shapes on a TPU? A float32 state
-    whose channels fill the lanes and whose state dimension whole sublanes."""
-    return (state.dtype == jnp.float32 and x.shape[-1] % 128 == 0
+    whose rows (a head's channels, or :func:`pack_heads`' heads side by
+    side) fill the lanes and whose state dimension whole sublanes."""
+    return (state.dtype == jnp.float32 and state.shape[-1] % 128 == 0
             and state.shape[-2] % 8 == 0)
+
+
+def heads_a_row(head_dim: int) -> int:
+    """Heads of ``head_dim`` channels that lie side by side in a row of the
+    resident state: as many as fill a row of 128 lanes (1 from 128 up)."""
+    return 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
+
+
+def pack_heads(h, k: int):
+    """``[..., H, N, P]`` -> ``[..., H / k, N, k P]``: ``k`` consecutive
+    heads side by side along the lanes (the resident state's layout; ``k``
+    divides a group's heads, so a row has one ``B`` and one ``C``)."""
+    if k == 1:
+        return h
+    *lead, H, N, P = h.shape
+    return jnp.swapaxes(h.reshape(*lead, H // k, k, N, P), -2, -3).reshape(
+        *lead, H // k, N, k * P)
+
+
+def unpack_heads(h, k: int):
+    """The inverse of :func:`pack_heads`."""
+    if k == 1:
+        return h
+    *lead, R, N, W = h.shape
+    return jnp.swapaxes(h.reshape(*lead, R, N, k, W // k), -2, -3).reshape(
+        *lead, R * k, N, W // k)
 
 
 def ssm_decode_update(state, x, dt, A, B, C, D, live, *, layer=0,
                       use_pallas: Optional[str] = None):
     """One token-step of one layer against the resident state.
 
-    ``state`` [L, S, H, N, P] float32, of which ``layer`` (a traced scalar
-    is fine) is read and written where it lies; one token a slot: ``x``
-    [S, H, P], ``dt`` [S, H] after its softplus, ``A`` [H] negative, ``B``,
-    ``C`` [S, G, N], ``D`` [H]; ``live`` bool [S]. A live slot's state becomes
+    ``state`` [L, S, H / k, N, k P] float32 (``k`` heads side by side a row,
+    :func:`pack_heads`; ``k`` = 1 is [L, S, H, N, P]), of which ``layer`` (a
+    traced scalar is fine) is read and written where it lies; one token a
+    slot: ``x`` [S, H, P], ``dt`` [S, H] after its softplus, ``A`` [H]
+    negative, ``B``, ``C`` [S, G, N], ``D`` [H]; ``live`` bool [S]. ``k`` is
+    read from the shapes. A live slot's state becomes
     ``exp(dt A) h + B (x) (dt x)`` and its ``y = C . h + D x``; an idle
     slot's state stays as it is, bit for bit, and its ``y`` is 0. Returns
     ``(y [S, H, P] float32, state, fetched)``: ``fetched`` int32, the slots

@@ -825,7 +825,10 @@ class ContinuousBatcher:
         ``(queue_wait_s, prefill_s)`` of the newest 512 of them, oldest
         first, the bytes a cached position holds, and whatever the model's
         decode step counts of itself, added up by name (the expert model:
-        ``expert_tokens`` [E], ``experts_touched``, ``expert_layer_steps``).
+        ``expert_tokens`` [E], ``experts_touched``, ``expert_layer_steps``;
+        a model with a state: ``state_rows_stepped``, ``state_rows_fetched``,
+        ``ssm_layer_steps``; one that holds a share of its experts also
+        ``expert_assignments`` and ``expert_assignments_held``).
         Any thread may call it; the copy is the caller's."""
         snap = self._published
         return {**snap, "phase_s": dict(snap["phase_s"]),
@@ -873,7 +876,8 @@ class LLMServer:
     """Deployment class: KV-cached batched generation on one chip.
 
     The model is ``config`` (a configuration object of models/: a
-    ``TransformerConfig``, a ``LatentMoEConfig``, a ``HybridSSMConfig``) or,
+    ``TransformerConfig``, a ``LatentMoEConfig``, a ``HybridSSMConfig``, a
+    ``NemotronHConfig``) or,
     without one, the
     ``TransformerConfig`` that ``preset`` names; the engine asks
     ``models.serving_model`` for its functions, and for its parameters from

@@ -131,8 +131,10 @@ def test_kernel_in_a_tp_sharded_jit_needs_the_mesh(topo):
 # ------------------------------------------------- the paged decode path
 # (Hkv, heads a group, page, table width): Mistral-7B's GQA at chat-online's
 # page, MHA at a small page, a wide group, and conversation-batch's (the
-# hybrid state-space model's 20 query heads on 4 K/V heads, pages of 512)
-PAGED = [(8, 4, 256, 16), (4, 1, 16, 8), (2, 16, 128, 4), (4, 5, 512, 8)]
+# hybrid state-space model's 20 query heads on 4 K/V heads, pages of 512),
+# and longanswer-batch's (the layer-pattern model's 32 on 2, pages of 512)
+PAGED = [(8, 4, 256, 16), (4, 1, 16, 8), (2, 16, 128, 4), (4, 5, 512, 8),
+         (2, 16, 512, 8)]
 
 
 @pytest.mark.parametrize("hkv,group,page,width", PAGED,
@@ -202,6 +204,88 @@ def test_chunked_scan_kernel_compiles_for_v5e(one_chip, T):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
     assert "ssd_chunk_scan" in text
+
+
+def test_state_update_kernel_compiles_for_v5e_at_heads_of_64_channels(
+        one_chip):
+    """``ssm_decode_update`` at longanswer-batch's shape (5 Mamba layers, 128
+    slots, 128 heads of 64 channels in 8 groups, a state of 128): the
+    resident state holds two heads a row of lanes, ``[5, 128, 64, 128,
+    128]`` float32, 2.68 GB with no padding; one Mosaic call, the state
+    aliased in and out, not copied."""
+    from ray_memory_management_tpu.ops import ssm
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    L, S, H, N, P_, G = 5, 128, 128, 128, 64, 8
+    f32 = jnp.float32
+    k = ssm.heads_a_row(P_)
+    resident = s((L, S, H // k, N, k * P_), f32)
+    assert k == 2 and ssm.ssm_kernel_takes(resident, s((S, H, P_)))
+    compiled = jax.jit(
+        lambda st, x, dt, A, B, C, D, live, layer: ssm.ssm_decode_update(
+            st, x, dt, A, B, C, D, live, layer=layer, use_pallas="on"),
+        donate_argnums=(0,)).lower(
+        resident, s((S, H, P_)), s((S, H), f32), s((H,), f32),
+        s((S, G, N)), s((S, G, N)), s((H,), f32), s((S,), jnp.bool_),
+        s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "ssm_decode_update" in text
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= L * S * H * N * P_ * 4
+    assert m.temp_size_in_bytes < 96 << 20
+
+
+@pytest.mark.parametrize("T", [512, 2048])
+def test_chunked_scan_kernel_compiles_for_v5e_at_heads_of_64_channels(
+        one_chip, T):
+    """``ssd_chunk_scan`` at longanswer-batch's shortest and longest bucket
+    (128 heads of 64 channels, a state of 128, 8 groups of 16, bf16
+    operands): a head's channels are half a row of lanes and a block of 16
+    heads eight whole rows; one Mosaic call."""
+    from ray_memory_management_tpu.ops import ssm
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    H, P_, G, N = 128, 64, 8, 128
+    f32 = jnp.float32
+    assert ssm.ssd_kernel_takes(s((T, H, P_)), s((T, G, N)))
+    compiled = jax.jit(lambda x, dt, A, B, C, D, n: ssm.ssd_scan(
+        x, dt, A, B, C, D, true_len=n, use_pallas="on")).lower(
+        s((T, H, P_)), s((T, H), f32), s((H,), f32), s((T, G, N)),
+        s((T, G, N)), s((H,), f32), s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "ssd_chunk_scan" in text
+
+
+@pytest.mark.parametrize("T", [128, 2048])
+def test_expert_tile_kernel_compiles_for_v5e(one_chip, T):
+    """``moe_expert_tiles`` at longanswer-batch's decode step and longest
+    bucket (128 held experts of a router 512 wide, 22 a token, two matrices
+    of 1,024 x 2,688 an expert, bf16): one Mosaic call named for its tiles,
+    two experts' matrices (22 MB) inside the VMEM it asks for."""
+    from ray_memory_management_tpu.ops import moe
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    E, Z, F = 128, 1024, 2688
+    layer = {"router": s((4096, 512)), "bias": s((512,)),
+             "w1": s((E, Z, F)), "w2": s((E, F, Z))}
+    assert moe.expert_kernel_takes(s((T, Z)), layer)
+    compiled = jax.jit(lambda x, c, w, layer, live: moe.grouped_experts(
+        x, c, w, layer, live, 0, use_pallas="on")).lower(
+        s((T, Z)), s((T, 22), jnp.int32), s((T, 22), jnp.float32), layer,
+        s((T,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert moe.expert_tiles(T, 22, E) == {128: 128, 2048: 480}[T]
+    assert f"moe_expert_tiles_{moe.expert_tiles(T, 22, E)}" in text
+    assert "ragged-dot" not in text
 
 
 def test_paged_step_keeps_the_pool_where_it_is(one_chip, monkeypatch):
