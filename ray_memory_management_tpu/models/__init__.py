@@ -19,8 +19,16 @@ def serving_model(cfg):
     ``mixed_step(params, pool, chunk_tokens, chunk_pages, chunk_last,
     tokens, positions, lengths, page_table, cfg, *, chunk_index)``: one
     chunk of a prompt and one decode token a live row in one pass over the
-    layers (models/gpt.py). The engine prefills in chunks that ride its
-    decode steps where a model offers it, and whole prompts through
+    layers (models/gpt.py). ``chunk_last`` is the position inside the chunk
+    whose logits are wanted **and the last of the chunk's real positions**:
+    the prompt's last token in its last chunk, the chunk's last position in
+    every other. A model with ``state_spec`` is also handed ``slot=``, the
+    index of the row being prefilled: its first chunk (``chunk_index`` 0)
+    starts from zeros whatever the slot's entry holds, a later one from the
+    entry, and each leaves there the state as of its last real position;
+    the row is idle among the decode rows, so only its chunks move its
+    entry (models/hybrid_ssm.py). The engine prefills in chunks that ride
+    its decode steps where a model offers it, and whole prompts through
     ``prefill_row`` where it does not."""
     from . import gpt, hybrid_ssm, latent_moe, nemotron_h
 

@@ -29,8 +29,12 @@ unrolled (as models/latent_moe.py).
 
 The serve engine (serve/llm.py) asks a configuration's model for
 ``init_params``, ``cache_spec``, ``prefill_row``, ``prefill_takes_kernel`` and
-``paged_decode``, as of the other two, and of this one also for
-``state_spec``: the arrays the pool holds a slot and not a position.
+``paged_decode``, as of the others, and of this one also for ``state_spec``:
+the arrays the pool holds a slot and not a position. It offers
+``mixed_step``, so its prompts ride the decode step in chunks: a chunk after
+a prompt's first starts from the slot's state and convolution tail.
+``prefill_row`` stays for whoever prefills a whole prompt (models/nemotron_h.py
+shares the mixer's pieces; the benchmark's references and fit tests).
 """
 
 from __future__ import annotations
@@ -369,6 +373,24 @@ def _write_kv(pages_of, new, pages, offs):
     return lax.fori_loop(0, new.shape[0], one, pages_of)
 
 
+def _write_rows(pool, k_new, v_new, positions, page_table):
+    """One decode position a row into the pages: ``k_new`` / ``v_new``
+    [B, L, Hkv, Dh] go to ``(page_table[i, positions[i] // page_tokens],
+    positions[i] % page_tokens)``, or to the sink where that lies beyond the
+    table. What :func:`paged_decode` and :func:`mixed_step` do after their
+    layer loops."""
+    page, width = pool["k"].shape[3], page_table.shape[1]
+    sink = pool["k"].shape[2] - 1
+    at = positions // page
+    inside = jnp.minimum(at, width - 1)[:, None]
+    pages = jnp.where(
+        at < width,
+        jnp.take_along_axis(page_table, inside, axis=1)[:, 0], sink)
+    offs = positions % page
+    return {"k": _write_kv(pool["k"], k_new, pages, offs),
+            "v": _write_kv(pool["v"], v_new, pages, offs)}
+
+
 def paged_decode(params, tokens, pool, positions, lengths, page_table,
                  cfg: HybridSSMConfig):
     """One decode token a row (row ``i`` is slot ``i``) against the pool, read
@@ -385,8 +407,6 @@ def paged_decode(params, tokens, pool, positions, lengths, page_table,
     over the layers; ``state_rows_fetched``, the rows whose state the update
     read (the same where idle slots are skipped); ``ssm_layer_steps``, the
     layers that ran with a live row."""
-    page, width = pool["k"].shape[3], page_table.shape[1]
-    sink = pool["k"].shape[2] - 1
     live = lengths > 0
     x = _embed(params, tokens, cfg)                              # [B, D]
     state, k_new, v_new, tails = pool["ssm"], [], [], []
@@ -419,14 +439,7 @@ def paged_decode(params, tokens, pool, positions, lengths, page_table,
         # write, and the compiler copies both pools to be safe, every step
         x, k_new, v_new = lax.optimization_barrier(
             (x, jnp.stack(k_new, 1), jnp.stack(v_new, 1)))
-        at = positions // page
-        inside = jnp.minimum(at, width - 1)[:, None]
-        pages = jnp.where(
-            at < width,
-            jnp.take_along_axis(page_table, inside, axis=1)[:, 0], sink)
-        offs = positions % page
-        pool = {"k": _write_kv(pool["k"], k_new, pages, offs),
-                "v": _write_kv(pool["v"], v_new, pages, offs)}
+        pool = _write_rows(pool, k_new, v_new, positions, page_table)
     with jax.named_scope("state_write"):
         pool.update(ssm=state, conv=jnp.stack(tails))
     with jax.named_scope("head_sample"):  # the engine's sampler joins it
@@ -437,3 +450,166 @@ def paged_decode(params, tokens, pool, positions, lengths, page_table,
         "state_rows_fetched": fetched,
         "ssm_layer_steps": jnp.where(n_live > 0, cfg.n_layers, 0).astype(
             jnp.int32)}
+
+
+def _carried(entry, first):
+    """What a chunk takes over from its slot's ``entry``: what the chunk
+    before it left there, and zeros where the chunk is its prompt's
+    ``first``, as before position 0 of :func:`prefill_row` (what an earlier
+    request left in the slot is never read, and nothing clears it)."""
+    return jnp.where(first, jnp.zeros_like(entry), entry)
+
+
+def mixed_step(params, pool, chunk_tokens, chunk_pages, chunk_last, tokens,
+               positions, lengths, page_table, cfg: HybridSSMConfig, *,
+               chunk_index, slot):
+    """One chunk of one row's prompt and one decode token a live row, in one
+    pass over the layers: the decode rows' weights are the chunk's. The
+    arguments are ``models/gpt.py::mixed_step``'s, and ``slot``: the index of
+    the row being prefilled, whose state entry the chunk continues.
+
+    ``chunk_tokens`` int32 [C] are the prompt's positions
+    ``[chunk_index * C, (chunk_index + 1) * C)`` (``C`` a whole number of
+    pages), of which the first ``chunk_last + 1`` are real: ``chunk_last`` is
+    the position inside the chunk whose logits are wanted, the prompt's last
+    token in its last chunk and ``C - 1`` in every other. ``chunk_pages`` is
+    the prompt's row of the block table in whole chunks. ``tokens``,
+    ``positions``, ``lengths`` and ``page_table`` are :func:`paged_decode`'s;
+    the row being prefilled is idle among them (length 0, a table row of
+    sink entries): the decode half neither fetches nor moves its state.
+
+    Embedding, norms, every projection, the gate and the MLP run once over
+    the ``C + B`` rows. The attention splits them as ``gpt.mixed_step``
+    does: the chunk in the flash forward kernel over the row's pages before
+    it (gathered outside a ``lax.switch`` over the prefix lengths a prompt
+    can have, ``chunk_index`` a run-time int32: ONE program) and itself, the
+    decode rows through the block table. So does the state-space branch:
+
+      - the chunk's convolution takes the taps before its first position
+        from the slot's tail and its scan starts from the slot's state
+        (``ssd_scan(..., h0=)``), **zeros both where ``chunk_index == 0``**:
+        whatever an earlier request left in the slot is never read, and no
+        program clears it. The scan stops at the chunk's real positions
+        (``true_len``), and the slot's entry then holds the state and the
+        last ``ssm_conv - 1`` inputs as of the chunk's last real position
+        (fewer real positions than that: the rest from the old tail);
+      - the live rows' update is :func:`paged_decode`'s kernel where the
+        state lies, under a name of its own (``ssm_mixed_update``: whoever
+        counts the decode program's token-steps by ``ssm_decode_update``'s
+        calls counts none here).
+
+    The slot's 4 MiB a layer are sliced out of the state after the live
+    rows' update and put back into it after the scan, so the state stays
+    where it is. The layer loop only reads the pages; after it the chunk's K
+    and V go to whole pages and each decode row's to its one position.
+    Returns (logits [B + 1, V] fp32: the decode rows', then the chunk's at
+    ``chunk_last``; the pool; a count int32 under a name of the mixed step's
+    own: ``mixed_state_rows_stepped``, the live rows whose state moved, summed
+    over the layers)."""
+    C, page = chunk_tokens.shape[0], pool["k"].shape[3]
+    Hkv, Dh, rep = cfg.kv_heads, cfg.head_dim, cfg.n_heads // cfg.kv_heads
+    tail, n_pages = cfg.ssm_conv - 1, C // page
+    n_chunks = chunk_pages.shape[0] // n_pages  # the longest prompt's
+    chunk_index = jnp.asarray(chunk_index, jnp.int32)
+    slot = jnp.asarray(slot, jnp.int32)
+    n_real = chunk_last + 1
+    first = chunk_index == 0
+    before = chunk_pages[:(n_chunks - 1) * n_pages]
+    use = _kernel_use(cfg, C)
+    live = lengths > 0
+    at = jnp.concatenate([chunk_index * C + jnp.arange(C), positions])
+    x = _embed(params, jnp.concatenate([chunk_tokens, tokens]), cfg)
+
+    def over(n_before):  # the chunk over ``n_before`` earlier chunks + itself
+        def attend(q, ks, vs):
+            # the kernel wants as many K/V heads as query heads
+            return flash_attention(
+                q, jnp.repeat(ks[:, :(n_before + 1) * C], rep, axis=0)[None],
+                jnp.repeat(vs[:, :(n_before + 1) * C], rep, axis=0)[None],
+                causal=True, use_pallas=use)
+        return attend
+
+    def row_so_far(pages_of, own, layer):
+        # the row's pages before its last chunk, then the chunk's own
+        # positions laid over them where the chunk starts: the first
+        # ``(chunk_index + 1) * C`` positions are what the chunk sees
+        so_far = jnp.concatenate([
+            lax.dynamic_slice(pages_of, (layer, 0, before[i], 0, 0),
+                              (1, Hkv, 1, page, Dh)).reshape(Hkv, page, Dh)
+            for i in range(before.shape[0])] + [own], axis=1)
+        return lax.dynamic_update_slice(so_far, own, (0, chunk_index * C, 0))
+
+    state, conv = pool["ssm"], pool["conv"]
+    k_chunk, v_chunk, k_rows, v_rows, tails = [], [], [], [], []
+    for i, p in enumerate(params["layers"]):
+        u = _rmsnorm(x, p["ln"], cfg.rms_norm_eps)
+        q, k, v = _qkv(u, p, at, cfg)
+        kt, vt = k[:C].transpose(1, 0, 2), v[:C].transpose(1, 0, 2)
+        with jax.named_scope("prefix_gather"):
+            ks, vs = row_so_far(pool["k"], kt, i), row_so_far(pool["v"], vt, i)
+        with jax.named_scope("prefill_attention"):
+            o_chunk = lax.switch(
+                chunk_index, [over(n) for n in range(n_chunks)],
+                q[:C].transpose(1, 0, 2)[None], ks, vs)[0]
+        with jax.named_scope("decode_attention"):
+            o_rows = paged_attention(
+                q[C:], pool["k"], pool["v"], lengths, page_table, layer=i,
+                k_cur=k[C:], v_cur=v[C:])
+        a = _attn_out(jnp.concatenate([o_chunk.transpose(1, 0, 2), o_rows]),
+                      p, cfg)
+        z, xbc, dt = _ssm_project(u, p, cfg)
+        with jax.named_scope("ssm_conv"):
+            old = conv[i]                               # [taps - 1, B, C]
+            mine = lax.dynamic_slice_in_dim(old, slot, 1, axis=1)[:, 0]
+            behind = jnp.concatenate([_carried(mine, first), xbc[:C]])
+            xs, b, c = _split_xbc(jnp.concatenate([
+                _conv([behind[j:j + C] for j in range(cfg.ssm_conv)], p, cfg),
+                _conv([*old, xbc[C:]], p, cfg)]), cfg)
+            # the live rows' tails move on by their token; the slot's holds
+            # the last real inputs: rows n_real - tail .. n_real - 1
+            tails.append(lax.dynamic_update_slice_in_dim(
+                jnp.where(live[None, :, None],
+                          jnp.concatenate([old[1:], xbc[None, C:]]), old),
+                lax.dynamic_slice_in_dim(behind, n_real, tail)[:, None],
+                slot, axis=1))
+        A = -jnp.exp(p["A_log"])
+        with jax.named_scope("ssm_mixed_update"):
+            y_rows, state, _ = ssm.ssm_decode_update(
+                state, xs[C:], dt[C:], A, b[C:], c[C:], p["D"], live,
+                layer=i, name="ssm_mixed_update")
+        with jax.named_scope("ssm_scan"):
+            h0 = lax.dynamic_slice(state, (i, slot, 0, 0, 0),
+                                   (1, 1) + state.shape[2:])[0, 0]
+            y_chunk, h = ssm.ssd_scan(
+                xs[:C], dt[:C], A, b[:C], c[:C], p["D"], true_len=n_real,
+                h0=_carried(h0, first))
+        with jax.named_scope("state_write"):
+            state = lax.dynamic_update_slice(state, h[None, None],
+                                             (i, slot, 0, 0, 0))
+        k_chunk.append(kt), v_chunk.append(vt)
+        k_rows.append(k[C:]), v_rows.append(v[C:])
+        x = x + a + _gate_out(jnp.concatenate([y_chunk, y_rows]), z, p, cfg)
+        x = x + _mlp(x, p, cfg)
+    with jax.named_scope("kv_write"):
+        # the pages are written only once every layer has read them
+        # (:func:`paged_decode`)
+        x, k_chunk, v_chunk, k_rows, v_rows = lax.optimization_barrier(
+            (x, jnp.stack(k_chunk), jnp.stack(v_chunk), jnp.stack(k_rows, 1),
+             jnp.stack(v_rows, 1)))
+        now = lax.dynamic_slice_in_dim(chunk_pages, chunk_index * n_pages,
+                                       n_pages)
+
+        def whole_pages(pages_of, new):  # new [L, Hkv, C, Dh]
+            return pages_of.at[:, :, now].set(
+                new.reshape(new.shape[:2] + (n_pages, page, Dh)))
+
+        pool = _write_rows({"k": whole_pages(pool["k"], k_chunk),
+                            "v": whole_pages(pool["v"], v_chunk)},
+                           k_rows, v_rows, positions, page_table)
+    with jax.named_scope("state_write"):
+        pool.update(ssm=state, conv=jnp.stack(tails))
+    with jax.named_scope("head_sample"):  # the engine's sampler joins it
+        logits = _head(jnp.concatenate(
+            [x[C:], lax.dynamic_slice_in_dim(x, chunk_last, 1)]), params, cfg)
+    return logits, pool, {"mixed_state_rows_stepped": cfg.n_layers * jnp.sum(
+        live, dtype=jnp.int32)}

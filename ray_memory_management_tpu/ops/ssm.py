@@ -379,7 +379,7 @@ def _update_kernel(order_ref, n_live_ref, layer_ref, decay_ref, xdt_ref,
 
 
 def _ssm_decode_update_pallas(state, x, dt, A, B, C, D, live, layer,
-                              interpret):
+                              interpret, name):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -426,7 +426,7 @@ def _ssm_decode_update_pallas(state, x, dt, A, B, C, D, live, layer,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-        name="ssm_decode_update",
+        name=name,
     )(order, n_live, jnp.asarray(layer, jnp.int32).reshape(1), decay, xdt,
       bc, state)
     # an idle slot's block was written as zeros and stays so
@@ -470,7 +470,8 @@ def unpack_heads(h, k: int):
 
 
 def ssm_decode_update(state, x, dt, A, B, C, D, live, *, layer=0,
-                      use_pallas: Optional[str] = None):
+                      use_pallas: Optional[str] = None,
+                      name: str = "ssm_decode_update"):
     """One token-step of one layer against the resident state.
 
     ``state`` [L, S, H / k, N, k P] float32 (``k`` heads side by side a row,
@@ -487,6 +488,9 @@ def ssm_decode_update(state, x, dt, A, B, C, D, live, *, layer=0,
 
     ``use_pallas``: "on", "interpret", "off", or None = the kernel on a TPU
     for a shape it can tile (:func:`ssm_kernel_takes`), else the plain form.
+    ``name``: the kernel's name in the compiled program and in a trace; a
+    caller that is not the decode program's token-step gives its own, so
+    that whoever counts token-steps by the kernel's calls counts its own.
     """
     if use_pallas is None:
         use_pallas = "on" if _on_tpu() and ssm_kernel_takes(state, x) \
@@ -495,4 +499,5 @@ def ssm_decode_update(state, x, dt, A, B, C, D, live, *, layer=0,
         return ssm_decode_update_reference(state, x, dt, A, B, C, D, live,
                                            layer=layer)
     return _ssm_decode_update_pallas(state, x, dt, A, B, C, D, live, layer,
-                                     interpret=(use_pallas == "interpret"))
+                                     interpret=(use_pallas == "interpret"),
+                                     name=name)

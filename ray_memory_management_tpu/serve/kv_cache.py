@@ -37,9 +37,12 @@ sized by slots and not by tokens. It is allocated with the pages, donated
 with them and counted beside them (``state_bytes``, ``store_bytes``); no page
 id and no reservation refers to it, since slot ``i``'s entry is row ``i``.
 Nobody zeroes it between requests: the prefill of an admission overwrites its
-slot's entry whole, with the state of the prompt's last real token, and the
-decode step moves only the entries of live slots. A model without
-``state_spec`` (or with an empty one) gets the pages alone, as before.
+slot's entry whole, with the state of the prompt's last real token (a prompt
+that rides the decode step in chunks starts its first chunk from zeros
+without reading the entry, and each chunk leaves it the state of its last
+real token), and the decode step moves only the entries of live slots. A
+model without ``state_spec`` (or with an empty one) gets the pages alone, as
+before.
 """
 
 from __future__ import annotations

@@ -14,8 +14,11 @@ TPU-shaped:
     prompt is prefilled in chunks of ``pad_multiple`` positions, each in
     the same program as one decode token-step of the live rows (the
     model's ``mixed_step``: the chunk's matmuls are compute-bound, so the
-    decode rows' weight stream is the chunk's); a model that offers none
-    gets a prefill program per prompt bucket while the loop stands.
+    decode rows' weight stream is the chunk's; a model that keeps a state
+    a slot is handed the row's index too, and its chunk carries on from
+    the slot's entry); a model that offers none gets a prefill program per
+    prompt bucket while the loop stands. What an iteration's token-steps
+    attend over is summed once an iteration, at assembly, on both paths.
   - :class:`LLMServer` — the deployment class: holds the parameters on the
     device, owns the engine, and answers requests and ``stats()``.
 
@@ -221,7 +224,8 @@ class ContinuousBatcher:
                         "mixed_steps": 0, "chunk_positions_live": 0}
         self._recent: deque = deque(maxlen=512)  # (queue_wait_s, prefill_s)
         # what the model's decode step counted of itself (paged_decode's
-        # third result), added up by name: arrays, or nothing
+        # third result, and mixed_step's under names of its own), added up
+        # by name: arrays, or nothing
         self._model_counts: Dict[str, Any] = {}
         self._publish()
         self._thread = threading.Thread(target=self._loop, daemon=True,
@@ -578,15 +582,19 @@ class ContinuousBatcher:
         ``mixed_step``): ONE program, whichever chunk of whichever prompt
         it carries. The mixed steps of an iteration chain on the device:
         each takes the last one's tokens and the rest of its key, so none
-        waits for the host."""
+        waits for the host. Where the model keeps a state a slot, the
+        program is also handed the prefilling row's index, ``slot`` (as the
+        whole-prompt prefill is): the chunk continues that slot's entry."""
         jax, jnp, model, cfg = self._jax, self._jnp, self._model, self.cfg
 
         def mixed_step(params, pool, chunk_tokens, chunk_pages, chunk_index,
-                       chunk_last, first_row, last, offsets, table, key):
+                       chunk_last, first_row, last, offsets, table, key,
+                       *slot):
             key, sub = jax.random.split(key)
             logits, pool, counts = model.mixed_step(
                 params, pool, chunk_tokens, chunk_pages, chunk_last, last,
-                offsets, offsets, table, cfg, chunk_index=chunk_index)
+                offsets, offsets, table, cfg, chunk_index=chunk_index,
+                **({"slot": slot[0]} if slot else {}))
             with jax.named_scope("head_sample"):
                 toks = self._sample(logits, sub)
             # the row whose prompt ends in this chunk (``first_row``; -1:
@@ -697,7 +705,12 @@ class ContinuousBatcher:
             last = jnp.asarray(self._slot_last)
             # a row with chunks to go is idle in the decode half: offset 0
             off = self._slot_offset.copy()
+            # what the iteration's token-steps fetch, and how much of it is
+            # live: once an iteration, as the decode program's
             counts["iterations"] += 1
+            self._count_positions(off[off > 0], min(K, sum(
+                len(self._slot_prompt[r][0]) // C - int(self._slot_chunks[r])
+                for r in self._prefilling)))
         # the most whole chunks a prompt can have
         per = C // pool.page_tokens
         most = -(-(self.cfg.max_seq - self.max_new_tokens) // C)
@@ -723,20 +736,23 @@ class ContinuousBatcher:
                 # the sink: the prefilling row's pages go in as the chunk's
                 # alone
                 table = np.where(live[:, None], pool.table, sink)
-                # the head's row of the chunk: the prompt's last token
-                at = np.int32(true_len - 1 - index * C if ends else 0)
+                # the head's row of the chunk, and the last of its real
+                # positions: the prompt's last token, or the chunk's (whose
+                # logits nobody reads)
+                at = np.int32(true_len - 1 - index * C if ends else C - 1)
+                # where the model keeps a state a slot, its entry is the row's
+                slot = (np.int32(row),) if pool.state_spec else ()
                 self._pool, last, key, stepped = self._mixed_step(
                     self.params, self._pool, arr[index * C:(index + 1) * C],
                     chunk_pages, np.int32(index), at,
                     np.int32(row if ends else -1), last, off.copy(), table,
-                    key)
+                    key, *slot)
                 counts["mixed_steps"] += 1
                 counts["prefill_positions"] += C
                 if C in self._prefill_kernel:
                     counts["prefill_kernel_positions"] += C
                 counts["chunk_positions_live"] += min(
                     C, true_len - index * C)
-                self._count_positions(off[live], 1)
                 off[live] += 1
                 self._slot_chunks[row] += 1
                 if ends:  # live from the next token-step on
@@ -811,10 +827,13 @@ class ContinuousBatcher:
         """Where the engine thread's time went and what the decode step
         attended over, cumulative since the engine started (a reader
         subtracts two snapshots): wall and thread-CPU seconds by phase,
-        iterations, KV positions the step fetches (each live row's pages up
-        to its last step's length) and the live ones among them (both
-        summed at assembly, once a decode program: a mixed step is one
-        token-step), requests admitted,
+        iterations, KV positions the iteration's token-steps fetch (each
+        live row's pages up to its last step's length) and the live ones
+        among them (both summed **once an iteration**, at assembly, from
+        the rows live then: the decode program's K token-steps, or as many
+        mixed steps as chunks wait, at most K; so ``live_positions`` over
+        ``iterations`` is a mean a token-step's assembly in both kinds of
+        iteration), requests admitted,
         the positions their prefill programs computed (the sum of the
         buckets' lengths, or of the chunks') and those of buckets whose
         program attends in the flash kernel (the model's
@@ -827,7 +846,9 @@ class ContinuousBatcher:
         decode step counts of itself, added up by name (the expert model:
         ``expert_tokens`` [E], ``experts_touched``, ``expert_layer_steps``;
         a model with a state: ``state_rows_stepped``, ``state_rows_fetched``,
-        ``ssm_layer_steps``; one that holds a share of its experts also
+        ``ssm_layer_steps``, of the decode program's token-steps alone, and
+        where its prompts ride in chunks ``mixed_state_rows_stepped`` of the
+        mixed steps; one that holds a share of its experts also
         ``expert_assignments`` and ``expert_assignments_held``).
         Any thread may call it; the copy is the caller's."""
         snap = self._published

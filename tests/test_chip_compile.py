@@ -507,3 +507,93 @@ def test_the_mixed_step_fits_and_keeps_the_pool_where_it_is(one_chip,
     # 7.0 GiB of weights, 2 GiB of pool, under 1 GiB of temporaries
     assert 8.9 * 2 ** 30 < total < 10.1 * 2 ** 30
     assert m.alias_size_in_bytes >= 2 * pool["k"].size * 2  # donated
+
+
+def test_the_hybrid_mixed_step_fits_and_keeps_pool_and_state_where_they_are(
+        one_chip, monkeypatch):
+    """``conversation-batch``'s mixed-step program (``falcon-h1-34b-d5``
+    widths, 64 rows, pages of 512, a chunk table of 6 chunks, the pool of
+    the cell's mix file): a layer holds the scan kernel for the chunk, the
+    live rows' update kernel **under the mixed step's own name** (none under
+    ``ssm_decode_update``, by whose calls the cell's rooflines count the
+    decode program's token-steps), a flash Mosaic call a branch of the
+    switch and one paged-attention call; K, V and the recurrence's state in
+    the layout they came in, none a copy (a slot's 4 MiB a layer are sliced
+    out and put back around the kernel that updates the live rows in
+    place); the pool donated, and the whole program inside the chip's
+    memory with 2 GB to spare."""
+    import importlib
+    import re
+
+    from chipbench import architectures, flops, manifest
+    from chipbench.drivers import serve as serve_driver
+    from ray_memory_management_tpu.serve.llm import ContinuousBatcher
+
+    # the dispatches ask where default computation lands: steer them here
+    for name in ("ops.flash_attention", "ops.paged_attention", "ops.ssm",
+                 "models.hybrid_ssm"):
+        monkeypatch.setattr(importlib.import_module(
+            "ray_memory_management_tpu." + name), "_on_tpu", lambda: True)
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg = manifest.config("falcon-h1-34b-d5")
+    arch = architectures.of(cfg)
+    e = serve_driver.engine_kwargs(cfg,
+                                   manifest.traffic("conversation-batch"))
+    pc = arch.program_config(cfg)
+    slots, page, layers = e["max_batch_size"], e["kv_page_tokens"], 5
+    eng = ContinuousBatcher(
+        None, pc, max_slots=slots, max_new_tokens=e["max_new_tokens"],
+        pad_multiple=e["pad_multiple"], steps_per_iter=e["steps_per_iter"],
+        kv_page_tokens=page, kv_pool_bytes=e["kv_pool_bytes"])
+    try:
+        params = shaped(jax.eval_shape(
+            lambda: arch.init_program_params(jax.random.PRNGKey(0), pc)))
+        pool = shaped(jax.eval_shape(eng.kv_pool.allocate))
+        assert {k: v.shape for k, v in pool.items()} == {
+            "k": (5, 4, 257, 512, 128), "v": (5, 4, 257, 512, 128),
+            "ssm": (5, 64, 32, 256, 128), "conv": (5, 3, 64, 5120)}
+        width = eng.kv_pool.table_width
+        # the longest prompt is 3,072 positions: six chunks of 512
+        reach = -(-(pc.max_seq - e["max_new_tokens"]) // page)
+        assert eng._mixed and eng._chunk == page and reach == 6
+        compiled = eng._mixed_step.lower(
+            params, pool, arr((page,)), arr((reach,)), arr(()), arr(()),
+            arr(()), arr((slots,)), arr((slots,)), arr((slots, width)),
+            arr((2,), jnp.uint32), arr(())).compile()
+        assert page in eng._prefill_kernel
+    finally:
+        eng.close()
+    text = compiled.as_text()
+
+    def calls(name):
+        return len(set(re.findall(r"%(" + name + r"[.\d]*) = ", text)))
+
+    # a layer: a flash call a branch, paged attention, the update, the scan
+    assert text.count("tpu_custom_call") == layers * (reach + 3)
+    assert calls("ssd_chunk_scan") == calls("ssm_mixed_update") == layers
+    assert calls("paged_decode_attention") == layers
+    # no operation of that name (the text's table of stack frames holds the
+    # wrapper function's; a trace reader goes by an operation's own name)
+    assert calls("ssm_decode_update") == 0
+    for shape in ("f32[5,64,32,256,128]", "bf16[5,4,257,512,128]"):
+        made = re.findall(
+            "= " + re.escape(shape) + r"\{([\d,]+)[^ ]* (\S+?)\(", text)
+        assert made and {lay for lay, _ in made} == {"4,3,2,1,0"}, shape
+        assert not {op for _, op in made} & {"copy", "copy-start"}, shape
+    held = sum(v.size * v.dtype.itemsize for v in pool.values())
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= held  # donated
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    # 9.65 GB of weights, 1.35 + 1.35 GB of pages and state, and under
+    # 0.3 GB of temporaries (a 3,072 prefill's were 0.5 GB)
+    weights = 2 * arch.n_params(cfg)
+    assert weights + held < total < weights + held + 0.3e9
+    assert total < flops.peak("TPU v5 lite")["hbm_bytes"] - 2.0e9

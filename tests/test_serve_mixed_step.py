@@ -11,7 +11,8 @@ engine's chunk path, serve/llm.py::ContinuousBatcher._iterate_mixed).
 (c) what an iteration costs the host: one readback, no key split, counts
     that add up, and an injected ``serve.admit`` fault that fails one
     request;
-(d) a model without ``mixed_step`` keeps the whole-prompt path.
+(d) a model without ``mixed_step`` keeps the whole-prompt path (the hybrid
+    state-space model's chunk path is tests/test_hybrid_ssm.py's).
 """
 
 import threading
@@ -22,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_memory_management_tpu.models import gpt, hybrid_ssm, latent_moe
+from ray_memory_management_tpu.models import gpt, latent_moe, nemotron_h
 from ray_memory_management_tpu.serve.llm import ContinuousBatcher
 from ray_memory_management_tpu.utils import faults
 
@@ -314,14 +315,17 @@ def test_a_prefilling_row_is_idle_in_the_decode_half(params):
         assert (table == sink).all() and not lengths.any()
         assert (pages[:(index + 1) * C // PAGE] != sink).all()
         assert first_row == (0 if index == 2 else -1)
-        assert at == (len(prompt) - 1 - 2 * C if index == 2 else 0)
+        # the head's row is also the last of the chunk's real positions
+        assert at == (len(prompt) - 1 - 2 * C if index == 2 else C - 1)
 
 
 # ----------------------------------- (d) a model that offers no mixed_step
-SSM = hybrid_ssm.HybridSSMConfig(
-    vocab_size=512, d_model=64, n_layers=2, n_heads=6, kv_heads=2,
-    head_dim=16, d_ff=96, ssm_heads=4, ssm_head_dim=16, ssm_state=8,
-    ssm_groups=2, ssm_conv=4, max_seq=128, dtype=jnp.float32,
+PATTERN = nemotron_h.NemotronHConfig(
+    vocab_size=512, d_model=64, pattern="ME*M", n_heads=4, kv_heads=2,
+    head_dim=16, ssm_heads=8, ssm_head_dim=16, ssm_state=8, ssm_groups=2,
+    moe_latent=32, moe_d_ff=48, shared_d_ff=96, n_routed_experts=16,
+    n_held_experts=8, first_held_expert=0, experts_per_tok=4,
+    routed_scaling_factor=5.0, max_seq=128, dtype=jnp.float32,
     param_dtype=jnp.float32)
 MOE = latent_moe.LatentMoEConfig(
     vocab_size=512, d_model=64, n_layers=2, n_heads=4, q_lora_rank=24,
@@ -331,8 +335,9 @@ MOE = latent_moe.LatentMoEConfig(
     dtype=jnp.float32, param_dtype=jnp.float32)
 
 
-@pytest.mark.parametrize("cfg,model", [(SSM, hybrid_ssm), (MOE, latent_moe)],
-                         ids=["hybrid_ssm", "latent_moe"])
+@pytest.mark.parametrize("cfg,model", [(PATTERN, nemotron_h),
+                                       (MOE, latent_moe)],
+                         ids=["nemotron_h", "latent_moe"])
 def test_a_model_without_mixed_step_prefills_whole(cfg, model):
     """The engine chooses by what the model offers: these two offer no
     ``mixed_step``, so their prompts go through ``_paged_prefill_fn`` and
