@@ -29,13 +29,15 @@ model-complete without shipping a tokenizer dependency.
 
 from __future__ import annotations
 
+import copy
+import statistics
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Sequence
 
 from ..utils import faults, timeline, tracing
-from ..utils.profiling import phase
+from ..utils.profiling import Flight, phase
 from .deployment import deployment
 
 # the engine thread is in exactly one of these at every instant (see
@@ -43,6 +45,9 @@ from .deployment import deployment
 # the KV in place; readers index the phases by name, so the name stays
 ENGINE_PHASES = ("idle_wait", "gate", "prefill", "assemble",
                  "step_dispatch", "step_wait", "emit", "disassemble")
+# an iteration stalled when its wall seconds pass this many times the median
+# of the last STALL_HISTORY iterations'; the newest STALL_ROWS are kept
+STALL_FACTOR, STALL_HISTORY, STALL_ROWS = 4.0, 32, 16
 
 
 class _Pending:
@@ -216,7 +221,12 @@ class ContinuousBatcher:
         # The engine thread is the only writer of these three; it publishes
         # a copy once per iteration (one reference assignment), and that
         # copy is all engine_stats() reads
-        self._phase = {name: [0.0, 0.0] for name in ENGINE_PHASES}
+        # [wall_s, cpu_s, starved_s] a phase; the third is the part of the
+        # wall seconds in which nothing this thread dispatched was unread
+        self._phase = {name: [0.0, 0.0, 0.0] for name in ENGINE_PHASES}
+        self._flight = Flight()
+        self._walls: deque = deque(maxlen=STALL_HISTORY)
+        self._stalls: deque = deque(maxlen=STALL_ROWS)
         self._counts = {"iterations": 0, "slab_positions": 0,
                         "live_positions": 0, "admitted": 0,
                         "prefill_positions": 0,
@@ -374,15 +384,21 @@ class ContinuousBatcher:
             self.params, self._pool, jnp.asarray(arr),
             jnp.asarray(self.kv_pool.table[row]),
             jnp.int32(len(toks)), sub, *slot)
+        # the chip has work from here (the split's two tiny programs and
+        # the uploads above do not count as work) until the first token is
+        # read back
+        self._flight.fill()
         # what the prefill programs computed, and how much of it in a
         # program that holds the kernel
         self._counts["prefill_positions"] += bucket
         if bucket in self._prefill_kernel:
             self._counts["prefill_kernel_positions"] += bucket
+        first = int(first)
+        self._flight.drain()
         self._slot_pending[row] = p
         self._slot_offset[row] = len(toks)
-        self._slot_last[row] = int(first)
-        self._slot_out[row] = [int(first)]
+        self._slot_last[row] = first
+        self._slot_out[row] = [first]
         self._slot_budget[row] = budget - 1
 
     def _retire(self, row: int) -> None:
@@ -461,8 +477,7 @@ class ContinuousBatcher:
         ``phase`` (ENGINE_PHASES), so the phases' wall seconds add up to the
         thread's, and each is a ``rmt.engine.<name>`` span on this thread's
         line of a profiler trace."""
-        jnp, np = self._jnp, self._np
-        acc, counts = self._phase, self._counts
+        acc, flight = self._phase, self._flight
         while True:
             with self._cond:
                 while (not self._stop and not self._q
@@ -488,14 +503,16 @@ class ContinuousBatcher:
                         p.error = RuntimeError("engine closed")
                         p.event.set()
                     return
-                with phase(acc, "gate"):
+                with phase(acc, "gate", flight):
                     admits = self._admit_gate()
+            rows, steps = 0, self.steps
             try:
-                (self._iterate_mixed if self._mixed else self._iterate)(
-                    admits)
+                rows = (self._iterate_mixed if self._mixed
+                        else self._iterate)(admits)
             except BaseException as e:  # noqa: BLE001 — fail loudly to
                 # every parked caller, keep serving
-                with phase(acc, "emit"):
+                flight.drain()  # nobody reads what the failed iteration left
+                with phase(acc, "emit", flight):
                     with self._cond:
                         victims = ([p for p in self._slot_pending
                                     if p is not None] + self._q)
@@ -510,16 +527,37 @@ class ContinuousBatcher:
                     for p in victims:
                         p.error = e
                         p.event.set()
+            self._note_iteration(rows, self.steps - steps)
             self._publish()
 
-    def _iterate(self, admits) -> None:
+    def _note_iteration(self, rows: int, token_steps: int) -> None:
+        """Engine thread, at an iteration's end: its wall seconds (the work
+        phases' since the gate: the accumulators less the copy published at
+        the last iteration's end; ``idle_wait`` is no part of an iteration)
+        against the median of the last ``STALL_HISTORY`` iterations'. One
+        over ``STALL_FACTOR`` times that is a stall and leaves a row."""
+        acc, last = self._phase, self._published
+        wall = sum(v[0] - last["phase_s"][k] for k, v in acc.items()
+                   if k != "idle_wait")
+        if self._walls:
+            median = statistics.median(self._walls)
+            if wall > STALL_FACTOR * median:
+                self._stalls.append({
+                    "t_end": time.time(), "wall_s": wall, "median_s": median,
+                    **{key: {k: v[i] - last[key][k] for k, v in acc.items()}
+                       for i, key in enumerate(
+                           ("phase_s", "phase_cpu_s", "starved_s"))},
+                    "rows": rows, "token_steps": token_steps})
+        self._walls.append(wall)
+
+    def _iterate(self, admits) -> int:
         """One iteration where a prompt is prefilled whole (the model offers
         no ``mixed_step``): a prefill program an admission, each with its
         first token read back while the loop stands, then the K token-steps
-        of every live row."""
-        acc, counts = self._phase, self._counts
+        of every live row. Returns the rows it stepped."""
+        acc, counts, flight = self._phase, self._counts, self._flight
         for p, row in admits:
-            with phase(acc, "prefill",
+            with phase(acc, "prefill", flight,
                        bucket=self._bucket_for(
                            self._clip_tokens(p.item[0])),
                        cap=self.kv_pool.row_tokens(row)):
@@ -544,23 +582,25 @@ class ContinuousBatcher:
         active = [r for r in range(self.max_slots)
                   if self._slot_pending[r] is not None]
         if not active:
-            return
+            return 0
         toks, stepped = self._dispatch_decode(active)
-        with phase(acc, "step_wait"):
+        with phase(acc, "step_wait", flight):
             # toks [K, B] and the model's counts, in one readback
             toks, stepped = self._jax.device_get((toks, stepped))
+            flight.drain()
             self._add_model_counts(stepped)
-        with phase(acc, "emit"):
+        with phase(acc, "emit", flight):
             self.steps += self.steps_per_iter
             self._emit(toks, active, {})
+        return len(active)
 
     def _dispatch_decode(self, rows):
         """Assemble and dispatch the K token-steps of the live ``rows`` (the
         one decode program); its tokens [K, B] and the model's counts, still
         on the device."""
         jnp = self._jnp
-        acc, counts = self._phase, self._counts
-        with phase(acc, "assemble", rows=len(rows)):
+        acc, counts, flight = self._phase, self._counts, self._flight
+        with phase(acc, "assemble", flight, rows=len(rows)):
             sub = self._iteration_key()
             # the host's part is the live slots' lengths and table
             # rows; the KV stays where it is
@@ -571,9 +611,10 @@ class ContinuousBatcher:
             counts["iterations"] += 1
             self._count_positions(self._slot_offset[rows],
                                   self.steps_per_iter)
-        with phase(acc, "step_dispatch"):
+        with phase(acc, "step_dispatch", flight):
             self._pool, toks, stepped = self._paged_step(
                 self.params, self._pool, last, offsets, table, sub)
+            flight.fill()
         return toks, stepped
 
     def _mixed_step_program(self):
@@ -633,16 +674,17 @@ class ContinuousBatcher:
         self._slot_budget[row] = budget
         self._prefilling.append(row)
 
-    def _iterate_mixed(self, admits) -> None:
+    def _iterate_mixed(self, admits) -> int:
         """One iteration where the model offers ``mixed_step``: the chunks
         waiting, oldest row first and at most K of them, each in one program
         with a decode token-step of the live rows, or, where no chunk waits,
         the K token-steps of the decode program; then ONE readback of every
-        token the iteration made."""
+        token the iteration made. Returns the rows it held."""
         np = self._np
-        acc, counts = self._phase, self._counts
+        acc, counts, flight = self._phase, self._counts, self._flight
         for p, row in admits:
-            with phase(acc, "prefill", cap=self.kv_pool.row_tokens(row)):
+            with phase(acc, "prefill", flight,
+                       cap=self.kv_pool.row_tokens(row)):
                 try:
                     self._begin_prefill(p, row)
                 except faults.FaultInjected as e:
@@ -654,7 +696,7 @@ class ContinuousBatcher:
         active = [r for r in range(self.max_slots)
                   if self._slot_pending[r] is not None]
         if not active:
-            return
+            return 0
         if self._prefilling:
             outs, began = self._dispatch_mixed(len(active))
         else:
@@ -664,14 +706,15 @@ class ContinuousBatcher:
             # which ended retire and the prompts queued behind them bring
             # the next chunks
             outs, began = [self._dispatch_decode(active)], {}
-        with phase(acc, "step_wait"):
+        with phase(acc, "step_wait", flight):
             # every token-step's tokens [B] (the decode program's [K, B])
             # and the model's counts, in one readback
             outs = self._jax.device_get(outs)
+            flight.drain()
             for _, stepped in outs:
                 self._add_model_counts(stepped)
             toks = np.concatenate([np.atleast_2d(t) for t, _ in outs])
-        with phase(acc, "emit"):
+        with phase(acc, "emit", flight):
             self.steps += len(toks)
             now = time.time()
             for row in began:  # the first token exists
@@ -682,6 +725,7 @@ class ContinuousBatcher:
                                      p.t_first - p.t_admit))
             self._emit(toks, [r for r in active
                               if r not in self._prefilling], began)
+        return len(active)
 
     def _dispatch_mixed(self, rows: int):
         """Dispatch the chunks that wait, oldest row first and at most K of
@@ -695,10 +739,10 @@ class ContinuousBatcher:
         offsets, tables) is known without reading anything back, and none
         waits for the host."""
         jnp, np = self._jnp, self._np
-        acc, counts = self._phase, self._counts
+        acc, counts, flight = self._phase, self._counts, self._flight
         K, C = self.steps_per_iter, self._chunk
         pool, sink = self.kv_pool, self.kv_pool.sink_page
-        with phase(acc, "assemble", rows=rows):
+        with phase(acc, "assemble", flight, rows=rows):
             if self._pool is None:  # the engine's first admission
                 self._pool = pool.allocate()
             key = self._iteration_key()
@@ -720,7 +764,7 @@ class ContinuousBatcher:
             arr, true_len = self._slot_prompt[row]
             index = int(self._slot_chunks[row])
             ends = (index + 1) * C >= len(arr)
-            with phase(acc, "prefill", chunk_index=index,
+            with phase(acc, "prefill", flight, chunk_index=index,
                        cap=pool.row_tokens(row)):
                 live = off > 0
                 # the chunk's table: the row's pages, sink entries past
@@ -747,6 +791,7 @@ class ContinuousBatcher:
                     chunk_pages, np.int32(index), at,
                     np.int32(row if ends else -1), last, off.copy(), table,
                     key, *slot)
+                flight.fill()
                 counts["mixed_steps"] += 1
                 counts["prefill_positions"] += C
                 if C in self._prefill_kernel:
@@ -815,6 +860,8 @@ class ContinuousBatcher:
         self._published = {
             "phase_s": {k: v[0] for k, v in self._phase.items()},
             "phase_cpu_s": {k: v[1] for k, v in self._phase.items()},
+            "starved_s": {k: v[2] for k, v in self._phase.items()},
+            "stalls": list(self._stalls),
             **self._counts, "recent": list(self._recent),
             # bytes a cached position holds, bytes a slot's state holds
             # (0: the model keeps none), and the model's own counts as plain
@@ -850,10 +897,44 @@ class ContinuousBatcher:
         where its prompts ride in chunks ``mixed_state_rows_stepped`` of the
         mixed steps; one that holds a share of its experts also
         ``expert_assignments`` and ``expert_assignments_held``).
+
+        ``starved_s`` (the keys of ``phase_s``) is, of each phase's wall
+        seconds, the part in which nothing the engine dispatched was still
+        unread: the chip had no work from this thread. The queue fills when
+        a program's call returns (the decode program, the mixed step, a
+        prefill program; not ``random.split``'s two tiny programs, nor an
+        upload) and drains when the readback that takes the last outstanding
+        result returns (``device_get`` of an iteration's tokens, the first
+        token's ``int`` in ``_admit``). A block between a drain and the next
+        fill counts whole, the block of the fill up to the call's return,
+        the block of the drain from the readback's return; ``idle_wait``
+        counts nothing (there is no work). So ``starved_s["prefill"]`` over
+        ``admitted`` is the host's part of an admission where prompts are
+        prefilled whole, and ``phase_s["prefill"] - starved_s["prefill"]``
+        the wait for the program. It is a **lower bound** of the device's
+        idle time (the way from a call's return to the program's start on
+        the chip, and from its end to the readback's return, is left out),
+        on the host's clock, over every second and with no profiler; the
+        trace's idle share is the device's own reading over its traced
+        seconds: the two are compared, not merged. When a readback overlaps
+        the next dispatch, phases stop being starved without getting
+        shorter: this is the number that shows it.
+
+        ``stalls`` is the newest 16 iterations, oldest first, whose wall
+        seconds (the work phases' since the gate) passed 4 x the median of
+        the 32 iterations before them: ``{"t_end": time.time(), "wall_s",
+        "median_s", "phase_s", "phase_cpu_s", "starved_s", "rows",
+        "token_steps"}``, the three dicts that iteration's own. A window
+        that lost seconds names the phase, says whether the thread was on a
+        core (wall against CPU) and whether the chip had work; warm-up's
+        compiles are rows too, so a reader keeps those whose ``t_end`` lies
+        inside its window (this process's ``time.time()``).
         Any thread may call it; the copy is the caller's."""
         snap = self._published
         return {**snap, "phase_s": dict(snap["phase_s"]),
                 "phase_cpu_s": dict(snap["phase_cpu_s"]),
+                "starved_s": dict(snap["starved_s"]),
+                "stalls": copy.deepcopy(snap["stalls"]),
                 "recent": list(snap["recent"])}
 
     def kv_stats(self) -> Dict[str, Any]:

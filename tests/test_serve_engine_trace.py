@@ -1,8 +1,10 @@
 """The serve engine measured from inside (``ContinuousBatcher``): loop
 phases that add up to the engine thread's wall time, counts that only grow
-and read consistently from other threads, a request's queue / prefill /
-decode spans under the caller's trace id, and the phases as
-``rmt.engine.*`` spans on the profiler's own clock.
+and read consistently from other threads, the seconds of each phase in
+which nothing the engine dispatched was unread (``starved_s``) and the
+iterations that stalled (``stalls``), a request's queue / prefill / decode
+spans under the caller's trace id, and the phases as ``rmt.engine.*`` spans
+on the profiler's own clock.
 
 CPU, toy preset: what is checked is the accounting, not a speed.
 """
@@ -19,7 +21,9 @@ from ray_memory_management_tpu import serve
 from ray_memory_management_tpu.serve.llm import (
     ENGINE_PHASES, ContinuousBatcher, llm_deployment,
 )
-from ray_memory_management_tpu.utils import profiling, timeline, tracing
+from ray_memory_management_tpu.utils import (
+    faults, profiling, timeline, tracing,
+)
 
 MAX_SLOTS, PAGE = 4, 16
 
@@ -32,6 +36,31 @@ def model():
 
     cfg = gpt.PRESETS["test"]
     return cfg, gpt.init_params(jax.random.PRNGKey(0), cfg)
+
+
+@pytest.fixture(scope="module")
+def whole_model():
+    """A model that offers no ``mixed_step``: its prompts are prefilled
+    whole (``_iterate``, ``_admit``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_memory_management_tpu.models import latent_moe
+
+    cfg = latent_moe.LatentMoEConfig(
+        vocab_size=512, d_model=64, n_layers=2, n_heads=4, q_lora_rank=24,
+        kv_lora_rank=32, qk_nope_head_dim=12, qk_rope_head_dim=4,
+        v_head_dim=16, d_ff=128, moe_d_ff=32, n_routed_experts=8,
+        n_shared_experts=1, experts_per_tok=2, routed_scaling_factor=1.8,
+        max_seq=128, dtype=jnp.float32, param_dtype=jnp.float32)
+    return cfg, latent_moe.init_params(jax.random.PRNGKey(7), cfg)
+
+
+@pytest.fixture(params=["chunk", "whole"])
+def either_model(request, model, whole_model):
+    """Both of the engine's paths: it chooses by ``mixed_step``'s presence."""
+    picked = model if request.param == "chunk" else whole_model
+    return request.param, picked
 
 
 def engine(model):
@@ -115,6 +144,10 @@ def test_counts_only_grow_and_read_whole_from_eight_threads(model):
                     "prefill_positions"]
                 for name in ENGINE_PHASES:
                     assert st["phase_s"][name] >= last["phase_s"][name]
+                    assert st["starved_s"][name] >= last["starved_s"][name]
+                    # of one copy: a block adds both before it is published
+                    assert st["starved_s"][name] <= st["phase_s"][name]
+                assert len(st["stalls"]) <= 16
                 # one copy, from one instant: counts that move together
                 # are never seen apart
                 assert len(st["recent"]) == min(st["admitted"], 512)
@@ -127,6 +160,8 @@ def test_counts_only_grow_and_read_whole_from_eight_threads(model):
                     "slab_positions"] <= st["iterations"] * MAX_SLOTS \
                     * cfg.max_seq
                 st["phase_s"].clear()  # the caller's own copy
+                st["starved_s"].clear()
+                st["stalls"].clear()
                 last = eng.engine_stats()
         except BaseException as e:  # noqa: BLE001 — reported below
             errors.append(e)
@@ -182,6 +217,196 @@ def test_prefill_positions_grow_by_the_bucket_an_admission(model, attention):
     assert [p for _, p, _ in seen] == totals
     assert [k for _, _, k in seen] == (
         totals if attention == "flash-interpret" else [0] * 5)
+
+
+# ------------------------------------- (a') seconds without a program in flight
+def settled(eng, admitted, timeout=60.0):
+    """The copy published after the iteration that answered the
+    ``admitted``-th request and every row's retirement (an answer leaves in
+    ``emit``, the copy after it)."""
+    seen = eng.engine_stats()["phase_s"]["idle_wait"]
+
+    def idle():
+        st = eng.engine_stats()
+        done = st["admitted"] >= admitted and all(
+            p is None for p in eng._slot_pending)
+        return st if done and st["phase_s"]["idle_wait"] > seen else None
+
+    st = _poll(idle, timeout)
+    assert st, "the engine never settled"
+    return st
+
+
+def grown(after, before, key):
+    return {k: v - before[key][k] for k, v in after[key].items()}
+
+
+def test_starved_seconds_lie_inside_each_phases_wall(either_model):
+    path, model = either_model
+    eng = engine(model)
+    assert eng._mixed == (path == "chunk")
+    try:
+        while eng.engine_stats()["iterations"] < 50:
+            wave(eng)
+    finally:
+        close(eng)
+    st = eng.engine_stats()
+    assert set(st["starved_s"]) == set(st["phase_s"]) == set(ENGINE_PHASES)
+    for name in ENGINE_PHASES:
+        assert 0.0 <= st["starved_s"][name] <= st["phase_s"][name], name
+    # nothing is in flight between an iteration's readback and its first
+    # dispatch: these blocks count whole, on the wall's own clock reads
+    for name in ("gate", "assemble", "emit"):
+        assert st["starved_s"][name] == pytest.approx(
+            st["phase_s"][name], rel=1e-9, abs=1e-9), name
+        assert st["phase_s"][name] > 0
+    assert st["starved_s"]["idle_wait"] == 0.0  # no work, so nobody starves
+    # the wait counts from the readback's return alone
+    assert st["starved_s"]["step_wait"] < st["phase_s"]["step_wait"]
+    assert 0 < st["starved_s"]["prefill"] <= st["phase_s"]["prefill"]
+    if path == "whole":
+        # the decode program is every iteration's first call after the
+        # admissions' readbacks: its dispatch is starved up to the call's
+        # return, a clock's read before the block's end
+        assert st["starved_s"]["step_dispatch"] == pytest.approx(
+            st["phase_s"]["step_dispatch"], abs=2e-5 * st["iterations"])
+    work = sum(st["phase_s"].values()) - st["phase_s"]["idle_wait"]
+    assert 0 < sum(st["starved_s"].values()) < work
+
+
+def test_of_three_chunks_an_iteration_one_is_starved(model):
+    """The chunk path: the first chunk's program is running while the host
+    makes the next two's arguments, so one ``prefill`` block of the three
+    is starved (up to its call's return) and the other two are not."""
+    eng = engine(model)
+    prompt = list(range(2, 2 + 2 * PAGE + 8))  # three chunks of 16
+    try:
+        eng.submit(prompt, max_new_tokens=2, timeout=120)  # compiles
+        before = settled(eng, 1)
+        call = eng._mixed_step
+
+        def slow_call(*a):  # 30 ms on the host inside each chunk's call
+            time.sleep(0.03)
+            return call(*a)
+
+        eng._mixed_step = slow_call
+        eng.submit(prompt, max_new_tokens=2, timeout=120)
+        after = settled(eng, 2)
+    finally:
+        close(eng)
+    assert after["mixed_steps"] - before["mixed_steps"] == 3
+    wall = grown(after, before, "phase_s")["prefill"]
+    starved = grown(after, before, "starved_s")["prefill"]
+    assert wall >= 0.09
+    assert 0.03 <= starved < 0.06, (starved, wall)
+
+
+def test_an_admission_is_starved_up_to_its_programs_call(whole_model):
+    """The whole-prompt path: ``prefill`` is starved from its start to the
+    prefill program's call returning and not while it waits for the first
+    token, so the two differ by the wait."""
+    eng = engine(whole_model)
+
+    class Late:  # a first token whose readback takes 20 ms
+        def __init__(self, first):
+            self.first = first
+
+        def __int__(self):
+            time.sleep(0.02)
+            return int(self.first)
+
+    try:
+        eng.submit(list(range(2, 20)), max_new_tokens=2, timeout=120)
+        before = settled(eng, 1)
+        build = eng._paged_prefill_fn
+
+        def late_first(bucket):
+            def fn(*a):
+                pool, first = build(bucket)(*a)
+                return pool, Late(first)
+            return fn
+
+        eng._paged_prefill_fn = late_first
+        for _ in range(3):
+            eng.submit(list(range(2, 20)), max_new_tokens=2, timeout=120)
+        after = settled(eng, 4)
+    finally:
+        close(eng)
+    assert after["admitted"] - before["admitted"] == 3
+    wall = grown(after, before, "phase_s")["prefill"]
+    starved = grown(after, before, "starved_s")["prefill"]
+    assert 0 < starved < wall
+    assert wall - starved >= 3 * 0.02  # the first-token wait between them
+
+
+# --------------------------------------------- (a'') the iterations that stalled
+def test_a_planted_stall_leaves_exactly_one_row(either_model):
+    path, model = either_model
+    eng = engine(model)
+    try:
+        while eng.engine_stats()["iterations"] < 40:
+            wave(eng)
+        n = settled(eng, 1)["admitted"]
+        t0 = time.time()
+        before = eng.engine_stats()
+        faults.configure("serve.admit:stall:stall=1.0:max=1", seed=3)
+        try:
+            eng.submit([3, 4, 5, 6], max_new_tokens=2, timeout=120)
+        finally:
+            faults.reset()
+        after = settled(eng, n + 1)
+        t1 = time.time()
+    finally:
+        close(eng)
+    assert len(after["stalls"]) <= 16
+    rows = [r for r in after["stalls"] if t0 <= r["t_end"] <= t1]
+    assert len(rows) == 1, after["stalls"]
+    row, = rows
+    assert row not in before["stalls"]
+    assert set(row) == {"t_end", "wall_s", "median_s", "phase_s",
+                        "phase_cpu_s", "starved_s", "rows", "token_steps"}
+    assert row["wall_s"] > 4 * row["median_s"] > 0
+    assert row["wall_s"] == pytest.approx(
+        sum(row["phase_s"].values()), rel=1e-9)
+    assert row["phase_s"]["idle_wait"] == 0.0
+    for key in ("phase_s", "phase_cpu_s", "starved_s"):
+        assert set(row[key]) == set(ENGINE_PHASES)
+    assert max(row["phase_s"], key=row["phase_s"].get) == "prefill"
+    assert row["phase_s"]["prefill"] >= 1.0
+    # the thread slept: it was off a core, and the chip had no work
+    assert sum(row["phase_cpu_s"].values()) < 0.1 * row["wall_s"]
+    assert row["starved_s"]["prefill"] >= 1.0
+    assert row["rows"] == 1 and row["token_steps"] >= 1
+    # the iteration's own seconds: the accumulators' difference holds them
+    assert grown(after, before, "phase_s")["prefill"] >= row[
+        "phase_s"]["prefill"]
+
+
+def test_the_ring_keeps_the_newest_sixteen_stalls(model):
+    eng = engine(model)
+    try:
+        while eng.engine_stats()["iterations"] < 40:
+            wave(eng)
+        n = settled(eng, 1)["admitted"]
+        t0 = time.time()
+        # every admission stalls; a request is three iterations, so the
+        # median of the last 32 stays a plain iteration's
+        faults.configure("serve.admit:stall:stall=0.3", seed=3)
+        try:
+            for i in range(19):
+                eng.submit([3, 4, 5 + i], max_new_tokens=9, timeout=120)
+        finally:
+            faults.reset()
+        after = settled(eng, n + 19)
+    finally:
+        close(eng)
+    rows = after["stalls"]
+    assert len(rows) == 16
+    ends = [r["t_end"] for r in rows]
+    assert ends == sorted(ends) and ends[0] > t0  # oldest first, the newest
+    # the planted ones (a plain iteration that the machine held up may sit
+    # among them)
+    assert sum(r["phase_s"]["prefill"] >= 0.3 for r in rows) >= 12
 
 
 # ------------------------------------------------------ (b) a request's spans
