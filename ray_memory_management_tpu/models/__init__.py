@@ -27,7 +27,15 @@ def serving_model(cfg):
     starts from zeros whatever the slot's entry holds, a later one from the
     entry, and each leaves there the state as of its last real position;
     the row is idle among the decode rows, so only its chunks move its
-    entry (models/hybrid_ssm.py). The engine prefills in chunks that ride
+    entry (models/hybrid_ssm.py). A model whose layers are of several kinds
+    walks its pattern in ``mixed_step`` as in ``paged_decode``, each kind
+    splitting the ``C + B`` rows its own way (models/nemotron_h.py: a
+    state layer as above, an attention layer as the dense decoder's, an
+    expert layer in one call over the chunk's real positions and the live
+    rows together). What ``mixed_step`` counts of itself it returns under
+    names of its own (``mixed_*``), never under ``paged_decode``'s: the
+    engine adds counts up by name, and a mean a decode token-step stays
+    the decode program's. The engine prefills in chunks that ride
     its decode steps where a model offers it, and whole prompts through
     ``prefill_row`` where it does not."""
     from . import gpt, hybrid_ssm, latent_moe, nemotron_h

@@ -35,6 +35,11 @@ program is traced.
 pattern has ``*``, ``state_spec`` as many as it has ``M``; an expert layer
 holds nothing a request leaves behind. Attention layer ``j`` and Mamba layer
 ``j`` (counted among their own kind) are entry ``j`` of their arrays.
+
+**A prompt rides the decode step in chunks** (:func:`mixed_step`, which the
+engine finds by name: serve/llm.py): one chunk of one row's prompt and one
+decode token of every live row in one walk over the pattern, so the weights
+(the held experts' among them) are read once for both.
 """
 
 from __future__ import annotations
@@ -49,8 +54,8 @@ from jax import lax
 from ..ops import moe, ssm
 from ..ops.flash_attention import flash_attention
 from ..ops.paged_attention import paged_attention
-from .hybrid_ssm import (_conv, _gate_out, _kernel_use, _mm, _mm32,
-                         _split_xbc, _ssm_project, _write_kv,
+from .hybrid_ssm import (_carried, _conv, _gate_out, _kernel_use, _mm, _mm32,
+                         _split_xbc, _ssm_project, _write_kv, _write_rows,
                          prefill_takes_kernel)  # noqa: F401 (the engine's)
 from .latent_moe import _rmsnorm
 
@@ -403,3 +408,190 @@ def paged_decode(params, tokens, pool, positions, lengths, page_table,
         "state_rows_stepped": n_live * cfg.count("M"),
         "state_rows_fetched": fetched,
         "ssm_layer_steps": ran * cfg.count("M")}
+
+
+def mixed_step(params, pool, chunk_tokens, chunk_pages, chunk_last, tokens,
+               positions, lengths, page_table, cfg: NemotronHConfig, *,
+               chunk_index, slot):
+    """One chunk of one row's prompt and one decode token a live row, in one
+    pass over the pattern: the decode rows' weights are the chunk's. The
+    arguments are ``models/hybrid_ssm.py::mixed_step``'s: ``chunk_tokens``
+    int32 [C] are the prompt's positions ``[chunk_index * C, (chunk_index +
+    1) * C)`` (``C`` a whole number of pages), of which the first
+    ``chunk_last + 1`` are real; ``chunk_pages`` is the prompt's row of the
+    block table in whole chunks; ``slot`` is the index of the row being
+    prefilled, whose state entry the chunk continues; ``tokens``,
+    ``positions``, ``lengths`` and ``page_table`` are :func:`paged_decode`'s,
+    the row being prefilled idle among them (length 0, a table row of sink
+    entries): the decode half neither fetches nor moves its state.
+
+    Embedding, norms and every projection run once over the ``C + B`` rows,
+    the chunk's first. By the kind of layer:
+
+      - ``M``: the chunk's convolution takes the taps before its first
+        position from the slot's tail and its scan starts from the slot's
+        state (``ssd_scan(..., h0=)``), **zeros both where ``chunk_index ==
+        0``** (``hybrid_ssm._carried``: what an earlier request left in the
+        slot is never read, and no program clears it); the scan stops at the
+        chunk's real positions, and the slot's entry then holds the state
+        and the last ``ssm_conv - 1`` inputs as of the chunk's last real
+        position. The live rows' update is :func:`paged_decode`'s kernel on
+        the packed state where it lies, under a name of its own
+        (``ssm_mixed_update``: whoever counts the decode program's
+        token-steps by ``ssm_decode_update``'s calls counts none here). The
+        slot's entry of the layer is sliced out of the state after that
+        update (unpacked for ``h0``) and put back after the scan (packed),
+        so the state stays where it is;
+      - ``*``: no position enters. The chunk attends in the flash forward
+        kernel over the row's pages before it (gathered outside a
+        ``lax.switch`` over the prefix lengths a prompt can have,
+        ``chunk_index`` a run-time int32: ONE program) and itself, the decode
+        rows through the block table; the layer loop only reads the pages,
+        and after it the chunk's K and V go to whole pages and each decode
+        row's to its one position;
+      - ``E``: router, latent projections, the held experts and the shared
+        expert **once over the ``C + B`` rows**, so a held expert's matrices
+        are read once a layer and mixed step; the chunk's padding and the
+        idle rows are routed nowhere.
+
+    Returns (logits [B + 1, V] fp32: the decode rows', then the chunk's at
+    ``chunk_last``; the pool; counts int32 **under names of the mixed step's
+    own**, so that every mean a decode token-step stays the decode
+    program's): ``mixed_state_rows_stepped``, the live decode rows whose
+    state the update kernel moved, summed over the Mamba layers (the chunk's
+    row moves in the scan and is not among them); ``mixed_ssm_layer_steps``,
+    the calls of that kernel (the Mamba layers); ``mixed_expert_layer_steps``,
+    the expert layers that ran; ``mixed_expert_assignments_held``, the
+    assignments of the chunk's real positions and of the live decode rows
+    together to experts held here, summed over the expert layers;
+    ``mixed_experts_touched``, the held experts with at least one of them,
+    summed over the expert layers."""
+    C, page = chunk_tokens.shape[0], pool["k"].shape[3]
+    Hkv, Dh, rep = cfg.kv_heads, cfg.head_dim, cfg.n_heads // cfg.kv_heads
+    tail, n_pages, pack = cfg.ssm_conv - 1, C // page, cfg.state_pack
+    n_chunks = chunk_pages.shape[0] // n_pages  # the longest prompt's
+    chunk_index = jnp.asarray(chunk_index, jnp.int32)
+    slot = jnp.asarray(slot, jnp.int32)
+    n_real = chunk_last + 1
+    first = chunk_index == 0
+    before = chunk_pages[:(n_chunks - 1) * n_pages]
+    use = _kernel_use(cfg, C)
+    live = lengths > 0
+    routed = jnp.concatenate([jnp.arange(C) < n_real, live])
+    x = params["tok_embed"][jnp.concatenate([chunk_tokens, tokens])].astype(
+        cfg.dtype)
+
+    def over(n_before):  # the chunk over ``n_before`` earlier chunks + itself
+        def attend(q, ks, vs):
+            # the kernel wants as many K/V heads as query heads
+            return flash_attention(
+                q, jnp.repeat(ks[:, :(n_before + 1) * C], rep, axis=0)[None],
+                jnp.repeat(vs[:, :(n_before + 1) * C], rep, axis=0)[None],
+                causal=True, use_pallas=use)
+        return attend
+
+    def row_so_far(pages_of, own, layer):
+        # the row's pages before its last chunk, then the chunk's own
+        # positions laid over them where the chunk starts: the first
+        # ``(chunk_index + 1) * C`` positions are what the chunk sees
+        so_far = jnp.concatenate([
+            lax.dynamic_slice(pages_of, (layer, 0, before[i], 0, 0),
+                              (1, Hkv, 1, page, Dh)).reshape(Hkv, page, Dh)
+            for i in range(before.shape[0])] + [own], axis=1)
+        return lax.dynamic_update_slice(so_far, own, (0, chunk_index * C, 0))
+
+    state, conv = pool["ssm"], pool["conv"]
+    k_chunk, v_chunk, k_rows, v_rows, tails = [], [], [], [], []
+    touched, held = jnp.int32(0), jnp.int32(0)
+    for kind, p in zip(cfg.pattern, params["layers"]):
+        u = _rmsnorm(x, p["ln"], cfg.rms_norm_eps)
+        if kind == "M":
+            i = len(tails)                       # among the Mamba layers
+            # the three parts are held as they are made: the gate is read
+            # after the scan, and the compiler would rather run the whole
+            # projection over the 640 rows again for it than keep it
+            z, xbc, dt = lax.optimization_barrier(_ssm_project(u, p, cfg))
+            with jax.named_scope("ssm_conv"):
+                old = conv[i]                           # [taps - 1, B, C]
+                mine = lax.dynamic_slice_in_dim(old, slot, 1, axis=1)[:, 0]
+                behind = jnp.concatenate([_carried(mine, first), xbc[:C]])
+                xs, b, c = _split_xbc(jnp.concatenate([
+                    _conv([behind[j:j + C] for j in range(cfg.ssm_conv)], p,
+                          cfg),
+                    _conv([*old, xbc[C:]], p, cfg)]), cfg)
+                # the live rows' tails move on by their token; the slot's
+                # holds the last real inputs: rows n_real - tail .. n_real - 1
+                tails.append(lax.dynamic_update_slice_in_dim(
+                    jnp.where(live[None, :, None],
+                              jnp.concatenate([old[1:], xbc[None, C:]]), old),
+                    lax.dynamic_slice_in_dim(behind, n_real, tail)[:, None],
+                    slot, axis=1))
+            A = -jnp.exp(p["A_log"])
+            with jax.named_scope("ssm_mixed_update"):
+                y_rows, state, _ = ssm.ssm_decode_update(
+                    state, xs[C:], dt[C:], A, b[C:], c[C:], p["D"], live,
+                    layer=i, name="ssm_mixed_update")
+            with jax.named_scope("ssm_scan"):
+                h0 = ssm.unpack_heads(lax.dynamic_slice(
+                    state, (i, slot, 0, 0, 0),
+                    (1, 1) + state.shape[2:])[0, 0], pack)
+                y_chunk, h = ssm.ssd_scan(
+                    xs[:C], dt[:C], A, b[:C], c[:C], p["D"], true_len=n_real,
+                    h0=_carried(h0, first))
+            with jax.named_scope("state_write"):
+                state = lax.dynamic_update_slice(
+                    state, ssm.pack_heads(h, pack)[None, None],
+                    (i, slot, 0, 0, 0))
+            x = x + _gate_out(jnp.concatenate([y_chunk, y_rows]), z, p, cfg)
+        elif kind == "*":
+            j = len(k_chunk)                     # among the attention layers
+            q, k, v = _qkv(u, p, cfg)
+            kt, vt = k[:C].transpose(1, 0, 2), v[:C].transpose(1, 0, 2)
+            with jax.named_scope("prefix_gather"):
+                ks = row_so_far(pool["k"], kt, j)
+                vs = row_so_far(pool["v"], vt, j)
+            with jax.named_scope("prefill_attention"):
+                o_chunk = lax.switch(
+                    chunk_index, [over(n) for n in range(n_chunks)],
+                    q[:C].transpose(1, 0, 2)[None], ks, vs)[0]
+            with jax.named_scope("decode_attention"):
+                o_rows = paged_attention(
+                    q[C:], pool["k"], pool["v"], lengths, page_table,
+                    layer=j, k_cur=k[C:], v_cur=v[C:])
+            k_chunk.append(kt), v_chunk.append(vt)
+            k_rows.append(k[C:]), v_rows.append(v[C:])
+            o = jnp.concatenate([o_chunk.transpose(1, 0, 2), o_rows])
+            x = x + _mm(o.reshape(o.shape[0], -1), p["wo"], cfg)
+        else:
+            y, counts = _experts(u, p, cfg, routed)
+            held = held + jnp.sum(counts, dtype=jnp.int32)
+            touched = touched + jnp.sum(counts > 0, dtype=jnp.int32)
+            x = x + y
+    with jax.named_scope("kv_write"):
+        # the pages are written only once every layer has read them
+        # (:func:`paged_decode`)
+        x, k_chunk, v_chunk, k_rows, v_rows = lax.optimization_barrier(
+            (x, jnp.stack(k_chunk), jnp.stack(v_chunk), jnp.stack(k_rows, 1),
+             jnp.stack(v_rows, 1)))
+        now = lax.dynamic_slice_in_dim(chunk_pages, chunk_index * n_pages,
+                                       n_pages)
+
+        def whole_pages(pages_of, new):  # new [attention layers, Hkv, C, Dh]
+            return pages_of.at[:, :, now].set(
+                new.reshape(new.shape[:2] + (n_pages, page, Dh)))
+
+        kv = _write_rows({"k": whole_pages(pool["k"], k_chunk),
+                          "v": whole_pages(pool["v"], v_chunk)},
+                         k_rows, v_rows, positions, page_table)
+    with jax.named_scope("state_write"):
+        pool = dict(kv, ssm=state, conv=jnp.stack(tails))
+    with jax.named_scope("head_sample"):  # the engine's sampler joins it
+        logits = _head(jnp.concatenate(
+            [x[C:], lax.dynamic_slice_in_dim(x, chunk_last, 1)]), params, cfg)
+    return logits, pool, {
+        "mixed_state_rows_stepped": jnp.sum(live, dtype=jnp.int32)
+        * cfg.count("M"),
+        "mixed_ssm_layer_steps": jnp.int32(cfg.count("M")),
+        "mixed_expert_layer_steps": jnp.int32(cfg.count("E")),
+        "mixed_expert_assignments_held": held,
+        "mixed_experts_touched": touched}

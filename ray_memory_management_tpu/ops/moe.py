@@ -415,11 +415,13 @@ def _tiled_experts(x, flat, order, back, sizes, layer, top_k, interpret):
     tile_expert = jnp.minimum(jnp.searchsorted(
         tile_end, jnp.arange(n_tiles), side="right"), E - 1).astype(jnp.int32)
     # the row each place of the layout holds (a tile's empty rest holds
-    # rows of the experts behind: computed, read by nobody)
-    at = jnp.arange(n_tiles * tile)
-    e_at = tile_expert[at // tile]
-    sorted_at = jnp.clip(start[e_at] + at - tile * first_tile[e_at], 0,
-                         flat.shape[0] - 1)
+    # rows of the experts behind: computed, read by nobody). A tile's first
+    # sorted row is worked out a tile (a lookup a layout row costs the TPU
+    # more than the tile's matmuls), the rows behind it follow on
+    tile_start = start[tile_expert] + tile * (
+        jnp.arange(n_tiles) - first_tile[tile_expert])
+    sorted_at = jnp.clip(tile_start[:, None] + jnp.arange(tile), 0,
+                         flat.shape[0] - 1).reshape(-1)
     rows = x[order[sorted_at] // top_k]                   # [tiles * tile, D]
 
     def last(i, active):  # a tile without rows: stay where we are
@@ -445,6 +447,6 @@ def _tiled_experts(x, flat, order, back, sizes, layer, top_k, interpret):
         interpret=interpret,
         name=f"moe_expert_tiles_{n_tiles}",
     )(tile_expert, active, rows, w1, w2)
-    # where each assignment's row lies in the layout
-    e_of = jnp.minimum(flat, E - 1)
-    return ys[tile * first_tile[e_of] + back - start[e_of]]
+    # where each assignment's row lies in the layout: as far behind its
+    # expert's first tile as it is behind the expert's first sorted row
+    return ys[(tile * first_tile - start)[jnp.minimum(flat, E - 1)] + back]

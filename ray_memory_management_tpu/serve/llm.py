@@ -896,7 +896,16 @@ class ContinuousBatcher:
         ``ssm_layer_steps``, of the decode program's token-steps alone, and
         where its prompts ride in chunks ``mixed_state_rows_stepped`` of the
         mixed steps; one that holds a share of its experts also
-        ``expert_assignments`` and ``expert_assignments_held``).
+        ``expert_assignments`` and ``expert_assignments_held``; the
+        layer-pattern model's mixed steps, under names of their own:
+        ``mixed_state_rows_stepped``, the live decode rows whose state the
+        update kernel moved, summed over the state layers,
+        ``mixed_ssm_layer_steps``, that kernel's calls,
+        ``mixed_expert_layer_steps``, the expert layers that ran,
+        ``mixed_expert_assignments_held``, the assignments of the chunks'
+        real positions and the live decode rows together to experts held
+        here, and ``mixed_experts_touched``, the held experts with at least
+        one of them, summed over the expert layers).
 
         ``starved_s`` (the keys of ``phase_s``) is, of each phase's wall
         seconds, the part in which nothing the engine dispatched was still

@@ -597,3 +597,119 @@ def test_the_hybrid_mixed_step_fits_and_keeps_pool_and_state_where_they_are(
     weights = 2 * arch.n_params(cfg)
     assert weights + held < total < weights + held + 0.3e9
     assert total < flops.peak("TPU v5 lite")["hbm_bytes"] - 2.0e9
+
+
+def test_the_pattern_mixed_step_fits_and_keeps_pool_and_state_where_they_are(
+        one_chip, monkeypatch):
+    """``longanswer-batch``'s mixed-step program (``nemotron-3-super-d11-e128``
+    widths, 128 rows, pages and chunks of 512, a chunk table of 5 chunks, the
+    pool of the cell's mix file): each of the five Mamba layers holds the
+    scan kernel for the chunk and the live rows' update kernel **under the
+    mixed step's own name** (none under ``ssm_decode_update``, by whose calls
+    the cell's rooflines count the decode program's token-steps); each of
+    the five expert layers ONE call of the expert kernel over the chunk's
+    and the decode rows' 640 rows together (``moe_expert_tiles_238``; none
+    of the decode step's ``moe_expert_tiles_128``, so a held expert's
+    matrices are read once a layer); the one attention layer a flash Mosaic
+    call a branch of the switch and one paged-attention call. K, V and the
+    packed state keep the layout they came in and none is a copy (a slot's
+    4 MiB a layer are sliced out and put back around the kernel that updates
+    the live rows in place); the pool is donated, and the whole program is
+    inside the chip's memory."""
+    import importlib
+    import re
+
+    from chipbench import architectures, flops, manifest
+    from chipbench.drivers import serve as serve_driver
+    from ray_memory_management_tpu.ops import moe
+    from ray_memory_management_tpu.serve.llm import ContinuousBatcher
+
+    # the dispatches ask where default computation lands: steer them here
+    for name in ("ops.flash_attention", "ops.paged_attention", "ops.ssm",
+                 "ops.moe", "models.hybrid_ssm"):
+        monkeypatch.setattr(importlib.import_module(
+            "ray_memory_management_tpu." + name), "_on_tpu", lambda: True)
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg = manifest.config("nemotron-3-super-d11-e128")
+    arch = architectures.of(cfg)
+    e = serve_driver.engine_kwargs(cfg, manifest.traffic("longanswer-batch"))
+    pc = arch.program_config(cfg)
+    slots, page = e["max_batch_size"], e["kv_page_tokens"]
+    eng = ContinuousBatcher(
+        None, pc, max_slots=slots, max_new_tokens=e["max_new_tokens"],
+        pad_multiple=e["pad_multiple"], steps_per_iter=e["steps_per_iter"],
+        kv_page_tokens=page, kv_pool_bytes=e["kv_pool_bytes"])
+    try:
+        params = shaped(jax.eval_shape(
+            lambda: arch.init_program_params(jax.random.PRNGKey(0), pc)))
+        pool = shaped(jax.eval_shape(eng.kv_pool.allocate))
+        assert {k: v.shape for k, v in pool.items()} == {
+            "k": (1, 2, 1025, 512, 128), "v": (1, 2, 1025, 512, 128),
+            "ssm": (5, 128, 64, 128, 128), "conv": (5, 3, 128, 10240)}
+        width = eng.kv_pool.table_width
+        # the longest prompt is 2,048 positions of the 2,560 a prompt may
+        # have beside a full answer: five chunks of 512
+        reach = -(-(pc.max_seq - e["max_new_tokens"]) // page)
+        assert eng._mixed and eng._chunk == page and reach == 5
+        compiled = eng._mixed_step.lower(
+            params, pool, arr((page,)), arr((reach,)), arr(()), arr(()),
+            arr(()), arr((slots,)), arr((slots,)), arr((slots, width)),
+            arr((2,), jnp.uint32), arr(())).compile()
+        assert page in eng._prefill_kernel
+    finally:
+        eng.close()
+    text = compiled.as_text()
+
+    def calls(name):
+        return len(set(re.findall(r"%(" + name + r"[.\d]*) = ", text)))
+
+    mamba, experts = arch.ssm_layers(cfg), arch.expert_layers(cfg)
+    tiles = moe.expert_tiles(page + slots, pc.experts_per_tok,
+                             pc.n_held_experts)
+    assert (mamba, experts, tiles) == (5, 5, 238)
+    assert tiles == arch.expert_kernel_tiles(cfg, page + slots)
+    # a Mamba layer: the update and the scan; an expert layer: one call; the
+    # attention layer: a flash call a branch and paged attention
+    assert text.count("tpu_custom_call") == 2 * mamba + experts + reach + 1
+    assert calls("ssd_chunk_scan") == calls("ssm_mixed_update") == mamba
+    assert calls(f"moe_expert_tiles_{tiles}") == experts
+    assert calls("paged_decode_attention") == 1
+    # no operation of the decode program's names (the text's table of stack
+    # frames holds the wrapper functions'; a trace reader goes by an
+    # operation's own name)
+    assert calls("ssm_decode_update") == 0
+    assert calls(f"moe_expert_tiles_{pc.n_held_experts}") == 0
+    assert "ragged-dot" not in text
+    # a Mamba layer's in-projection over the 640 rows runs once: its parts
+    # are held behind a barrier (without it the compiler ran the whole
+    # projection again, 0.52 ms a time on the chip, for the gate it reads
+    # after the scan); and the expert layout's lookups are a tile's, not a
+    # layout row's: one of 30,464 a layer (the sorted order's) where
+    # four were
+    wide = pc.ssm_proj_width
+    assert len(re.findall(rf"^\s+%fusion\S* = \(?bf16\[640,{wide}\]", text,
+                          flags=re.M)) == mamba
+    assert len(re.findall(rf"= s32\[{tiles * moe.EXPERT_TILE}\]\S* gather\(",
+                          text)) == experts
+    for shape in ("f32[5,128,64,128,128]", "bf16[1,2,1025,512,128]"):
+        made = re.findall(
+            "= " + re.escape(shape) + r"\{([\d,]+)[^ ]* (\S+?)\(", text)
+        assert made and {lay for lay, _ in made} == {"4,3,2,1,0"}, shape
+        assert not {op for _, op in made} & {"copy", "copy-start"}, shape
+    held = sum(v.size * v.dtype.itemsize for v in pool.values())
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= held  # donated
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    # 9.30 GB of weights, 0.54 + 2.72 GB of pages and state, and 0.33 GB
+    # of temporaries (the 2,048 prefill's were 0.55 GB): 12.89 GB
+    weights = 2 * arch.n_params(cfg)
+    assert weights + held < total < weights + held + 0.4e9
+    assert total < flops.peak("TPU v5 lite")["hbm_bytes"] - 2.0e9

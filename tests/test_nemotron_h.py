@@ -8,6 +8,7 @@ comparison with the plain reference is tests/chipbench_tests/
 test_nemotron_h_cell.py's.
 """
 
+import functools
 import threading
 
 import jax
@@ -292,9 +293,9 @@ def test_an_idle_row_of_a_share_is_routed_nowhere():
 def test_the_engine_finds_the_model_and_its_specifications_by_kind():
     assert serving_model(CFG) is nemotron_h
     for name in ("init_params", "cache_spec", "state_spec", "prefill_row",
-                 "prefill_takes_kernel", "paged_decode", "forward"):
+                 "prefill_takes_kernel", "paged_decode", "forward",
+                 "mixed_step"):
         assert callable(getattr(nemotron_h, name)), name
-    assert not hasattr(nemotron_h, "mixed_step")
     assert (CFG.n_layers, CFG.count("M"), CFG.count("E"), CFG.count("*")) \
         == (6, 3, 2, 1)
     # one attention layer leaves K and V; three Mamba layers keep a state,
@@ -320,6 +321,7 @@ def test_the_engine_finds_the_model_and_its_specifications_by_kind():
     # the mixer's pieces are models/hybrid_ssm.py's own
     assert nemotron_h._ssm_project is hybrid_ssm._ssm_project
     assert nemotron_h._gate_out is hybrid_ssm._gate_out
+    assert nemotron_h._carried is hybrid_ssm._carried
 
 
 def _prefill(params, prompt, bucket, pool, table_row, slot):
@@ -424,6 +426,155 @@ def test_the_held_share_is_what_the_program_computes(params):
                                           "first_held_expert": 0})
     assert float(jnp.max(jnp.abs(
         nemotron_h.forward(params, tokens, moved) - here))) > 1e-2
+
+
+# ------------------------------------- a prompt's chunks on the decode step
+C = 2 * PAGE  # a chunk is two pages
+MIXED_COUNTS = {"mixed_state_rows_stepped", "mixed_ssm_layer_steps",
+                "mixed_expert_layer_steps", "mixed_expert_assignments_held",
+                "mixed_experts_touched"}
+
+
+def _chunked_beside_riders(params, n_prompt, *, fill=3.0, idle=True):
+    """A prompt of ``n_prompt`` tokens through ``mixed_step``, chunk by
+    chunk, into slot 1 and pages of its own, beside two rows that decode
+    (slots 0 and 2) and an idle slot 3; every slot's entry starts as
+    ``fill`` (junk an earlier request left, or zeros). ``idle`` false plants
+    a fault: the row is live among the decode rows while its chunks ride.
+    Returns what the chunks left (pool, the last chunk's logits), the same
+    riders stepped by ``paged_decode`` alone, the whole prompt through
+    ``prefill_row``, the prompt's pages, and each mixed step's counts beside
+    (the riders' ``expert_assignments_held`` of ``paged_decode``, the
+    chunk's own of a mixed step with every decode row idle)."""
+    rng = np.random.default_rng(n_prompt)
+    n_chunks, per = -(-n_prompt // C), C // PAGE
+    sink, width = 4 * per + 8, 4 * per
+    table = np.full((4, width), sink, np.int32)
+    table[0, :2], table[2, :2] = [sink - 2, 1], [sink - 5, 3]
+    mine = np.full(4 * per, sink, np.int32)
+    mine[:n_chunks * per] = [sink - 1, 0, sink - 3, 2, sink - 4, 4,
+                             sink - 6, 5][:n_chunks * per]
+    riders = {0: rng.integers(2, CFG.vocab_size, 13).tolist(),
+              2: rng.integers(2, CFG.vocab_size, 6).tolist()}
+    pool, last = _empty_pool(4, sink, fill), [1, 1, 1, 1]
+    for r, p in riders.items():
+        logits, pool = _prefill(params, p, -(-len(p) // PAGE) * PAGE, pool,
+                                table[r], r)
+        last[r] = int(jnp.argmax(logits))
+    prompt = rng.integers(2, CFG.vocab_size, n_prompt)
+    toks = np.full(n_chunks * C, 9, np.int32)   # the junk tail is not token 0
+    toks[:n_prompt] = prompt
+    step = jax.jit(functools.partial(nemotron_h.mixed_step, cfg=CFG))
+    alone = jax.jit(functools.partial(nemotron_h.paged_decode, cfg=CFG))
+    mixed, plain, seen = pool, pool, []
+    m_last = p_last = jnp.asarray(last, jnp.int32)
+    off = np.asarray([13, 0, 6, 0], np.int32)
+    none = jnp.zeros((4,), jnp.int32)
+    for index in range(n_chunks):
+        ends = index == n_chunks - 1
+        lengths = off + index * (off > 0)
+        if not idle:  # the fault: live at the positions its chunks filled
+            lengths[1], table[1] = index * C, mine[:width]
+        lengths = jnp.asarray(lengths)
+        chunk = (jnp.asarray(toks[index * C:(index + 1) * C]),
+                 jnp.asarray(mine),
+                 jnp.int32(n_prompt - 1 - index * C if ends else C - 1))
+        at = dict(chunk_index=jnp.int32(index), slot=jnp.int32(1))
+        # the chunk beside no live row: what its real positions alone count
+        _, _, own = step(params, mixed, *chunk, m_last, none, none,
+                         jnp.asarray(table), **at)
+        logits, mixed, counts = step(params, mixed, *chunk, m_last, lengths,
+                                     lengths, jnp.asarray(table), **at)
+        assert logits.shape == (5, CFG.vocab_size)
+        # no count under the decode program's names: its readers count
+        # token-steps of the decode program alone
+        assert set(counts) == MIXED_COUNTS
+        assert all(v.dtype == jnp.int32 and v.shape == ()
+                   for v in counts.values())
+        if idle:
+            ref, plain, c = alone(params, p_last, plain, lengths, lengths,
+                                  jnp.asarray(table))
+            np.testing.assert_allclose(logits[:4][np.asarray([0, 2])],
+                                       ref[np.asarray([0, 2])], atol=2e-4)
+            p_last = jnp.argmax(ref, axis=-1)
+            seen.append(({k: int(v) for k, v in counts.items()},
+                         int(c["expert_assignments_held"]),
+                         int(own["mixed_expert_assignments_held"])))
+        m_last = jnp.argmax(logits[:4], axis=-1)
+    want_logits, whole = _prefill(params, prompt, n_chunks * C,
+                                  _empty_pool(4, sink, fill), mine, 1)
+    return (mixed, logits[4], plain, want_logits, whole,
+            mine[:n_chunks * per], seen)
+
+
+@pytest.mark.parametrize("n_prompt,fill", [
+    (2, 3.0), (C + 1, 3.0), (50, 3.0), (2 * C, 3.0), (70, 3.0), (70, 0.0)],
+    ids=["under-the-taps", "one-past-a-chunk", "mid-chunk", "two-chunks",
+         "three-chunks", "three-chunks-clean-slot"])
+def test_chunks_leave_what_the_whole_prefill_leaves(params, n_prompt, fill):
+    """Prompts that end inside their last chunk (one of them shorter than the
+    convolution's ``ssm_conv - 1`` taps, one a single position into its
+    second chunk, so that its tail reaches back into the chunk before) and
+    one of whole chunks: the chunks leave the prompt's K and V in its pages,
+    the packed state, the tail and the last position's logits of
+    ``prefill_row``, **whether the slot held an earlier request's state and
+    tail or zeros**; the riders get ``paged_decode``'s logits and state, the
+    idle slot keeps its junk to the bit, and the counts are the mixed
+    step's own: the chunk's padding and the idle rows reach no expert."""
+    mixed, logits, plain, want_logits, whole, pages, seen = \
+        _chunked_beside_riders(params, n_prompt, fill=fill)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want_logits),
+                               atol=2e-4)
+    for name in ("k", "v"):  # the prompt's positions
+        got, want = (np.asarray(a[name][:, :, pages]).reshape(
+            1, 2, -1, 16)[:, :, :n_prompt] for a in (mixed, whole))
+        np.testing.assert_allclose(got, want, atol=2e-5)
+        assert np.abs(got).max() > 0
+    np.testing.assert_allclose(np.asarray(mixed["ssm"][:, 1]),
+                               np.asarray(whole["ssm"][:, 1]), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(mixed["conv"][:, :, 1]),
+                               np.asarray(whole["conv"][:, :, 1]), atol=1e-5)
+    for r in (0, 2):  # the riders moved as they move alone
+        np.testing.assert_allclose(np.asarray(mixed["ssm"][:, r]),
+                                   np.asarray(plain["ssm"][:, r]), atol=1e-4)
+        np.testing.assert_allclose(np.asarray(mixed["conv"][:, :, r]),
+                                   np.asarray(plain["conv"][:, :, r]),
+                                   atol=1e-5)
+    assert bool(jnp.all(mixed["ssm"][:, 3] == fill))
+    assert bool(jnp.all(mixed["conv"][:, :, 3] == fill))
+    for index, (c, riders_held, chunk_held) in enumerate(seen):
+        # two riders live: three Mamba layers' update kernel moved them,
+        # not the chunk's row; both expert layers ran
+        assert c["mixed_state_rows_stepped"] == 2 * 3
+        assert c["mixed_ssm_layer_steps"] == 3
+        assert c["mixed_expert_layer_steps"] == 2
+        # the riders' assignments are ``paged_decode``'s of the same tokens
+        # and the chunk's those of its real positions alone: 6 choices a
+        # position and layer of which a share is held; 30 rows of padding
+        # beside 2 real ones would pass that bound
+        real = min(C, n_prompt - index * C)
+        assert c["mixed_expert_assignments_held"] \
+            == riders_held + chunk_held
+        assert 0 < chunk_held <= real * 2 * 6
+        assert 0 < c["mixed_experts_touched"] <= 2 * 8
+
+
+@pytest.mark.parametrize("fault", ["none", "live_in_the_decode_half",
+                                   "carries_at_the_first_chunk_too"])
+def test_only_the_chunks_move_the_prefilling_rows_state(params, fault,
+                                                        monkeypatch):
+    """Three chunks of a row that is idle among the decode rows leave the
+    whole prefill's state. Were the row live there, the decode half would
+    move its state between its chunks; were a first chunk to start from the
+    slot's entry, it would carry on from an earlier request's: both faults
+    show, so the comparison can fail."""
+    if fault == "carries_at_the_first_chunk_too":
+        monkeypatch.setattr(nemotron_h, "_carried",
+                            lambda entry, first: entry)
+    mixed, _, _, _, whole, _, _ = _chunked_beside_riders(
+        params, 70, idle=fault != "live_in_the_decode_half")
+    gap = float(jnp.max(jnp.abs(mixed["ssm"][:, 1] - whole["ssm"][:, 1])))
+    assert (gap < 1e-4) if fault == "none" else (gap > 1e-3), gap
 
 
 # ------------------------------------------------------ through the engine

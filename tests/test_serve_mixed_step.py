@@ -11,8 +11,10 @@ engine's chunk path, serve/llm.py::ContinuousBatcher._iterate_mixed).
 (c) what an iteration costs the host: one readback, no key split, counts
     that add up, and an injected ``serve.admit`` fault that fails one
     request;
-(d) a model without ``mixed_step`` keeps the whole-prompt path (the hybrid
-    state-space model's chunk path is tests/test_hybrid_ssm.py's).
+(d) a model without ``mixed_step`` keeps the whole-prompt path, and the
+    layer-pattern model, which offers one, rides the chunk path through the
+    same engine (the hybrid state-space model's chunk path is
+    tests/test_hybrid_ssm.py's).
 """
 
 import threading
@@ -335,16 +337,15 @@ MOE = latent_moe.LatentMoEConfig(
     dtype=jnp.float32, param_dtype=jnp.float32)
 
 
-@pytest.mark.parametrize("cfg,model", [(PATTERN, nemotron_h),
-                                       (MOE, latent_moe)],
-                         ids=["nemotron_h", "latent_moe"])
-def test_a_model_without_mixed_step_prefills_whole(cfg, model):
-    """The engine chooses by what the model offers: these two offer no
-    ``mixed_step``, so their prompts go through ``_paged_prefill_fn`` and
+def test_a_model_without_mixed_step_prefills_whole():
+    """The engine chooses by what the model offers: the latent model offers
+    no ``mixed_step``, so its prompts go through ``_paged_prefill_fn`` and
     no mixed program is ever built."""
-    assert not hasattr(model, "mixed_step") and hasattr(gpt, "mixed_step")
-    eng = ContinuousBatcher(model.init_params(jax.random.PRNGKey(1), cfg),
-                            cfg, max_slots=2, max_new_tokens=6,
+    assert not hasattr(latent_moe, "mixed_step") \
+        and hasattr(gpt, "mixed_step")
+    eng = ContinuousBatcher(latent_moe.init_params(jax.random.PRNGKey(1),
+                                                   MOE),
+                            MOE, max_slots=2, max_new_tokens=6,
                             pad_multiple=16, steps_per_iter=K,
                             kv_page_tokens=16)
     try:
@@ -358,3 +359,50 @@ def test_a_model_without_mixed_step_prefills_whole(cfg, model):
     assert not hasattr(eng, "_mixed_step")
     assert st["mixed_steps"] == st["chunk_positions_live"] == 0
     assert st["prefill_positions"] == 32 and st["admitted"] == 1
+
+
+@pytest.fixture(scope="module")
+def pattern_engine():
+    params = nemotron_h.init_params(jax.random.PRNGKey(1), PATTERN)
+    eng = ContinuousBatcher(params, PATTERN, max_slots=3, max_new_tokens=12,
+                            pad_multiple=16, steps_per_iter=K,
+                            kv_page_tokens=16)
+    yield eng, params
+    eng.close()
+
+
+@pytest.mark.parametrize("lengths,budgets", [
+    ((3, 17, 40, 16), (5, 12, 1, 9)), ((70, 2, 33, 21, 9), (3, 8, 12, 2, 6))],
+    ids=["four-on-three-slots", "five-with-a-long-one"])
+def test_the_layer_pattern_model_rides_the_chunk_path(pattern_engine,
+                                                      lengths, budgets):
+    """A model whose layers are of kinds (Mamba-2, experts, attention) and
+    that offers ``mixed_step``: more requests than slots, prompts of one to
+    five chunks that end inside a chunk, budgets to, on and past an
+    iteration of K. Each answer is greedy decoding by the whole ``forward``,
+    every chunk was a mixed step, the counts come under the mixed step's own
+    names, and no prefill program was built."""
+    eng, params = pattern_engine
+    assert eng._mixed and eng._chunk == 16
+    rng = np.random.default_rng(sum(lengths))
+    prompts = [rng.integers(2, 512, n).tolist() for n in lengths]
+    before = _settled(eng, 0)
+    got = _together(eng, prompts, list(budgets))
+    after = _settled(eng, before["admitted"] + len(prompts))
+    for prompt, budget, out in zip(prompts, budgets, got):
+        seq = list(prompt)
+        for _ in range(budget):
+            logits = nemotron_h.forward(params, jnp.asarray([seq]),
+                                        PATTERN)[0, -1]
+            seq.append(int(jnp.argmax(logits)))
+        assert out == seq[len(prompt):], len(prompt)
+    chunks = sum(-(-n // 16) for n in lengths)
+    assert after["mixed_steps"] - before["mixed_steps"] == chunks
+    assert after["chunk_positions_live"] - before["chunk_positions_live"] \
+        == sum(lengths)
+    # two Mamba layers' update kernel and one expert layer a mixed step
+    assert after["mixed_ssm_layer_steps"] == 2 * after["mixed_steps"]
+    assert after["mixed_expert_layer_steps"] == after["mixed_steps"]
+    assert after["mixed_expert_assignments_held"] \
+        >= after["mixed_experts_touched"] > 0
+    assert not eng._prefill_cache and eng.kv_pool.pages_in_use == 0
