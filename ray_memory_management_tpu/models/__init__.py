@@ -37,13 +37,28 @@ def serving_model(cfg):
     engine adds counts up by name, and a mean a decode token-step stays
     the decode program's. The engine prefills in chunks that ride
     its decode steps where a model offers it, and whole prompts through
-    ``prefill_row`` where it does not."""
-    from . import gpt, hybrid_ssm, latent_moe, nemotron_h
+    ``prefill_row`` where it does not (a model that offers ``mixed_step``
+    is never asked for ``prefill_row``, but is still asked
+    ``prefill_takes_kernel`` for its chunk).
+
+    **A model with two paged arrays of different depth**
+    (models/latent_sparse_moe.py: a latent vector in every layer, an index
+    key in the layers that have an indexer) names both in ``cache_spec``,
+    each with its own leading dimension and width; what it owes the pool is
+    that **one page id means the same positions of the same row in every
+    array**, so that one block table, one reservation and one sink serve
+    both: it indexes each array by its own count of layers (entry ``j`` of
+    the shallower array is the ``j``-th layer of its kind), writes a
+    position into every array that holds it before any of its layers reads
+    it back, and never reads an array at a layer that does not keep one."""
+    from . import gpt, hybrid_ssm, latent_moe, latent_sparse_moe, nemotron_h
 
     for module, kind in ((gpt, gpt.TransformerConfig),
                          (latent_moe, latent_moe.LatentMoEConfig),
                          (hybrid_ssm, hybrid_ssm.HybridSSMConfig),
-                         (nemotron_h, nemotron_h.NemotronHConfig)):
+                         (nemotron_h, nemotron_h.NemotronHConfig),
+                         (latent_sparse_moe,
+                          latent_sparse_moe.LatentSparseMoEConfig)):
         if isinstance(cfg, kind):
             return module
     raise TypeError(f"no model serves a {type(cfg).__name__}")

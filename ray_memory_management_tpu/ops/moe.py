@@ -287,7 +287,7 @@ def grouped_experts(x, chosen, w, layer, live=None, first: int = 0,
         else:
             h = jnp.square(jax.nn.relu(grouped(xs, layer["w1"])))
         ys = grouped(h.astype(x.dtype), layer["w2"])      # [T * k, D'] f32
-        y = jnp.sum(ys[back].reshape(T, top_k, -1) * w[..., None], axis=1)
+        y = jnp.sum(_held(ys[back], flat, layer, first, T) * w[..., None], 1)
     return y.astype(x.dtype), sizes
 
 
@@ -450,3 +450,17 @@ def _tiled_experts(x, flat, order, back, sizes, layer, top_k, interpret):
     # where each assignment's row lies in the layout: as far behind its
     # expert's first tile as it is behind the expert's first sorted row
     return ys[(tile * first_tile - start)[jnp.minimum(flat, E - 1)] + back]
+
+
+def _held(ys, flat, layer, first, T):
+    """The grouped matmul's rows [T * k, D'] as [T, k, D']. With a share of
+    the experts, a row whose expert is not held (``flat`` says ``E``) lay
+    past every group and came back as junk, and most of a live token's rows
+    are such: they read 0 here, since junk times a weight of 0 need not be 0.
+    With every expert held the rows are handed on as they are (the program of
+    a model that holds them all does not change). At the end of the file so
+    that no kernel above moves."""
+    E = layer["w1"].shape[0]
+    if first or E != layer["router"].shape[-1]:
+        ys = jnp.where((flat < E)[:, None], ys, 0.0)
+    return ys.reshape(T, -1, ys.shape[-1])

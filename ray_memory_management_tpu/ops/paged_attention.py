@@ -388,3 +388,417 @@ def latent_attention(q, pages, lengths, page_indices, cur, *, layer=0,
     return _latent_attention_pallas(q, pages, lengths, page_indices, cur,
                                     layer, value_width, scale,
                                     interpret=(use_pallas == "interpret"))
+
+
+# ------------------------------------------- latent pages under a selection
+# (Everything from here on was appended below the kernels above, and the
+# module's docstring left as it was: a kernel's place in this file is part of
+# its compiled program's cache key.)
+# A learned indexer (DeepSeek-V3.2's sparse attention, GLM-5.2) lets a query
+# attend only the ``top_k`` cached positions whose index score is largest.
+# Three steps, each a plain ``jax.numpy`` form (the contract, the CPU's path
+# and the kernels' oracle) and a Pallas kernel of the same name:
+#
+#   index_scores             I[t, s] = sum_j w[t, j] relu(q[t, j] . k[s]), s <= t
+#   index_select             the top_k largest a query, ties to the lower
+#                            position, as an additive mask (0 / _NEG_INF)
+#   sparse_latent_attention  absorbed latent attention under that mask
+#
+# Queries come in groups that share a row of the block table and lie at
+# consecutive positions: a chunk of a prompt is one group of C queries, a
+# decode step B groups of one. Every step reads the pool only: a query's own
+# key and vector are written before it scores and attends.
+#
+# The plain forms do what the words say (``lax.top_k``, then the selected
+# rows gathered from the pages). The kernels compute the same numbers another
+# way, because a TPU gathers 1,280-byte rows at a fraction of its bandwidth
+# and sorts slowly: the selection is a threshold (the k-th largest score by a
+# search over the bits of the float, ties cut at a position by a second
+# search), and the attention walks the row's pages in order with the mask
+# added to the scores, skipping the pages past the group's last position.
+_SELECT_ROWS = 16       # queries a tile of the selection kernel (a bf16 tile)
+_ATTEND_QUERIES = 16    # queries a tile of the attention kernel
+_KEY_BLOCK = 1024       # cached positions a grid step of either walk
+
+
+def index_scores_reference(q, w, pages, page_indices, positions, *, layer=0):
+    """Plain jnp reading of the block table; see :func:`index_scores`."""
+    G, T = q.shape[0], page_indices.shape[1] * pages.shape[2]
+    keys = pages[layer][page_indices].reshape(G, T, q.shape[-1])
+    s = jnp.einsum("gnjd,gtd->gnjt", q, keys,
+                   preferred_element_type=jnp.float32)
+    s = jnp.sum(jax.nn.relu(s) * w[..., None], axis=2)
+    return jnp.where(jnp.arange(T) <= positions[..., None], s, _NEG_INF)
+
+
+def _index_scores_kernel(table_ref, first_ref, layer_ref, q_ref, w_ref, k_ref,
+                         o_ref, *, bq, tk, heads):
+    import jax.experimental.pallas as pl
+
+    g, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    p0 = first_ref[g] + qi * bq               # the tile's first query
+    seen = ki * tk <= p0 + bq - 1             # a key no query is behind: none
+
+    @pl.when(seen)
+    def _a_block_some_query_sees():
+        keys = k_ref[...]                                    # [tk, Di]
+        acc = jnp.zeros((bq, tk), jnp.float32)
+        for j in range(heads):
+            s = lax.dot_general(q_ref[j], keys, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            acc = acc + jnp.maximum(s, 0.0) * w_ref[:, j:j + 1]
+        at_q = p0 + lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+        at_k = ki * tk + lax.broadcasted_iota(jnp.int32, acc.shape, 1)
+        o_ref[...] = jnp.where(at_k <= at_q, acc, _NEG_INF)
+
+    @pl.when(jnp.logical_not(seen))
+    def _a_block_past_every_query():
+        o_ref[...] = jnp.full(o_ref.shape, _NEG_INF, jnp.float32)
+
+
+def _walk(page, width, tk, bq):
+    """Index maps of a walk over a row's pages in blocks of ``tk`` positions:
+    ``at(g, qi, ki, table, first)`` -> (page id, block inside the page) of
+    the ``ki``-th block, held at the last block the tile of queries sees (a
+    block past it is skipped, and is not fetched either)."""
+    per = page // tk
+
+    def at(g, qi, ki, table, first):
+        last = (first[g] + (qi + 1) * bq - 1) // tk
+        k = jnp.minimum(ki, last)
+        return table[g * width + k // per], k % per
+
+    return at
+
+
+def _index_scores_pallas(q, w, pages, page_indices, positions, layer,
+                         interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    G, n, J, Di = q.shape
+    page, width = pages.shape[2], page_indices.shape[1]
+    T, tk, bq = width * page, min(_KEY_BLOCK, page), min(256, n)
+    at = _walk(page, width, tk, bq)
+
+    def keys_at(g, qi, ki, table, first, layer):
+        pid, sub = at(g, qi, ki, table, first)
+        return layer[0], pid, sub, 0
+
+    return pl.pallas_call(
+        functools.partial(_index_scores_kernel, bq=bq, tk=tk, heads=J),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(G, n // bq, T // tk),
+            in_specs=[
+                pl.BlockSpec((None, J, bq, Di),
+                             lambda g, qi, ki, *_: (g, 0, qi, 0)),
+                pl.BlockSpec((None, bq, J),
+                             lambda g, qi, ki, *_: (g, qi, 0)),
+                pl.BlockSpec((None, None, tk, Di), keys_at)],
+            out_specs=pl.BlockSpec((None, bq, tk),
+                                   lambda g, qi, ki, *_: (g, qi, ki))),
+        out_shape=jax.ShapeDtypeStruct((G, n, T), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+        name="index_scores",
+    )(page_indices.reshape(-1).astype(jnp.int32),
+      positions[:, 0].astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      q.transpose(0, 2, 1, 3), w.astype(jnp.float32), pages)
+
+
+def index_kernel_takes(q, pages) -> bool:
+    """Can the compiled scoring kernel tile these shapes on a TPU? A group
+    of whole tiles of queries (a chunk: a decode row's one query takes the
+    plain form, which is a few small matmuls), an index key that fills the
+    lanes, a page of whole key blocks."""
+    page = pages.shape[2]
+    return (q.shape[1] % 256 == 0 and q.shape[-1] % 128 == 0
+            and page % min(_KEY_BLOCK, page) == 0 and page % 128 == 0
+            and q.dtype == pages.dtype)
+
+
+def index_scores(q, w, pages, page_indices, positions, *, layer=0,
+                 use_pallas: Optional[str] = None):
+    """The indexer's score of every cached position a query may attend.
+
+    ``q`` [G, n, J, Di]: ``G`` groups of ``n`` queries, ``J`` index heads;
+    ``w`` float32 [G, n, J], the heads' weights; ``pages`` [Lf, P,
+    page_tokens, Di], the paged index keys (one key a token), of which
+    ``layer`` is read where it lies; ``page_indices`` int32 [G,
+    pages_per_row], a row of the block table a group; ``positions`` int32
+    [G, n], each query's own position, **consecutive inside a group**: a
+    query scores the positions ``s <= positions[g, i]`` of its group's row,
+    its own among them (its key is in the pool already). Returns float32
+    [G, n, pages_per_row * page_tokens]: ``sum_j w[j] * relu(q[j] . k[s])``
+    accumulated in float32, ``_NEG_INF`` past the query's position.
+    ``use_pallas`` as :func:`paged_attention`'s."""
+    if use_pallas is None:
+        use_pallas = "on" if _on_tpu() and index_kernel_takes(q, pages) \
+            else "off"
+    if use_pallas == "off":
+        return index_scores_reference(q, w, pages, page_indices, positions,
+                                      layer=layer)
+    return _index_scores_pallas(q, w, pages, page_indices, positions, layer,
+                                interpret=(use_pallas == "interpret"))
+
+
+def index_select_reference(scores, top_k: int):
+    """Plain jnp selection; see :func:`index_select`."""
+    T = scores.shape[-1]
+    flat = scores.reshape(-1, T)
+    _, idx = lax.top_k(flat, min(top_k, T))     # ties: the lower index first
+    hit = jnp.zeros(flat.shape, bool).at[
+        jnp.arange(flat.shape[0])[:, None], idx].set(True)
+    hit = hit & (flat > _NEG_INF / 2)           # fewer than top_k to choose
+    return jnp.where(hit, 0.0, _NEG_INF).astype(jnp.bfloat16).reshape(
+        scores.shape)
+
+
+def _index_select_kernel(s_ref, o_ref, *, top_k):
+    s = s_ref[...]                                           # [rows, T]
+    T = s.shape[-1]
+    bits = lax.bitcast_convert_type(s, jnp.int32)
+    # the floats' order in signed integers
+    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    k = jnp.float32(top_k)
+
+    def count(mask):
+        return jnp.sum(mask.astype(jnp.float32), axis=-1, keepdims=True)
+
+    # the k-th largest key, bit by bit: the largest ``lo`` that at least k
+    # keys reach
+    lo = jnp.where(count(key >= 0) >= k, jnp.int32(0),
+                   jnp.int32(-2 ** 31))
+
+    def value_bit(i, lo):
+        cand = lo + jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(count(key >= cand) >= k, cand, lo)
+
+    lo = lax.fori_loop(0, 31, value_bit, lo)
+    above, tie = key > lo, key == lo
+    # of the keys that tie at the threshold, those at the lowest positions
+    # fill what is left: the position of the last of them, bit by bit
+    left = k - count(above)                                  # at least 1
+    at = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    n_bits = max(1, (T - 1).bit_length())
+
+    def place_bit(i, last):
+        cand = last | jnp.left_shift(jnp.int32(1), n_bits - 1 - i)
+        return jnp.where(count(tie & (at < cand)) <= left - 1.0, cand, last)
+
+    last = lax.fori_loop(0, n_bits, place_bit, jnp.zeros_like(lo))
+    hit = (above | (tie & (at <= last))) & (s > _NEG_INF / 2)
+    o_ref[...] = jnp.where(hit, 0.0, _NEG_INF).astype(o_ref.dtype)
+
+
+def _index_select_pallas(scores, top_k, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    T = scores.shape[-1]
+    flat = scores.reshape(-1, T)
+    N = flat.shape[0]
+    rows = -(-N // _SELECT_ROWS) * _SELECT_ROWS
+    out = pl.pallas_call(
+        functools.partial(_index_select_kernel, top_k=top_k),
+        grid=(rows // _SELECT_ROWS,),
+        in_specs=[pl.BlockSpec((_SELECT_ROWS, T), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((_SELECT_ROWS, T), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, T), jnp.bfloat16),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+        name="index_select",
+    )(jnp.pad(flat, ((0, rows - N), (0, 0)), constant_values=_NEG_INF))
+    return out[:N].reshape(scores.shape)
+
+
+def index_select(scores, top_k: int, *, use_pallas: Optional[str] = None):
+    """The ``top_k`` positions of largest score a query, as a mask to add to
+    attention scores: ``scores`` float32 [..., T] (``_NEG_INF`` where a query
+    may not look) -> bfloat16 [..., T], 0 at the selected positions and
+    ``_NEG_INF`` elsewhere. A query with no more than ``top_k`` positions to
+    choose from selects them all; **ties go to the lower position**
+    (``lax.top_k``'s order), so the selection is a function of the scores.
+    ``use_pallas`` as :func:`paged_attention`'s: None = the kernel on a TPU
+    for a row of whole lanes."""
+    if use_pallas is None:
+        use_pallas = "on" if _on_tpu() and scores.shape[-1] % 128 == 0 \
+            else "off"
+    if use_pallas == "off":
+        return index_select_reference(scores, top_k)
+    return _index_select_pallas(scores, top_k,
+                                interpret=(use_pallas == "interpret"))
+
+
+def sparse_latent_attention_reference(q, pages, page_indices, mask, *,
+                                      layer=0, top_k: int, value_width: int,
+                                      scale: float):
+    """Plain jnp form: the selected rows gathered from the pages; see
+    :func:`sparse_latent_attention`."""
+    G, n, H, W = q.shape
+    page, T = pages.shape[2], mask.shape[-1]
+    k, block = min(top_k, T), min(n, 64)
+
+    def attend(args):
+        qb, mb = args                             # [G, b, H, W], [G, b, T]
+        # the selected positions first, the lower first
+        held, idx = lax.top_k(mb.astype(jnp.float32), k)     # [G, b, k]
+        pid = jnp.take_along_axis(page_indices[:, None, :], idx // page, 2)
+        rows = pages[layer, pid, idx % page]                 # [G, b, k, W]
+        s = jnp.einsum("gbhw,gbkw->gbhk", qb, rows,
+                       preferred_element_type=jnp.float32) * scale
+        p = jax.nn.softmax(s + held[:, :, None, :], axis=-1)
+        return jnp.einsum("gbhk,gbkv->gbhv", p.astype(rows.dtype),
+                          rows[..., :value_width]).astype(q.dtype)
+
+    pad = (-n) % block
+    qs = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    ms = jnp.pad(mask, ((0, 0), (0, pad), (0, 0)))
+    ms = ms.at[:, n:, 0].set(0)                  # a padded query sees one
+    nb = (n + pad) // block
+    out = lax.map(attend, (
+        qs.reshape(G, nb, block, H, W).transpose(1, 0, 2, 3, 4),
+        ms.reshape(G, nb, block, T).transpose(1, 0, 2, 3)))
+    return out.transpose(1, 0, 2, 3, 4).reshape(G, n + pad, H,
+                                                value_width)[:, :n]
+
+
+def _sparse_latent_kernel(table_ref, first_ref, layer_ref, q_ref, mask_ref,
+                          kv_ref, o_ref, m_ref, l_ref, acc_ref, *, bq, tk,
+                          pair, value_width, scale):
+    import jax.experimental.pallas as pl
+
+    g, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _first_block():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(ki * tk <= first_ref[g] + (qi + 1) * bq - 1)
+    def _a_block_some_query_sees():
+        kv = kv_ref[...]                          # [tk, W]: key and value
+        H = q_ref.shape[1]
+        # ``pair`` queries a matmul: their heads fill the MXU's rows
+        for i in range(0, bq, pair):
+            rows = pair * H
+            q = q_ref[i:i + pair].reshape(rows, q_ref.shape[2])
+            s = lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            mask = mask_ref[i:i + 1, :].astype(jnp.float32)
+            if pair == 2:   # each query's own row of the mask
+                second = lax.broadcasted_iota(jnp.int32, s.shape, 0) >= H
+                mask = jnp.where(
+                    second, mask_ref[i + 1:i + 2, :].astype(jnp.float32),
+                    mask)
+            s = s + mask                                       # [rows, tk]
+            m = m_ref[i:i + pair].reshape(rows, 1)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[i:i + pair] = (
+                alpha * l_ref[i:i + pair].reshape(rows, 1)
+                + jnp.sum(p, axis=-1, keepdims=True)).reshape(pair, H, 1)
+            acc_ref[i:i + pair] = (
+                alpha * acc_ref[i:i + pair].reshape(rows, value_width)
+                + jnp.dot(p.astype(kv.dtype), kv[:, :value_width],
+                          preferred_element_type=jnp.float32)).reshape(
+                              pair, H, value_width)
+            m_ref[i:i + pair] = m_new.reshape(pair, H, 1)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _last_block():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
+            o_ref.dtype)
+
+
+def _sparse_latent_pallas(q, pages, page_indices, mask, positions, layer,
+                          value_width, scale, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    G, n, H, W = q.shape
+    page, width = pages.shape[2], page_indices.shape[1]
+    T, tk, bq = width * page, min(_KEY_BLOCK, page), min(_ATTEND_QUERIES, n)
+    at = _walk(page, width, tk, bq)
+
+    def kv_at(g, qi, ki, table, first, layer):
+        pid, sub = at(g, qi, ki, table, first)
+        return layer[0], pid, sub, 0
+
+    def mask_at(g, qi, ki, table, first, layer):
+        return g, qi, jnp.minimum(ki, (first[g] + (qi + 1) * bq - 1) // tk)
+
+    return pl.pallas_call(
+        functools.partial(_sparse_latent_kernel, bq=bq, tk=tk,
+                          pair=2 if bq % 2 == 0 else 1,
+                          value_width=value_width, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(G, n // bq, T // tk),
+            in_specs=[
+                pl.BlockSpec((None, bq, H, W),
+                             lambda g, qi, ki, *_: (g, qi, 0, 0)),
+                pl.BlockSpec((None, bq, tk), mask_at),
+                pl.BlockSpec((None, None, tk, W), kv_at)],
+            out_specs=pl.BlockSpec((None, bq, H, value_width),
+                                   lambda g, qi, ki, *_: (g, qi, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((bq, H, 1), jnp.float32),
+                            pltpu.VMEM((bq, H, 1), jnp.float32),
+                            pltpu.VMEM((bq, H, value_width), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((G, n, H, value_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+        name="sparse_latent_attention",
+    )(page_indices.reshape(-1).astype(jnp.int32),
+      positions[:, 0].astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, mask, pages)
+
+
+def sparse_kernel_takes(q, pages, value_width: int) -> bool:
+    """Can the compiled kernel tile these shapes on a TPU? The latent
+    kernel's conditions, heads in whole sublane tiles, and a group of one
+    query (a decode row) or of whole tiles of queries (a chunk)."""
+    n, page = q.shape[1], pages.shape[2]
+    return (latent_kernel_takes(q, pages, value_width)
+            and q.shape[2] % _GROUP_ROWS == 0
+            and (n == 1 or n % _ATTEND_QUERIES == 0)
+            and page % min(_KEY_BLOCK, page) == 0 and page % 128 == 0)
+
+
+def sparse_latent_attention(q, pages, page_indices, mask, positions, *,
+                            layer=0, top_k: int, value_width: int,
+                            scale: float, use_pallas: Optional[str] = None):
+    """Absorbed latent attention over the selected positions of a row.
+
+    ``q`` [G, n, H, W]: every head's absorbed query at the cached vector's
+    width, ``G`` groups of ``n`` queries; ``pages`` [L, P, page_tokens, W],
+    of which ``layer`` is read where it lies; ``page_indices`` int32 [G,
+    pages_per_row]; ``mask`` bfloat16 [G, n, pages_per_row * page_tokens],
+    :func:`index_select`'s: 0 at the at most ``top_k`` positions a query
+    attends (its own among them or not, as the indexer chose; the vector of
+    a query's own position is in the pool already), ``_NEG_INF`` elsewhere;
+    ``positions`` int32 [G, n], consecutive inside a group, past which no
+    query of the group has a selected position (the kernel stops there).
+    A selected position is key (all ``W`` columns) and value (the first
+    ``value_width``) of all ``H`` heads. Returns [G, n, H, value_width] in
+    ``q``'s dtype. ``use_pallas`` as :func:`paged_attention`'s."""
+    if use_pallas is None:
+        use_pallas = "on" if _on_tpu() and sparse_kernel_takes(
+            q, pages, value_width) else "off"
+    if use_pallas == "off":
+        return sparse_latent_attention_reference(
+            q, pages, page_indices, mask, layer=layer, top_k=top_k,
+            value_width=value_width, scale=scale)
+    return _sparse_latent_pallas(q, pages, page_indices, mask, positions,
+                                 layer, value_width, scale,
+                                 interpret=(use_pallas == "interpret"))

@@ -4,8 +4,17 @@ The cache of a replica is the device arrays its model's cache specification
 names (``models.serving_model(cfg).cache_spec(cfg)``: name -> dims before the
 pages, dims after a page's positions, dtype): for the dense decoder K and V
 of shape ``[L, Hkv, P, page_tokens, Dh]``, for the latent-attention model one
-array ``[L, P, page_tokens, cache_width]``; ``P - 1`` pages that requests
-reserve and one sink. They are allocated once (:meth:`KVPagePool.allocate`,
+array ``[L, P, page_tokens, cache_width]``, for the model that attends under
+a learned selection two arrays of different depth, ``latent`` ``[L, P,
+page_tokens, cache_width]`` and ``index`` ``[L_full, P, page_tokens,
+index_head_dim]`` (models/latent_sparse_moe.py); ``P - 1`` pages that requests
+reserve and one sink. **Every array has the same pages**: the pool sums the
+specification's arrays into one ``token_bytes`` (a layer that keeps nothing
+in an array adds nothing to it), sizes ``capacity_pages`` from that sum,
+allocates each array with its own leading dimensions over the same ``P`` page
+ids, and hands out a page id once, for all of them; the block table, the
+reservation, the sink and ``pages_in_use`` know nothing of how many arrays
+there are. They are allocated once (:meth:`KVPagePool.allocate`,
 by the engine thread before its first admission) and stay where they are:
 prefill scatters a prompt's cache into the row's pages, the decode step
 writes one position a live row and attends through the block table
