@@ -20,9 +20,10 @@ A layer, with RMSNorm before each half and a residual around it:
     are no more; ties to the lower position);
   - a ``shared`` layer has no indexer and no index key: it attends the
     selection of the nearest ``full`` layer below it;
-  - *attention* in absorbed form over the selected positions only
-    (ops/paged_attention.py: ``index_scores``, ``index_select``,
-    ``sparse_latent_attention``);
+  - *attention* over the selected positions only (ops/paged_attention.py:
+    ``index_scores``, ``index_select``, then ``sparse_latent_attention`` or
+    ``sparse_expanded_attention``: one attention in two algebraic forms,
+    below);
   - a SwiGLU MLP in the first ``first_k_dense`` layers; after them the
     router over all ``n_routed_experts`` (sigmoid, a choosing bias, top
     ``experts_per_tok``) beside one shared expert. **This chip holds a share
@@ -39,10 +40,27 @@ kind) is entry ``j`` of ``index``. One page id is a page of both.
 **One mechanism serves prompt and answer.** A chunk's query does what a
 decode row's does: its own vector and index key go into the pool first, then
 it scores the row's cached index keys up to its own position, selects, and
-attends absorbed over the selection. So there is no prefill program: the
+attends over the selection. So there is no prefill program: the
 engine (serve/llm.py) finds :func:`mixed_step` by name and sends every prompt
 through it in chunks, beside the decode rows' tokens; :func:`paged_decode` is
 the same walk without a chunk.
+
+**A decode row attends absorbed, a chunk expanded.** ``(q W_k) . c`` is ``q .
+(W_k c)``: the absorbed form carries every head's query to the cached vector's
+width and pays ``2 * cache_width`` FLOPs for a (query, position, head)'s score
+and ``2 * kv_lora_rank`` for its value (2,304 at GLM-5.2's widths); the
+expanded form makes a position's key and value of every head from ``c_kv``
+first (``2 * kv_lora_rank * H * (qk_nope_head_dim + v_head_dim)`` FLOPs, 29.4 M,
+once) and then pays ``2 * qk_head_dim + 2 * v_head_dim`` (1,024). A decode
+row's one query cannot share its row's expansion with anyone and stays
+absorbed; the ``n`` queries of a chunk share one row of the table, and from
+:func:`_expanded_pays` on (``n * H * 2 * 640 > 2 * 512 * H * 448``: 359
+queries) the expanded form is the cheaper, 2.1 times at a chunk of 4,096. The
+group's size chooses, not a switch; the same bf16 operands and float32 sums
+either way. **Both kernels are called ``sparse_latent_attention`` in the
+trace**: the benchmark's readers count the traced steps from the calls under
+that name (two a layer in a mixed step, one in a decode step) and divide the
+chunks' and the rows' work by the seconds under it.
 """
 
 from __future__ import annotations
@@ -55,7 +73,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops import moe
-from ..ops.paged_attention import (index_scores, index_select,
+from ..ops.paged_attention import (expanded_form_runs, index_scores,
+                                   index_select, sparse_expanded_attention,
                                    sparse_latent_attention)
 from .latent_moe import (_LANES, _cached, _head, _kv_b_halves, _mm, _rmsnorm,
                          _rope, _swiglu)
@@ -186,10 +205,9 @@ def init_params(key, cfg: LatentSparseMoEConfig) -> Dict[str, Any]:
 # ------------------------------------------------------------------- pieces
 def _project(h, p, positions, cfg: LatentSparseMoEConfig):
     """h [T, D] at ``positions`` [T] -> (c_q [T, q_lora_rank] after its
-    norm, which the indexer reads too; every head's absorbed query [T, H,
-    cache_width]; the vector the cache holds [T, cache_width]): what
-    models/latent_moe.py::_attend_absorbed makes of a token, the query's
-    bottleneck kept."""
+    norm, which the indexer reads too; every head's plain query [T, H,
+    qk_head_dim], ``q_nope`` beside the rotated ``q_rope``; the vector the
+    cache holds [T, cache_width])."""
     T, H, kl = h.shape[0], cfg.n_heads, cfg.kv_lora_rank
     c_q = _rmsnorm(_mm(h, p["q_a"], cfg), p["q_ln"], cfg.rms_norm_eps)
     q = _mm(c_q, p["q_b"], cfg).reshape(T, H, cfg.qk_head_dim)
@@ -197,13 +215,35 @@ def _project(h, p, positions, cfg: LatentSparseMoEConfig):
     kv = _mm(h, p["kv_a"], cfg)
     cached = _cached(_rmsnorm(kv[:, :kl], p["kv_ln"], cfg.rms_norm_eps),
                      _rope(kv[:, kl:], positions, cfg.rope_theta), cfg)
-    to_k, _ = _kv_b_halves(p, cfg)
+    q = jnp.concatenate(
+        [q_nope, _rope(q_rope, positions, cfg.rope_theta)], -1)
+    return c_q, q, cached
+
+
+def _absorbed(q, to_k, cfg: LatentSparseMoEConfig):
+    """Plain queries [..., H, qk_head_dim] -> every head's absorbed query
+    [..., H, cache_width]: ``q_nope`` carried through ``to_k`` to the
+    latent's width, what models/latent_moe.py::_attend_absorbed makes of a
+    token."""
     pad = cfg.cache_width - cfg.latent_width
-    q_abs = jnp.pad(jnp.concatenate(
-        [jnp.einsum("thn,lhn->thl", q_nope, to_k),
-         _rope(q_rope, positions, cfg.rope_theta)], -1),
-        ((0, 0), (0, 0), (0, pad)))
-    return c_q, q_abs, cached
+    return jnp.pad(jnp.concatenate(
+        [jnp.einsum("...hn,lhn->...hl", q[..., :cfg.qk_nope_head_dim], to_k),
+         q[..., cfg.qk_nope_head_dim:]], -1),
+        ((0, 0),) * (q.ndim - 1) + ((0, pad),))
+
+
+def _expanded_pays(cfg: LatentSparseMoEConfig, n: int) -> bool:
+    """Whether a group of ``n`` queries on one row of the table attends
+    cheaper in the expanded form, by the configuration's own widths: what
+    ``n`` queries save on a cached position (absorbed: the cached width for a
+    head's score and ``kv_lora_rank`` for its value; expanded: ``qk_head_dim``
+    and ``v_head_dim``) against what expanding that position for every head
+    costs. At GLM-5.2's widths 640 n against 229,376: from 359 queries on."""
+    saved = n * cfg.n_heads * 2 * (
+        (cfg.cache_width + cfg.kv_lora_rank)
+        - (cfg.qk_head_dim + cfg.v_head_dim))
+    return saved > 2 * cfg.kv_lora_rank * cfg.n_heads * (
+        cfg.qk_nope_head_dim + cfg.v_head_dim)
 
 
 def _partly_rotary(x, positions, cfg: LatentSparseMoEConfig):
@@ -285,14 +325,14 @@ def _walk(params, pool, tokens, positions, routed, parts,
     [T, D] before the final norm, the pool, expert_tokens [n_held_experts],
     experts touched summed over the expert layers)."""
     scale = cfg.qk_head_dim ** -0.5
-    H, W = cfg.n_heads, cfg.cache_width
+    H, vd = cfg.n_heads, cfg.v_head_dim
     latent, index = pool["latent"], pool["index"]
     x = params["tok_embed"][tokens].astype(cfg.dtype)
     expert_tokens = jnp.zeros((cfg.n_held_experts,), jnp.int32)
     touched, n_full, masks = jnp.int32(0), 0, None
     for i, layer in enumerate(params["layers"]):
         h = _rmsnorm(x, layer["ln"], cfg.rms_norm_eps)
-        c_q, q_abs, cached = _project(h, layer, positions, cfg)
+        c_q, q, cached = _project(h, layer, positions, cfg)
         with jax.named_scope("latent_kv_write"):
             for rows, _, _, put in parts:
                 latent = put(latent, i, cached[rows])
@@ -311,17 +351,28 @@ def _walk(params, pool, tokens, positions, routed, parts,
                 with jax.named_scope("index_select"):
                     masks.append(index_select(scores, cfg.index_topk))
             n_full += 1
+        to_k, to_v = _kv_b_halves(layer, cfg)
         outs = []
         for (rows, (G, n), table, _), mask in zip(parts, masks):
+            at = positions[rows].reshape(G, n)
+            mine = q[rows].reshape(G, n, H, cfg.qk_head_dim)
+            # one attention in two algebraic forms, and the group's size
+            # says which is cheaper: many queries on one row of the table
+            # share the row's expansion, a decode row's one query does not
             with jax.named_scope("sparse_latent_attention"):
-                outs.append(sparse_latent_attention(
-                    q_abs[rows].reshape(G, n, H, W), latent, table, mask,
-                    positions[rows].reshape(G, n), layer=i,
-                    top_k=cfg.index_topk, value_width=cfg.kv_lora_rank,
-                    scale=scale).reshape(G * n, H, cfg.kv_lora_rank))
-        _, to_v = _kv_b_halves(layer, cfg)
-        o = jnp.einsum("thl,lhv->thv", jnp.concatenate(outs), to_v)
-        x = x + _mm(o.reshape(o.shape[0], -1), layer["o"], cfg)
+                if _expanded_pays(cfg, n) and expanded_form_runs(
+                        mine, latent, to_k, to_v):
+                    o = sparse_expanded_attention(
+                        mine, latent, table, mask, at, to_k, to_v, layer=i,
+                        scale=scale)
+                else:
+                    o = jnp.einsum("gnhl,lhv->gnhv", sparse_latent_attention(
+                        _absorbed(mine, to_k, cfg), latent, table, mask, at,
+                        layer=i, top_k=cfg.index_topk,
+                        value_width=cfg.kv_lora_rank, scale=scale), to_v)
+            outs.append(o.reshape(G * n, H * vd))
+        o = jnp.concatenate(outs)
+        x = x + _mm(o, layer["o"], cfg)
         # the next layer writes the pool only once this one has read it:
         # without the barrier nothing orders the two, and the compiler
         # copies the pool to be safe
@@ -439,9 +490,10 @@ def mixed_step(params, pool, chunk_tokens, chunk_pages, chunk_last, tokens,
     rows' to their positions (in a ``full`` layer the index keys too); then
     the chunk, one group of ``C`` queries on the prompt's row of the table,
     and the decode rows, a group of one each, score the cached index keys up
-    to their own position, select and attend absorbed (the chunk's padding
-    beyond ``chunk_last`` computes what nobody reads, and writes what the
-    row's first decode tokens overwrite). ``chunk_index`` is a run-time
+    to their own position, select and attend: the rows absorbed, the chunk
+    expanded where its size pays for it (the module's header; the chunk's
+    padding beyond ``chunk_last`` computes what nobody reads, and writes what
+    the row's first decode tokens overwrite). ``chunk_index`` is a run-time
     int32: ONE program. The experts run once over the chunk's real positions
     and the live rows together.
 

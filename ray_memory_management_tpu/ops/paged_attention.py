@@ -402,8 +402,8 @@ def latent_attention(q, pages, lengths, page_indices, cur, *, layer=0,
 #   index_scores             I[t, s] = sum_j w[t, j] relu(q[t, j] . k[s]), s <= t
 #   index_select             the top_k largest a query, ties to the lower
 #                            position, as an additive mask (0 / _NEG_INF)
-#   sparse_latent_attention  absorbed latent attention under that mask
-#
+#   sparse_latent_attention  latent attention under that mask: absorbed for
+#                            a decode row, expanded for a chunk (last section)
 # Queries come in groups that share a row of the block table and lie at
 # consecutive positions: a chunk of a prompt is one group of C queries, a
 # decode step B groups of one. Every step reads the pool only: a query's own
@@ -802,3 +802,247 @@ def sparse_latent_attention(q, pages, page_indices, mask, positions, *,
     return _sparse_latent_pallas(q, pages, page_indices, mask, positions,
                                  layer, value_width, scale,
                                  interpret=(use_pallas == "interpret"))
+
+
+# -------------------------------- a chunk under a selection: the expanded form
+# (Appended below the last kernel, as the section above was.)
+# ``sparse_latent_attention`` above is the *absorbed* form: every head's query
+# is carried to the cached vector's width, so a (query, key, head) triple
+# costs 2 * W FLOPs for its score and 2 * kv_lora_rank for its value: 2,304 at
+# GLM-5.2's widths (W 640, rank 512). The *expanded* form makes a cached
+# position's key and value of every head from ``c_kv`` first (``k_nope = c_kv
+# @ to_k[h]``, ``v = c_kv @ to_v[h]``) and pays 2 * qk_head_dim + 2 *
+# v_head_dim a triple: 1,024 there. It is the same product in another order,
+# ``(q W_k) . c = q . (W_k c)``, in the same bf16 operands with float32
+# accumulation. Expanding a position costs 2 * rank * H * (nope + v_head_dim)
+# FLOPs once (29.4 M), and a group of ``n`` queries on one row of the table
+# shares it: it pays when ``n * H * 2 * ((W + rank) - (qk_head_dim +
+# v_head_dim))`` exceeds that, from about 360 queries on. So a chunk of a
+# prompt attends expanded (:func:`sparse_expanded_attention`) and a decode
+# row, a group of one, stays absorbed; the caller chooses by the group's
+# size (models/latent_sparse_moe.py::_expanded_pays), never by a switch.
+#
+# The kernel keeps the expansion in VMEM: keys and values of all heads for a
+# row of 32,768 positions would be 2.1 GB in HBM. Grid (group, head group,
+# key block, query tile): at the first query tile of a (head group, key
+# block) the block's keys and values of the group's heads are made into
+# scratch, and every tile of the chunk's queries that can see the block
+# attends it there; the online softmax's state of *all* the group's queries
+# for those heads stays in VMEM scratch while the key blocks pass (4 heads x
+# 4,096 queries: 16 MB of accumulators, 16 MB of maxima and sums), and the
+# values leave at the chunk's last block. Blocks past the chunk's last
+# position are neither fetched nor computed, and a tile skips the blocks
+# ahead of it.
+#
+# **Both kernels are called ``sparse_latent_attention`` in the trace.** The
+# benchmark's readers count the traced steps from the calls under that name
+# (two a layer in a mixed step, the chunk and the rows; one in a decode step)
+# and sum the seconds under it: a chunk's kernel under a name of its own
+# would leave the rows' seconds alone under the chunks' work.
+_EXPAND_QUERIES = 1024  # queries a grid step of the expanded kernel
+_EXPAND_ROWS = 512      # of them a matmul
+_EXPAND_HEADS = 4       # heads whose expansion and softmax state share VMEM
+_EXPAND_VMEM = 100 << 20
+
+
+def sparse_expanded_attention_reference(q, pages, page_indices, mask, to_k,
+                                        to_v, *, layer=0, scale: float):
+    """Plain jnp form: the row's pages expanded, a masked softmax; see
+    :func:`sparse_expanded_attention`."""
+    (G, _, H, Dq), T = q.shape, mask.shape[-1]
+    kl, nope = to_k.shape[0], to_k.shape[2]
+    rows = pages[layer][page_indices].reshape(G, T, pages.shape[3])
+    c_kv, k_rope = rows[..., :kl], rows[..., kl:kl + Dq - nope]
+    k = jnp.concatenate(
+        [jnp.einsum("gtl,lhn->gthn", c_kv, to_k),
+         jnp.broadcast_to(k_rope[:, :, None], (G, T, H, Dq - nope))], -1)
+    v = jnp.einsum("gtl,lhv->gthv", c_kv, to_v)
+    s = jnp.einsum("gnhd,gthd->gnht", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(s + mask[:, :, None, :].astype(jnp.float32), -1)
+    return jnp.einsum("gnht,gthv->gnhv", p.astype(v.dtype), v).astype(q.dtype)
+
+
+def _sparse_expanded_kernel(table_ref, first_ref, layer_ref, q_ref, mask_ref,
+                            kv_ref, wk_ref, wv_ref, o_ref, k_ref, v_ref,
+                            m_ref, l_ref, acc_ref, *, n, bq, rq, tk, rank,
+                            scale):
+    import jax.experimental.pallas as pl
+
+    g, ki, qi = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    first = first_ref[g]
+    last = (first + n - 1) // tk          # the last block any query sees
+    heads = q_ref.shape[0]
+    tile = pl.ds(pl.multiple_of(qi * bq, bq), bq)
+
+    @pl.when(ki == 0)
+    def _first_block():
+        m_ref[:, tile] = jnp.full((heads, bq, 1), _NEG_INF, jnp.float32)
+        l_ref[:, tile] = jnp.zeros((heads, bq, 1), jnp.float32)
+        acc_ref[:, tile] = jnp.zeros((heads, bq) + acc_ref.shape[2:],
+                                     jnp.float32)
+
+    @pl.when((qi == 0) & (ki <= last))
+    def _expand_the_block():
+        kv = kv_ref[...]                          # [tk, W]
+        for h in range(heads):
+            k_ref[h] = jnp.dot(kv, wk_ref[h],
+                               preferred_element_type=jnp.float32).astype(
+                                   k_ref.dtype)
+            v_ref[h] = jnp.dot(kv[:, :rank], wv_ref[h],
+                               preferred_element_type=jnp.float32).astype(
+                                   v_ref.dtype)
+
+    @pl.when((ki <= last) & (ki * tk <= first + (qi + 1) * bq - 1))
+    def _a_block_some_query_sees():
+        for i in range(0, bq, rq):
+            at = pl.ds(pl.multiple_of(qi * bq + i, rq), rq)
+            mask = mask_ref[i:i + rq, :].astype(jnp.float32)
+            for h in range(heads):
+                s = lax.dot_general(q_ref[h, i:i + rq], k_ref[h],
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+                s = s * scale + mask                          # [rq, tk]
+                m = m_ref[h, at]
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new)
+                l_ref[h, at] = alpha * l_ref[h, at] \
+                    + jnp.sum(p, axis=-1, keepdims=True)
+                acc_ref[h, at] = alpha * acc_ref[h, at] + jnp.dot(
+                    p.astype(v_ref.dtype), v_ref[h],
+                    preferred_element_type=jnp.float32)
+                m_ref[h, at] = m_new
+
+    @pl.when(ki == last)
+    def _last_block():
+        o_ref[...] = (acc_ref[:, tile] / jnp.maximum(l_ref[:, tile], 1e-30)
+                      ).astype(o_ref.dtype)
+
+
+def _sparse_expanded_pallas(q, pages, page_indices, mask, positions, to_k,
+                            to_v, layer, scale, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    G, n, H, Dq = q.shape
+    rank, _, nope = to_k.shape
+    vd, W = to_v.shape[2], pages.shape[3]
+    page, width = pages.shape[2], page_indices.shape[1]
+    T, tk, bq = width * page, min(_KEY_BLOCK, page), min(_EXPAND_QUERIES, n)
+    hg, per, tiles = min(_EXPAND_HEADS, H), page // tk, n // bq
+    # a head's key from the cached vector in one matmul: ``to_k`` over the
+    # latent's columns, and a one that carries each rotary column across
+    wk = jnp.pad(to_k.astype(pages.dtype).transpose(1, 0, 2),
+                 ((0, 0), (0, W - rank), (0, Dq - nope)))
+    wk = wk.at[:, rank:rank + Dq - nope, nope:].set(
+        jnp.eye(Dq - nope, dtype=pages.dtype))
+    wv = to_v.astype(pages.dtype).transpose(1, 0, 2)
+
+    def live(g, ki, qi, first):
+        """The (key block, query tile) a step works on, held where it has no
+        work: at a block's first tile that sees it, and at the chunk's last
+        step for the blocks past its last position (nothing is fetched)."""
+        last = (first[g] + n - 1) // tk
+        ke = jnp.minimum(ki, last)
+        ahead = jnp.maximum(ke * tk - first[g], 0) // bq
+        return ke, jnp.where(ki > last, tiles - 1, jnp.maximum(qi, ahead))
+
+    def q_at(g, h, ki, qi, table, first, layer):
+        return g, h, live(g, ki, qi, first)[1], 0
+
+    def mask_at(g, h, ki, qi, table, first, layer):
+        ke, qe = live(g, ki, qi, first)
+        return g, qe, ke
+
+    def kv_at(g, h, ki, qi, table, first, layer):
+        ke = live(g, ki, qi, first)[0]
+        return layer[0], table[g * width + ke // per], ke % per, 0
+
+    def out_at(g, h, ki, qi, table, first, layer):
+        # written at the chunk's last block, a tile a step; before it and
+        # after it the block stays, so nothing unwritten goes out
+        last = (first[g] + n - 1) // tk
+        return g, h, jnp.where(ki < last, 0,
+                               jnp.where(ki == last, qi, tiles - 1)), 0
+
+    out = pl.pallas_call(
+        functools.partial(_sparse_expanded_kernel, n=n, bq=bq,
+                          rq=min(_EXPAND_ROWS, bq), tk=tk, rank=rank,
+                          scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(G, H // hg, T // tk, tiles),
+            in_specs=[
+                pl.BlockSpec((None, hg, bq, Dq), q_at),
+                pl.BlockSpec((None, bq, tk), mask_at),
+                pl.BlockSpec((None, None, tk, W), kv_at),
+                pl.BlockSpec((hg, W, Dq), lambda g, h, ki, qi, *_: (h, 0, 0)),
+                pl.BlockSpec((hg, rank, vd),
+                             lambda g, h, ki, qi, *_: (h, 0, 0))],
+            out_specs=pl.BlockSpec((None, hg, bq, vd), out_at),
+            scratch_shapes=[pltpu.VMEM((hg, tk, Dq), pages.dtype),
+                            pltpu.VMEM((hg, tk, vd), pages.dtype),
+                            pltpu.VMEM((hg, n, 1), jnp.float32),
+                            pltpu.VMEM((hg, n, 1), jnp.float32),
+                            pltpu.VMEM((hg, n, vd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((G, H, n, vd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 4,
+            vmem_limit_bytes=_EXPAND_VMEM),
+        interpret=interpret,
+        name="sparse_latent_attention",
+    )(page_indices.reshape(-1).astype(jnp.int32),
+      positions[:, 0].astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      q.transpose(0, 2, 1, 3), mask, pages, wk, wv)
+    return out.transpose(0, 2, 1, 3)
+
+
+def expanded_kernel_takes(q, pages, to_k, to_v) -> bool:
+    """Can the compiled expanded kernel tile these shapes on a TPU? Whole
+    tiles of queries, whole groups of heads, every width in whole lanes, a
+    page of whole key blocks, and the softmax state of a head group's queries
+    inside the VMEM the kernel asks for."""
+    n, H, Dq = q.shape[1:]
+    rank, vd, page = to_k.shape[0], to_v.shape[2], pages.shape[2]
+    bq, hg = min(_EXPAND_QUERIES, n), min(_EXPAND_HEADS, H)
+    state = hg * n * (vd + 2 * 128) * 4
+    return (n % bq == 0 and bq % min(_EXPAND_ROWS, bq) == 0
+            and bq % _GROUP_ROWS == 0 and H % hg == 0
+            and all(w % 128 == 0 for w in (Dq, vd, rank, pages.shape[3]))
+            and page % min(_KEY_BLOCK, page) == 0 and page % 128 == 0
+            and q.dtype == pages.dtype and state <= _EXPAND_VMEM // 2)
+
+
+def expanded_form_runs(q, pages, to_k, to_v) -> bool:
+    """Whether :func:`sparse_expanded_attention` has a way to run these shapes
+    where computation lands: off the TPU the plain form takes any; on it the
+    kernel alone, since the plain form expands the whole row into HBM."""
+    return not _on_tpu() or expanded_kernel_takes(q, pages, to_k, to_v)
+
+
+def sparse_expanded_attention(q, pages, page_indices, mask, positions, to_k,
+                              to_v, *, layer=0, scale: float,
+                              use_pallas: Optional[str] = None):
+    """Latent attention over the selected positions of a row in the expanded
+    form, for groups of many queries (a chunk of a prompt).
+
+    ``q`` [G, n, H, nope + rope]: every head's plain query, ``q_nope`` beside
+    the rotated ``q_rope``; ``pages``, ``page_indices``, ``mask`` and
+    ``positions`` as :func:`sparse_latent_attention`'s; ``to_k`` [rank, H,
+    nope] and ``to_v`` [rank, H, v_head_dim], the two halves of ``kv_b``. A
+    cached vector's first ``rank`` columns are ``c_kv``, the next ``rope`` the
+    rotary key every head shares. Returns the heads' values [G, n, H,
+    v_head_dim] in ``q``'s dtype: what :func:`sparse_latent_attention` returns
+    carried through ``to_v``. ``use_pallas`` as :func:`paged_attention`'s."""
+    if use_pallas is None:
+        use_pallas = "on" if _on_tpu() and expanded_kernel_takes(
+            q, pages, to_k, to_v) else "off"
+    if use_pallas == "off":
+        return sparse_expanded_attention_reference(
+            q, pages, page_indices, mask, to_k, to_v, layer=layer,
+            scale=scale)
+    return _sparse_expanded_pallas(q, pages, page_indices, mask, positions,
+                                   to_k, to_v, layer, scale,
+                                   interpret=(use_pallas == "interpret"))

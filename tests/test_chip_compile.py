@@ -713,3 +713,41 @@ def test_the_pattern_mixed_step_fits_and_keeps_pool_and_state_where_they_are(
     weights = 2 * arch.n_params(cfg)
     assert weights + held < total < weights + held + 0.4e9
     assert total < flops.peak("TPU v5 lite")["hbm_bytes"] - 2.0e9
+
+
+def test_expanded_chunk_attention_kernel_compiles_for_v5e(one_chip):
+    """The chunk's attention in the expanded form at ``glm-5.2-d6-e16``'s
+    shape (a chunk of 4,096 queries, 64 heads of 192 + 64 and 256, a row of
+    8 pages of 4,096 in the cell's pool of 97, bf16): one Mosaic call under
+    the name the rows' kernel carries, the softmax state of four heads' 4,096
+    queries (32 MB) and their expansion of a key block inside the VMEM the
+    kernel asks for, and the pool read where it lies: the program's
+    temporaries are the transposed queries and values and the two weight
+    arrays, no copy of the pool."""
+    import re
+
+    from ray_memory_management_tpu.ops import paged_attention as pa
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    C, H = 4096, 64
+    q, pages = s((1, C, H, 256)), s((6, 97, 4096, 640))
+    to_k, to_v = s((512, H, 192)), s((512, H, 256))
+    assert pa.expanded_kernel_takes(q, pages, to_k, to_v)
+    compiled = jax.jit(
+        lambda q, pages, table, mask, at, to_k, to_v, layer:
+        pa.sparse_expanded_attention(q, pages, table, mask, at, to_k, to_v,
+                                     layer=layer, scale=1 / 16,
+                                     use_pallas="on")).lower(
+        q, pages, s((1, 8), jnp.int32), s((1, C, 32768)),
+        s((1, C), jnp.int32), to_k, to_v, s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(set(re.findall(r"%(sparse_latent_attention[.\d]*) = ",
+                              text))) == 1
+    assert "bf16[6,97,4096,640]" in text and not re.findall(
+        r"= bf16\[6,97,4096,640\]\{[^ ]* copy", text)
+    m = compiled.memory_analysis()
+    assert m.output_size_in_bytes == C * H * 256 * 2
+    assert m.temp_size_in_bytes < 0.2e9
