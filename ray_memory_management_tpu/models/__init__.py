@@ -50,15 +50,25 @@ def serving_model(cfg):
     both: it indexes each array by its own count of layers (entry ``j`` of
     the shallower array is the ``j``-th layer of its kind), writes a
     position into every array that holds it before any of its layers reads
-    it back, and never reads an array at a layer that does not keep one."""
-    from . import gpt, hybrid_ssm, latent_moe, latent_sparse_moe, nemotron_h
+    it back, and never reads an array at a layer that does not keep one.
+
+    **A strided array** (models/sparse_linear.py: a pooled key a 16
+    positions beside the keys and values) names its stride in positions as
+    a fourth item of its ``cache_spec`` entry; a page then holds ``page_tokens
+    / stride`` of its entries, entry ``e`` standing for the page's positions
+    from ``stride * e`` on. Beside the rule above the model owes the pool to
+    write such an entry into the page of the positions it stands for, and to
+    read it only once it is complete (serve/kv_cache.py)."""
+    from . import (gpt, hybrid_ssm, latent_moe, latent_sparse_moe, nemotron_h,
+                   sparse_linear)
 
     for module, kind in ((gpt, gpt.TransformerConfig),
                          (latent_moe, latent_moe.LatentMoEConfig),
                          (hybrid_ssm, hybrid_ssm.HybridSSMConfig),
                          (nemotron_h, nemotron_h.NemotronHConfig),
                          (latent_sparse_moe,
-                          latent_sparse_moe.LatentSparseMoEConfig)):
+                          latent_sparse_moe.LatentSparseMoEConfig),
+                         (sparse_linear, sparse_linear.SparseLinearConfig)):
         if isinstance(cfg, kind):
             return module
     raise TypeError(f"no model serves a {type(cfg).__name__}")

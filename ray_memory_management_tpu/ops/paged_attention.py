@@ -595,7 +595,7 @@ def _index_select_kernel(s_ref, o_ref, *, top_k):
     o_ref[...] = jnp.where(hit, 0.0, _NEG_INF).astype(o_ref.dtype)
 
 
-def _index_select_pallas(scores, top_k, interpret):
+def _index_select_pallas(scores, top_k, interpret, name="index_select"):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -613,7 +613,7 @@ def _index_select_pallas(scores, top_k, interpret):
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 << 20),
         interpret=interpret,
-        name="index_select",
+        name=name,
     )(jnp.pad(flat, ((0, rows - N), (0, 0)), constant_values=_NEG_INF))
     return out[:N].reshape(scores.shape)
 
@@ -1046,3 +1046,495 @@ def sparse_expanded_attention(q, pages, page_indices, mask, positions, to_k,
     return _sparse_expanded_pallas(q, pages, page_indices, mask, positions,
                                    to_k, to_v, layer, scale,
                                    interpret=(use_pallas == "interpret"))
+
+
+# ----------------------------- whole blocks chosen through pooled keys
+# (Appended below the last kernel, as the sections above were.)
+# MiniCPM4's InfLLM-v2 attention (models/sparse_linear.py): a query attends
+# whole blocks of ``block`` positions, those its K/V group chose through
+# *pooled keys*, one key a ``stride`` positions, the mean of the ``window``
+# keys from there (``c_j = mean(k[stride j .. stride j + window - 1])``,
+# kept in a paged array of its own at a ``stride``-th of the position rate,
+# serve/kv_cache.py). Three steps, each a plain ``jax.numpy`` form (the
+# contract, the CPU's path and the kernels' oracle) and a Pallas kernel of
+# the same name:
+#
+#   block_scores            p[h, j] = softmax_j(q_h . c_j * scale) over the
+#                           windows complete at the query, summed over the
+#                           group's heads; a block scores the largest sum of
+#                           a window that meets it
+#   block_select            the first ``init_blocks`` blocks and those that
+#                           hold one of the last ``local`` positions, then the
+#                           highest scores up to ``top_k`` blocks, ties to the
+#                           lower block; every block up to the query's while
+#                           it stands before ``dense_len``
+#   block_sparse_attention  softmax attention over the chosen blocks' positions
+#                           up to the query's: a decode row fetches its chosen
+#                           blocks alone, a chunk walks the row's key tiles
+#                           and skips those no query of a tile chose
+# Queries come in groups on a row of the block table at consecutive positions
+# (a chunk: one group of C; a decode step: B groups of one), each query as its
+# K/V heads' ``R`` query heads ([G, n, Hkv, R, D]).
+_SCORE_QUERIES = 32     # queries a grid step of the scoring kernel (a chunk)
+_BLOCK_TILE = 512       # key positions a grid step of a chunk's attention
+_BLOCK_QUERIES = 64     # queries a tile of a chunk's attention
+_FORCED = 1e30          # the score of a block that is always chosen
+_BLOCK_VMEM = 64 << 20
+
+
+def _window_probs_reference(q, pooled, page_indices, positions, layer,
+                            stride, window, scale):
+    """[G, n, Hkv, pooled keys a row]: each window's softmax share, summed
+    over a group's heads; 0 for a window not complete at the query."""
+    G, n, Hkv, R, D = q.shape
+    NJ = page_indices.shape[1] * pooled.shape[3]
+    c = pooled[layer][:, page_indices].reshape(Hkv, G, NJ, D)
+    s = jnp.einsum("gnhrd,hgjd->gnhrj", q, c,
+                   preferred_element_type=jnp.float32) * scale
+    done = (stride * jnp.arange(NJ) + window - 1
+            <= positions[:, :, None, None, None])
+    s = jnp.where(done, s, _NEG_INF)
+    p = jnp.where(done, jnp.exp(s - jnp.max(s, -1, keepdims=True)), 0.0)
+    p = p / jnp.maximum(jnp.sum(p, -1, keepdims=True), 1e-30)
+    return jnp.sum(p, axis=3)
+
+
+def _block_max(s, per_block: int, per_window: int):
+    """[..., NJ] window sums -> [..., NJ / per_block]: block ``b`` takes the
+    largest sum of the windows that meet it, ``j`` from ``per_block * b -
+    per_window + 1`` to ``per_block * (b + 1) - 1`` (none below 0: a sum is
+    never negative, so the padding of 0 adds nothing)."""
+    nb = s.shape[-1] // per_block
+    s = jnp.pad(s, ((0, 0),) * (s.ndim - 1) + ((per_window - 1, 0),))
+    return functools.reduce(jnp.maximum, [
+        s[..., i:i + per_block * nb:per_block]
+        for i in range(per_block + per_window - 1)])
+
+
+def _block_scores_kernel(table_ref, first_ref, layer_ref, q_ref, pooled_hbm,
+                         o_ref, buf, sem, *, width, bq, stride, window,
+                         scale):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    g, h, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    entries, D = buf.shape[1], buf.shape[2]
+    first = first_ref[g] + qi * bq             # the tile's first query
+    n_pages = jnp.minimum((first + bq - 1) // (entries * stride) + 1, width)
+
+    def copy(i):
+        return pltpu.make_async_copy(
+            pooled_hbm.at[layer_ref[0], h, table_ref[g * width + i]],
+            buf.at[i], sem.at[0])
+
+    lax.fori_loop(0, n_pages, lambda i, c: (copy(i).start(), c)[1], 0)
+    lax.fori_loop(0, n_pages, lambda i, c: (copy(i).wait(), c)[1], 0)
+    R = q_ref.shape[1]
+    keys = buf[...].reshape(width * entries, D)
+    s = lax.dot_general(q_ref[...].reshape(bq * R, D), keys,
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+    s = s.reshape(bq, R, width * entries)
+    at = first + lax.broadcasted_iota(jnp.int32, (bq, 1, 1), 0)
+    j = lax.broadcasted_iota(jnp.int32, (1, 1, width * entries), 2)
+    # a window of a page past the tile's last query is never complete: what
+    # the buffer holds there is never read
+    done = stride * j + window - 1 <= at
+    s = jnp.where(done, s, _NEG_INF)
+    p = jnp.where(done, jnp.exp(s - jnp.max(s, -1, keepdims=True)), 0.0)
+    p = p / jnp.maximum(jnp.sum(p, -1, keepdims=True), 1e-30)
+    o_ref[...] = jnp.sum(p, axis=1)
+
+
+def _block_scores_pallas(q, pooled, page_indices, positions, layer, stride,
+                         window, interpret, scale):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    G, n, Hkv, R, D = q.shape
+    entries, width = pooled.shape[3], page_indices.shape[1]
+    bq = min(_SCORE_QUERIES, n)
+    NJ = width * entries
+    out = pl.pallas_call(
+        functools.partial(_block_scores_kernel, width=width, bq=bq,
+                          stride=stride, window=window, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(G, Hkv, n // bq),
+            in_specs=[pl.BlockSpec((None, bq, None, R, D),
+                                   lambda g, h, qi, *_: (g, qi, h, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, None, bq, NJ),
+                                   lambda g, h, qi, *_: (g, h, qi, 0)),
+            scratch_shapes=[pltpu.VMEM((width, entries, D), pooled.dtype),
+                            pltpu.SemaphoreType.DMA((1,))]),
+        out_shape=jax.ShapeDtypeStruct((G, Hkv, n, NJ), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=_BLOCK_VMEM),
+        interpret=interpret,
+        name="block_scores",
+    )(page_indices.reshape(-1).astype(jnp.int32),
+      positions[:, 0].astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, pooled)
+    return out.transpose(0, 2, 1, 3)
+
+
+def block_scores_kernel_takes(q, pooled) -> bool:
+    """Can the compiled scoring kernel tile these shapes on a TPU? A head
+    that fills the lanes, a group of heads in whole bf16 tiles, a page of
+    pooled keys in whole tiles, and a group of one query (a decode row) or
+    of whole tiles of queries (a chunk)."""
+    n, R, D = q.shape[1], q.shape[3], q.shape[4]
+    return (D % 128 == 0 and R % _GROUP_ROWS == 0
+            and pooled.shape[3] % _GROUP_ROWS == 0
+            and (n == 1 or n % _SCORE_QUERIES == 0)
+            and q.dtype == pooled.dtype)
+
+
+def block_scores(q, pooled, page_indices, positions, *, layer=0, stride: int,
+                 window: int, block: int, scale: float,
+                 use_pallas: Optional[str] = None):
+    """Every block's score of every query's K/V group.
+
+    ``q`` [G, n, Hkv, R, D]: ``G`` groups of ``n`` queries, each as the ``R``
+    query heads of each K/V head; ``pooled`` [L, Hkv, P, page_tokens /
+    stride, D], the paged pooled keys, of which ``layer`` is read where it
+    lies; ``page_indices`` int32 [G, pages_per_row]; ``positions`` int32 [G,
+    n], consecutive inside a group. A query scores the windows complete at
+    its position (``stride * j + window - 1 <= t``): the softmax over them of
+    ``q_h . c_j * scale``, summed over the group's heads; a block of
+    ``block`` positions takes the largest sum of a window that meets it (0
+    where none is complete). Returns float32 [G, n, Hkv, pages_per_row *
+    page_tokens / block]. ``use_pallas`` as :func:`paged_attention`'s: the
+    kernel computes the sums, the block's largest is a few ``jax.numpy``
+    maxima of strided slices either way."""
+    if use_pallas is None:
+        use_pallas = "on" if _on_tpu() and block_scores_kernel_takes(
+            q, pooled) else "off"
+    if use_pallas == "off":
+        s = _window_probs_reference(q, pooled, page_indices, positions, layer,
+                                    stride, window, scale)
+    else:
+        s = _block_scores_pallas(q, pooled, page_indices, positions, layer,
+                                 stride, window,
+                                 interpret=(use_pallas == "interpret"),
+                                 scale=scale)
+    return _block_max(s, block // stride, window // stride)
+
+
+def block_select(scores, positions, *, top_k: int, block: int,
+                 init_blocks: int, local: int, dense_len: int,
+                 use_pallas: Optional[str] = None):
+    """The blocks each query's K/V group attends: ``scores`` float32 [G, n,
+    Hkv, NB] (:func:`block_scores`), ``positions`` int32 [G, n] -> bool [G,
+    n, Hkv, NB]. A block that starts after the query is never chosen; the
+    first ``init_blocks`` and every block holding one of the last ``local``
+    positions up to the query's always are; the highest scores fill the rest
+    up to ``top_k`` blocks, **ties to the lower block**; a query before
+    ``dense_len`` takes every block up to its own. ``use_pallas`` as
+    :func:`index_select`'s: the kernel is its threshold search (one pass over
+    the bits of the float, ties cut by a second), under its own name."""
+    NB = scores.shape[-1]
+    b = jnp.arange(NB)
+    t = positions[:, :, None, None]
+    exists = b * block <= t
+    forced = (b < init_blocks) | ((b + 1) * block > t - local + 1)
+    prepared = jnp.where(exists, jnp.where(forced, _FORCED, scores),
+                         _NEG_INF).astype(jnp.float32)
+    if use_pallas is None:
+        use_pallas = "on" if _on_tpu() and NB % 128 == 0 else "off"
+    if use_pallas == "off":
+        mask = index_select_reference(prepared, top_k)
+    else:
+        mask = _index_select_pallas(prepared, top_k,
+                                    interpret=(use_pallas == "interpret"),
+                                    name="block_select")
+    return jnp.where(t < dense_len, exists, mask > _NEG_INF / 2)
+
+
+def block_sparse_attention_reference(q, k_pages, v_pages, page_indices,
+                                     chosen, positions, *, layer=0,
+                                     block: int, scale: float):
+    """Plain jnp form: the row's pages read whole, masked by the chosen
+    blocks and the query's position; see :func:`block_sparse_attention`."""
+    G, n, Hkv, R, D = q.shape
+    T = page_indices.shape[1] * k_pages.shape[3]
+
+    def rows(pages):  # the row's pages, in table order: [G, Hkv, T, D]
+        got = pages[layer][:, page_indices]      # [Hkv, G, W, page, D]
+        return jnp.moveaxis(got, 0, 1).reshape(G, Hkv, T, D)
+
+    seen = jnp.repeat(chosen, block, axis=-1)[..., :T] & (
+        jnp.arange(T) <= positions[:, :, None, None])       # [G, n, Hkv, T]
+    s = jnp.einsum("gnhrd,ghtd->gnhrt", q, rows(k_pages),
+                   preferred_element_type=jnp.float32) * scale
+    seen = seen[:, :, :, None]
+    s = jnp.where(seen, s, _NEG_INF)
+    p = jnp.where(seen, jnp.exp(s - jnp.max(s, -1, keepdims=True)), 0.0)
+    o = jnp.einsum("gnhrt,ghtd->gnhrd", p.astype(q.dtype), rows(v_pages),
+                   preferred_element_type=jnp.float32)
+    return (o / jnp.maximum(jnp.sum(p, -1)[..., None], 1e-30)).astype(
+        q.dtype)
+
+
+def _block_rows_kernel(table_ref, ids_ref, count_ref, pos_ref, layer_ref,
+                       q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, *,
+                       width, per, block, hkv, most, scale):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    i = pl.program_id(0)             # (row, K/V head): i // hkv, i % hkv
+    slot = lax.rem(i, 2)
+    layer = layer_ref[0]
+
+    def copies(step, buf, j):
+        """The DMAs of the ``j``-th chosen block of ``step``: its keys and
+        values, where its page lies."""
+        b = ids_ref[step * most + j]
+        pid = table_ref[(step // hkv) * width + b // per]
+        at = (layer, lax.rem(step, hkv), pid, pl.ds((b % per) * block, block))
+        return (pltpu.make_async_copy(k_hbm.at[at], k_buf.at[buf, j],
+                                      sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[at], v_buf.at[buf, j],
+                                      sem.at[1, buf]))
+
+    def each(step, buf, go):
+        def one(j, c):
+            for cp in copies(step, buf, j):
+                go(cp)
+            return c
+        lax.fori_loop(0, count_ref[step], one, 0)
+
+    @pl.when(i == 0)
+    def _first():
+        each(0, 0, lambda cp: cp.start())
+
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _next():
+        each(i + 1, 1 - slot, lambda cp: cp.start())
+
+    each(i, slot, lambda cp: cp.wait())
+    n_blk = count_ref[i]
+    t = pos_ref[i // hkv]
+    # the chosen blocks lie in order and the last holds the query: what is
+    # seen is every position of the others and the last's up to ``t``
+    last = ids_ref[i * most + jnp.maximum(n_blk - 1, 0)] * block
+    limit = jnp.where(n_blk > 0, (n_blk - 1) * block + t - last + 1, 0)
+    span, D = most * block, k_buf.shape[-1]
+    k = k_buf[slot].reshape(span, D)
+    v = v_buf[slot].reshape(span, D)
+    s = lax.dot_general(q_ref[...], k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+    ok = lax.broadcasted_iota(jnp.int32, s.shape, 1) < limit
+    s = jnp.where(ok, s, _NEG_INF)
+    p = jnp.where(ok, jnp.exp(s - jnp.max(s, -1, keepdims=True)), 0.0)
+    # a slot of the buffer this row left unfilled is never read
+    v = jnp.where(lax.broadcasted_iota(jnp.int32, v.shape, 0) < limit, v, 0)
+    o = jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    o_ref[...] = (o / jnp.maximum(jnp.sum(p, -1, keepdims=True), 1e-30)
+                  ).astype(o_ref.dtype)
+
+
+def _block_rows_pallas(q, k_pages, v_pages, page_indices, chosen, positions,
+                       live, layer, block, most, scale, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    G, _, Hkv, R, D = q.shape
+    page, width = k_pages.shape[3], page_indices.shape[1]
+    NB = chosen.shape[-1]
+    most = min(most, NB)
+    picked = chosen[:, 0].reshape(G * Hkv, NB)
+    # the chosen blocks' ids in order, the lowest first; a row that is not
+    # live fetches nothing
+    ids = lax.top_k(jnp.where(picked, -jnp.arange(NB), -NB), most)[1]
+    count = jnp.where(jnp.repeat(live, Hkv),
+                      jnp.sum(picked, -1, dtype=jnp.int32), 0)
+    one = pl.BlockSpec((None, R, D), lambda i, *_: (i, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_block_rows_kernel, width=width,
+                          per=page // block, block=block, hkv=Hkv,
+                          most=most, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(G * Hkv,),
+            in_specs=[one, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=one,
+            scratch_shapes=[pltpu.VMEM((2, most, block, D), k_pages.dtype),
+                            pltpu.VMEM((2, most, block, D), v_pages.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=jax.ShapeDtypeStruct((G * Hkv, R, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_BLOCK_VMEM),
+        interpret=interpret,
+        name="block_sparse_attention",
+    )(page_indices.reshape(-1).astype(jnp.int32),
+      ids.reshape(-1).astype(jnp.int32), count,
+      positions[:, 0].astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      q.reshape(G * Hkv, R, D), k_pages, v_pages)
+    return out.reshape(G, 1, Hkv, R, D)
+
+
+def _block_chunk_kernel(table_ref, fetch_ref, any_ref, first_ref, layer_ref,
+                        q_ref, chosen_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                        acc_ref, *, bq, tk, block, scale):
+    import jax.experimental.pallas as pl
+
+    g, h, qi, ki = (pl.program_id(0), pl.program_id(1), pl.program_id(2),
+                    pl.program_id(3))
+    QT, KT = pl.num_programs(2), pl.num_programs(3)
+    bq_, R, D = q_ref.shape
+
+    @pl.when(ki == 0)
+    def _first_tile():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(any_ref[((g * pl.num_programs(1) + h) * QT + qi) * KT + ki] > 0)
+    def _a_tile_some_query_chose():
+        s = lax.dot_general(q_ref[...].reshape(bq * R, D), k_ref[...],
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        s = s.reshape(bq, R, tk)
+        # the chosen blocks as positions of the tile: a one-hot product,
+        # exact for 0 and 1
+        NB = chosen_ref.shape[-1]
+        spread = (lax.broadcasted_iota(jnp.int32, (NB, tk), 0)
+                  == ki * (tk // block)
+                  + lax.broadcasted_iota(jnp.int32, (NB, tk), 1) // block)
+        seen = jnp.dot(chosen_ref[...], spread.astype(chosen_ref.dtype),
+                       preferred_element_type=jnp.float32) > 0.5
+        at_q = first_ref[g] + qi * bq + lax.broadcasted_iota(
+            jnp.int32, (bq, tk), 0)
+        at_k = ki * tk + lax.broadcasted_iota(jnp.int32, (bq, tk), 1)
+        seen = (seen & (at_k <= at_q))[:, None, :]
+        s = jnp.where(seen, s, _NEG_INF)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.reshape(bq * R, tk).astype(v_ref.dtype), v_ref[...],
+            preferred_element_type=jnp.float32).reshape(bq, R, D)
+        m_ref[...] = m_new
+
+    @pl.when(ki == KT - 1)
+    def _last_tile():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
+            o_ref.dtype)
+
+
+def _block_chunk_pallas(q, k_pages, v_pages, page_indices, chosen, positions,
+                        layer, block, scale, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    G, n, Hkv, R, D = q.shape
+    page, width = k_pages.shape[3], page_indices.shape[1]
+    NB = chosen.shape[-1]
+    tk, bq = min(_BLOCK_TILE, page), min(_BLOCK_QUERIES, n)
+    QT, KT, per = n // bq, width * page // tk, tk // block
+    # which key tiles a tile of queries chose a block of; where it chose
+    # none, the tile to hold (the last it fetched, or its first): nothing new
+    # is fetched for a tile it skips
+    hit = jnp.any(chosen.reshape(G, QT, bq, Hkv, KT, per), axis=(2, 5))
+    hit = hit.transpose(0, 2, 1, 3)                          # [G, Hkv, QT, KT]
+    tiles = jnp.arange(KT)
+    held = lax.cummax(jnp.where(hit, tiles, -1), axis=3)
+    fetch = jnp.where(held >= 0, held, jnp.argmax(hit, axis=3)[..., None])
+
+    def kv_at(g, h, qi, ki, table, fetch, any_, first, layer):
+        f = fetch[((g * Hkv + h) * QT + qi) * KT + ki]
+        return layer[0], h, table[g * width + f * tk // page], \
+            (f * tk % page) // tk, 0
+
+    q_spec = pl.BlockSpec((None, bq, None, R, D),
+                          lambda g, h, qi, ki, *_: (g, qi, h, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_block_chunk_kernel, bq=bq, tk=tk, block=block,
+                          scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(G, Hkv, QT, KT),
+            in_specs=[q_spec,
+                      pl.BlockSpec((None, None, bq, NB),
+                                   lambda g, h, qi, ki, *_: (g, h, qi, 0)),
+                      pl.BlockSpec((None, None, None, tk, D), kv_at),
+                      pl.BlockSpec((None, None, None, tk, D), kv_at)],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((bq, R, 1), jnp.float32),
+                            pltpu.VMEM((bq, R, 1), jnp.float32),
+                            pltpu.VMEM((bq, R, D), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 4,
+            vmem_limit_bytes=_BLOCK_VMEM),
+        interpret=interpret,
+        name="block_sparse_attention",
+    )(page_indices.reshape(-1).astype(jnp.int32),
+      fetch.reshape(-1).astype(jnp.int32), hit.reshape(-1).astype(jnp.int32),
+      positions[:, 0].astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q,
+      chosen.transpose(0, 2, 1, 3).astype(q.dtype), k_pages, v_pages)
+
+
+def block_attention_kernel_takes(q, k_pages, block: int) -> bool:
+    """Can the compiled kernels tile these shapes on a TPU? A head that
+    fills the lanes, a group of heads in whole bf16 tiles, a page of whole
+    key tiles of whole blocks, and a group of one query (a decode row) or of
+    whole tiles of queries (a chunk)."""
+    n, R, D = q.shape[1], q.shape[3], q.shape[4]
+    page = k_pages.shape[3]
+    tk = min(_BLOCK_TILE, page)
+    return (D % 128 == 0 and R % _GROUP_ROWS == 0 and block % 16 == 0
+            and page % tk == 0 and tk % block == 0
+            and (n == 1 or n % min(_BLOCK_QUERIES, n) == 0 and n >= 16)
+            and q.dtype == k_pages.dtype)
+
+
+def block_sparse_attention(q, k_pages, v_pages, page_indices, chosen,
+                           positions, *, layer=0, block: int, scale: float,
+                           most: int, live=None,
+                           use_pallas: Optional[str] = None):
+    """Attention of each query over the positions of the blocks its K/V
+    group chose, up to its own.
+
+    ``q`` [G, n, Hkv, R, D]; ``k_pages`` / ``v_pages`` [L, Hkv, P,
+    page_tokens, D], of which ``layer`` is read where it lies;
+    ``page_indices`` int32 [G, pages_per_row]; ``chosen`` bool [G, n, Hkv,
+    NB] (:func:`block_select`: blocks of ``block`` positions, none past a
+    query's own and the one that holds it always among them); ``positions``
+    int32 [G, n], consecutive inside a group; ``most``: the most blocks a
+    query chooses; ``live`` bool [G] (decode rows): a row that is not live
+    reads nothing and gets 0. Returns [G, n, Hkv, R, D] in ``q``'s dtype.
+
+    A decode row (``n`` = 1) fetches its ``most`` blocks at most, each once,
+    keys and values, all of them in flight together and the next row's
+    behind them; a chunk walks its row's key tiles with an online softmax,
+    and a tile that no query of a tile of queries chose a block of is
+    neither fetched nor computed. ``use_pallas`` as
+    :func:`paged_attention`'s."""
+    if use_pallas is None:
+        use_pallas = "on" if _on_tpu() and block_attention_kernel_takes(
+            q, k_pages, block) else "off"
+    if use_pallas == "off":
+        o = block_sparse_attention_reference(
+            q, k_pages, v_pages, page_indices, chosen, positions, layer=layer,
+            block=block, scale=scale)
+        if live is not None:
+            o = jnp.where(live[:, None, None, None, None], o, 0)
+        return o
+    interpret = use_pallas == "interpret"
+    if q.shape[1] == 1:
+        live = jnp.ones(q.shape[:1], bool) if live is None else live
+        return _block_rows_pallas(q, k_pages, v_pages, page_indices, chosen,
+                                  positions, live, layer, block, most, scale,
+                                  interpret)
+    return _block_chunk_pallas(q, k_pages, v_pages, page_indices, chosen,
+                               positions, layer, block, scale, interpret)

@@ -19,7 +19,13 @@ fetch would move half padding; side by side it is dense, and the update is
 the same elementwise pass, the decay and the input taking a head's value in
 that head's lanes. Both kernels take ``P`` = 128 (Falcon-H1: 32 heads,
 state 256, 2 groups) and ``P`` = 64 (Nemotron-H: 128 heads, state 128, 8
-groups of 16).
+groups of 16), and a third shape, **a group a head** (MiniCPM-SALA's
+Lightning attention, models/sparse_linear.py: 32 heads of 128, state 128,
+``G`` = ``H``: linear attention with a key a head is this recurrence with
+``dt`` = 1, ``A`` = -slope, ``B`` = k, ``x`` = v, ``C`` = q and ``D`` = 0).
+The decode kernel's grid step never crosses a group where a group has several
+rows of state; where each has one, a step moves a block of groups, each row
+with its own ``B`` and ``C`` (:func:`_blocks_of`).
 
   - :func:`ssd_scan`: the recurrence over a whole prompt in chunks of
     ``SSD_CHUNK`` positions (Mamba-2's state-space duality): inside a chunk a
@@ -182,29 +188,49 @@ def ssd_kernel_takes(x, B, chunk: int = SSD_CHUNK) -> bool:
     of 64 channels or whole lanes of them whose block (``_head_block``) is
     whole lanes wide, bf16 or float32 operands."""
     H, P = x.shape[1:]
+    G, N = B.shape[1:]
     return (x.shape[0] >= chunk and chunk % 128 == 0
-            and P % 64 == 0 and B.shape[-1] % 128 == 0
-            and H % B.shape[1] == 0
-            and _head_block(H // B.shape[1], B.shape[-1], P) * P % 128 == 0
+            and P % 64 == 0 and N % 128 == 0 and H % G == 0
+            and _scan_blocks(H, G, N, P)[0] * P % 128 == 0
             and x.dtype in (jnp.bfloat16, jnp.float32))
 
 
+def _scan_blocks(H: int, G: int, n: int, p: int):
+    """(heads a grid step of the scan kernel takes, groups among them):
+    within one group where a group has several heads (:func:`_head_block`);
+    eight heads, each its own group, where every head is a group (a key a
+    head of whole rows of lanes), so that a block of heads fills the
+    sublanes of its rows."""
+    if G == H and H % 8 == 0 and p % 128 == 0:
+        return 8, 8
+    return _head_block(H // G, n, p), 1
+
+
 def _scan_kernel(x_ref, cs_row, dt_row, cs_col, w_col, keep_ref, bt_ref, c_ref,
-                 h0_ref, y_ref, h_ref, *, hb: int, p: int):
+                 h0_ref, y_ref, h_ref, *, hb: int, p: int, gb: int = 1):
     import jax.experimental.pallas as pl
 
     f32, op = jnp.float32, x_ref.dtype
     q = c_ref.shape[0]
+    n = bt_ref.shape[0] // gb
 
     @pl.when(pl.program_id(1) == 0)
     def _first_chunk():
         h_ref[...] = h0_ref[...]
 
-    c, bt = c_ref[...], bt_ref[...]                  # [Q, N], [N, Q]
-    scores = jnp.dot(c, bt, preferred_element_type=f32)          # [t, s]
+    def group(g):
+        """The chunk's C [Q, N] and B^T [N, Q] of the block's ``g``-th group,
+        and the scores between them."""
+        c, bt = c_ref[:, g * n:(g + 1) * n], bt_ref[g * n:(g + 1) * n, :]
+        return c, bt, jnp.dot(c, bt, preferred_element_type=f32)  # [t, s]
+
+    if gb == 1:
+        c, bt, scores = group(0)
     t_at = lax.broadcasted_iota(jnp.int32, (q, q), 0)
     s_at = lax.broadcasted_iota(jnp.int32, (q, q), 1)
     for i in range(hb):
+        if gb > 1:
+            c, bt, scores = group(i * gb // hb)
         csr, dtr = cs_row[i:i + 1, :], dt_row[i:i + 1, :]        # [1, Q]
         csc, w = cs_col[:, i:i + 1], w_col[:, i:i + 1]           # [Q, 1]
         x = x_ref[:, i * p:(i + 1) * p]                          # [Q, P]
@@ -231,7 +257,7 @@ def _ssd_scan_pallas(x, dt, A, B, C, D, chunk, true_len, h0, interpret):
     R, f32, Q = H // G, jnp.float32, chunk
     pad = (-T) % Q
     n = (T + pad) // Q
-    hb = _head_block(R, N, P)
+    hb, gb = _scan_blocks(H, G, N, P)
     dt_m = jnp.pad(_stopped(dt, true_len), ((0, pad), (0, 0)))
     # a chunk's own running sum of dt A, as rows and as columns; what a
     # position's input keeps to the chunk's end (w) and the state over it
@@ -257,12 +283,14 @@ def _ssd_scan_pallas(x, dt, A, B, C, D, chunk, true_len, h0, interpret):
     heads = pl.BlockSpec((hb, N, P), lambda j, c: (j, 0, 0))
     wide = pl.BlockSpec((Q, hb * P), lambda j, c: (c, j))
     y, h = pl.pallas_call(
-        functools.partial(_scan_kernel, hb=hb, p=P),
+        functools.partial(_scan_kernel, hb=hb, p=P, gb=gb),
         grid=(H // hb, n),
         in_specs=[wide, row, row, col, col,
                   pl.BlockSpec((None, hb, P), lambda j, c: (c, j, 0)),
-                  pl.BlockSpec((N, Q), lambda j, c: (j * hb // R, c)),
-                  pl.BlockSpec((Q, N), lambda j, c: (c, j * hb // R)),
+                  pl.BlockSpec((gb * N, Q), lambda j, c: (j * hb // R // gb,
+                                                          c)),
+                  pl.BlockSpec((Q, gb * N), lambda j, c: (c, j * hb // R
+                                                          // gb)),
                   heads],
         out_specs=[wide, heads],
         out_shape=[jax.ShapeDtypeStruct((n * Q, H * P), f32),
@@ -307,9 +335,21 @@ def _head_block(heads_a_group: int, n: int, p: int) -> int:
                if heads_a_group % d == 0 and d <= fit)
 
 
+def _blocks_of(rows_h: int, groups: int, n: int, p: int):
+    """(rows a grid step of the decode kernel moves, groups among them):
+    within one group where a group has several rows of state
+    (:func:`_head_block`); where each row is a group of its own (a key a
+    head, a state as wide as it is deep), as many whole rows as
+    ``_BLOCK_BYTES`` holds, each its own group."""
+    if groups == rows_h and n == p:
+        hb = _head_block(rows_h, n, p)
+        return hb, hb
+    return _head_block(rows_h // groups, n, p), 1
+
+
 def _update_kernel(order_ref, n_live_ref, layer_ref, decay_ref, xdt_ref,
                    bc_ref, h_in, y_ref, h_out, fetched_ref, in_buf, out_buf,
-                   in_sem, out_sem, *, blocks: int, hb: int):
+                   in_sem, out_sem, *, blocks: int, hb: int, gb: int):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -351,10 +391,21 @@ def _update_kernel(order_ref, n_live_ref, layer_ref, decay_ref, xdt_ref,
         def _left():
             store(t - 2, k).wait()
 
-        b = jnp.broadcast_to(bc_ref[:, 0:1], bc_ref.shape[:1]
-                             + decay_ref.shape[-1:])           # [N, P]
-        c = jnp.broadcast_to(bc_ref[:, 1:2], b.shape)
+        # B and C of each of the block's ``gb`` groups, as [N, P]: a group's
+        # pair as two columns, or, a group a row, as two rows across the
+        # lanes (two columns would be padded to a row of lanes each in HBM
+        # and moved so) turned into columns
+        if gb == 1:
+            shape = bc_ref.shape[1:2] + decay_ref.shape[-1:]
+            bc = [(jnp.broadcast_to(bc_ref[0, :, 0:1], shape),
+                   jnp.broadcast_to(bc_ref[0, :, 1:2], shape))]
+        else:
+            shape = decay_ref.shape[-1:] + bc_ref.shape[-1:]
+            bc = [(jnp.broadcast_to(bc_ref[g, 0:1, :], shape).T,
+                   jnp.broadcast_to(bc_ref[g, 1:2, :], shape).T)
+                  for g in range(gb)]
         for hh in range(hb):
+            b, c = bc[hh * gb // hb]
             h = in_buf[k, hh] * decay_ref[hh:hh + 1, :] \
                 + b * xdt_ref[hh:hh + 1, :]
             out_buf[k, hh] = h
@@ -388,8 +439,8 @@ def _ssm_decode_update_pallas(state, x, dt, A, B, C, D, live, layer,
     f32 = jnp.float32
     # rows of the resident state: ``k`` heads side by side (1: a head a row)
     rows_h, lanes = state.shape[2], state.shape[-1]
-    hb = _head_block(rows_h // G, N, lanes)
-    blocks, a_group = rows_h // hb, rows_h // G // hb
+    hb, gb = _blocks_of(rows_h, G, N, lanes)
+    blocks, per_group = rows_h // hb, rows_h // G
     dt, x = dt.astype(f32), x.astype(f32)
     # the live slots first, in order: the kernel's work list
     order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
@@ -398,19 +449,22 @@ def _ssm_decode_update_pallas(state, x, dt, A, B, C, D, live, layer,
     decay = jnp.broadcast_to(jnp.exp(dt * A)[..., None],
                              (S, H, P)).reshape(S, rows_h, lanes)
     xdt = (dt[..., None] * x).reshape(S, rows_h, lanes)
-    # B and C of a group as columns (the state dimension along the sublanes)
-    bc = jnp.stack([B.astype(f32), C.astype(f32)], axis=-1)   # [S, G, N, 2]
+    # B and C of a group as columns (the state dimension along the
+    # sublanes), or, a group a row, as rows
+    bc = jnp.stack([B.astype(f32), C.astype(f32)], axis=-1 if gb == 1 else 2)
+    pair = (N, 2) if gb == 1 else (2, N)               # [S, G, pair]
     rows = pl.BlockSpec((None, hb, lanes),
                         lambda i, j, order, *_: (order[i], j, 0))
     y, state, fetched = pl.pallas_call(
-        functools.partial(_update_kernel, blocks=blocks, hb=hb),
+        functools.partial(_update_kernel, blocks=blocks, hb=hb, gb=gb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(S, blocks),
             in_specs=[rows, rows,
-                      pl.BlockSpec((None, None, N, 2),
+                      pl.BlockSpec((None, gb) + pair,
                                    lambda i, j, order, *_:
-                                   (order[i], j // a_group, 0, 0)),
+                                   (order[i], j * hb // per_group // gb, 0,
+                                    0)),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=[rows, pl.BlockSpec(memory_space=pl.ANY),
                        pl.BlockSpec(memory_space=pltpu.SMEM)],

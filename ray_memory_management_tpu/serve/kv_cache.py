@@ -52,6 +52,20 @@ without reading the entry, and each chunk leaves it the state of its last
 real token), and the decode step moves only the entries of live slots. A
 model without ``state_spec`` (or with an empty one) gets the pages alone, as
 before.
+
+**A strided array.** An entry of the cache specification may name a fourth
+item, a stride in positions (models/sparse_linear.py: pooled keys, one a 16
+positions): the array then holds ``page_tokens / stride`` entries a page,
+``[lead..., P, page_tokens / stride, trail...]``, and counts at a
+``stride``-th of a position in ``token_bytes``. Entry ``e`` of page ``p``
+stands for positions ``stride * e .. stride * e + stride - 1`` of that page,
+so one page id still means the same positions of the same row in every
+array. What a model owes the pool for such an array: ``page_tokens`` a whole
+number of strides, an entry written into the page of the positions it
+stands for (even where what it holds is computed from positions of the page
+before it), and an entry read only once what it holds is complete (the
+pool zeroes nothing, and a page's entries past a row's positions hold
+whatever its last owner left there).
 """
 
 from __future__ import annotations
@@ -70,13 +84,24 @@ def row_token_bytes(cfg) -> int:
     return _spec_bytes(serving_model(cfg).cache_spec(cfg))
 
 
+def _entry(spec_item):
+    """(dims before, dims after, dtype, stride in positions) of an entry of
+    a specification: a stride of 1 where it names none."""
+    lead, trail, dtype, *stride = spec_item
+    return lead, trail, dtype, (stride[0] if stride else 1)
+
+
 def _spec_bytes(spec) -> int:
     """Bytes of one entry (a position, or a slot) over a specification's
-    arrays."""
+    arrays; a strided array's at a ``stride``-th of a position."""
     import jax.numpy as jnp
 
-    return sum(int(np.prod(lead + trail)) * jnp.dtype(dtype).itemsize
-               for lead, trail, dtype in spec.values())
+    total = 0.0
+    for item in spec.values():
+        lead, trail, dtype, stride = _entry(item)
+        total += int(np.prod(lead + trail)) * jnp.dtype(dtype).itemsize \
+            / stride
+    return int(total)
 
 
 class KVPagePool:
@@ -96,6 +121,11 @@ class KVPagePool:
         model = serving_model(cfg)
         self.page_tokens = max(1, int(page_tokens))
         self.spec = model.cache_spec(cfg)
+        for name, item in self.spec.items():
+            if self.page_tokens % _entry(item)[3]:
+                raise ValueError(
+                    f"a page of {self.page_tokens} positions holds no whole "
+                    f"number of {name!r}'s strides of {_entry(item)[3]}")
         self.token_bytes = _spec_bytes(self.spec)
         self.page_bytes = self.page_tokens * self.token_bytes
         # what a slot holds whatever its length (most models: nothing)
@@ -129,9 +159,12 @@ class KVPagePool:
         donated cannot also be pinned in a store."""
         import jax.numpy as jnp
 
-        pages = (self.capacity_pages + 1, self.page_tokens)
-        pool = {name: jnp.zeros(lead + pages + trail, dtype)
-                for name, (lead, trail, dtype) in self.spec.items()}
+        pool = {}
+        for name, item in self.spec.items():
+            lead, trail, dtype, stride = _entry(item)
+            pool[name] = jnp.zeros(
+                lead + (self.capacity_pages + 1, self.page_tokens // stride)
+                + trail, dtype)
         pool.update({name: jnp.zeros(lead + (self.max_slots,) + trail, dtype)
                      for name, (lead, trail, dtype)
                      in self.state_spec.items()})

@@ -751,3 +751,111 @@ def test_expanded_chunk_attention_kernel_compiles_for_v5e(one_chip):
     m = compiled.memory_analysis()
     assert m.output_size_in_bytes == C * H * 256 * 2
     assert m.temp_size_in_bytes < 0.2e9
+
+
+# --------------- MiniCPM-SALA's kernels (models/sparse_linear.py, longdoc-batch)
+# at the cell's shapes: 32 decode rows or a chunk of 4,096 queries, 2 K/V
+# heads of 16 query heads at 128, pages of 4,096 (pooled keys: 256 a page),
+# blocks of 64, rows of 8 pages
+SALA = dict(L=2, hkv=2, rep=16, dh=128, P=257, page=4096, width=8, rows=32,
+            chunk=4096)
+
+
+def _sala_shapes(one_chip, n):
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    c = SALA
+    G = c["rows"] if n == 1 else 1
+    pages = s((c["L"], c["hkv"], c["P"], c["page"], c["dh"]))
+    return (s, G, s((G, n, c["hkv"], c["rep"], c["dh"])), pages,
+            s((c["L"], c["hkv"], c["P"], c["page"] // 16, c["dh"])),
+            s((G, c["width"]), jnp.int32), s((G, n), jnp.int32))
+
+
+@pytest.mark.parametrize("n", [1, 4096], ids=["rows", "chunk"])
+@pytest.mark.parametrize("kernel", ["block_scores", "block_select",
+                                    "block_sparse_attention"])
+def test_block_sparse_kernels_compile_for_v5e(one_chip, kernel, n):
+    """The three kernels of the block selection, each one Mosaic call under
+    its own name, for the decode rows and for a chunk, at the cell's shapes:
+    the scores with their softmax and the sum over a group's heads; the
+    threshold top-64 of 512 blocks; the attention over the chosen blocks
+    (128 at most: every block before ``dense_len``)."""
+    from ray_memory_management_tpu.ops import paged_attention as pa
+
+    s, G, q, pages, pooled, table, pos = _sala_shapes(one_chip, n)
+    NB = SALA["width"] * SALA["page"] // 64
+    if kernel == "block_scores":
+        fn, args = (lambda q, c, t, p: pa.block_scores(
+            q, c, t, p, layer=1, stride=16, window=32, block=64,
+            scale=128 ** -0.5, use_pallas="on")), (q, pooled, table, pos)
+    elif kernel == "block_select":
+        fn, args = (lambda r, p: pa.block_select(
+            r, p, top_k=64, block=64, init_blocks=1, local=2048,
+            dense_len=8192, use_pallas="on")), (
+                s((G, n, SALA["hkv"], NB), jnp.float32), pos)
+    else:
+        fn, args = (lambda q, k, v, t, ch, p, live: pa.block_sparse_attention(
+            q, k, v, t, ch, p, layer=1, block=64, scale=128 ** -0.5,
+            most=128, live=live if n == 1 else None, use_pallas="on")), (
+                q, pages, pages, table,
+                s((G, n, SALA["hkv"], NB), jnp.bool_), pos,
+                s((G,), jnp.bool_))
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert kernel in text
+    # the pool is read where it lies: no copy of a page array
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_lightning_state_update_compiles_for_v5e_with_a_group_a_head(
+        one_chip):
+    """``ssm_decode_update`` at longdoc-batch's shape (6 lightning layers, 32
+    slots, 32 heads of a [128, 128] float32 state, **a group a head**):
+    one Mosaic call under the name the cell's reader counts, a grid step a
+    block of 16 heads each with its own B and C, and the 403 MB state
+    aliased in and out, not copied."""
+    from ray_memory_management_tpu.ops import ssm
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    L, S, H, N = 6, 32, 32, 128
+    f32 = jnp.float32
+    compiled = jax.jit(
+        lambda st, x, dt, A, B, C, D, live, layer: ssm.ssm_decode_update(
+            st, x, dt, A, B, C, D, live, layer=layer, use_pallas="on",
+            name="lightning_decode_update"),
+        donate_argnums=(0,)).lower(
+        s((L, S, H, N, N), f32), s((S, H, N)), s((S, H), f32),
+        s((H,), f32), s((S, H, N)), s((S, H, N)), s((H,), f32),
+        s((S,), jnp.bool_), s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "lightning_decode_update" in text
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= L * S * H * N * N * 4
+    assert m.temp_size_in_bytes < 64 << 20
+
+
+def test_lightning_chunk_scan_compiles_for_v5e_with_a_group_a_head(one_chip):
+    """``ssd_chunk_scan`` over a chunk of 4,096 with a group a head (32
+    heads of 128, a state of 128, bf16 operands): one Mosaic call, eight
+    heads a grid step."""
+    from ray_memory_management_tpu.ops.ssm import ssd_scan
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    T, H, N = 4096, 32, 128
+    f32 = jnp.float32
+    compiled = jax.jit(lambda x, dt, A, B, C, D, n, h0: ssd_scan(
+        x, dt, A, B, C, D, true_len=n, h0=h0, use_pallas="on")).lower(
+        s((T, H, N)), s((T, H), f32), s((H,), f32), s((T, H, N)),
+        s((T, H, N)), s((H,), f32), s((), jnp.int32),
+        s((H, N, N), f32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "ssd_chunk_scan" in text
