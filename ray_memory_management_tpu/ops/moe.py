@@ -31,11 +31,11 @@ of a layer's experts**: told the first id it holds, it computes the part of
 the result that the held experts give and nothing in place of the rest (the
 guide's share cut: a router as wide as published, one chip's experts; the
 exchange between chips is not here: ``parallel/sharding.py`` has none). On a
-TPU the two-matrix form runs in a Pallas kernel of its own,
-``moe_expert_tiles_<tiles>`` (tiles of 128 rows, one expert each, the
-expert's two matrices whole in VMEM; a decode step's rows are every held
-expert's tile as they lie); the SwiGLU form and every other platform take
-``jax.lax.ragged_dot``.
+TPU the two-matrix form runs in a Pallas kernel, ``moe_expert_tiles_<tiles>``
+(tiles of 128 rows, one expert each, the expert's matrices whole in VMEM; a
+decode step's rows are every held expert's tile as they lie), and the SwiGLU
+form in a decode step, ``moe_swiglu_tiles_<E>``. Longer SwiGLU steps, experts
+too wide for VMEM and every other platform take ``jax.lax.ragged_dot``.
 """
 
 from __future__ import annotations
@@ -220,22 +220,22 @@ def grouped_experts(x, chosen, w, layer, live=None, first: int = 0,
         come back as junk, not as zeros**, and every expert's rows are
         walked in tiles of 512: at 5 to 90 rows an expert it runs at a
         third of the bandwidth and a twentieth of the peak);
-      - for the two-matrix form on a TPU, the Pallas kernel
-        ``name="moe_expert_tiles_<tiles>"``: each expert's sorted rows are
-        laid out from a tile boundary of their own (``EXPERT_TILE`` rows: one
+      - on a TPU, the Pallas kernel ``name="moe_expert_tiles_<tiles>"``
+        (SwiGLU: ``moe_swiglu_tiles_<E>``, a step of a tile at most; a longer
+        one keeps the grouped matmul): each expert's sorted rows are laid
+        out from a tile boundary of their own (``EXPERT_TILE`` rows: one
         pass of the MXU), so a tile has one expert; the grid walks the tiles
-        that have rows, in expert order; a tile's two matmuls and the
-        squared ReLU between them run in one visit with the expert's two
-        matrices in VMEM (fetched once an expert, whole and contiguous,
-        double buffered against the tile before); an expert no token chose
-        has no tile and is never fetched. **A step of no more rows than a
-        tile (a decode step) needs no sort at all**: every held expert's
-        tile is the step's own rows, the grid walks the held experts, a
-        column of the [T, E] matrix of weights says which rows count for
-        the expert (0: not its), and the results add up in one block that
-        stays in VMEM. Chosen from the platform and the shapes
-        (:func:`expert_kernel_takes`), never by a flag; ``use_pallas``:
-        "on", "interpret", "off", or None = that choice.
+        that have rows, in expert order; a tile's matmuls and the activation
+        between them run in one visit with the expert's matrices in VMEM
+        (fetched once an expert, whole and contiguous, double buffered
+        against the tile before); an expert no token chose has no tile and
+        is never fetched. **A step of no more rows than a tile (a decode
+        step) needs no sort at all**: every held expert's tile is the step's
+        own rows, the grid walks the held experts, a column of the [T, E]
+        matrix of weights says which rows count for the expert (0: not its),
+        and the results add up in one block that stays in VMEM. Chosen from
+        the platform and the shapes (:func:`expert_kernel_takes`), never by
+        a flag; ``use_pallas`` "on", "interpret", "off", or None: that choice.
 
     **A share of the experts** (``E`` less than the router's width, or
     ``first`` > 0: one chip's part of a layer that several chips share): an
@@ -262,13 +262,13 @@ def grouped_experts(x, chosen, w, layer, live=None, first: int = 0,
             chosen = jnp.where(held, chosen, E)           # past every group
             w = jnp.where(held, w, 0.0)
         if use_pallas != "off" and T <= EXPERT_TILE:
-            return _one_tile_experts(x, chosen, w, layer,
-                                     interpret=(use_pallas == "interpret"))
+            return (_one_tile_swiglu if "w3" in layer else _one_tile_experts)(
+                x, chosen, w, layer, interpret=(use_pallas == "interpret"))
         flat = chosen.reshape(-1)                         # [T * k]
         order = jnp.argsort(flat, stable=True)
         sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
         back = jnp.argsort(order)                         # where each lies
-        if use_pallas != "off":
+        if use_pallas != "off" and "w3" not in layer:
             ys = _tiled_experts(x, flat, order, back, sizes, layer, top_k,
                                 interpret=(use_pallas == "interpret"))
             # a row that lies nowhere reads whatever its index points at
@@ -308,15 +308,15 @@ def expert_tiles(rows: int, top_k: int, experts: int) -> int:
 
 
 def expert_kernel_takes(x, layer) -> bool:
-    """Can the compiled expert kernel run this layer on a TPU? The
-    two-matrix form (the SwiGLU experts keep the compiler's grouped matmul),
-    widths in whole lanes, rows and matrices of one type, and two experts'
-    matrices within the kernel's VMEM."""
-    w1, w2 = layer["w1"], layer["w2"]
-    return ("w3" not in layer and x.dtype == w1.dtype == w2.dtype
+    """Can the compiled expert kernel run this layer on a TPU? Either form
+    of expert, widths in whole lanes, rows and matrices of one type, and two
+    experts' matrices (one computed on, one arriving) within the kernel's
+    VMEM: wider SwiGLU experts keep the compiler's grouped matmul."""
+    mats = [layer[k] for k in ("w1", "w3", "w2") if k in layer]
+    return (all(m.dtype == x.dtype for m in mats)
             and x.dtype in (jnp.bfloat16, jnp.float32)
-            and all(n % 128 == 0 for n in w1.shape[1:] + w2.shape[2:])
-            and 2 * (w1.size + w2.size) // w1.shape[0]
+            and all(n % 128 == 0 for m in mats for n in m.shape[1:])
+            and 2 * sum(m.size for m in mats) // mats[0].shape[0]
             * jnp.dtype(x.dtype).itemsize <= _EXPERT_VMEM * 3 // 4)
 
 
@@ -464,3 +464,74 @@ def _held(ys, flat, layer, first, T):
     if first or E != layer["router"].shape[-1]:
         ys = jnp.where((flat < E)[:, None], ys, 0.0)
     return ys.reshape(T, -1, ys.shape[-1])
+
+
+# ------------------------------------------ the kernel's three-matrix form
+# (below the two-matrix form's functions, whose lines the programs of the
+# models that run it carry). A step of a tile at most alone: past a tile the
+# SwiGLU experts keep the grouped matmul, whose programs set up sooner
+def _swiglu_rows(x_ref, w1_ref, w3_ref, w2_ref, weigh):
+    """A tile's rows through one SwiGLU expert: ``w2(silu(w1 x) * w3 x)``
+    in float32, each row's middle times ``weigh`` [rows, 1]."""
+    x = x_ref[...]
+    h = jax.nn.silu(jnp.dot(x, w1_ref[...],
+                            preferred_element_type=jnp.float32)) \
+        * jnp.dot(x, w3_ref[...], preferred_element_type=jnp.float32)
+    return jnp.dot((h * weigh).astype(x.dtype), w2_ref[...],
+                   preferred_element_type=jnp.float32)
+
+
+def _swiglu_one_tile_kernel(fetch_ref, count_ref, x_ref, gate_ref, w1_ref,
+                            w3_ref, w2_ref, o_ref):
+    import jax.experimental.pallas as pl
+
+    e = pl.program_id(0)
+
+    @pl.when(e == 0)
+    def _first_expert():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(count_ref[e] > 0)
+    def _an_expert_with_rows():
+        at = jax.lax.broadcasted_iota(jnp.int32, gate_ref.shape, 1)
+        mine = jnp.sum(jnp.where(at == e, gate_ref[...], 0.0), axis=1,
+                       keepdims=True)                          # [T, 1]
+        o_ref[...] += _swiglu_rows(x_ref, w1_ref, w3_ref, w2_ref, mine)
+
+
+def _one_tile_swiglu(x, chosen, w, layer, interpret):
+    """:func:`_one_tile_experts` for SwiGLU experts: the step's rows through
+    every held expert that a row chose, the expert's three matrices fetched
+    whole (an expert without rows: not at all). (y [T, D'], expert_tokens)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    T, D = x.shape
+    w1, w3, w2 = layer["w1"], layer["w3"], layer["w2"]
+    E, F, Dout = w1.shape[0], w1.shape[2], w2.shape[2]
+    hit = chosen[..., None] == jnp.arange(E)                   # [T, k, E]
+    gate = jnp.sum(jnp.where(hit, w[..., None], 0.0), axis=1)  # [T, E]
+    sizes = jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
+    fetch = jax.lax.cummax(jnp.where(sizes > 0, jnp.arange(E), 0)).astype(
+        jnp.int32)
+    expert = lambda e, fetch, n: (fetch[e], 0, 0)  # noqa: E731
+    whole = lambda e, fetch, n: (0, 0)  # noqa: E731
+    y = pl.pallas_call(
+        _swiglu_one_tile_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(E,),
+            in_specs=[pl.BlockSpec((T, D), whole),
+                      pl.BlockSpec((T, E), whole),
+                      pl.BlockSpec((None, D, F), expert),
+                      pl.BlockSpec((None, D, F), expert),
+                      pl.BlockSpec((None, F, Dout), expert)],
+            out_specs=pl.BlockSpec((T, Dout), whole)),
+        out_shape=jax.ShapeDtypeStruct((T, Dout), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_EXPERT_VMEM),
+        interpret=interpret,
+        name=f"moe_swiglu_tiles_{E}",
+    )(fetch, sizes, x, gate, w1, w3, w2)
+    return y.astype(x.dtype), sizes

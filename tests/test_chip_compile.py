@@ -288,6 +288,126 @@ def test_expert_tile_kernel_compiles_for_v5e(one_chip, T):
     assert "ragged-dot" not in text
 
 
+def _cell_engine(one_chip, monkeypatch, config, traffic):
+    """A cell's engine, its program configuration, and its weights and pool
+    as shapes on a described chip, the kernels' dispatch steered there:
+    (engine, program configuration, params, pool, shape maker)."""
+    import importlib
+
+    from chipbench import architectures, manifest
+    from chipbench.drivers import serve as serve_driver
+    from ray_memory_management_tpu.serve.llm import ContinuousBatcher
+
+    for name in ("ops.flash_attention", "ops.paged_attention", "ops.moe"):
+        monkeypatch.setattr(importlib.import_module(
+            "ray_memory_management_tpu." + name), "_on_tpu", lambda: True)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg = manifest.config(config)
+    arch = architectures.of(cfg)
+    e = serve_driver.engine_kwargs(cfg, manifest.traffic(traffic))
+    pc = arch.program_config(cfg)
+    shaped = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: arr(x.shape, x.dtype), tree)
+    params = shaped(jax.eval_shape(
+        lambda: arch.init_program_params(jax.random.PRNGKey(0), pc)))
+    eng = ContinuousBatcher(
+        None, pc, max_slots=e["max_batch_size"],
+        max_new_tokens=e["max_new_tokens"], pad_multiple=e["pad_multiple"],
+        steps_per_iter=e["steps_per_iter"], kv_page_tokens=e["kv_page_tokens"],
+        kv_pool_bytes=e["kv_pool_bytes"])
+    return eng, pc, params, shaped(jax.eval_shape(eng.kv_pool.allocate)), arr
+
+
+@pytest.mark.parametrize("bucket", [None, 4096], ids=["decode", "4096"])
+def test_swiglu_expert_kernel_holds_reasoning_batchs_decode_program(
+        one_chip, monkeypatch, bucket):
+    """``reasoning-batch``'s decode program (``glm-4.7-flash-d7``: 32 rows,
+    64 SwiGLU experts of 2,048 x 1,536, 4 a token, six expert layers) holds
+    the three-matrix expert kernel once an expert layer, under its own name
+    (``moe_swiglu_tiles_64``; none of the two-matrix form's), and no grouped
+    matmul of the compiler's; two experts' three matrices (37.7 MB) lie
+    inside the VMEM the kernel asks for, which the compile holds it to. The
+    prefill program of its longest bucket, 4,096 rows, keeps the compiler's
+    grouped matmul, three an expert layer, and no expert kernel."""
+    import re
+
+    from ray_memory_management_tpu.ops import moe
+
+    eng, pc, params, pool, arr = _cell_engine(
+        one_chip, monkeypatch, "glm-4.7-flash-d7", "reasoning-batch")
+    try:
+        slots, width = eng.max_slots, eng.kv_pool.table_width
+        if bucket is None:
+            rows = slots
+            compiled = eng._paged_step.lower(
+                params, pool, arr((slots,)), arr((slots,)),
+                arr((slots, width)), arr((2,), jnp.uint32)).compile()
+        else:
+            rows = bucket
+            compiled = eng._paged_prefill_fn(bucket).lower(
+                params, pool, arr((1, bucket)), arr((width,)), arr(()),
+                arr((2,), jnp.uint32)).compile()
+    finally:
+        eng.close()
+    layer = params["layers"][-1]["moe"]
+    assert layer["w1"].shape == (64, 2048, 1536) and slots == 32
+    assert moe.expert_kernel_takes(arr((rows, 2048), jnp.bfloat16), layer)
+    text = compiled.as_text()
+    experts = pc.n_layers - pc.first_k_dense
+    kernels = re.findall(r"%(moe_\w+_tiles_\d+)[.\d]* = ", text)
+    assert experts == 6
+    if bucket is None:
+        assert kernels == ["moe_swiglu_tiles_64"] * experts
+        assert "ragged-dot" not in text
+    else:
+        assert kernels == []
+        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
+            == 3 * experts
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+def test_experts_too_wide_for_the_kernel_keep_the_grouped_matmul(
+        one_chip, monkeypatch, program):
+    """``longcontext-batch``'s decode program and its mixed program
+    (``glm-5.2-d6-e16``: a share of 16 SwiGLU experts of 6,144 x 2,048 a
+    layer, five expert layers; two experts' three matrices are 151 MB) are
+    refused the kernel: each expert layer lowers to three of the compiler's
+    grouped matmuls and no expert kernel, as before the kernel took three
+    matrices."""
+    import re
+
+    from ray_memory_management_tpu.ops import moe
+
+    eng, pc, params, pool, arr = _cell_engine(
+        one_chip, monkeypatch, "glm-5.2-d6-e16", "longcontext-batch")
+    try:
+        slots, width = eng.max_slots, eng.kv_pool.table_width
+        key, chunk = arr((2,), jnp.uint32), eng._chunk
+        if program == "decode":
+            lowered = eng._paged_step.lower(
+                params, pool, arr((slots,)), arr((slots,)),
+                arr((slots, width)), key)
+        else:
+            lowered = eng._mixed_step.lower(
+                params, pool, arr((chunk,)), arr((width,)), arr(()), arr(()),
+                arr(()), arr((slots,)), arr((slots,)), arr((slots, width)),
+                key)
+    finally:
+        eng.close()
+    layer = params["layers"][-1]["moe"]
+    assert layer["w1"].shape == (16, 6144, 2048)
+    assert not moe.expert_kernel_takes(arr((slots, 6144), jnp.bfloat16),
+                                       layer)
+    text = lowered.as_text()
+    experts = pc.n_layers - pc.first_k_dense
+    assert experts == 5
+    assert text.count('"chlo.ragged_dot"') == 3 * experts
+    assert not re.findall(r'kernel_name = "moe_', text)
+
+
 def test_paged_step_keeps_the_pool_where_it_is(one_chip, monkeypatch):
     """The engine's one decode program at chat-online's size (Mistral-7B
     widths, 16 layers, 16 slots, 96 pages of 256 and the sink): the kernel

@@ -135,6 +135,40 @@ def test_idle_rows_touch_no_expert_and_are_counted_nowhere():
     assert float(jnp.abs(y[~live]).max()) == 0.0
 
 
+# (rows, first held expert, experts held of 64): one row; a decode step of
+# 32 with idle rows; more rows than a tile (which keep the grouped matmul); a
+# share of the experts, in a decode step and past a tile
+SWIGLU = [(1, 0, 64), (32, 0, 64), (300, 0, 64), (32, 16, 16), (300, 48, 16)]
+
+
+@pytest.mark.parametrize("T,first,held", SWIGLU, ids=str)
+def test_swiglu_expert_kernel_in_interpret_mode_against_the_grouped_matmul(
+        T, first, held):
+    """The Pallas form of the SwiGLU experts (a decode step's rows through
+    every held expert's three matrices; past a tile the grouped matmul
+    whatever ``use_pallas`` says) against the grouped matmul over the rows
+    as they lie: the same results to float32 rounding, the same counts, and
+    0 in every idle row."""
+    layer = _expert_layer(T, d=128, f=256, e=64)
+    share = dict(layer, **{k: layer[k][first:first + held]
+                           for k in ("w1", "w3", "w2")})
+    x = jax.random.normal(jax.random.PRNGKey(T + 1), (T, 128))
+    assert moe.expert_kernel_takes(x, share)
+    chosen, w = moe.route_sigmoid_top_k(x, layer["router"], layer["bias"],
+                                        4, 1.8)
+    live = jnp.arange(T) % 5 != 3
+    want, n = moe.grouped_experts(x, chosen, w, share, live, first,
+                                  use_pallas="off")
+    got, m = jax.jit(lambda x, c, w, s: moe.grouped_experts(
+        x, c, w, s, live, first, use_pallas="interpret"))(x, chosen, w, share)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert np.array_equal(np.asarray(n), np.asarray(m))
+    assert not np.any(np.asarray(got)[~np.asarray(live)])
+    assert int(m.sum()) == (int(jnp.sum((chosen[live] >= first)
+                                        & (chosen[live] < first + held)))
+                            if held < 64 else 4 * int(live.sum()))
+
+
 # ------------------------------------------------------- the latent kernel
 def _latent_case(lengths, width=4, heads=5, w=256, seed=0, pages=12, L=2):
     """Rows of ``lengths`` on shuffled page ids; every table entry a row

@@ -253,15 +253,22 @@ def test_expert_tile_kernel_in_interpret_mode_against_the_grouped_matmul(
             live[:, None], chosen, -1), w, share, first)), atol=5e-5)
 
 
-def test_the_expert_kernel_is_for_the_two_matrix_form_in_whole_lanes():
+def test_the_expert_kernel_takes_either_form_in_whole_lanes_within_vmem():
     bf = jnp.bfloat16
     z = lambda *shape: jnp.zeros(shape, bf)  # noqa: E731
     nemotron = {"w1": z(4, 1024, 2688), "w2": z(4, 2688, 1024)}
     assert moe.expert_kernel_takes(z(128, 1024), nemotron)
-    # the SwiGLU experts keep the compiler's grouped matmul
-    assert not moe.expert_kernel_takes(
-        z(32, 2048), {"w1": z(4, 2048, 1536), "w3": z(4, 2048, 1536),
-                      "w2": z(4, 1536, 2048)})
+
+    def swiglu(d, f):
+        return {"w1": z(4, d, f), "w3": z(4, d, f), "w2": z(4, f, d)}
+
+    # glm-4.7-flash-d7's SwiGLU experts: two experts' three matrices are
+    # 37.7 MB; glm-5.2-d6-e16's are 151 MB, past the kernel's VMEM, and keep
+    # the compiler's grouped matmul
+    assert moe.expert_kernel_takes(z(32, 2048), swiglu(2048, 1536))
+    assert not moe.expert_kernel_takes(z(32, 6144), swiglu(6144, 2048))
+    assert not moe.expert_kernel_takes(z(32, 2048), dict(
+        swiglu(2048, 1536), w3=jnp.zeros((4, 2048, 1536))))
     assert not moe.expert_kernel_takes(z(8, 32), {"w1": z(4, 32, 24),
                                                   "w2": z(4, 24, 32)})
     assert not moe.expert_kernel_takes(jnp.zeros((8, 1024)), nemotron)
