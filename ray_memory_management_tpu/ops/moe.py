@@ -33,9 +33,9 @@ guide's share cut: a router as wide as published, one chip's experts; the
 exchange between chips is not here: ``parallel/sharding.py`` has none). On a
 TPU the two-matrix form runs in a Pallas kernel, ``moe_expert_tiles_<tiles>``
 (tiles of 128 rows, one expert each, the expert's matrices whole in VMEM; a
-decode step's rows are every held expert's tile as they lie), and the SwiGLU
-form in a decode step, ``moe_swiglu_tiles_<E>``. Longer SwiGLU steps, experts
-too wide for VMEM and every other platform take ``jax.lax.ragged_dot``.
+decode step's rows are every held expert's tile as they lie), the SwiGLU form
+in a decode step, ``moe_swiglu_tiles_<E>``, and SwiGLU experts too wide for
+VMEM in blocks of F at any step, ``moe_swiglu_blocks_<grid>``; else ragged_dot.
 """
 
 from __future__ import annotations
@@ -221,21 +221,21 @@ def grouped_experts(x, chosen, w, layer, live=None, first: int = 0,
         walked in tiles of 512: at 5 to 90 rows an expert it runs at a
         third of the bandwidth and a twentieth of the peak);
       - on a TPU, the Pallas kernel ``name="moe_expert_tiles_<tiles>"``
-        (SwiGLU: ``moe_swiglu_tiles_<E>``, a step of a tile at most; a longer
-        one keeps the grouped matmul): each expert's sorted rows are laid
-        out from a tile boundary of their own (``EXPERT_TILE`` rows: one
-        pass of the MXU), so a tile has one expert; the grid walks the tiles
-        that have rows, in expert order; a tile's matmuls and the activation
-        between them run in one visit with the expert's matrices in VMEM
-        (fetched once an expert, whole and contiguous, double buffered
-        against the tile before); an expert no token chose has no tile and
-        is never fetched. **A step of no more rows than a tile (a decode
-        step) needs no sort at all**: every held expert's tile is the step's
+        (SwiGLU: ``moe_swiglu_tiles_<E>``, a step of a tile at most, else
+        the grouped matmul; too wide: :func:`_blocked_swiglu`): sorted rows
+        are laid out from a tile boundary of their own (``EXPERT_TILE``
+        rows: one pass of the MXU), so a tile has one expert; the grid walks
+        the tiles that have rows, in expert order; a tile's matmuls and the
+        activation between them run in one visit with the expert's matrices
+        in VMEM (fetched once an expert, whole and contiguous, double
+        buffered against the tile before); an expert no token chose has no
+        tile and is never fetched. **A step of no more rows than a tile (a
+        decode step) needs no sort**: every held expert's tile is the step's
         own rows, the grid walks the held experts, a column of the [T, E]
         matrix of weights says which rows count for the expert (0: not its),
-        and the results add up in one block that stays in VMEM. Chosen from
-        the platform and the shapes (:func:`expert_kernel_takes`), never by
-        a flag; ``use_pallas`` "on", "interpret", "off", or None: that choice.
+        and the results add up in one block that stays in VMEM. By platform
+        and shapes (:func:`expert_kernel_takes`, :func:`expert_blocks_take`),
+        never a flag; ``use_pallas`` "on", "interpret", "off", or None: that.
 
     **A share of the experts** (``E`` less than the router's width, or
     ``first`` > 0: one chip's part of a layer that several chips share): an
@@ -249,8 +249,8 @@ def grouped_experts(x, chosen, w, layer, live=None, first: int = 0,
     T = x.shape[0]
     E, top_k = layer["w1"].shape[0], chosen.shape[-1]
     if use_pallas is None:
-        use_pallas = "on" if _on_tpu() and expert_kernel_takes(x, layer) \
-            else "off"
+        use_pallas = "on" if _on_tpu() and (expert_kernel_takes(
+            x, layer) or expert_blocks_take(x, layer)) else "off"
     with jax.named_scope("moe_experts"):
         held = None
         if first or E != layer["router"].shape[-1]:
@@ -261,8 +261,8 @@ def grouped_experts(x, chosen, w, layer, live=None, first: int = 0,
         if held is not None:
             chosen = jnp.where(held, chosen, E)           # past every group
             w = jnp.where(held, w, 0.0)
-        if use_pallas != "off" and T <= EXPERT_TILE:
-            return (_one_tile_swiglu if "w3" in layer else _one_tile_experts)(
+        if use_pallas != "off" and (call := _one_call(x, layer)):
+            return call(
                 x, chosen, w, layer, interpret=(use_pallas == "interpret"))
         flat = chosen.reshape(-1)                         # [T * k]
         order = jnp.argsort(flat, stable=True)
@@ -534,4 +534,327 @@ def _one_tile_swiglu(x, chosen, w, layer, interpret):
         interpret=interpret,
         name=f"moe_swiglu_tiles_{E}",
     )(fetch, sizes, x, gate, w1, w3, w2)
+    return y.astype(x.dtype), sizes
+
+
+
+# ------------------------------------------- the three-matrix form in blocks
+# (below every kernel above, whose lines the programs of the models that run
+# them carry). SwiGLU experts too wide for two of them in VMEM: an expert's
+# matrices cross in blocks of the intermediate width F (columns of w1 and w3,
+# rows of w2), and the down projection adds up over the blocks in float32
+def _expert_block(x, layer) -> int:
+    """Columns of F a block takes: the widest whole lanes that divide F and
+    whose slices of the three matrices, two of each (one computed on, one
+    arriving), take no more than three eighths of the kernel's VMEM (a
+    pass's rows take the rest); 0 where none does."""
+    D, F = layer["w1"].shape[1:]
+    per = 2 * (2 * D + layer["w2"].shape[2]) * jnp.dtype(x.dtype).itemsize
+    return max((b for b in range(EXPERT_TILE, F + 1, EXPERT_TILE)
+                if F % b == 0 and per * b <= _EXPERT_VMEM * 3 // 8),
+               default=0)
+
+
+def expert_blocks_take(x, layer) -> bool:
+    """SwiGLU experts that :func:`expert_kernel_takes` refuses by VMEM alone
+    (widths in whole lanes, rows and matrices of one type) and whose
+    intermediate width splits into blocks that fit: the blocked kernel's."""
+    mats = [layer[k] for k in ("w1", "w3", "w2") if k in layer]
+    return ("w3" in layer and not expert_kernel_takes(x, layer)
+            and all(m.dtype == x.dtype for m in mats)
+            and x.dtype in (jnp.bfloat16, jnp.float32)
+            and all(n % 128 == 0 for m in mats for n in m.shape[1:])
+            and _expert_block(x, layer) > 0)
+
+
+def _one_call(x, layer):
+    """The kernel that :func:`grouped_experts` hands a whole step to, or
+    None: the blocked form for SwiGLU experts too wide for VMEM, at any T;
+    else the one-tile form of the whole-matrix kernel, up to a tile of rows
+    (a longer step is sorted there)."""
+    if expert_blocks_take(x, layer):
+        return _blocked_swiglu
+    if x.shape[0] <= EXPERT_TILE:
+        return _one_tile_swiglu if "w3" in layer else _one_tile_experts
+    return None
+
+
+def _slab_rows(width: int) -> int:
+    """Rows of 128 lanes that a token's vector of ``width`` takes in a slab
+    (:func:`_blocked_swiglu`): whole tiles of 8, so that a token's rows are
+    one DMA from an aligned row."""
+    return -(-width // 1024) * 8
+
+
+def _pass_rows(x, layer) -> int:
+    """Rows of a pass of the blocked kernel, in whole tiles: its rows as they
+    arrive (float32) and for the MXU, the sums it adds up and those it reads
+    back, within three eighths of the VMEM; no more than the step's rows."""
+    D, Dout = x.shape[1], layer["w2"].shape[2]
+    per = 128 * 4 * (_slab_rows(D) + _slab_rows(Dout)) \
+        + D * jnp.dtype(x.dtype).itemsize + Dout * 4
+    most = _EXPERT_VMEM * 3 // 8 // per // EXPERT_TILE * EXPERT_TILE
+    return max(EXPERT_TILE,
+               min(most, -(-x.shape[0] // EXPERT_TILE) * EXPERT_TILE))
+
+
+def _as_column(row):
+    """A sub-tile's weights [1, EXPERT_TILE] as a column [EXPERT_TILE, 1]."""
+    tile = EXPERT_TILE
+    at = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
+    mine = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+    return jnp.sum(jnp.where(at == mine, row, 0.0), axis=1, keepdims=True)
+
+
+def _swiglu_blocks_kernel(fe_ref, fb_ref, count_ref, x_ref, gate_ref, w1_ref,
+                          w3_ref, w2_ref, o_ref):
+    import jax.experimental.pallas as pl
+
+    e, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((e == 0) & (j == 0))
+    def _first_block():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(count_ref[e] > 0)
+    def _an_expert_with_rows():
+        at = jax.lax.broadcasted_iota(jnp.int32, gate_ref.shape, 1)
+        mine = jnp.sum(jnp.where(at == e, gate_ref[...], 0.0), axis=1,
+                       keepdims=True)                          # [T, 1]
+        x = x_ref[...]
+
+        def columns(c, carry):  # 128 of the block's columns: less code
+            cols = pl.ds(pl.multiple_of(c * 128, 128), 128)
+            h = jax.nn.silu(jnp.dot(x, w1_ref[:, cols],
+                                    preferred_element_type=jnp.float32)) \
+                * jnp.dot(x, w3_ref[:, cols],
+                          preferred_element_type=jnp.float32)
+            o_ref[...] += jnp.dot((h * mine).astype(x.dtype),
+                                  w2_ref[cols, :],
+                                  preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, w1_ref.shape[1] // 128, columns, 0)
+
+
+def _swiglu_pass_kernel(we_ref, wb_ref, count_ref, tok_ref, gate_ref, x_hbm,
+                        w1_ref, w3_ref, w2_ref, _, o_hbm, slab, rows, acc,
+                        sums, sem):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    p, j = pl.program_id(0), pl.program_id(1)
+    n, tile, sub = count_ref[p], rows.shape[0], EXPERT_TILE
+    rx, ro = slab.shape[0] // tile, sums.shape[0] // tile
+
+    def each(copy, act):  # the pass's ``n`` rows, one DMA a token
+        def one(r, c):
+            act(copy(r, tok_ref[p * tile + r]))
+            return c
+        jax.lax.fori_loop(0, n, one, 0)
+
+    def rows_of(ref, i, per):
+        return ref.at[pl.ds(pl.multiple_of(i * per, 8), per)]
+
+    def x_in(r, t):
+        return pltpu.make_async_copy(rows_of(x_hbm, t, rx),
+                                     rows_of(slab, r, rx), sem.at[0])
+
+    def o_in(r, t):
+        return pltpu.make_async_copy(rows_of(o_hbm, t, ro),
+                                     rows_of(sums, r, ro), sem.at[1])
+
+    def o_out(r, t):
+        return pltpu.make_async_copy(rows_of(sums, r, ro),
+                                     rows_of(o_hbm, t, ro), sem.at[2])
+
+    def sub_tiles(body):  # those that hold rows, in one copy of the code
+        def one(s, carry):
+            body(s, pl.ds(pl.multiple_of(s * sub, sub), sub))
+            return carry
+        jax.lax.fori_loop(0, (n + sub - 1) // sub, one, 0)
+
+    def lanes(width, body):  # a sub-tile's columns, 128 at a time
+        def one(c, carry):
+            body(c, pl.ds(pl.multiple_of(c * 128, 128), 128))
+            return carry
+        jax.lax.fori_loop(0, width // 128, one, 0)
+
+    def unslab(s, at):  # a sub-tile's rows from the slab, as the MXU takes
+        def chunk(c, cols):
+            rows[at, cols] = slab[pl.ds(s * sub * rx + c, sub, stride=rx),
+                                  :].astype(rows.dtype)
+        lanes(rows.shape[1], chunk)
+
+    @pl.when((n > 0) & (j == 0))
+    def _fetch_the_rows():
+        each(x_in, lambda c: c.start())
+        each(o_in, lambda c: c.start())
+        each(x_in, lambda c: c.wait())
+        sub_tiles(unslab)
+
+    def through(s, at):
+        part = _swiglu_rows(rows.at[at], w1_ref, w3_ref, w2_ref,
+                            _as_column(gate_ref[pl.ds(s, 1), :]))
+        # the first block's sum is the sub-tile's first: what ``acc`` held
+        # before is another pass's, or nothing
+        acc[at] = jnp.where(j > 0, acc[at], 0.0) + part
+
+    sub_tiles(through)
+
+    def add_back(s, at):
+        def chunk(c, cols):
+            where = pl.ds(s * sub * ro + c, sub, stride=ro)
+            sums[where, :] = sums[where, :] + acc[at, cols]
+        lanes(acc.shape[1], chunk)
+
+    @pl.when((n > 0) & (j == pl.num_programs(1) - 1))
+    def _add_the_rows_back():
+        each(o_in, lambda c: c.wait())
+        sub_tiles(add_back)
+        each(o_out, lambda c: c.start())
+        each(o_out, lambda c: c.wait())
+
+
+def _blocked_swiglu(x, chosen, w, layer, interpret):
+    """SwiGLU experts too wide for VMEM, their matrices in blocks of F
+    (:func:`_expert_block`): (y [T, D'] in ``x``'s type, expert_tokens).
+    Each held expert that a row chose crosses HBM once, block by block; one
+    that no row chose is never fetched (a grid step without rows stays on the
+    block before it, or takes the next expert's first).
+
+      - **At most a tile of rows** (a decode step): the grid of
+        :func:`_one_tile_swiglu`, each held expert's blocks in turn, and the
+        step's rows add up in one block that stays in VMEM;
+      - **more** (a step with a chunk of a prompt): the held assignments
+        alone are sorted by expert and laid out in passes of
+        :func:`_pass_rows` rows, one expert a pass (an expert past a pass's
+        rows takes more, and its matrices cross again for each). A pass
+        fetches its tokens' rows itself, a DMA a token from a float32 slab
+        of ``x`` (a token's vector in whole tiles of rows of 128 lanes:
+        :func:`_slab_rows`), skips the sub-tiles of 128 rows that hold none,
+        and adds its weighted sums to its tokens' float32 rows of the result
+        in HBM, which it reads and writes back the same way. Assignments to
+        experts held elsewhere, and idle rows, cost nothing, and no
+        [T * k, D'] array is made.
+
+    Named ``moe_swiglu_blocks_<grid>`` for the grid's first axis (the held
+    experts; the passes), so a decode step's calls and a longer step's
+    differ."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    T, D = x.shape
+    w1, w3, w2 = layer["w1"], layer["w3"], layer["w2"]
+    E, F, Dout = w1.shape[0], w1.shape[2], w2.shape[2]
+    top_k = chosen.shape[-1]
+    bf = _expert_block(x, layer)
+    nF = F // bf
+    params = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"),
+        vmem_limit_bytes=_EXPERT_VMEM)
+    if T <= EXPERT_TILE:
+        hit = chosen[..., None] == jnp.arange(E)               # [T, k, E]
+        gate = jnp.sum(jnp.where(hit, w[..., None], 0.0), axis=1)
+        sizes = jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
+        has = sizes > 0
+        # an expert without rows stays on the last block of the one before
+        # with rows; before the first with rows, on the first's first block
+        ids = jnp.arange(E)
+        before = jax.lax.cummax(jnp.where(has, ids, -1))
+        after = jax.lax.cummin(jnp.where(has, ids, E - 1), reverse=True)
+        fe = jnp.where(has, ids, jnp.where(before >= 0, before, after))
+        fb = jnp.where(has[:, None], jnp.arange(nF),
+                       jnp.where(before[:, None] >= 0, nF - 1, 0))
+
+        def block(e, j, fe, fb, n):  # w1 / w3 (the F axis last), w2
+            return fe[e], fb[e * nF + j]
+
+        whole = lambda e, j, fe, fb, n: (0, 0)  # noqa: E731
+        y = pl.pallas_call(
+            _swiglu_blocks_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(E, nF),
+                in_specs=[
+                    pl.BlockSpec((T, D), whole),
+                    pl.BlockSpec((T, E), whole),
+                    pl.BlockSpec((None, D, bf), lambda *a: (
+                        block(*a)[0], 0, block(*a)[1])),
+                    pl.BlockSpec((None, D, bf), lambda *a: (
+                        block(*a)[0], 0, block(*a)[1])),
+                    pl.BlockSpec((None, bf, Dout), lambda *a: (
+                        *block(*a), 0))],
+                out_specs=pl.BlockSpec((T, Dout), whole)),
+            out_shape=jax.ShapeDtypeStruct((T, Dout), jnp.float32),
+            compiler_params=params,
+            interpret=interpret,
+            name=f"moe_swiglu_blocks_{E}",
+        )(fe.astype(jnp.int32), fb.reshape(-1).astype(jnp.int32), sizes, x,
+          gate, w1, w3, w2)
+        return y.astype(x.dtype), sizes
+    tile = _pass_rows(x, layer)
+    n_sub, passes = tile // EXPERT_TILE, -(-T * top_k // tile) + E
+    rx, ro = _slab_rows(D), _slab_rows(Dout)
+    flat = chosen.reshape(-1)                             # [T * k]
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+    # an expert's sorted rows start at ``start`` and fill passes from
+    # ``first_pass`` on; a pass past the last with rows has none
+    passes_of = -(-sizes // tile)
+    pass_end = jnp.cumsum(passes_of)
+    first_pass, start = pass_end - passes_of, jnp.cumsum(sizes) - sizes
+    active = pass_end[-1]
+    at = jnp.arange(passes)
+    expert = jnp.minimum(jnp.searchsorted(pass_end, at, side="right"), E - 1)
+    done = tile * (at - first_pass[expert])
+    count = jnp.where(at < active, jnp.clip(sizes[expert] - done, 0, tile), 0)
+    slot = jnp.arange(tile)
+    which = order[jnp.clip((start[expert] + done)[:, None] + slot, 0,
+                           flat.shape[0] - 1)]            # [passes, tile]
+    inside = slot < count[:, None]
+    tok = jnp.where(inside, which // top_k, 0)
+    gate = jnp.where(inside, w.reshape(-1)[which], 0.0)
+    # a pass without rows stays on the last block of the last with rows
+    last = jnp.maximum(active - 1, 0)
+    we = jnp.where(at < active, expert, expert[last])
+    wb = jnp.where((at < active)[:, None], jnp.arange(nF), nF - 1)
+
+    def block(p, j, we, wb, n, t):  # w1 / w3 (the F axis last), w2
+        return we[p], wb[p * nF + j]
+
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    slab_x = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, rx * 128 - D)))
+    y = pl.pallas_call(
+        _swiglu_pass_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(passes, nF),
+            in_specs=[
+                pl.BlockSpec((None, n_sub, EXPERT_TILE),
+                             lambda p, j, *_: (p, 0, 0)),
+                anywhere,
+                pl.BlockSpec((None, D, bf), lambda *a: (
+                    block(*a)[0], 0, block(*a)[1])),
+                pl.BlockSpec((None, D, bf), lambda *a: (
+                    block(*a)[0], 0, block(*a)[1])),
+                pl.BlockSpec((None, bf, Dout), lambda *a: (*block(*a), 0)),
+                anywhere],
+            out_specs=anywhere,
+            scratch_shapes=[pltpu.VMEM((tile * rx, 128), jnp.float32),
+                            pltpu.VMEM((tile, D), x.dtype),
+                            pltpu.VMEM((tile, Dout), jnp.float32),
+                            pltpu.VMEM((tile * ro, 128), jnp.float32),
+                            pltpu.SemaphoreType.DMA((3,))]),
+        out_shape=jax.ShapeDtypeStruct((T * ro, 128), jnp.float32),
+        input_output_aliases={9: 0},
+        compiler_params=params,
+        interpret=interpret,
+        name=f"moe_swiglu_blocks_{passes}",
+    )(we.astype(jnp.int32), wb.reshape(-1).astype(jnp.int32),
+      count.astype(jnp.int32), tok.reshape(-1).astype(jnp.int32),
+      gate.reshape(passes, n_sub, EXPERT_TILE).astype(jnp.float32),
+      slab_x.reshape(T * rx, 128), w1, w3, w2,
+      jnp.zeros((T * ro, 128), jnp.float32))
+    y = y.reshape(T, ro * 128)[:, :Dout]
     return y.astype(x.dtype), sizes

@@ -15,11 +15,28 @@ if "host_platform_device_count" not in prev:
     os.environ["XLA_FLAGS"] = (
         prev + " --xla_force_host_platform_device_count=8"
     ).strip()
+# the suite compiles thousands of small CPU programs and runs few long ones:
+# LLVM's cheapest level takes about a fifth off a run of six xdist workers
+# on eight cores. Only the CPU backend reads it: a described chip's compile
+# (test_chip_compile.py) comes out the same text with and without it.
+if "xla_backend_optimization_level" not in os.environ["XLA_FLAGS"]:
+    os.environ["XLA_FLAGS"] += " --xla_backend_optimization_level=0"
 
-# a CPU run gains nothing from jax's persistent compile cache, and what a
-# described-chip compile (test_chip_compile.py) writes to it cannot be read
-# back without the chip; spawned workers inherit the switch
-os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
+# one persistent compile cache for the run, shared by the xdist workers and
+# every process they spawn: tests build the same small programs again and
+# again (a fresh engine a test, jax.clear_caches()), and each is compiled
+# once a run. The directory is new each run and is removed when the run
+# exits normally. The described-chip fixtures switch the cache off before
+# they compile: what they would write cannot be read back without the chip.
+if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+    import atexit
+    import shutil
+    import tempfile
+
+    _cache_dir = tempfile.mkdtemp(prefix="jax-compile-cache-")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = _cache_dir
+    atexit.register(shutil.rmtree, _cache_dir, True)
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 
 import pytest  # noqa: E402
 

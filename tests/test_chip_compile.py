@@ -369,14 +369,16 @@ def test_swiglu_expert_kernel_holds_reasoning_batchs_decode_program(
 
 
 @pytest.mark.parametrize("program", ["decode", "mixed"])
-def test_experts_too_wide_for_the_kernel_keep_the_grouped_matmul(
+def test_experts_too_wide_for_the_kernel_take_its_blocked_form(
         one_chip, monkeypatch, program):
     """``longcontext-batch``'s decode program and its mixed program
     (``glm-5.2-d6-e16``: a share of 16 SwiGLU experts of 6,144 x 2,048 a
     layer, five expert layers; two experts' three matrices are 151 MB) are
-    refused the kernel: each expert layer lowers to three of the compiler's
-    grouped matmuls and no expert kernel, as before the kernel took three
-    matrices."""
+    refused the whole-matrix kernel by its VMEM and take the blocked one
+    (blocks of 512 of the 2,048 columns): each expert layer lowers to one
+    ``moe_swiglu_blocks_<grid>`` call, the decode step's named for its 16
+    held experts and the mixed step's for its passes, and to none of the
+    compiler's grouped matmuls and no whole-matrix kernel."""
     import re
 
     from ray_memory_management_tpu.ops import moe
@@ -387,10 +389,12 @@ def test_experts_too_wide_for_the_kernel_keep_the_grouped_matmul(
         slots, width = eng.max_slots, eng.kv_pool.table_width
         key, chunk = arr((2,), jnp.uint32), eng._chunk
         if program == "decode":
+            rows = slots
             lowered = eng._paged_step.lower(
                 params, pool, arr((slots,)), arr((slots,)),
                 arr((slots, width)), key)
         else:
+            rows = chunk + slots
             lowered = eng._mixed_step.lower(
                 params, pool, arr((chunk,)), arr((width,)), arr(()), arr(()),
                 arr(()), arr((slots,)), arr((slots,)), arr((slots, width)),
@@ -398,14 +402,20 @@ def test_experts_too_wide_for_the_kernel_keep_the_grouped_matmul(
     finally:
         eng.close()
     layer = params["layers"][-1]["moe"]
+    x = arr((rows, 6144), jnp.bfloat16)
     assert layer["w1"].shape == (16, 6144, 2048)
-    assert not moe.expert_kernel_takes(arr((slots, 6144), jnp.bfloat16),
-                                       layer)
+    assert not moe.expert_kernel_takes(x, layer)
+    assert moe.expert_blocks_take(x, layer)
+    assert moe._expert_block(x, layer) == 512
+    grid = 16 if program == "decode" else \
+        -(-rows * 8 // moe._pass_rows(x, layer)) + 16
     text = lowered.as_text()
     experts = pc.n_layers - pc.first_k_dense
     assert experts == 5
-    assert text.count('"chlo.ragged_dot"') == 3 * experts
-    assert not re.findall(r'kernel_name = "moe_', text)
+    assert re.findall(r'kernel_name = "(moe_\w+)"', text) \
+        == [f"moe_swiglu_blocks_{grid}"] * experts
+    assert '"chlo.ragged_dot"' not in text
+    assert "moe_swiglu_tiles_" not in text
 
 
 def test_paged_step_keeps_the_pool_where_it_is(one_chip, monkeypatch):

@@ -169,6 +169,56 @@ def test_swiglu_expert_kernel_in_interpret_mode_against_the_grouped_matmul(
                             if held < 64 else 4 * int(live.sum()))
 
 
+
+# (rows, first held expert, experts held, the router's width, an expert the
+# router's bias sends every row to): one row; a decode step of 12; more rows
+# than a tile; a share of the experts (16 of 64), in a decode step and past a
+# tile; past a tile with one expert's rows over a pass (128 rows here), which
+# takes three passes
+BLOCKED = [(1, 0, 16, 16, None), (12, 0, 16, 16, None),
+           (300, 0, 16, 16, None), (12, 16, 16, 64, None),
+           (300, 16, 16, 64, None), (400, 0, 16, 16, 3)]
+
+
+@pytest.mark.parametrize("T,first,held,width,push", BLOCKED, ids=str)
+def test_blocked_swiglu_kernel_in_interpret_mode_against_the_grouped_matmul(
+        T, first, held, width, push, monkeypatch):
+    """The F-blocked form of the SwiGLU experts, for experts too wide for
+    the whole-matrix kernel's VMEM (here a VMEM of 1 MiB refuses experts of
+    128 x 512 and takes blocks of 128 of their 512 columns, so that four
+    blocks add up): at a decode step the held experts' blocks over the
+    step's rows, past a tile the held assignments in passes fetched and
+    added back a row at a time; against the grouped matmul over the rows as
+    they lie: the same results to float32 rounding, the same counts, and 0
+    in every idle row."""
+    monkeypatch.setattr(moe, "_EXPERT_VMEM", 1 << 20)
+    layer = _expert_layer(T, d=128, f=512, e=width)
+    if push is not None:
+        layer["bias"] = layer["bias"].at[push].set(100.0)
+    share = dict(layer, **{k: layer[k][first:first + held]
+                           for k in ("w1", "w3", "w2")})
+    x = jax.random.normal(jax.random.PRNGKey(T + 1), (T, 128))
+    assert not moe.expert_kernel_takes(x, share)
+    assert moe.expert_blocks_take(x, share)
+    assert moe._expert_block(x, share) == 128
+    assert moe._pass_rows(x, share) == 128
+    chosen, w = moe.route_sigmoid_top_k(x, layer["router"], layer["bias"],
+                                        4, 1.8)
+    live = jnp.arange(T) % 5 != 3
+    want, n = moe.grouped_experts(x, chosen, w, share, live, first,
+                                  use_pallas="off")
+    got, m = jax.jit(lambda x, c, w, s: moe.grouped_experts(
+        x, c, w, s, live, first, use_pallas="interpret"))(x, chosen, w, share)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert np.array_equal(np.asarray(n), np.asarray(m))
+    assert not np.any(np.asarray(got)[~np.asarray(live)])
+    assert int(m.sum()) == (int(jnp.sum((chosen[live] >= first)
+                                        & (chosen[live] < first + held)))
+                            if held < width else 4 * int(live.sum()))
+    if push is not None:
+        assert int(m[push]) == int(live.sum()) > 2 * 128
+
+
 # ------------------------------------------------------- the latent kernel
 def _latent_case(lengths, width=4, heads=5, w=256, seed=0, pages=12, L=2):
     """Rows of ``lengths`` on shuffled page ids; every table entry a row

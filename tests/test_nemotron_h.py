@@ -263,10 +263,19 @@ def test_the_expert_kernel_takes_either_form_in_whole_lanes_within_vmem():
         return {"w1": z(4, d, f), "w3": z(4, d, f), "w2": z(4, f, d)}
 
     # glm-4.7-flash-d7's SwiGLU experts: two experts' three matrices are
-    # 37.7 MB; glm-5.2-d6-e16's are 151 MB, past the kernel's VMEM, and keep
-    # the compiler's grouped matmul
+    # 37.7 MB; glm-5.2-d6-e16's are 151 MB, past the kernel's VMEM, and take
+    # its blocked form, in blocks of 512 of their 2,048 columns, and only
+    # they: refused for VMEM alone, SwiGLU, whole lanes, one type
     assert moe.expert_kernel_takes(z(32, 2048), swiglu(2048, 1536))
     assert not moe.expert_kernel_takes(z(32, 6144), swiglu(6144, 2048))
+    assert moe.expert_blocks_take(z(32, 6144), swiglu(6144, 2048))
+    assert moe._expert_block(z(32, 6144), swiglu(6144, 2048)) == 512
+    assert not moe.expert_blocks_take(z(32, 2048), swiglu(2048, 1536))
+    assert not moe.expert_blocks_take(z(32, 6144), dict(
+        swiglu(6144, 2048), w3=jnp.zeros((4, 6144, 2048))))
+    shape = jax.ShapeDtypeStruct   # a shape suffices: 403 MB a matrix
+    assert not moe.expert_blocks_take(z(32, 6144), {
+        "w1": shape((4, 6144, 8192), bf), "w2": shape((4, 8192, 6144), bf)})
     assert not moe.expert_kernel_takes(z(32, 2048), dict(
         swiglu(2048, 1536), w3=jnp.zeros((4, 2048, 1536))))
     assert not moe.expert_kernel_takes(z(8, 32), {"w1": z(4, 32, 24),
