@@ -164,11 +164,14 @@ class phase:
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        # the block's end, read first: the CPU clock below is a system call,
+        # one at which a loaded host's scheduler takes the core away, and
+        # that is the timer's time, not the block's
+        now = time.perf_counter()
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
         slot = self._slot
         slot[1] += time.thread_time() - self._c0
-        now = time.perf_counter()
         wall = now - self._t0
         slot[0] += wall
         if self._flight is not None:

@@ -8,6 +8,7 @@ import importlib.util
 import json
 import os
 import sys
+import time
 
 import pytest
 
@@ -117,15 +118,27 @@ def test_headline_line_carries_transfer_summary(bench):
         assert line["transfer"]["egress_flatten"] == 4.0
 
 
-def test_locality_suite_reports_required_fields(bench):
+def test_locality_suite_reports_required_fields(bench, monkeypatch):
     """The locality suite must emit every field the BENCH_DETAIL.json
     contract names (on/off tasks-per-s, bytes moved, locality counters,
     prestage overlap) — run a mini-sized pass so CI proves the real code
     path, not a fixture."""
+    from ray_memory_management_tpu.core.runtime import Runtime
     from ray_memory_management_tpu.utils.locality_bench import (
         run_locality_suite,
     )
 
+    pull = Runtime._transfer_from
+
+    def cross_host_pull(self, *a, **kw):
+        # a same-host copy of 4 MB lands in about 2 ms, at times before the
+        # router has stamped SCHEDULED on the task it handed on; a pull
+        # from another host takes longer, and 50 ms of it makes the pull
+        # certain to outlast the hand-off to the dispatch queue
+        time.sleep(0.05)
+        return pull(self, *a, **kw)
+
+    monkeypatch.setattr(Runtime, "_transfer_from", cross_host_pull)
     out = run_locality_suite(n_nodes=2, n_tasks=4, arg_mb=4, trials=1)
     missing = [k for k in bench.REQUIRED_LOCALITY_FIELDS if k not in out]
     assert not missing, missing

@@ -129,7 +129,7 @@ class TestTSDB:
         vals = [v for _, v in other["points"]]
         assert vals == sorted(vals)  # admission is stable -> monotonic
 
-    def test_sample_registry_counts_drops(self):
+    def test_sample_registry_counts_drops(self, monkeypatch):
         prev = tsdb.is_enabled()
         tsdb.set_enabled(True)
         try:
@@ -137,6 +137,10 @@ class TestTSDB:
                                 tag_keys=("node",))
             for i in range(5):
                 c.inc(1.0, tags={"node": f"n{i}"})
+            # the tick folds this test's instrument alone: the registry is
+            # process-wide, and what earlier tests in the same process
+            # registered would fold past the cap of 2 as well
+            monkeypatch.setattr(metrics, "registry_metrics", lambda: [c])
             store = tsdb.TSDB(max_series_per_name=2)
             before = sum(mdefs.tsdb_dropped().series().values())
             store.sample_registry(now=T0)

@@ -22,6 +22,8 @@ from ray_memory_management_tpu.ops.paged_attention import (
 )
 from ray_memory_management_tpu.serve.kv_cache import KVPagePool
 
+from lm_oracle import greedy_answer
+
 CFG = latent_moe.LatentMoEConfig(
     vocab_size=512, d_model=64, n_layers=3, n_heads=4, q_lora_rank=24,
     kv_lora_rank=32, qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16,
@@ -423,10 +425,8 @@ def test_the_engine_serves_the_model_and_counts_live_rows_only(params):
         assert srv.cfg is CFG
         prompt = list(range(2, 25))
         out = srv.generate(prompt, max_new_tokens=9)
-        logits = latent_moe.forward(srv.params, jnp.asarray(
-            [prompt + out[:-1]]), CFG)[0]
-        assert out == np.argmax(np.asarray(logits[len(prompt) - 1:]),
-                                -1).tolist()
+        assert out == greedy_answer(latent_moe.forward, CFG, srv.params,
+                                    prompt, out)
         e = srv.stats()["engine"]
         assert e["cache_token_bytes"] == CFG.n_layers * CFG.cache_width * 4
         # 8 decode steps (two iterations of four) of one live row of four:

@@ -13,15 +13,16 @@ import time
 import ray_memory_management_tpu as rmt
 
 
-def _wait_for(capfd, needle: str, timeout: float = 30.0) -> str:
-    """Poll captured stderr until ``needle`` shows up (log frames are
-    asynchronous — they can trail the task's done reply)."""
+def _wait_for(capfd, *needles: str, timeout: float = 30.0) -> str:
+    """Poll captured output until every one of ``needles`` shows up (log
+    frames are asynchronous — they can trail the task's done reply, and a
+    worker's stdout and stderr ride frames of their own)."""
     collected = ""
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
         out, err = capfd.readouterr()
         collected += out + err
-        if needle in collected:
+        if all(n in collected for n in needles):
             return collected
         time.sleep(0.1)
     return collected
@@ -35,7 +36,8 @@ def test_task_print_reaches_driver(rmt_start_regular, capfd):
         return 1
 
     assert rmt.get(chatty.remote(), timeout=120) == 1
-    got = _wait_for(capfd, "hello from the worker side")
+    got = _wait_for(capfd, "hello from the worker side",
+                    "stderr travels too")
     assert "hello from the worker side" in got
     assert "stderr travels too" in got
     # the log monitor prefix carries the worker identity

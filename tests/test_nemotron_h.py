@@ -21,6 +21,8 @@ from ray_memory_management_tpu.models import (hybrid_ssm, nemotron_h,
 from ray_memory_management_tpu.ops import moe, ssm
 from ray_memory_management_tpu.serve.kv_cache import KVPagePool
 
+from lm_oracle import greedy_answer
+
 CFG = nemotron_h.NemotronHConfig(
     vocab_size=512, d_model=64, pattern="MEM*EM", n_heads=4, kv_heads=2,
     head_dim=16, ssm_heads=8, ssm_head_dim=16, ssm_state=8, ssm_groups=2,
@@ -603,12 +605,7 @@ def _server(**over):
     return LLMServer(**kw)
 
 
-def _greedy(params, prompt, out):
-    seq = list(prompt)
-    for _ in range(out):
-        logits = nemotron_h.forward(params, jnp.asarray([seq]), CFG)[0, -1]
-        seq.append(int(jnp.argmax(logits)))
-    return seq[len(prompt):]
+_greedy = functools.partial(greedy_answer, nemotron_h.forward, CFG)
 
 
 def test_the_engine_serves_rows_of_different_lengths_together(params):
@@ -631,7 +628,7 @@ def test_the_engine_serves_rows_of_different_lengths_together(params):
         t.join(timeout=300)
     try:
         for p, b, o in zip(prompts, budgets, outs):
-            assert o == _greedy(params, p, b)
+            assert len(o) == b and o == _greedy(params, p, o)
         eng = srv.stats()["engine"]
         assert len(eng["expert_tokens"]) == 8
         assert eng["expert_assignments"] > eng["expert_assignments_held"] > 0

@@ -29,6 +29,8 @@ from ray_memory_management_tpu.models import gpt, latent_moe, nemotron_h
 from ray_memory_management_tpu.serve.llm import ContinuousBatcher
 from ray_memory_management_tpu.utils import faults
 
+from lm_oracle import greedy_answer
+
 PAGE, C, K = 8, 16, 4  # a chunk is two pages
 
 
@@ -390,12 +392,8 @@ def test_the_layer_pattern_model_rides_the_chunk_path(pattern_engine,
     got = _together(eng, prompts, list(budgets))
     after = _settled(eng, before["admitted"] + len(prompts))
     for prompt, budget, out in zip(prompts, budgets, got):
-        seq = list(prompt)
-        for _ in range(budget):
-            logits = nemotron_h.forward(params, jnp.asarray([seq]),
-                                        PATTERN)[0, -1]
-            seq.append(int(jnp.argmax(logits)))
-        assert out == seq[len(prompt):], len(prompt)
+        assert len(out) == budget and out == greedy_answer(
+            nemotron_h.forward, PATTERN, params, prompt, out), len(prompt)
     chunks = sum(-(-n // 16) for n in lengths)
     assert after["mixed_steps"] - before["mixed_steps"] == chunks
     assert after["chunk_positions_live"] - before["chunk_positions_live"] \
